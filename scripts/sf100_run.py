@@ -16,7 +16,6 @@ import time
 import traceback
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-_force = os.environ.pop("JAX_PLATFORMS", None)
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

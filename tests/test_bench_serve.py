@@ -13,8 +13,8 @@ not the whole phase.
 The 5x acceptance ratios are NOT asserted here: the 1-core build box's
 load makes absolute latency ratios flaky at smoke scale — the ratios are
 recorded in the payload (``repeat_p50_speedup``,
-``{point,param}_template_qps_speedup``) and captured for real by
-scripts/tpu_watch.sh's serve A/B.
+``{point,param}_template_qps_speedup``); the serve A/B has not been run on
+the chip.
 """
 
 import json

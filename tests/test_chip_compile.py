@@ -1,0 +1,147 @@
+"""What the chip's own compiler says, asked without a chip.
+
+libtpu is installed here, and it compiles for a v5e that is DESCRIBED, not
+attached (`jax.experimental.topologies`).  Interpret mode cannot show what
+these cases show: every Pallas kernel the TPU backend takes by default went
+through every interpret-mode parity test and was still refused by Mosaic
+(PR 22).  Each case lowers with `interpret=False`, `jax_enable_x64` on as the
+engine runs, at both ends of the shapes the kernel's gate admits, so a later
+PR that widens a gate or edits a kernel meets the compiler here, at no chip
+time.  Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import,
+in a skipif, in parametrize or in conftest.py) and the compiles happen in
+this process: one process at a time may load libtpu, and under xdist every
+worker imports every test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS, SingleDeviceSharding
+
+from trino_tpu.ops import pallas_kernels as pk
+
+PAGE_ROWS = 1 << 21  # chip_smoke.py's / bench.py's split_rows at SF1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """conftest.py turns the persistent compile cache on; an executable for a
+    described chip can be written to it but never read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch, no_persistent_cache):
+    """Steer the trace the way the TPU backend would: kernels on, compiled.
+    (`jax.default_backend()` still says cpu here, so the gates are steered
+    from the test, not through an option of the program.)"""
+    monkeypatch.setattr(pk, "pallas_interpret", lambda: False)
+    pk.force(True)
+    yield
+    pk.force(None)
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled.as_text()
+
+
+def _s(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+# Q1's group table starts at 64 slots; 2 and PALLAS_TABLE_MAX are the gate's ends
+@pytest.mark.parametrize("capacity", [2, 64, 4096, pk.PALLAS_TABLE_MAX])
+def test_hash_probe_compiles_for_v5e(capacity, one_chip, as_on_tpu):
+    assert pk.table_kernels_enabled(capacity)
+    assert not pk.table_kernels_enabled(2 * pk.PALLAS_TABLE_MAX)
+    n = PAGE_ROWS
+    text = _compile(
+        lambda t, v, p, h, s, ok: pk.hash_probe(t, v, p, h, s, ok),
+        _s(one_chip, (capacity,), jnp.int64), _s(one_chip, (capacity,), jnp.int32),
+        _s(one_chip, (n,), jnp.int64), _s(one_chip, (n,), jnp.int64),
+        _s(one_chip, (n,), jnp.int64), _s(one_chip, (n,), jnp.bool_))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("capacity", [2, 64, 4096, pk.PALLAS_TABLE_MAX])
+def test_hash_insert_compiles_for_v5e(capacity, one_chip, as_on_tpu):
+    n = PAGE_ROWS
+    text = _compile(
+        lambda t, p, ok: pk.hash_insert(t, p, ok),
+        _s(one_chip, (capacity + 1,), jnp.int64),
+        _s(one_chip, (n,), jnp.int64), _s(one_chip, (n,), jnp.bool_))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("out_len,limbs", [
+    (1, 1), (1 << 14, 4), (pk.COMPACT_OUT_MAX, 1),
+    (pk.COMPACT_OUT_MAX, pk.COMPACT_LIMBS_MAX)])
+def test_compact_compiles_for_v5e(out_len, limbs, one_chip, as_on_tpu):
+    n = PAGE_ROWS
+    text = _compile(
+        lambda m, ok: pk.compact_rows_matrix(m, ok, out_len),
+        _s(one_chip, (n, limbs), jnp.int32), _s(one_chip, (n,), jnp.bool_))
+    assert "tpu_custom_call" in text
+
+
+def test_q1_page_step_compiles_for_v5e(one_chip, as_on_tpu):
+    """The jitted per-page step of Q1 (scan transform -> group-by insert into
+    the 64-slot table) — the first aggregation of the first query."""
+    import __graft_entry__
+
+    step, args = __graft_entry__.entry()
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
+    text = _compile(step, *shapes)
+    assert "tpu_custom_call" in text  # hash_insert at capacity 64
+
+
+def test_exchange_fragment_compiles_over_four_chips(topo, as_on_tpu):
+    """bucketize -> all_to_all inside shard_map with check_vma ON over a Mesh
+    of the four described chips: the Pallas compaction sits inside the
+    partitioned pack, and the exchange is a real all-to-all."""
+    from trino_tpu.ops.exchange import bucketize, exchange_all_to_all
+    from trino_tpu.parallel.mesh import WORKER_AXIS
+
+    W = 4
+    mesh = Mesh(np.array(topo.devices).reshape(W), (WORKER_AXIS,))
+    per, bucket = 1 << 16, 1 << 15
+
+    def frag(keys, vals):
+        k, v = keys[0], vals[0]
+        pid = (k % W).astype(jnp.int32)
+        packed, pvalid, _ = bucketize((k, v), jnp.ones_like(k, bool), pid, W, bucket)
+        recv, rvalid = exchange_all_to_all(packed, pvalid, WORKER_AXIS, W)
+        return recv[0][None], recv[1][None], rvalid[None]
+
+    f = jax.shard_map(frag, mesh=mesh, in_specs=(PS(WORKER_AXIS),) * 2,
+                      out_specs=(PS(WORKER_AXIS),) * 3)
+    sharded = NamedSharding(mesh, PS(WORKER_AXIS))
+    text = _compile(f, jax.ShapeDtypeStruct((W, per), jnp.int64, sharding=sharded),
+                    jax.ShapeDtypeStruct((W, per), jnp.int32, sharding=sharded))
+    assert "all-to-all" in text
+    assert "tpu_custom_call" in text

@@ -33,7 +33,7 @@ def _engine():
 
 def _spawn_worker(tmp_path, coord_url, node_id):
     env = dict(os.environ)
-    env["TRINO_TPU_WORKER_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     repo_root = str(pathlib.Path(__file__).resolve().parents[1])
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.Popen(
@@ -308,7 +308,7 @@ def test_stalled_worker_marked_degraded_and_unscheduled(tmp_path):
     url = coord.start()
     # realistic threshold: a genuine cold compile on this box takes seconds
     # and must NOT read as a stall; the wedge below is injected as an entry
-    # aged far past it (the same record a _jit stuck on a dead tunnel holds)
+    # aged far past it (the same record a _jit stuck on a dead device holds)
     wa = WorkerServer(CATALOGS, str(tmp_path / "spool"), coordinator_url=url,
                       node_id="wa", stall_s=30.0)
     wb = WorkerServer(CATALOGS, str(tmp_path / "spool"), coordinator_url=url,
@@ -525,7 +525,7 @@ def test_cluster_tpcds_star(tmp_path):
            "order by rev desc, i_category")
     try:
         env = dict(os.environ)
-        env["TRINO_TPU_WORKER_CPU"] = "1"
+        env["JAX_PLATFORMS"] = "cpu"
         repo_root = str(pathlib.Path(__file__).resolve().parents[1])
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
         procs = []

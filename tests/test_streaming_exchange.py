@@ -151,7 +151,7 @@ def test_worker_concurrent_fragments(tmp_path):
 # ------------------------------------------- cluster plane (OS processes)
 def _spawn_worker(tmp_path, coord_url, node_id):
     env = dict(os.environ)
-    env["TRINO_TPU_WORKER_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     repo_root = str(pathlib.Path(__file__).resolve().parents[1])
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.Popen(

@@ -17,45 +17,36 @@ jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: query pipelines re-used across processes skip
 # the (slow) TPU compile — the analog of the reference's bytecode caches surviving
-# in a long-lived server JVM (sql/gen/PageFunctionCompiler.java:103).  Opt out with
-# TRINO_TPU_NO_COMPILE_CACHE=1.
-if not _os.environ.get("TRINO_TPU_NO_COMPILE_CACHE"):
-    def _machine_tag() -> str:
-        # CPU AOT entries embed target-machine features; loading them on a
-        # different host risks SIGILL (xla cpu_aot_loader warns).  Key the cache
-        # by a cheap machine fingerprint so each host population is disjoint.
-        import hashlib
-        import platform
+# in a long-lived server JVM (sql/gen/PageFunctionCompiler.java:103).  This is the
+# ONE place the repo sets the directory.  JAX_COMPILATION_CACHE_DIR places it from
+# outside (JAX reads that variable itself); otherwise it is a FIXED path inside the
+# checkout — the path is part of the cache key, so a directory that moves between
+# runs never hits.  Opt out with TRINO_TPU_NO_COMPILE_CACHE=1.
 
-        probe = platform.machine() + platform.processor() + platform.node()
-        try:
-            with open("/proc/cpuinfo") as f:
-                for line in f:
-                    if line.startswith("flags"):
-                        probe += line
-                        break
-        except OSError:
-            pass
-        try:
-            # boot identity: cpuinfo flags do NOT capture the compile-time
-            # machine features XLA bakes into cached executables — loading an
-            # entry compiled on a different host SEGFAULTS (observed).  Keying
-            # by boot keeps the in-session cross-process reuse (workers,
-            # subprocess tests, bench) and forfeits risky cross-host reuse.
-            with open("/proc/sys/kernel/random/boot_id") as f:
-                probe += f.read()
-        except OSError:
-            pass
-        return hashlib.sha1(probe.encode()).hexdigest()[:12]
 
-    _cache_dir = _os.environ.get("JAX_COMPILATION_CACHE_DIR") or _os.path.join(
-        _os.path.expanduser("~"), ".cache", "trino_tpu", f"xla-{_machine_tag()}")
+def _default_cache_dir() -> str:
+    root = _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))), ".jax_cache")
+    if jax.config.jax_platforms != "cpu":
+        return root
+    # CPU AOT entries embed the compiling machine's features and can crash a
+    # CPU that lacks them: keep each feature set (a property of the machine,
+    # stable across boots and processes) in its own sub-directory.
+    import hashlib
+
+    flags = ""
     try:
-        _os.makedirs(_cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
+        with open("/proc/cpuinfo") as f:
+            flags = next((line for line in f if line.startswith("flags")), "")
+    except OSError:
         pass
+    return _os.path.join(root, "cpu-" + hashlib.sha1(flags.encode()).hexdigest()[:12])
+
+
+if not _os.environ.get("TRINO_TPU_NO_COMPILE_CACHE"):
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _default_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 from .engine import Engine, Session  # noqa: E402
 

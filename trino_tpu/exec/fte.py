@@ -117,7 +117,7 @@ def serialize_page(columns: list, null_masks: list,
     buf = io.BytesIO()
     arrays = {}
     # ONE batched device->host pull for the whole page (serialization is a
-    # transfer chokepoint on tunneled links, and it must show on the counters)
+    # transfer chokepoint, and it must show on the counters)
     host = _host(list(columns) + [m for m in null_masks if m is not None],
                  site=site)
     hcols, rest = host[:len(columns)], host[len(columns):]

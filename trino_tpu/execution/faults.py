@@ -348,9 +348,9 @@ def maybe_inject(point: str, site: Optional[str] = None) -> Optional[str]:
 
 
 def _arm_from_env() -> None:
-    """One-shot env arming (TRINO_TPU_FAULTS) at import: scripts/chaos.py and
-    tpu_watch capture runs arm whole processes this way; tests use the
-    arm()/injected() API instead."""
+    """One-shot env arming (TRINO_TPU_FAULTS) at import: scripts/chaos.py
+    arms whole processes this way; tests use the arm()/injected() API
+    instead."""
     import os
 
     spec = os.environ.get("TRINO_TPU_FAULTS")

@@ -42,20 +42,24 @@ _SCOPE = threading.local()  # current query key for per-query attribution
 
 
 def device_memory_budget(fraction: float = 0.75) -> int:
-    """Usable bytes of accelerator memory (fraction of HBM; conservative CPU
-    default when the backend exposes no stats)."""
+    """Usable bytes of accelerator memory: a fraction of the HBM the device
+    itself reports.  Only the CPU backend, which reports no memory stats, gets
+    the fixed 4 GiB default; an accelerator whose ``memory_stats()`` fails or
+    comes back empty RAISES — the page cache and the spill ladder are sized
+    from this number, and a guess would hide that the device is not there."""
     import jax
 
-    try:
-        d = jax.devices()[0]
-        stats = d.memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if limit:
-                return int(limit * fraction)
-    except Exception:
-        pass
-    return 4 << 30  # CPU / unknown backend default
+    d = jax.devices()[0]
+    if d.platform == "cpu":
+        return 4 << 30
+    stats = d.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{d.platform} device {d.device_kind!r} reports no memory limit "
+            f"(memory_stats() = {stats!r}); refusing to size device memory "
+            "from a default")
+    return int(limit * fraction)
 
 
 class MemoryPool:

@@ -146,7 +146,7 @@ def compact_rows(arrays, valid, out_len: int):
     if not arrs:
         return tuple(arrays), jnp.sum(valid)
     n = valid.shape[0]
-    if pk.compact_enabled(n, out_len, pk.compact_limbs(arrs)):
+    if pk.compact_enabled(n, out_len, arrs):
         packed, total = pk.compact_columns(tuple(arrs), valid, out_len)
         it = iter(packed)
         return tuple(None if a is None else next(it) for a in arrays), total

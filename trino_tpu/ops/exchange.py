@@ -48,10 +48,10 @@ def bucketize(cols, valid, pid, n_partitions: int, bucket: int):
     shard_map on the distributed path — the python loop is trace-time static.
     """
     from .arrays import compact_rows
-    from .pallas_kernels import compact_enabled, compact_limbs, use_pallas
+    from .pallas_kernels import compact_enabled
 
     n = pid.shape[0]
-    if use_pallas() and n and compact_enabled(n, bucket, compact_limbs(cols)):
+    if compact_enabled(n, bucket, cols):
         packed_p, counts = [], []
         for p in range(n_partitions):
             sel = valid & (pid == p)
