@@ -300,6 +300,42 @@ def test_jit_outside_exec_is_annotated(path):
         "'# compile-ok: <reason>'")
 
 
+def _unnamed_generator_jits(path):
+    """jax.jit calls in a connector whose first argument is not a
+    ``site_program(...)`` call: a generator program the device trace would
+    show as ``jit__lambda_`` or under one name for every table."""
+    hits = []
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Attribute) \
+                and isinstance(node.value, ast.Name) \
+                and node.value.id == "jax" and node.attr in ("jit", "pjit"):
+            hits.append(node.lineno)  # a reference that is not a call: unnamed
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) \
+                and isinstance(node.func.value, ast.Name) \
+                and node.func.value.id == "jax" \
+                and node.func.attr in ("jit", "pjit"):
+            first = node.args[0] if node.args else None
+            if isinstance(first, ast.Call) \
+                    and getattr(first.func, "id", None) == "site_program":
+                hits.remove(node.func.lineno)
+    return hits
+
+
+@pytest.mark.parametrize(
+    "path", sorted((PKG_DIR / "connectors").glob("*.py")),
+    ids=lambda p: p.name)
+def test_connector_generator_programs_are_named(path):
+    """PR 25: a connector's jitted generator passes through
+    ``tracing.site_program`` (``generate.<table>``), so that the device
+    plane names page generation apart from the operators."""
+    hits = _unnamed_generator_jits(path)
+    assert not hits, (
+        f"{path.name}: jax.jit at line(s) {', '.join(map(str, hits))} "
+        "compiles a program without a site name — wrap the function in "
+        "execution.tracing.site_program(fn, 'generate.<table>')")
+
+
 def _pallas_call_hits(path):
     """pallas_call(...) invocations missing an ``interpret=`` keyword —
     both attribute form (pl.pallas_call) and a direct-imported name."""

@@ -382,3 +382,108 @@ def test_query_log_compile_columns(obs_engine):
     # the module's first QUERY execution was cold: its record carries the
     # compiles it paid; this (warm) re-execution's record will carry 0
     assert rows[qid][1] is not None
+
+
+# ------------------------------------------ device program names (PR 25)
+@pytest.mark.parametrize("site,module", [
+    ("join.probe", "jit_join_probe"),
+    ("agg.sorted.select", "jit_agg_sorted_select"),
+    (None, "jit_named_step"),
+])
+def test_jit_site_is_the_device_programs_name(site, module):
+    """The XLA module is named after the STATIC site and its ops carry the
+    site as a scope; the operator label of the plan is in neither, so two
+    plans lower one site to the same text (one compile-cache key)."""
+    import jax.numpy as jnp
+
+    from trino_tpu.exec.local_executor import _jit
+
+    def named_step(x, k=2):
+        return jnp.cumsum(x) * k
+
+    def make():
+        if site is None:
+            return _jit(named_step)
+        return _jit(lambda x, k=2: jnp.cumsum(x) * k, site=site)
+
+    x = jnp.arange(8.0)
+    texts = []
+    for op in ("Aggregate#3", "Join#5"):
+        run = make()
+        with tracing.operator_scope(op):
+            lowered = run.lower(x)
+            assert float(run(x)[-1]) == 56.0
+        text = lowered.as_text()
+        assert f"module @{module} " in text
+        assert op not in lowered.as_text(debug_info=True)
+        scope = site or "named_step"
+        assert f"/{scope}/" in lowered.as_text(debug_info=True)
+        assert f"/{scope}/" in lowered.compile().as_text()
+        texts.append(text)
+    assert texts[0] == texts[1]
+    # callers run the step eagerly through __wrapped__: the python function
+    assert run.__wrapped__(x)[-1] == 56.0
+    assert run.__wrapped__.__name__ in ("<lambda>", "named_step")
+
+
+@pytest.mark.parametrize("connector", ["tpch", "tpcds"])
+def test_generator_programs_are_named_after_their_table(connector):
+    import importlib
+
+    mod = importlib.import_module(f"trino_tpu.connectors.{connector}")
+    conn = getattr(mod, connector.capitalize() + "Connector")(sf=0.01)
+    table = "nation" if connector == "tpch" else "reason"
+    split = conn.splits(table)[0]
+    page = conn.generate(split)
+    assert page.capacity == split.hi - split.lo
+    program = mod._GENERATE_PROGRAMS[table]
+    assert set(mod._GENERATE_PROGRAMS) >= {table}
+    if connector == "tpch":
+        lowered = program.lower(conn.sf, split.lo, split.hi - split.lo,
+                                conn.table_bound(table),
+                                tuple(mod.TPCH_SCHEMAS[table].names))
+    else:
+        lowered = program.lower(conn.sf, split.lo, split.hi - split.lo,
+                                tuple(mod.SCHEMAS[table].names), 0)
+    assert f"module @jit_generate_{table} " in lowered.as_text()
+    assert f"/generate.{table}/" in lowered.as_text(debug_info=True)
+
+
+def test_compiles_are_requests_and_cache_misses_are_compilations(tmp_path):
+    """``compiles`` counts first-seen signatures at a wrapper, whoever serves
+    the executable; ``compile_cache_misses`` counts what XLA really compiled:
+    over a filled persistent cache a new wrapper is one request, no miss."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import compilation_cache
+
+    from trino_tpu.exec.local_executor import _jit
+
+    def make():
+        return _jit(lambda x: jnp.cumsum(jnp.sin(x)) * 3.0,
+                    site="cache.miss.probe")
+
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    compilation_cache.reset_cache()
+    try:
+        x = jnp.arange(1000.0)
+        readings = []
+        for _ in range(2):
+            run, c = make(), QueryCounters()
+            with tracing.track_counters(c):
+                run(x)
+                run(x)  # the same signature again: neither
+            readings.append((c.compiles, c.compile_cache_misses,
+                             c.device_dispatches))
+            jax.clear_caches()  # the in-process executables go, the files stay
+        assert readings == [(1, 1, 2), (1, 0, 2)]
+        assert any("cache_miss_probe" in f.name for f in tmp_path.iterdir())
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        compilation_cache.reset_cache()
+    a = QueryCounters()
+    a.compile_cache_misses = 2
+    b = QueryCounters.from_dict(a.as_dict())
+    b.merge(a)
+    assert b.compile_cache_misses == 4 and b.snapshot().as_dict() == b.as_dict()

@@ -229,8 +229,7 @@ def test_trace_endpoint_serves_completed_statements_from_recorder(
     engine.execute_sql(QUERY, s)
     qid = engine.last_query_trace["query_id"]
     engine.execute_sql("select count(*) from region", s)  # a later statement
-    with engine.tracer._lock:
-        engine.tracer.finished.clear()  # live tracer can no longer serve it
+    engine.tracer.clear()  # the live tracer can no longer serve it
     payload = json.loads(urllib.request.urlopen(
         flight_server.url + f"/v1/query/{qid}/trace", timeout=10)
         .read().decode())

@@ -555,3 +555,269 @@ def test_runtime_queries_boundary_columns(engine):
     assert finished, rows
     qid, _, dd, hb, elapsed = finished[-1]
     assert dd > 0 and hb > 0 and elapsed > 0
+
+
+# ------------------------------------------- the statement timeline (PR 25)
+def _otlp_spans(url, qid):
+    payload = json.loads(urllib.request.urlopen(
+        url + f"/v1/query/{qid}/trace", timeout=10).read().decode())
+    return payload["resourceSpans"][0]["scopeSpans"][0]["spans"]
+
+
+def _otlp_s(span):
+    return (int(span["endTimeUnixNano"])
+            - int(span["startTimeUnixNano"])) / 1e9
+
+
+def _otlp_attr(span, key):
+    return next((list(a["value"].values())[0] for a in span["attributes"]
+                 if a["key"] == key), None)
+
+
+def test_server_phases_are_spans_of_one_trace_and_seconds_counters(
+        engine, monkeypatch):
+    """One dispatch thread, three statements posted at once: the later ones'
+    ``server.queued`` covers the first's run; every phase of a statement is
+    a span under the ENGINE's trace id and root span; the totals' seconds
+    equal the spans'."""
+    from trino_tpu.execution import tracing
+    from trino_tpu.server.client import Client
+    from trino_tpu.server.server import CoordinatorServer
+
+    s = engine.create_session("tpch")
+    engine.execute_sql(QUERY, s)
+    engine.execute_sql(QUERY, s)  # warm: the runs below compile nothing
+    monkeypatch.setattr(tracing, "DISPATCH_TEST_HOOK",
+                        lambda label: time.sleep(0.1))
+    srv = CoordinatorServer(engine, port=0, dispatch_threads=1)
+    srv.start()
+    try:
+        before = engine.counters_total.as_dict()
+        client = Client(srv.url, catalog="tpch", poll_interval=0.01)
+        posted = [client._request(srv.url + "/v1/statement", "POST",
+                                  QUERY.encode()) for _ in range(3)]
+
+        def drain(out):
+            while "nextUri" in out:
+                time.sleep(0.01)
+                out = client._request(out["nextUri"])
+            assert "error" not in out, out
+
+        threads = [threading.Thread(target=drain, args=(p,)) for p in posted]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        trees, deadline = {}, time.time() + 10
+        for p in posted:  # server.encode is recorded just after FINISHED
+            while True:
+                spans = _otlp_spans(srv.url, p["id"])
+                if {"server.encode", "server.deliver"} \
+                        <= {sp["name"] for sp in spans}:
+                    break
+                assert time.time() < deadline, [sp["name"] for sp in spans]
+                time.sleep(0.01)
+            trees[p["id"]] = spans
+            # the engine's id answers with the same tree
+            qid = srv.queries[p["id"]].trace["query_id"]
+            assert qid.startswith("query_") and qid != p["id"]
+            assert len(_otlp_spans(srv.url, qid)) == len(spans)
+        after = engine.counters_total.as_dict()
+    finally:
+        srv.stop()
+    sums = {"server.queued": 0.0, "server.encode": 0.0, "server.deliver": 0.0}
+    runs = []
+    for sid, spans in trees.items():
+        by_name = {sp["name"]: sp for sp in spans}
+        assert {"server.queued", "query", "executor.checkout",
+                "server.encode", "server.deliver"} <= set(by_name)
+        assert len({sp["traceId"] for sp in spans}) == 1
+        queries = [sp for sp in spans if sp["name"] == "query"]
+        roots = [sp for sp in spans if sp["parentSpanId"] == ""]
+        assert len(queries) == 1 and roots == queries
+        for name in sums:
+            assert by_name[name]["parentSpanId"] == queries[0]["spanId"]
+            assert _otlp_attr(by_name[name], "server_query_id") == sid
+            sums[name] += _otlp_s(by_name[name])
+        runs.append((int(queries[0]["startTimeUnixNano"]),
+                     _otlp_s(queries[0]), _otlp_s(by_name["server.queued"])))
+    runs.sort()
+    first_run = runs[0][1]
+    assert first_run >= 0.25  # the hook's sleeps
+    assert all(queued >= 0.9 * first_run for _, _, queued in runs[1:]), runs
+    for name, field in (("server.queued", "queued_s"),
+                        ("server.encode", "encode_s"),
+                        ("server.deliver", "deliver_wait_s")):
+        assert after[field] - before[field] == pytest.approx(
+            sums[name], abs=1e-4), (name, sums)
+
+
+def test_batcher_wait_is_the_queued_members_not_the_leaders(monkeypatch):
+    """The leader parked in LEADER_EXIT_HOOK: every queued member's
+    ``batch_wait_s`` (and ``batcher.wait`` span) covers the parked time, the
+    leader's is 0."""
+    from trino_tpu.execution import batcher as BA
+    from trino_tpu.execution import tracing
+
+    parked, ready = 0.15, threading.Event()
+    monkeypatch.setattr(BA, "LEADER_EXIT_HOOK",
+                        lambda key: (ready.wait(30), time.sleep(parked)))
+    bt = BA.TemplateBatcher(window_ms=1.0, max_batch=8, enabled=True)
+    tr, out = Tracer(), {}
+
+    def run(name):
+        with tracing.activate_tracer(tr), tracing.statement_waits() as w, \
+                tr.span("query", trace_id=name):
+            bt.execute("k", (name,), lambda rt: ("serial", rt),
+                       lambda rts: [("batched", rt) for rt in rts])
+        out[name] = dict(w)
+
+    lead = threading.Thread(target=run, args=("leader",))
+    lead.start()
+    while "k" not in bt._lanes:
+        time.sleep(0.001)
+    members = [threading.Thread(target=run, args=(f"m{i}",))
+               for i in range(2)]
+    for t in members:
+        t.start()
+    while len(bt._lanes["k"].queue) < 2:
+        time.sleep(0.001)
+    ready.set()
+    for t in [lead] + members:
+        t.join(30)
+    assert out["leader"].get("batch_wait_s", 0.0) == 0.0
+    assert not [s for s in tr.spans_for("leader") if s.name == "batcher.wait"]
+    for name in ("m0", "m1"):
+        assert out[name]["batch_wait_s"] >= parked
+        spans = [s for s in tr.spans_for(name) if s.name == "batcher.wait"]
+        assert sum(s.duration_s for s in spans) == pytest.approx(
+            out[name]["batch_wait_s"], abs=1e-4)
+
+
+def test_executor_checkout_wait_is_counted(engine):
+    """Every executor of the pool held: a statement's ``executor_wait_s``
+    (snapshot and totals) grows by the time it waited for one."""
+    s = engine.create_session("tpch")
+    engine.execute_sql(QUERY, s)  # the pool and its semaphore exist
+    held = [engine._checkout_executor()
+            for _ in range(engine.MAX_CONCURRENT_EXECUTORS)]
+    before = engine.counters_total.executor_wait_s
+    out = {}
+
+    def run():
+        engine.execute_sql(QUERY, s)
+        out["snap"] = engine._thread_accounting.snap
+        out["trace"] = engine._thread_accounting.trace
+
+    t = threading.Thread(target=run)
+    try:
+        t.start()
+        time.sleep(0.2)
+    finally:
+        for ex in held:
+            engine._release_executor(ex)
+    t.join(60)
+    assert out["snap"].executor_wait_s >= 0.19
+    assert engine.counters_total.executor_wait_s - before == pytest.approx(
+        out["snap"].executor_wait_s)
+    spans = [sp for sp in out["trace"]["spans"]
+             if sp["name"] == "executor.checkout"]
+    assert sum(sp["duration_s"] for sp in spans) == pytest.approx(
+        out["snap"].executor_wait_s, abs=1e-4)
+
+
+def test_wall_buckets_fold_into_each_concurrent_statements_counters(engine):
+    """Two statements at once: each one's ``wall_*_s`` counters are ITS
+    breakdown (they sum to its root span), and the totals grow by both."""
+    s = engine.create_session("tpch")
+    engine.execute_sql(QUERY, s)
+    engine.execute_sql(QUERY, s)  # warm: no compile bucket
+    fields = ("wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
+              "wall_dispatch_s", "wall_host_pull_s", "wall_unattributed_s")
+    before = engine.counters_total.as_dict()
+    out = []
+
+    def run():
+        engine.execute_sql(QUERY, s)
+        out.append((engine._thread_accounting.snap,
+                    engine._thread_accounting.trace))
+
+    threads = [threading.Thread(target=run) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    assert len(out) == 2 and out[0][1]["query_id"] != out[1][1]["query_id"]
+    for snap, trace in out:
+        # a second executor of the pool may trace its programs anew: the
+        # compile bucket is not folded (compile_s has the XLA seconds)
+        folded = sum(getattr(snap, f) for f in fields) \
+            + trace["wall_breakdown"]["compile"]
+        assert folded == pytest.approx(trace["root_span_s"], rel=1e-3)
+        assert snap.wall_dispatch_s > 0 and snap.queued_s > 0
+    after = engine.counters_total.as_dict()
+    for f in fields:
+        assert after[f] - before[f] == pytest.approx(
+            sum(getattr(snap, f) for snap, _ in out), abs=1e-6)
+
+
+def test_spans_are_annotations_on_the_profilers_clock(engine, tmp_path):
+    """Under a profiler session the statement's spans and dispatches are
+    ``trino_tpu:<name>`` events of the host plane, carrying its query id."""
+    import glob
+
+    import jax
+
+    s = engine.create_session("tpch")
+    engine.execute_sql(QUERY, s)  # warm
+    with jax.profiler.trace(str(tmp_path)):
+        engine.execute_sql(QUERY, s)
+    qid = engine.last_query_trace["query_id"]
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    seen = {}
+    for plane in data.planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("trino_tpu:"):
+                        seen.setdefault(ev.name, set()).add(
+                            dict(ev.stats).get("query_id"))
+    for name in ("trino_tpu:query", "trino_tpu:dispatch",
+                 "trino_tpu:host_pull", "trino_tpu:executor.checkout"):
+        assert qid in seen.get(name, ()), (name, seen)
+
+
+@pytest.mark.parametrize("max_finished", [6, 1000])
+def test_tracer_indexes_by_trace_and_evicts_whole_traces(max_finished):
+    """``spans_for`` returns a trace's spans in finishing order however the
+    traces interleave, and the bound drops whole traces, oldest first."""
+    tr = Tracer(max_finished=max_finished)
+    with tr.span("query", trace_id="a") as ra:
+        tr.add_completed("dispatch", 0.001)
+        with tr.span("query", trace_id="b"):  # another trace in between
+            tr.add_completed("dispatch", 0.001)
+        tr.add_completed("dispatch", 0.002)
+    for name in ("c", "d"):
+        with tr.span("query", trace_id=name):
+            tr.add_completed("dispatch", 0.001)
+            tr.add_completed("host_pull", 0.001, site="x")
+    if max_finished == 1000:
+        assert [s.name for s in tr.spans_for("a")] \
+            == ["dispatch", "dispatch", "query"]
+        assert [s.name for s in tr.spans_for("b")] == ["dispatch", "query"]
+        assert tr._count == 11
+    else:
+        # 11 spans against a bound of 6: traces "a" and "b" went, each whole
+        assert tr.spans_for("a") == [] and tr.spans_for("b") == []
+        assert [s.name for s in tr.spans_for("c")] \
+            == ["dispatch", "host_pull", "query"]
+        assert len(tr.spans_for("d")) == 3 and tr._count == 6
+    # an explicit end and parent: the server's after-the-fact phases
+    late = tr.add_completed("server.encode", 0.5, parent=ra, end_s=100.0)
+    assert (late.trace_id, late.parent_id) == ("a", ra.span_id)
+    assert (late.start_s, late.end_s) == (99.5, 100.0)
+    assert late in tr.spans_for("a")
+    tr.clear()
+    assert tr._count == 0 and tr.spans_for("c") == []

@@ -1324,9 +1324,8 @@ class TpcdsConnector:
         return cols, valid
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))  # compile-ok: host-side table generation; dispatched from connector code outside the executor's _jit paths, one compile per (table, split shape)
-def _jit_generate(table: str, sf: float, lo: int, length: int, names: tuple,
-                  n: int = 0):
+def _generate_cols(table: str, sf: float, lo: int, length: int, names: tuple,
+                   n: int = 0):
     all_cols = GENERATORS[table](sf, lo, length)
     schema = SCHEMAS[table]
     out = []
@@ -1335,3 +1334,19 @@ def _jit_generate(table: str, sf: float, lo: int, length: int, names: tuple,
         out.append(v.astype(schema.field(c).type.dtype))
     valid = None if n == 0 else (jnp.arange(length, dtype=jnp.int64) + lo) < n
     return tuple(out), valid
+
+
+_GENERATE_PROGRAMS: dict = {}  # table -> its jitted generator
+
+
+def _jit_generate(table: str, sf: float, lo: int, length: int, names: tuple,
+                  n: int = 0):
+    """One program per table, named ``generate.<table>`` (see tpch)."""
+    run = _GENERATE_PROGRAMS.get(table)
+    if run is None:
+        from ..execution.tracing import site_program
+
+        run = _GENERATE_PROGRAMS[table] = jax.jit(  # compile-ok: host-side table generation; dispatched from connector code outside the executor's _jit paths, one compile per (table, split shape)
+            site_program(partial(_generate_cols, table), f"generate.{table}"),
+            static_argnums=(0, 1, 2, 3, 4))
+    return run(sf, lo, length, names, n)

@@ -647,6 +647,18 @@ def _generate_cols(table, sf, lo, length, n, names):
     return tuple(cols[c] for c in names), valid
 
 
-@partial(jax.jit, static_argnums=(0, 1, 3, 4, 5))  # compile-ok: host-side table generation; dispatched from connector code outside the executor's _jit paths, one compile per (table, split shape)
+_GENERATE_PROGRAMS: dict = {}  # table -> its jitted generator
+
+
 def _jit_generate(table: str, sf: float, lo: int, length: int, n: int, names: tuple):
-    return _generate_cols(table, sf, lo, length, n, names)
+    """One program per table, named after it (``generate.<table>``: XLA module
+    ``jit_generate_<table>``), so a device trace tells the generator's time
+    from the operators' and one table's from another's."""
+    run = _GENERATE_PROGRAMS.get(table)
+    if run is None:
+        from ..execution.tracing import site_program
+
+        run = _GENERATE_PROGRAMS[table] = jax.jit(  # compile-ok: host-side table generation; dispatched from connector code outside the executor's _jit paths, one compile per (table, split shape)
+            site_program(partial(_generate_cols, table), f"generate.{table}"),
+            static_argnums=(0, 2, 3, 4))
+    return run(sf, lo, length, n, names)

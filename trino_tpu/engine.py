@@ -16,6 +16,15 @@ __all__ = ["Engine", "Session"]
 
 _query_ids = itertools.count(1)
 
+# wall_breakdown bucket -> the QueryCounters seconds field it folds into at
+# the statement's end (compile has compile_s from the XLA events)
+_WALL_COUNTERS = (("plan", "wall_plan_s"),
+                  ("split_generation", "wall_split_generation_s"),
+                  ("h2d", "wall_h2d_s"),
+                  ("device_dispatch", "wall_dispatch_s"),
+                  ("host_pull", "wall_host_pull_s"),
+                  ("unattributed", "wall_unattributed_s"))
+
 
 @dataclasses.dataclass
 class Session:
@@ -1067,7 +1076,10 @@ class Engine:
 
                 self._executor_sem = threading.Semaphore(
                     self.MAX_CONCURRENT_EXECUTORS)
-        self._executor_sem.acquire()
+        from .execution import tracing
+
+        with tracing.wait_span("executor.checkout", "executor_wait_s"):
+            self._executor_sem.acquire()
         with self._init_lock:
             if self._executor_pool:
                 return self._executor_pool.pop()
@@ -1103,9 +1115,14 @@ class Engine:
         re-planning when a template exists; otherwise the values substitute
         as literals."""
         import threading
+        import time
 
+        from .execution import tracing
         from .execution.query_state import QueryState, QueryStateMachine
 
+        # where the statement's queue phase starts: where the front end says
+        # it accepted it (the server's POST), else here
+        accepted = tracing.take_accepted() or (time.perf_counter(), {})
         sess = session or Session()
         # per-thread accounting slots cleared FIRST — even a statement that
         # fails admission/access below must not leave a pooled server thread
@@ -1137,8 +1154,7 @@ class Engine:
             queued=lambda: qsm.transition(QueryState.WAITING_FOR_RESOURCES),
             queued_on_memory=lambda: queued_on_memory.append(1))
         admitted.wait()
-        from .execution import tracing
-
+        waits: dict = {}  # the statement's wait seconds by counter field
         try:
             qsm.transition(QueryState.DISPATCHING)
             # activate the engine tracer for this thread: executor dispatch
@@ -1149,7 +1165,14 @@ class Engine:
             # doing right now" surface)
             with tracing.query_scope(qid), \
                     tracing.activate_tracer(self.tracer), \
-                    self.tracer.span("query", trace_id=qid, sql=sql):
+                    tracing.statement_waits() as waits, \
+                    self.tracer.span("query", trace_id=qid, sql=sql) as root:
+                # accepted -> the root span opens: the server's pool queue
+                # and statement lock, session set-up, admission
+                tracing.record_wait(
+                    "server.queued", "queued_s",
+                    max(time.perf_counter() - accepted[0], 0.0),
+                    end_s=root.start_s, **accepted[1])
                 result = self._execute_admitted(sql, sess, qsm, distributed, mesh,
                                                 fault_tolerant,
                                                 parameters=parameters)
@@ -1183,6 +1206,20 @@ class Engine:
             # boundary data for the completion event: only when THIS statement
             # executed a plan (DDL leaves the slot None)
             snap = self._thread_accounting.snap
+            # the statement's waits, and the buckets of its wall breakdown as
+            # wall_*_s seconds: stamped like admission_queued, since they
+            # were measured outside the executor's counters context
+            bd = self._thread_accounting.trace.get("wall_breakdown") or {}
+            for bucket, field in _WALL_COUNTERS:
+                if bd.get(bucket):
+                    waits[field] = bd[bucket]
+            with self._init_lock:
+                for field, seconds in waits.items():
+                    setattr(self.counters_total, field,
+                            getattr(self.counters_total, field) + seconds)
+            if snap is not None:
+                for field, seconds in waits.items():
+                    setattr(snap, field, getattr(snap, field) + seconds)
             if queued_on_memory:
                 # the admission deferral happened BEFORE any counters context
                 # existed: stamp it onto the per-query snapshot (the same
@@ -1230,7 +1267,8 @@ class Engine:
         from .execution import tracing
 
         spans = self.tracer.spans_for(qid)
-        root = next((s for s in spans if s.parent_id is None), None)
+        root = next((s for s in spans
+                     if s.parent_id is None and s.name == "query"), None)
         self.last_query_trace = {
             "query_id": qid,
             "root_span_s": root.duration_s if root else None,
@@ -1244,6 +1282,7 @@ class Engine:
         # parallel since round 12) — the statement's own thread reads this
         # slot for an attribution-safe capture
         self._thread_accounting.trace = self.last_query_trace
+        self._thread_accounting.root = root
         return root
 
     def _record_flight(self, qsm, sess) -> None:

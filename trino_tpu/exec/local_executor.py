@@ -81,7 +81,10 @@ def _jit(fn, site=None, **kwargs):
     tests/test_boundary_lint.py); each invocation's wall time also feeds the
     per-query + engine-total dispatch-latency histograms.  ``__wrapped__``
     stays the original python function (callers use it to run the step eagerly
-    for untraceable object columns).
+    for untraceable object columns).  The site is also the device program's
+    name (tracing.site_program: XLA module ``jit_<site>``, body under
+    ``jax.named_scope(site)``), so a device trace can be read by site, and
+    each dispatch is a ``trino_tpu:dispatch`` annotation of the profiler.
 
     Round 17 — the compile observatory lives HERE, so the boundary lint that
     forces all executor code through ``_jit`` guarantees compile coverage the
@@ -98,8 +101,8 @@ def _jit(fn, site=None, **kwargs):
     CompileLog census."""
     import time as _time
 
-    compiled = jax.jit(fn, **kwargs)
     label = site or getattr(fn, "__name__", "jit")
+    compiled = jax.jit(tracing.site_program(fn, label), **kwargs)
     # two signature sets, both under `lock` (an unsynchronized check-then-
     # act would double-record when concurrent queries race a shared
     # MODULE-LEVEL wrapper's first dispatch):
@@ -145,7 +148,8 @@ def _jit(fn, site=None, **kwargs):
             # every dispatch in the engine is injectable (disarmed = one
             # global None test, nothing on the budget counters)
             faults.maybe_inject("dispatch", label)
-            out = compiled(*args, **kw)
+            with tracing.annotate("dispatch"):
+                out = compiled(*args, **kw)
             ok = True
             return out
         finally:
@@ -162,7 +166,8 @@ def _jit(fn, site=None, **kwargs):
                         xla_s if xla_s is not None else dt, site=label,
                         signature=tracing.signature_summary(sig_key),
                         sig_key=f"{hash(sig_key) & 0xffffffffffffffff:016x}",
-                        exe_bytes=exe, wrapper=wrapper_id)
+                        exe_bytes=exe, wrapper=wrapper_id,
+                        cache_misses=tracing.compile_capture_misses(cap))
                 else:
                     # a first-seen dispatch that raises (injected fault,
                     # transient device error) records nothing and releases
@@ -173,7 +178,8 @@ def _jit(fn, site=None, **kwargs):
                         claimed.discard(sig_key)
             tracing.record_dispatch(site=label, seconds=dt)
 
-    run.__wrapped__ = getattr(compiled, "__wrapped__", fn)
+    run.__wrapped__ = fn
+    run.lower = compiled.lower  # the program as XLA will name it (tests)
     return run
 
 
@@ -4796,7 +4802,8 @@ def _host(arrays, site=None):
                 except Exception:
                     pass
         tracing.record_host_pull(nbytes, site=site)
-        return [None if a is None else np.asarray(a) for a in arrays]
+        with tracing.annotate("host_pull"):
+            return [None if a is None else np.asarray(a) for a in arrays]
     finally:
         reg.exit(tok)
         # wall-decomposition feed: each batched pull is one "host_pull" span

@@ -23,13 +23,17 @@ coalesce into one dispatch (the per-REQUEST analog of the round-6 per-split
 
 The batcher is pure host-side thread choreography: zero _jit/_host traffic
 of its own (the fused execution accounts its spend on the driver's
-statement like any executed plan)."""
+statement like any executed plan).  What a member spends queued on a busy
+lane, and the driver in its gather window, is the statement's
+``batcher.wait`` span and ``batch_wait_s`` counter."""
 
 from __future__ import annotations
 
 import os
 import threading
 import time
+
+from . import tracing
 
 __all__ = ["TemplateBatcher"]
 
@@ -129,7 +133,10 @@ class TemplateBatcher:
                     except Exception:
                         pass
                 self._handoff(lane)
-        member.event.wait()
+        # the lane's wait state: joined a busy lane -> woken (to drive, to run
+        # serially, or resolved by the driver's fused run)
+        with tracing.wait_span("batcher.wait", "batch_wait_s"):
+            member.event.wait()
         if member.drive:
             return self._drive(lane, member, serial_fn, batch_fn)
         if member.serial:
@@ -143,7 +150,9 @@ class TemplateBatcher:
         """First queued member after a handoff: gather a window, run the
         fused batch, resolve every member, hand the lane on."""
         if self.window_s > 0:
-            time.sleep(self.window_s)
+            with tracing.wait_span("batcher.wait", "batch_wait_s",
+                                   phase="gather"):
+                time.sleep(self.window_s)
         with self._lock:
             take = lane.queue[:self.max_batch - 1]
             del lane.queue[:len(take)]

@@ -12,12 +12,19 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import logging
+import re
 import threading
 import time
 from typing import Optional
 
-__all__ = ["Span", "Tracer", "NOOP_TRACER", "QueryCounters", "track_counters",
+try:  # the profiler's annotations and named scopes; the rest is jax-free
+    import jax
+except Exception:  # pragma: no cover - jax is the engine's own dependency
+    jax = None
+
+__all__ = ["Span", "Tracer", "QueryCounters", "track_counters",
            "current_counters", "record_dispatch", "record_host_pull",
            "record_coalesced", "record_page_cache", "record_build_cache",
            "record_fault", "record_task_retry", "record_spill",
@@ -34,7 +41,10 @@ __all__ = ["Span", "Tracer", "NOOP_TRACER", "QueryCounters", "track_counters",
            "COMPILE_BUCKETS_S", "CompileLog", "COMPILE_LOG",
            "record_compile", "arg_signature", "signature_summary",
            "install_compile_listener",
-           "begin_compile_capture", "end_compile_capture"]
+           "begin_compile_capture", "end_compile_capture",
+           "compile_capture_misses", "site_program", "wait_span",
+           "statement_waits", "accepted_scope", "take_accepted",
+           "record_wait", "annotate"]
 
 _log = logging.getLogger("trino_tpu.stall")
 
@@ -224,6 +234,28 @@ class QueryCounters:
     # the recompile-regression guard test_query_budgets pins.
     compiles: int = 0
     compile_s: float = 0.0
+    # PR 25: ``compiles`` counts REQUESTS (first-seen signatures at a wrapper,
+    # persistent-cache serves included); compile_cache_misses counts the
+    # programs XLA really compiled (backend-compile events of the
+    # jax.monitoring listener less its persistent-cache hits, captured on
+    # the dispatching thread like compile_s)
+    compile_cache_misses: int = 0
+    # PR 25: the statement's wait states, seconds (each also a span of the
+    # same name family: server.queued, batcher.wait, executor.checkout,
+    # server.encode, server.deliver), recorded where the wait happens, and
+    # the buckets of its wall_breakdown folded in at its end (wall_*_s), so
+    # that concurrent statements' attribution sums in counters_total
+    queued_s: float = 0.0
+    batch_wait_s: float = 0.0
+    executor_wait_s: float = 0.0
+    encode_s: float = 0.0
+    deliver_wait_s: float = 0.0
+    wall_plan_s: float = 0.0
+    wall_split_generation_s: float = 0.0
+    wall_h2d_s: float = 0.0
+    wall_dispatch_s: float = 0.0
+    wall_host_pull_s: float = 0.0
+    wall_unattributed_s: float = 0.0
     # round 19: adaptive execution.  A replan means the statement ran a
     # CORRECTED plan (the advisor's history-backed cardinality/capacity
     # facts re-planned it); a hold means a material misestimate existed but
@@ -264,8 +296,12 @@ class QueryCounters:
                    "spill_tier_disk", "admission_queued",
                    "plan_template_hits", "plan_template_misses",
                    "compiles", "adaptive_replans", "adaptive_holds",
-                   "batched_requests")
-    _FLOAT_FIELDS = ("compile_s",)
+                   "batched_requests", "compile_cache_misses")
+    _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
+                     "executor_wait_s", "encode_s", "deliver_wait_s",
+                     "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
+                     "wall_dispatch_s", "wall_host_pull_s",
+                     "wall_unattributed_s")
 
     def reset(self) -> None:
         for f in self._INT_FIELDS:
@@ -724,6 +760,21 @@ def signature_summary(sig_key) -> str:
 # in-flight entry that triggered them
 _compile_capture_tls = threading.local()
 _COMPILE_LISTENER = {"installed": False, "failed": False}
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _CompileCapture:
+    """One dispatch's captured compile events: the summed phase seconds, and
+    how many programs reached the backend-compile step against how many of
+    those the persistent cache served."""
+
+    __slots__ = ("seconds", "backend_compiles", "cache_hits")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.backend_compiles = 0
+        self.cache_hits = 0
 
 
 def _on_compile_event(event: str, duration_s: float, **kw) -> None:
@@ -736,13 +787,25 @@ def _on_compile_event(event: str, duration_s: float, **kw) -> None:
         return
     acc = getattr(_compile_capture_tls, "acc", None)
     if acc is not None:
-        acc[event] = acc.get(event, 0.0) + duration_s
+        acc.seconds += duration_s
+        # the backend-compile event wraps compile_or_get_cached: it fires
+        # for a persistent-cache serve too, which the hit event tells apart
+        if event == _BACKEND_COMPILE_EVENT:
+            acc.backend_compiles += 1
+
+
+def _on_cache_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        acc = getattr(_compile_capture_tls, "acc", None)
+        if acc is not None:
+            acc.cache_hits += 1
 
 
 def install_compile_listener() -> bool:
-    """Idempotently register the jax.monitoring duration listener (the
-    /jax/core/compile/* family).  Called once at the _jit module's import;
-    safe without jax (returns False, captures fall back to span wall)."""
+    """Idempotently register the jax.monitoring listeners (the
+    /jax/core/compile/* durations and the persistent cache's hit event).
+    Called once at the _jit module's import; safe without jax (returns
+    False, captures fall back to span wall)."""
     if _COMPILE_LISTENER["installed"]:
         return True
     if _COMPILE_LISTENER["failed"]:
@@ -752,6 +815,7 @@ def install_compile_listener() -> bool:
 
         jax.monitoring.register_event_duration_secs_listener(
             _on_compile_event)
+        jax.monitoring.register_event_listener(_on_cache_event)
         _COMPILE_LISTENER["installed"] = True
         return True
     except Exception:
@@ -760,11 +824,11 @@ def install_compile_listener() -> bool:
 
 
 def begin_compile_capture():
-    """Start accumulating this thread's jax compile-event durations; returns
-    an opaque token for end_compile_capture.  Nestable (inner capture wins
+    """Start accumulating this thread's jax compile events; returns an
+    opaque token for end_compile_capture.  Nestable (inner capture wins
     its own events — jit-of-jit compiles charge the innermost dispatch)."""
     prev = getattr(_compile_capture_tls, "acc", None)
-    acc: dict = {}
+    acc = _CompileCapture()
     _compile_capture_tls.acc = acc
     return prev, acc
 
@@ -781,16 +845,25 @@ def end_compile_capture(token) -> Optional[float]:
     _compile_capture_tls.acc = prev
     if not _COMPILE_LISTENER["installed"]:
         return None
-    return sum(acc.values()) or None
+    return acc.seconds or None
+
+
+def compile_capture_misses(token) -> int:
+    """Programs the capture saw XLA really compile: backend-compile events
+    less the ones the persistent compilation cache served."""
+    acc = token[1]
+    return max(acc.backend_compiles - acc.cache_hits, 0)
 
 
 def record_compile(seconds: float, site: Optional[str] = None,
                    signature: Optional[str] = None,
                    sig_key: Optional[str] = None,
                    exe_bytes: Optional[int] = None,
-                   wrapper: Optional[int] = None) -> None:
-    """One observed XLA compilation (first-seen arg signature at a _jit
-    wrapper): per-query counters + "<op>/<site>" attribution, a "compile"
+                   wrapper: Optional[int] = None,
+                   cache_misses: int = 0) -> None:
+    """One observed XLA compilation REQUEST (first-seen arg signature at a
+    _jit wrapper; ``cache_misses`` of its programs were really compiled, the
+    rest came from the persistent cache): per-query counters + "<op>/<site>" attribution, a "compile"
     span for the wall decomposition (priority above device_dispatch), and
     the process-global CompileLog census.  Host-side bookkeeping only — the
     budget suite runs with all of this enabled and its ceilings are
@@ -799,6 +872,7 @@ def record_compile(seconds: float, site: Optional[str] = None,
     if c is not None:
         c.compiles += 1
         c.compile_s += seconds
+        c.compile_cache_misses += cache_misses
     _attribute_extra(site, compiles=1, compile_s=round(seconds, 6))
     tr = current_tracer()
     if tr is not None and seconds > 0:
@@ -1332,13 +1406,36 @@ class Span:
         return None if self.end_s is None else self.end_s - self.start_s
 
 
+def annotate(name: str, query_id: Optional[str] = None):
+    """``jax.profiler.TraceAnnotation("trino_tpu:<name>", query_id=...)``: the
+    program's span on the profiler's clock, on this thread's line of a traced
+    run's host plane.  One atomic flag test when no profiler session is
+    active."""
+    if jax is None:
+        return contextlib.nullcontext()
+    if query_id is None:
+        query_id = getattr(_counter_local, "query_id", None)
+    return jax.profiler.TraceAnnotation("trino_tpu:" + name,
+                                        query_id=query_id or "")
+
+
 class Tracer:
+    """In-memory span sink.  Finished spans are indexed by trace id (a
+    statement's tree is read back at its end and by the trace endpoint), and
+    the ``max_finished`` bound evicts whole traces, oldest first."""
+
     def __init__(self, max_finished: int = 10_000):
         self._lock = threading.Lock()
         self._next_id = 1
         self.max_finished = max_finished
-        self.finished: list[Span] = []
+        self._by_trace: dict = {}  # trace id -> [Span...], oldest trace first
+        self._count = 0
         self._local = threading.local()
+
+    def clear(self) -> None:
+        with self._lock:
+            self._by_trace.clear()
+            self._count = 0
 
     def _current(self) -> Optional[Span]:
         return getattr(self._local, "span", None)
@@ -1356,9 +1453,18 @@ class Tracer:
 
     def _finish(self, s: Span) -> None:
         with self._lock:
-            self.finished.append(s)
-            if len(self.finished) > self.max_finished:
-                del self.finished[:len(self.finished) - self.max_finished]
+            self._by_trace.setdefault(s.trace_id, []).append(s)
+            self._count += 1
+            while self._count > self.max_finished:
+                oldest = next(iter(self._by_trace))
+                spans = self._by_trace[oldest]
+                if len(self._by_trace) > 1:
+                    del self._by_trace[oldest]
+                    self._count -= len(spans)
+                else:  # one trace alone over the bound: its oldest spans go
+                    drop = self._count - self.max_finished
+                    del spans[:drop]
+                    self._count -= drop
 
     @contextlib.contextmanager
     def span(self, name: str, trace_id: str = "", parent: Optional[Span] = None,
@@ -1367,7 +1473,8 @@ class Tracer:
         parenting) or of this thread's current span.  Parenting used to be
         thread-local ONLY, so a prefetch/producer thread's spans were orphans;
         background-thread sites must pass the parent captured on the query
-        thread."""
+        thread.  The span is also a ``trino_tpu:<name>`` annotation of the
+        profiler, when one is recording."""
         if parent is None:
             parent = self._current()
         s = Span(name=name,
@@ -1378,7 +1485,8 @@ class Tracer:
         prev = self._current()
         self._local.span = s
         try:
-            yield s
+            with annotate(name, s.trace_id):
+                yield s
         except BaseException as e:
             s.status = f"ERROR: {type(e).__name__}"
             raise
@@ -1388,12 +1496,14 @@ class Tracer:
             self._finish(s)
 
     def add_completed(self, name: str, duration_s: float,
-                      parent: Optional[Span] = None, **attributes) -> Span:
-        """Record an already-measured interval as a finished span ending now
-        (the dispatch-span fast path: no context manager in the hot loop)."""
+                      parent: Optional[Span] = None,
+                      end_s: Optional[float] = None, **attributes) -> Span:
+        """Record an already-measured interval as a finished span ending now,
+        or at ``end_s`` (the dispatch-span fast path: no context manager in
+        the hot loop; the server's phases, appended after the fact)."""
         if parent is None:
             parent = self._current()
-        end = time.time()
+        end = time.time() if end_s is None else end_s
         s = Span(name=name,
                  trace_id=parent.trace_id if parent else "",
                  span_id=self._new_id(),
@@ -1405,20 +1515,7 @@ class Tracer:
 
     def spans_for(self, trace_id: str) -> list[Span]:
         with self._lock:
-            return [s for s in self.finished if s.trace_id == trace_id]
-
-
-class _NoopTracer(Tracer):
-    @contextlib.contextmanager
-    def span(self, name: str, trace_id: str = "", parent: Optional[Span] = None,
-             **attributes):
-        yield Span(name, trace_id, 0, None, time.time())
-
-    def add_completed(self, name, duration_s, parent=None, **attributes):
-        return Span(name, "", 0, None, time.time())
-
-
-NOOP_TRACER = _NoopTracer()
+            return list(self._by_trace.get(trace_id, ()))
 
 
 # -- tracer activation ---------------------------------------------------------
@@ -1455,6 +1552,97 @@ def maybe_span(name: str, parent: Optional[Span] = None, **attributes):
         return
     with tr.span(name, parent=parent, **attributes) as s:
         yield s
+
+
+# -- wait states ---------------------------------------------------------------
+#
+# Where a statement waits for something other than its own work (the server's
+# dispatch pool, a batcher lane, the executor semaphore) the wait is measured
+# where it happens: two perf_counter reads, written as a span of the statement
+# and as seconds on one float field of its QueryCounters.  The waits precede
+# or outlive the executor's counters context, so they gather in a per-thread
+# dict that ``Engine.execute_sql`` opens and folds into the statement's
+# snapshot and the engine totals at its end (the admission_queued pattern).
+
+
+@contextlib.contextmanager
+def statement_waits():
+    """Gather this thread's wait seconds by counter field for one statement."""
+    prev = getattr(_counter_local, "waits", None)
+    waits: dict = {}
+    _counter_local.waits = waits
+    try:
+        yield waits
+    finally:
+        _counter_local.waits = prev
+
+
+def record_wait(name: str, field: str, seconds: float,
+                end_s: Optional[float] = None, **attributes) -> None:
+    """One measured wait: a finished span ``name`` (ending now, or at
+    ``end_s``) under the thread's current span, and ``seconds`` on the
+    statement's ``field`` counter."""
+    tr = current_tracer()
+    if tr is not None:
+        tr.add_completed(name, seconds, end_s=end_s, **attributes)
+    waits = getattr(_counter_local, "waits", None)
+    if waits is not None:
+        waits[field] = waits.get(field, 0.0) + seconds
+
+
+@contextlib.contextmanager
+def wait_span(name: str, field: str, **attributes):
+    """Measure the body as a wait state (``record_wait``), annotated on the
+    profiler's timeline while it lasts."""
+    t0 = time.perf_counter()
+    try:
+        with annotate(name):
+            yield
+    finally:
+        record_wait(name, field, time.perf_counter() - t0, **attributes)
+
+
+@contextlib.contextmanager
+def accepted_scope(accepted_pc: float, **attributes):
+    """The front end's handoff to ``Engine.execute_sql`` on this thread: the
+    ``perf_counter`` reading at which it accepted the statement (``queued_s``
+    runs from there to the root span's start) and the attributes its
+    ``server.queued`` span carries (the server's own query id)."""
+    prev = getattr(_counter_local, "accepted", None)
+    _counter_local.accepted = (accepted_pc, attributes)
+    try:
+        yield
+    finally:
+        _counter_local.accepted = prev
+
+
+def take_accepted():
+    """The pending handoff, once: a statement nested in this one on the same
+    thread starts its own queue phase."""
+    accepted = getattr(_counter_local, "accepted", None)
+    _counter_local.accepted = None
+    return accepted
+
+
+# -- device program names ------------------------------------------------------
+
+
+def site_program(fn, site: str):
+    """``fn`` as jax.jit should see it: named after its call site, so that the
+    XLA module is ``jit_<site>`` (dots to underscores: ``join.probe`` ->
+    ``jit_join_probe``, which the device plane's ``XLA Modules`` line and every
+    op's module stat print), with its body under ``jax.named_scope(site)`` so
+    op metadata carries the site too.  The STATIC site only, never the
+    per-plan operator label: the lowered text, and with it every compile-cache
+    key, is the same for every plan that uses the site."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        with jax.named_scope(site):
+            return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = \
+        re.sub(r"\W", "_", site).strip("_") or "jit"
+    return program
 
 
 # -- export --------------------------------------------------------------------
@@ -1542,7 +1730,6 @@ _SPAN_BUCKETS = {
     "h2d": "h2d",
     "exchange.read": "exchange_wait",
     "exchange.stream": "exchange_wait",
-    "exchange.write": "exchange_wait",
     # round 18: the mesh exchange (exec/distributed.py) opens these around its
     # shard_map route/merge steps, so distributed statements attribute
     # exchange time too (before, only the HTTP SpoolingExchange path did)

@@ -27,6 +27,20 @@ __all__ = ["CoordinatorServer"]
 
 DATA_ROWS_PER_FETCH = 4096
 
+# QueryCounters seconds field -> /v1/metrics series stem and its HELP text
+_SECONDS_SERIES = (
+    ("queued_s", "queued", "Seconds from a statement's acceptance to its "
+     "root span (dispatch pool, statement lock, session, admission)."),
+    ("batch_wait_s", "batch_wait", "Seconds statements waited on a template "
+     "batcher lane, gather windows included."),
+    ("executor_wait_s", "executor_wait", "Seconds statements waited for an "
+     "executor of the pool."),
+    ("encode_s", "encode", "Seconds from the engine's answer to FINISHED "
+     "published (rows to JSON, spooling)."),
+    ("deliver_wait_s", "deliver_wait", "Seconds from FINISHED published to "
+     "the response carrying the last page."),
+)
+
 _qids = itertools.count(1)
 
 
@@ -166,6 +180,13 @@ class _Query:
     # engine's plan-template path when one exists
     params: Optional[list] = None
     lock: threading.Lock = dataclasses.field(default_factory=threading.Lock)
+    # the statement's timeline outside the engine (perf_counter readings):
+    # the POST accepted, FINISHED published (None again once the last page
+    # is delivered); ``root`` is the engine's root span, under which the
+    # server's phases are recorded
+    accepted_pc: float = dataclasses.field(default_factory=time.perf_counter)
+    finished_pc: Optional[float] = None
+    root: Optional[object] = None
 
 
 class _StatementLock:
@@ -400,7 +421,10 @@ class CoordinatorServer:
                     if not server._owns(self.headers, q):
                         self._send(403, {"error": "not your query"})
                         return
-                    self._send(200, server._results_response(q, token))
+                    out = server._results_response(q, token)
+                    self._send(200, out)
+                    if "nextUri" not in out:
+                        server._delivered(q)
                     return
                 # /v1/query/{id}/trace — OTLP-shaped span tree of the query
                 # (reference: airlift TracingModule's OTLP export, served
@@ -730,6 +754,27 @@ class CoordinatorServer:
                 f"trino_tpu_plan_template_misses_total "
                 f"{getattr(ct, 'plan_template_misses', 0)}",
             ]
+            # the statements' wait states (spans server.queued, batcher.wait,
+            # executor.checkout, server.encode, server.deliver) and the
+            # buckets of their wall breakdowns, summed
+            for field, name, what in _SECONDS_SERIES:
+                lines += [f"# HELP trino_tpu_{name}_seconds_total {what}",
+                          f"# TYPE trino_tpu_{name}_seconds_total counter",
+                          f"trino_tpu_{name}_seconds_total "
+                          f"{getattr(ct, field, 0.0):.6f}"]
+            lines += ["# HELP trino_tpu_wall_seconds_total Root-span seconds "
+                      "of finished statements by wall_breakdown bucket.",
+                      "# TYPE trino_tpu_wall_seconds_total counter"]
+            for bucket in ("plan", "split_generation", "h2d", "dispatch",
+                           "host_pull", "unattributed"):
+                lines.append(
+                    f'trino_tpu_wall_seconds_total{{bucket="{bucket}"}} '
+                    f"{getattr(ct, f'wall_{bucket}_s', 0.0):.6f}")
+            lines += ["# HELP trino_tpu_compile_cache_misses_total Programs "
+                      "XLA compiled because no compilation cache served them.",
+                      "# TYPE trino_tpu_compile_cache_misses_total counter",
+                      f"trino_tpu_compile_cache_misses_total "
+                      f"{getattr(ct, 'compile_cache_misses', 0)}"]
             # round 21: continuous template batching — fused same-template
             # windows (one device program amortized over N requests), the
             # per-request count, and the fused batch-size distribution
@@ -1213,8 +1258,15 @@ class CoordinatorServer:
 
     def _run(self, q: _Query, catalog: Optional[str],
              user: str = "user") -> None:
+        from ..execution import tracing
+
         try:
-            with self._engine_lock.statement_scope(q.sql):
+            # the engine dates the statement's queue phase (server.queued)
+            # from the POST, and records it under ITS trace id with ours as
+            # an attribute: one timeline per statement
+            with tracing.accepted_scope(q.accepted_pc,
+                                        server_query_id=q.query_id), \
+                    self._engine_lock.statement_scope(q.sql):
                 if not self._set_state(q, "PLANNING"):
                     return  # canceled while queued: never execute
                 session = self.engine.create_session(catalog)
@@ -1236,37 +1288,85 @@ class CoordinatorServer:
                     # shared slot: a None here (statement failed before
                     # admission) is honest, another statement's trace isn't.
                     acct = getattr(self.engine, "_thread_accounting", None)
-                    q.trace = getattr(acct, "trace", None)
-            if res is None:  # DDL
-                columns = [{"name": "result", "type": "boolean"}]
-                rows = [[True]]
-            else:
-                columns = [{"name": n, "type": t.name}
-                           for n, t in zip(res.names, res.types)]
-                rows = [[_json_value(v) for v in row] for row in res.rows()]
-            if self.spool_dir is not None and len(rows) >= self.spool_threshold_rows:
-                segments = self._spool_rows(q.query_id, rows)
-                rows = []  # spooled results live on disk, not inline
-            else:
-                segments = None
-            with q.lock:
-                canceled = q.state == "CANCELED"
-                if not canceled:
-                    q.segments = segments
-                    q.columns = columns
-                    q.rows = rows
-                    q.state = "FINISHED"
+                    trace = getattr(acct, "trace", None)
+                    if trace:  # our own copy: the server's phases join it
+                        q.trace = dict(trace, spans=list(trace["spans"]))
+                        q.root = getattr(acct, "root", None)
+            encode_pc = time.perf_counter()
+            with tracing.annotate("server.encode",
+                                  (q.trace or {}).get("query_id")):
+                if res is None:  # DDL
+                    columns = [{"name": "result", "type": "boolean"}]
+                    rows = [[True]]
+                else:
+                    columns = [{"name": n, "type": t.name}
+                               for n, t in zip(res.names, res.types)]
+                    rows = [[_json_value(v) for v in row]
+                            for row in res.rows()]
+                if self.spool_dir is not None \
+                        and len(rows) >= self.spool_threshold_rows:
+                    segments = self._spool_rows(q.query_id, rows)
+                    rows = []  # spooled results live on disk, not inline
+                else:
+                    segments = None
+                with q.lock:
+                    canceled = q.state == "CANCELED"
+                    if not canceled:
+                        q.segments = segments
+                        q.columns = columns
+                        q.rows = rows
+                        # stamped BEFORE the state is published: eviction
+                        # orders terminal statements by it
+                        q.finished_at = time.time()
+                        finished_pc = q.finished_pc = time.perf_counter()
+                        q.state = "FINISHED"
             if canceled and segments:
                 self._drop_spool(q.query_id)  # orphaned mid-cancel segments
+            if not canceled:
+                self._phase(q, "server.encode", "encode_s",
+                            finished_pc - encode_pc, q.finished_at)
         except Exception as e:  # noqa: BLE001 - protocol surface reports all failures
             with q.lock:
                 if q.state != "CANCELED":
                     q.error = f"{type(e).__name__}: {e}"
+                    q.finished_at = time.time()
                     q.state = "FAILED"
             traceback.print_exc()
         finally:
-            q.finished_at = time.time()
+            if q.finished_at is None:  # canceled
+                q.finished_at = time.time()
             self._evict_finished()
+
+    def _phase(self, q: _Query, name: str, field: str, seconds: float,
+               end_s: Optional[float] = None) -> None:
+        """One of the server's own phases of a statement, measured after the
+        engine closed it: a span under the engine's root span (in the
+        engine's tracer and in the tree this server serves for the
+        statement, carrying our query id) and seconds on the engine's
+        totals, under the lock its own merge uses."""
+        tracer = getattr(self.engine, "tracer", None)
+        if q.root is not None and tracer is not None:
+            from ..execution.tracing import span_dict
+
+            span = tracer.add_completed(name, seconds, parent=q.root,
+                                        end_s=end_s,
+                                        server_query_id=q.query_id)
+            q.trace["spans"].append(span_dict(span))
+        totals = getattr(self.engine, "counters_total", None)
+        lock = getattr(self.engine, "_init_lock", None)
+        if totals is not None and lock is not None:
+            with lock:
+                setattr(totals, field, getattr(totals, field) + seconds)
+
+    def _delivered(self, q: _Query) -> None:
+        """The response that carries the statement's last page was written:
+        close ``server.deliver`` (FINISHED published -> here: the client's
+        poll lag), once."""
+        with q.lock:
+            finished_pc, q.finished_pc = q.finished_pc, None
+        if finished_pc is not None:
+            self._phase(q, "server.deliver", "deliver_wait_s",
+                        time.perf_counter() - finished_pc)
 
     def _evict_finished(self, keep: int = 100) -> None:
         """Bound coordinator memory: retain only the most recent terminal queries'
@@ -1387,7 +1487,9 @@ class CoordinatorServer:
         return {"info": cl.info(), "records": cl.snapshot()}
 
     def _query_trace(self, qid: str):
-        """OTLP/JSON trace for a server query id (captured trace), an ENGINE
+        """OTLP/JSON trace for a server query id or the engine's id of the
+        same statement (one tree: the captured trace plus the server's own
+        phases), an ENGINE
         or CLUSTER query id served from the FLIGHT RECORDER (round-16
         satellite: a completed statement's trace resolves long after the
         next statement landed — and a distributed query's record carries the
@@ -1397,6 +1499,9 @@ class CoordinatorServer:
         from ..execution.tracing import spans_to_otlp
 
         q = self.queries.get(qid)
+        if q is None:  # the engine's id of a statement this server ran
+            q = next((x for x in list(self.queries.values())
+                      if x.trace and x.trace.get("query_id") == qid), None)
         if q is not None:
             if not q.trace:
                 return None
