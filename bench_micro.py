@@ -256,7 +256,7 @@ def bench_hashagg_insert_ab(n):
 
 def bench_compact_ab(n):
     """The pipeline-boundary masked-lane pack at 1/16 selectivity: XLA
-    cumsum-scatter vs the Pallas prefix-sum + one-hot matmul — byte-identical."""
+    index-then-gather vs the Pallas prefix-sum + one-hot matmul — byte-identical."""
     import numpy as np
 
     from trino_tpu.ops.arrays import compact_rows

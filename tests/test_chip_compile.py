@@ -108,6 +108,28 @@ def test_compact_compiles_for_v5e(out_len, limbs, one_chip, as_on_tpu):
     assert "tpu_custom_call" in text
 
 
+# three stacked 2^21-lane pages into the n/16 bucket (the shape ISSUE 26
+# named), what q9's gated compaction really runs at SF1 (lineitem as one
+# resident page into n >> 6; PERF.md section 5), one page into
+# _compact_part*'s floor
+@pytest.mark.parametrize("n,size", [(3 * PAGE_ROWS, 3 * PAGE_ROWS // 16),
+                                    (12_582_906, 196_607), (PAGE_ROWS, 1024)])
+def test_live_index_pack_compiles_for_v5e(n, size, one_chip, no_persistent_cache):
+    """`ops/arrays.live_indices` plus seven 64-bit gathers: the XLA compaction
+    every bucket past the Pallas gate takes, and no scatter in what the v5e
+    compiler makes of it."""
+    from trino_tpu.ops.arrays import gather_rows, live_indices
+
+    def pack(cols, valid):
+        idx, total = live_indices(valid, size)
+        return tuple(gather_rows(c, idx) for c in cols), total
+
+    text = _compile(pack, tuple(_s(one_chip, (n,), jnp.int64) for _ in range(7)),
+                    _s(one_chip, (n,), jnp.bool_))
+    assert "sort" in text and "gather" in text
+    assert "scatter" not in text
+
+
 def test_q1_page_step_compiles_for_v5e(one_chip, as_on_tpu):
     """The jitted per-page step of Q1 (scan transform -> group-by insert into
     the 64-slot table) — the first aggregation of the first query."""

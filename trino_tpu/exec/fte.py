@@ -641,6 +641,7 @@ def _serialize_partial_state(node, state, nk) -> bytes:
     n_groups = int(hashagg.group_count(state))
     bucket = max(1 << max(n_groups - 1, 1).bit_length(), 64)
     keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
+    tracing.record_compaction(state.capacity, bucket)
     got = _host(list(keys) + list(key_nulls) + list(accs),
                 site="fte.partial.groups")
     cols = [g[:n_groups] for g in got[:nk]] + [g[:n_groups] for g in got[2 * nk:]]
@@ -919,6 +920,7 @@ def _merge_partial_cols(node, key_types, acc_specs, acc_kinds, payloads):
     n_groups = int(hashagg.group_count(state))
     bucket = max(1 << max(n_groups - 1, 1).bit_length(), 64)
     keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
+    tracing.record_compaction(state.capacity, bucket)
     got = _host(list(keys) + list(key_nulls) + list(accs),
                 site="fte.merge.groups")
     key_cols = [k[:n_groups] for k in got[:nk]]

@@ -29,7 +29,7 @@ import numpy as np
 from ..connectors.tpch import Dictionary
 from ..execution import faults, tracing
 from ..ops import hashagg
-from ..ops.arrays import compact_rows
+from ..ops.arrays import compact_rows, gather_rows, live_indices
 from ..ops.hashing import ceil_pow2
 from ..ops.hashjoin import (DIRECT_JOIN_RANGE_MAX, DirectJoinTable,
                             DirectMultiJoinTable, JoinTable, MultiJoinTable,
@@ -1217,16 +1217,11 @@ class LocalExecutor:
                     continue
                 jc = compact_jits.get(bucket)
                 if jc is None:
-                    def jc_fn(cols, nulls, valid, bucket=bucket):
-                        # the shared masked-lane pack (ops/arrays.compact_rows:
-                        # XLA cumsum-scatter, or the round-13 Pallas kernel)
-                        packed, total = compact_rows(
-                            tuple(cols) + tuple(nulls), valid, bucket)
-                        cvalid = jnp.arange(bucket) < total
-                        return (packed[:len(cols)], packed[len(cols):], cvalid)
-                    jc = _jit(jc_fn)
+                    jc = _jit(partial(_compact_page, bucket=bucket),
+                              site="jc_fn")
                     compact_jits[bucket] = jc
                 ccols, cnulls, cvalid = jc(cols, nulls, valid)
+                tracing.record_compaction(n, bucket)
                 yield Page(up.schema, ccols, cnulls, cvalid)
 
         si = up.scan_info
@@ -2366,6 +2361,7 @@ class LocalExecutor:
                 nulls_list = list(knulls) + [nu for v, nu in inputs if v is not None]
                 ccols, cnulls = _compact_part(tuple(cols_list), tuple(nulls_list),
                                               valid, bucket)
+                tracing.record_compaction(width, bucket)
                 nk = len(keys)
                 rest_v, rest_n = list(ccols[nk:]), list(cnulls[nk:])
                 cinputs = []
@@ -2453,8 +2449,9 @@ class LocalExecutor:
             def pstep_body(cols, nulls, valid, node=node):
                 n = valid.shape[0]
                 # order-preserving compaction of EVERY array this step reads,
-                # in one pack (ops/arrays.compact_rows: XLA cumsum-scatter or
-                # the round-13 Pallas kernel — one launch for the whole page)
+                # in one pack (ops/arrays.compact_rows: live-lane index then
+                # gathers, or the round-13 Pallas kernel — one launch for the
+                # whole page)
                 vn_raw = []
                 for e in acc_exprs:
                     if e is None:
@@ -2575,6 +2572,7 @@ class LocalExecutor:
         n_groups = int(hashagg.group_count(state))
         bucket = max(1 << max(n_groups - 1, 1).bit_length(), 64)
         keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
+        tracing.record_compaction(state.capacity, bucket)
         nk = len(keys)
         dicts = tuple(stream.dicts[i] for i in node.keys) + tuple(None for _ in node.aggs)
 
@@ -3475,9 +3473,10 @@ class LocalExecutor:
             if n == 0:
                 continue
             n = min(n, node.count - total)
-            bucket = max(1 << max(n - 1, 1).bit_length(), 1024)
-            ccols, cnulls = _compact_part(cols, nulls, valid,
-                                          min(bucket, valid.shape[0]))
+            bucket = min(max(1 << max(n - 1, 1).bit_length(), 1024),
+                         valid.shape[0])
+            ccols, cnulls = _compact_part(cols, nulls, valid, bucket)
+            tracing.record_compaction(valid.shape[0], bucket)
             parts.append((ccols, cnulls, n))
             total += n
             if total >= node.count:
@@ -3875,13 +3874,25 @@ def _finalize_aggs_device(aggs, acc_cols):
     return tuple(out), tuple(nulls), bad
 
 
+def _compact_page(cols, nulls, valid, bucket: int):
+    """_compacted_stream's step: the shared masked-lane pack
+    (ops/arrays.compact_rows: live-lane index then gathers, or the round-13
+    Pallas kernel) of a page into ``bucket`` lanes, with its validity mask."""
+    packed, total = compact_rows(tuple(cols) + tuple(nulls), valid, bucket)
+    cvalid = jnp.arange(bucket) < total
+    return packed[:len(cols)], packed[len(cols):], cvalid
+
+
+def _gather_part(cols, nulls, idx):
+    return (tuple(gather_rows(c, idx) for c in cols),
+            tuple(None if n is None else gather_rows(n, idx) for n in nulls))
+
+
 @partial(_jit, static_argnums=(3,))
 def _compact_part(cols, nulls, valid, size: int):
-    """Gather valid rows into dense ``size``-bounded arrays (device-side)."""
-    idx = jnp.nonzero(valid, size=size, fill_value=0)[0]
-    out_cols = tuple(c[idx] for c in cols)
-    out_nulls = tuple(None if n is None else n[idx] for n in nulls)
-    return out_cols, out_nulls
+    """Gather valid rows into dense ``size``-bounded arrays (device-side);
+    lanes beyond the live count hold a real row, the caller masks them."""
+    return _gather_part(cols, nulls, live_indices(valid, size)[0])
 
 
 @partial(_jit, static_argnums=(3,))
@@ -3890,12 +3901,9 @@ def _compact_part_sized(cols, nulls, valid, size: int):
     (``arange(size) < live``), computed INSIDE the same dispatch — what lets
     _concat_stream's single-part fast path skip the _concat_all dispatch
     without any uncounted eager device work."""
-    idx = jnp.nonzero(valid, size=size, fill_value=0)[0]
-    out_cols = tuple(c[idx] for c in cols)
-    out_nulls = tuple(None if n is None else n[idx] for n in nulls)
-    pvalid = jnp.arange(size, dtype=jnp.int32) < \
-        jnp.sum(valid, dtype=jnp.int32)
-    return out_cols, out_nulls, pvalid
+    idx, live = live_indices(valid, size)
+    return _gather_part(cols, nulls, idx) \
+        + (jnp.arange(size, dtype=jnp.int32) < live,)
 
 
 def _concat_traced(stream: _Stream):
@@ -3976,10 +3984,10 @@ def _concat_traced(stream: _Stream):
 def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
     """Materialize a streaming segment into a single device page (compacted).
 
-    Compaction runs ON DEVICE (nonzero-gather per page, then a device concat): pages
-    never cross to the host between pipeline-breaking stages — device->host bandwidth
-    is the scarce resource, not FLOPs (reference analog: pages stay in worker memory
-    between operators).  ``batch``>1 coalesces shape-uniform pages: each group
+    Compaction runs ON DEVICE (live-lane index + gathers per page, then a device
+    concat): pages never cross to the host between pipeline-breaking stages —
+    device->host bandwidth is the scarce resource, not FLOPs (reference analog:
+    pages stay in worker memory between operators).  ``batch``>1 coalesces shape-uniform pages: each group
     of K splits runs its transform in ONE dispatch (and its compaction and
     live-count sync amortize K-fold with it)."""
     fused = _concat_traced(stream)
@@ -4011,9 +4019,11 @@ def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
                                for m in nulls)
                 parts.append((ccols, cnulls, None, n))
                 continue
-            bucket = max(1 << max(n - 1, 1).bit_length(), 1024)
+            bucket = min(max(1 << max(n - 1, 1).bit_length(), 1024),
+                         valid.shape[0])
             ccols, cnulls, pvalid = _compact_part_sized(
-                cols, nulls, valid, min(bucket, valid.shape[0]))
+                cols, nulls, valid, bucket)
+            tracing.record_compaction(valid.shape[0], bucket)
             parts.append((ccols, cnulls, pvalid, n))
         staged.clear()
         sums.clear()

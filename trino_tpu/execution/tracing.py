@@ -240,6 +240,13 @@ class QueryCounters:
     # jax.monitoring listener less its persistent-cache hits, captured on
     # the dispatching thread like compile_s)
     compile_cache_misses: int = 0
+    # PR 26: device-side row compactions (ops/arrays.live_indices + gathers:
+    # the pipeline-boundary pack, _compact_part*, compact_groups) and the
+    # lanes they read and keep: the static n and bucket of each dispatch,
+    # host ints the caller holds
+    compactions: int = 0
+    compact_lanes_in: int = 0
+    compact_lanes_out: int = 0
     # PR 25: the statement's wait states, seconds (each also a span of the
     # same name family: server.queued, batcher.wait, executor.checkout,
     # server.encode, server.deliver), recorded where the wait happens, and
@@ -296,7 +303,8 @@ class QueryCounters:
                    "spill_tier_disk", "admission_queued",
                    "plan_template_hits", "plan_template_misses",
                    "compiles", "adaptive_replans", "adaptive_holds",
-                   "batched_requests", "compile_cache_misses")
+                   "batched_requests", "compile_cache_misses",
+                   "compactions", "compact_lanes_in", "compact_lanes_out")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
                      "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
@@ -529,6 +537,14 @@ def record_coalesced(n_splits: int) -> None:
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.coalesced_splits += n_splits
+
+
+def record_compaction(lanes_in: int, lanes_out: int) -> None:
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.compactions += 1
+        c.compact_lanes_in += lanes_in
+        c.compact_lanes_out += lanes_out
 
 
 def _attribute_extra(site: Optional[str], **extras) -> None:

@@ -20,9 +20,11 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 __all__ = ["SPAN_BITS", "ArrayData", "MapData", "pack_span", "span_start",
-           "span_len", "encode_arrays", "compact_rows", "append_rows"]
+           "span_len", "encode_arrays", "live_indices", "gather_rows",
+           "compact_rows", "append_rows"]
 
 SPAN_BITS = 24  # max 16M elements per array; 2^39 heap rows
 _LEN_MASK = (1 << SPAN_BITS) - 1
@@ -127,6 +129,36 @@ def encode_arrays(rows, elem_dtype, encoder=None):
     return spans, (nulls if nulls.any() else None), heap
 
 
+def live_indices(valid, size: int):
+    """Lane numbers of the first ``size`` live lanes of ``valid``, ascending
+    (arrival order kept), and the live count: ``(idx int32[size], count int32
+    scalar)``.  ``idx`` beyond ``count`` is filler ``n - 1``: in bounds, and the
+    whole vector stays sorted, so a gather may be promised both.
+
+    THE index of every device-side row compaction (compact_rows,
+    local_executor._compact_part*, hashagg.compact_groups).  Scatter-free on
+    purpose: on a v5e an XLA scatter (and ``jnp.nonzero(size=)``, whose
+    ``bincount`` is a scatter-add) pays 175-290 ns for EVERY input lane, a
+    sort key under 2 ns; the data then moves by gathers of the OUTPUT size.
+    One single-key int32 sort (int32 on purpose: under x64 ``arange`` is int64
+    and doubles the sort); dead lanes carry the key ``n`` and sort behind
+    every live one.  The one form kept of three measured (PERF.md section 6,
+    PR 26): at 6.3 M lanes 11.5 ms whatever ``size`` is, where ``nonzero``
+    takes 400-440 ms; a ``searchsorted`` in ``cumsum(valid)`` wins only for
+    ``size`` under about 32 K of millions of lanes, by at most 9 ms a call."""
+    n = valid.shape[0]  # at least one lane
+    lanes = jnp.where(valid, lax.iota(jnp.int32, n), jnp.int32(n))
+    idx = jnp.minimum(jnp.sort(lanes)[:size], n - 1)
+    if size > n:
+        idx = jnp.concatenate([idx, jnp.full((size - n,), n - 1, jnp.int32)])
+    return idx, jnp.sum(valid, dtype=jnp.int32)
+
+
+def gather_rows(a, idx):
+    """``a[idx]`` for an ``idx`` of `live_indices`: in bounds and sorted."""
+    return a.at[idx].get(mode="promise_in_bounds", indices_are_sorted=True)
+
+
 def compact_rows(arrays, valid, out_len: int):
     """Order-preserving masked-lane pack, THE shared filter->compaction step:
     live lanes move to the front of ``out_len``-sized outputs (zeros beyond
@@ -134,12 +166,12 @@ def compact_rows(arrays, valid, out_len: int):
     Returns (packed tuple, live-count device scalar).
 
     Consumers: the pipeline-boundary compaction and streaming-agg pre-pack
-    (exec/local_executor) and the exchange bucketizer (ops/exchange) — all
-    three used to hand-roll the same cumsum-scatter.  Round-13 backend split:
-    `pallas_kernels.compact_columns` (block prefix-sum + one-hot matmul, one
-    kernel launch for the whole page) when `use_pallas()` and the packed
-    output fits the VMEM gate; the XLA cumsum-scatter below otherwise.
-    Byte-identical by contract (tests/test_pallas_kernels.py pins it)."""
+    (exec/local_executor) and the exchange bucketizer (ops/exchange).
+    Round-13 backend split: `pallas_kernels.compact_columns` (block prefix-sum
+    + one-hot matmul, one kernel launch for the whole page) when
+    `use_pallas()` and the packed output fits the VMEM gate; the XLA
+    index-then-gather below otherwise.  Byte-identical by contract
+    (tests/test_pallas_kernels.py pins it)."""
     from . import pallas_kernels as pk
 
     arrs = [a for a in arrays if a is not None]
@@ -150,18 +182,21 @@ def compact_rows(arrays, valid, out_len: int):
         packed, total = pk.compact_columns(tuple(arrs), valid, out_len)
         it = iter(packed)
         return tuple(None if a is None else next(it) for a in arrays), total
-    # XLA path: cumsum-scatter pack — linear, no sort; dst slots are unique
-    # (plus the clamped drop sink) so last-wins scatter is exact.  Invalid
-    # rows route straight to the drop slot at out_len: clamping a shared
-    # where(..., n) would leak an invalid row's value INTO the output
-    # whenever out_len > n
-    pos = jnp.cumsum(valid) - 1
-    dst = jnp.where(valid, jnp.minimum(pos, out_len), out_len)
+    # XLA path: `live_indices`, then one gather of min(out_len, n) rows per
+    # array.  The filler lanes of idx gather a real row, so everything beyond
+    # the live count is zeroed (and zero-padded when out_len > n)
+    k = min(out_len, n)
+    if k == 0:  # nothing to gather from, or nothing to keep
+        return tuple(None if a is None else jnp.zeros((out_len,), a.dtype)
+                     for a in arrays), jnp.sum(valid, dtype=jnp.int32)
+    idx, total = live_indices(valid, k)
+    live = jnp.arange(k, dtype=jnp.int32) < total
     packed = tuple(
         None if a is None
-        else jnp.zeros((out_len + 1,), a.dtype).at[dst].set(a)[:out_len]
+        else jnp.pad(jnp.where(live, gather_rows(a, idx), jnp.zeros((), a.dtype)),
+                     (0, out_len - k))
         for a in arrays)
-    return packed, jnp.sum(valid)
+    return packed, total
 
 
 def append_rows(bufs, cursor, arrays, valid):

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..types import BOOLEAN as _BOOL_KEY
+from .arrays import gather_rows, live_indices
 from .hashing import ceil_pow2, probe_step, EMPTY_KEY, pack_keys, splitmix64
 
 __all__ = ["GroupByState", "groupby_init", "groupby_insert", "AGG_INITS", "agg_update",
@@ -490,8 +491,8 @@ def compact_groups(state: GroupByState, size: int):
     (cached executable per bucket)."""
     C = state.capacity
     occupied = state.table[:C] != EMPTY_KEY
-    idx = jnp.nonzero(occupied, size=size, fill_value=0)[0]
-    keys = tuple(k[:C][idx] for k in state.key_cols)
-    key_nulls = tuple(kn[:C][idx] for kn in state.key_nulls)
-    accs = tuple(a[:C][idx] for a in state.accs)
+    idx, _ = live_indices(occupied, size)
+    keys = tuple(gather_rows(k[:C], idx) for k in state.key_cols)
+    key_nulls = tuple(gather_rows(kn[:C], idx) for kn in state.key_nulls)
+    accs = tuple(gather_rows(a[:C], idx) for a in state.accs)
     return keys, key_nulls, accs

@@ -512,7 +512,7 @@ def compact_rows_matrix(mat, valid, out_len: int, interpret: bool | None = None)
     [block, block] one-hot matmul over 8-bit limbs (exact products); the
     running output offset rides an SMEM output across the sequential grid.
     Rows past ``out_len`` drop into a write-and-discard pad zone — the same
-    semantics as the XLA cumsum-scatter's clamped sink.  Returns
+    semantics as the XLA path's dropped overflow lanes.  Returns
     (packed, total_live_count)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu

@@ -775,6 +775,15 @@ class CoordinatorServer:
                       "# TYPE trino_tpu_compile_cache_misses_total counter",
                       f"trino_tpu_compile_cache_misses_total "
                       f"{getattr(ct, 'compile_cache_misses', 0)}"]
+            for field, what in (
+                    ("compactions", "Device-side row compactions "
+                     "(live-lane index, then gathers)."),
+                    ("compact_lanes_in", "Lanes read by row compactions."),
+                    ("compact_lanes_out", "Lanes kept by row compactions "
+                     "(their output buckets).")):
+                lines += [f"# HELP trino_tpu_{field}_total {what}",
+                          f"# TYPE trino_tpu_{field}_total counter",
+                          f"trino_tpu_{field}_total {getattr(ct, field, 0)}"]
             # round 21: continuous template batching — fused same-template
             # windows (one device program amortized over N requests), the
             # per-request count, and the fused batch-size distribution

@@ -82,6 +82,12 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
             # regexes unchanged), so the line itself is a cold-path marker
             lines.append(f"Compile: {cm} compilations, "
                          f"{getattr(counters, 'compile_s', 0.0):.3f}s")
+        cp = getattr(counters, "compactions", 0)
+        if cp:
+            lines.append(
+                f"Compaction: {cp} compactions, "
+                f"{getattr(counters, 'compact_lanes_in', 0)} lanes in, "
+                f"{getattr(counters, 'compact_lanes_out', 0)} lanes out")
         sp = getattr(counters, "spilled_bytes", 0)
         aq = getattr(counters, "admission_queued", 0)
         if sp or aq:
