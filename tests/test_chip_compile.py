@@ -130,6 +130,25 @@ def test_live_index_pack_compiles_for_v5e(n, size, one_chip, no_persistent_cache
     assert "scatter" not in text
 
 
+# q3's TopN at SF10 (10 of 113,513 groups by revenue desc, o_orderdate) and the
+# widest the gate admits (TOPN_SELECT_MAX rows over TOPN_SELECT_WORK lane-rounds)
+@pytest.mark.parametrize("n,count", [(113_513, 10), (1 << 21, 1024)])
+def test_topn_selection_compiles_for_v5e(n, count, one_chip, no_persistent_cache):
+    """`ops/arrays.first_rows`: reductions in a loop, and no sort in what the
+    v5e compiler makes of it (a sort that carries a payload costs the TPU
+    compiler one to two minutes, PERF.md PR 27)."""
+    import time
+
+    from trino_tpu.ops.arrays import first_rows
+
+    t0 = time.perf_counter()
+    text = first_rows.lower(
+        (_s(one_chip, (n,), jnp.int32), _s(one_chip, (n,), jnp.int64),
+         _s(one_chip, (n,), jnp.bool_)), count).compile().as_text()
+    assert " sort(" not in text  # the HLO instruction, not a name in the metadata
+    assert time.perf_counter() - t0 < 60
+
+
 def test_q1_page_step_compiles_for_v5e(one_chip, as_on_tpu):
     """The jitted per-page step of Q1 (scan transform -> group-by insert into
     the 64-slot table) — the first aggregation of the first query."""
@@ -167,3 +186,51 @@ def test_exchange_fragment_compiles_over_four_chips(topo, as_on_tpu):
                     jax.ShapeDtypeStruct((W, per), jnp.int32, sharding=sharded))
     assert "all-to-all" in text
     assert "tpu_custom_call" in text
+
+
+def _gathers_from_arguments(text):
+    """Names of the entry parameters that a gather of the compiled program reads
+    directly (not through a copy or a fusion of the program's own)."""
+    import re
+
+    entry = text[text.index("\nENTRY"):]
+    params = set(re.findall(r"%([\w.\-]+) = \S+ parameter\(", entry))
+    hit = set()
+    for line in entry.splitlines():
+        if "kind=kCustom" in line and "gather" in line:
+            ops = re.search(r" fusion\(([^)]*)\)", line).group(1)
+            hit |= {o.strip().lstrip("%") for o in ops.split(",")} & params
+    return hit
+
+
+# q3's probe of the table over orders at SF10 (14,999,994 slots, a build page of
+# 2^23 lanes, four 2^21-lane pages a dispatch) and the gate's own size
+@pytest.mark.parametrize("slots,build,lanes", [(14_999_994, 1 << 23, 1 << 23),
+                                               (1 << 22, 1 << 21, 1 << 21)])
+def test_staged_direct_probe_compiles_for_v5e(slots, build, lanes, one_chip,
+                                              no_persistent_cache):
+    """`hashjoin.stage_direct_table`: every gather of the probe reads a copy that
+    the program made (the barriers hold: the copies are neither folded away nor
+    fused into the gathers), none reads an argument where the allocator put it."""
+    from trino_tpu.ops import hashjoin as hj
+
+    def probe(dt, key, valid, staged):
+        if staged:
+            dt = hj.stage_direct_table(dt)
+        rows, matched = hj.direct_probe(dt, key, valid)
+        safe = jnp.where(matched, rows, 0)
+        return tuple(c[safe] for c in dt.build_columns), matched
+
+    dt = hj.DirectJoinTable(
+        rows=_s(one_chip, (slots,), jnp.int32), occ=_s(one_chip, (slots,), jnp.bool_),
+        build_columns=tuple(_s(one_chip, (build,), d)
+                            for d in (jnp.int64, jnp.int32, jnp.int32)),
+        build_null_masks=(None, None, None), dup_count=_s(one_chip, (), jnp.int32), lo=0)
+    args = (dt, _s(one_chip, (lanes,), jnp.int64), _s(one_chip, (lanes,), jnp.bool_))
+    staged = jax.jit(probe, static_argnums=3).lower(*args, True).compile().as_text()
+    assert "gather" in staged
+    assert _gathers_from_arguments(staged) == set()
+    small = hj.DirectJoinTable(
+        rows=jnp.zeros(9, jnp.int32), occ=jnp.zeros(9, bool), build_columns=(),
+        build_null_masks=(), dup_count=jnp.int32(0), lo=0)
+    assert hj.stage_direct_table(small) is small  # under the gate: left as it is

@@ -21,6 +21,7 @@ formatter dictionary.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from functools import partial
 from typing import Callable, Optional
 
@@ -463,6 +464,7 @@ class TpchConnector:
     def __init__(self, sf: float = 1.0, split_rows: int = 1 << 20):
         self.sf = sf
         self.split_rows = split_rows
+        self._warmed: set = set()  # (table, columns) whose generator was started
 
     # metadata ---------------------------------------------------------------
     def tables(self):
@@ -634,6 +636,31 @@ class TpchConnector:
         cols, valid = _jit_generate(split.table, self.sf, split.lo, split.hi - split.lo,
                                     self.table_bound(split.table), tuple(names))
         return Page(out_schema, cols, tuple(None for _ in cols), valid)
+
+    def warm_scan(self, table: str, columns) -> None:
+        """Start compiling the page generator of (table, columns) on a
+        background thread, once a connector: the executor calls this for every
+        scan of a plan before it runs the first, so that the compile of a probe
+        side's generator (45 s for four lineitem columns at SF10 on a v5e,
+        PERF.md PR 27) runs beside the build sides and not after them.  The
+        thread generates the first split's page and drops it.  (An
+        ahead-of-time ``lower().compile()`` was tried in its place: the first
+        ``sf10_scan`` run with it lost a quarter of its window, PERF.md PR 27.)"""
+        key = (table, tuple(columns))
+        if key in self._warmed:
+            return
+        self._warmed.add(key)
+        splits = self.splits(table)
+        if not splits:
+            return
+
+        def warm(split=splits[0], columns=list(columns)):
+            try:
+                self.generate(split, columns)
+            except Exception:
+                pass  # the scan itself will raise what is wrong
+
+        threading.Thread(target=warm, daemon=True, name="generate-warm").start()
 
     def generate_traced(self, table: str, lo, length: int, columns):
         """Trace-time generation with traced ``lo`` and static ``length`` (for

@@ -29,13 +29,14 @@ import numpy as np
 from ..connectors.tpch import Dictionary
 from ..execution import faults, tracing
 from ..ops import hashagg
-from ..ops.arrays import compact_rows, gather_rows, live_indices
+from ..ops.arrays import compact_rows, first_rows, gather_rows, live_indices
 from ..ops.hashing import ceil_pow2
 from ..ops.hashjoin import (DIRECT_JOIN_RANGE_MAX, DirectJoinTable,
                             DirectMultiJoinTable, JoinTable, MultiJoinTable,
                             build_insert, build_table_init, direct_build,
                             direct_multi_build, direct_probe, direct_probe_slots,
-                            expand_counts, multi_build, probe, probe_slots)
+                            expand_counts, multi_build, probe, probe_slots,
+                            stage_direct_table)
 from ..page import Field, Page, Schema
 from ..types import BIGINT, DOUBLE, BOOLEAN, DecimalType, Type
 from ..sql import plan as P
@@ -732,6 +733,7 @@ class LocalExecutor:
 
         wrapped = self._rewrap_pruned_pages(raw, conn, len(splits))
         bp = self.buffer_pool
+        split_rows = _split_base_rows(conn, table, splits)
 
         def pages(self=self):
             key = None
@@ -749,14 +751,18 @@ class LocalExecutor:
                 tracing.record_page_cache(misses=1, site=site)
             acc = [] if key is not None and not bp.has_page(key) else None
             acc_bytes = 0
-            for pg in wrapped():
+            for i, pg in enumerate(wrapped()):  # one page a split
+                tracing.record_rows_generated(split_rows[i])
                 if acc is not None:
-                    # stop pinning pages the pool would reject anyway: a scan
-                    # past the whole budget (or one with object columns that
-                    # cannot live on device) reverts to pure streaming —
+                    # pin no page the pool would reject anyway: a scan whose
+                    # splits (one shape class, so the first page prices them
+                    # all) pass the pool's per-entry cap, or one with object
+                    # columns that cannot live on device, is pure streaming —
                     # pages release as consumed, exactly like cache-off
-                    acc_bytes += _page_bytes(pg)
-                    if acc_bytes > bp.budget() or any(
+                    nbytes = _page_bytes(pg)
+                    acc_bytes += nbytes
+                    if max(acc_bytes, nbytes * len(splits)) \
+                            > bp.page_entry_cap() or any(
                             isinstance(c, np.ndarray) and c.dtype == object
                             for c in pg.columns):
                         acc = None
@@ -864,7 +870,8 @@ class LocalExecutor:
         identity so warm cached-plan executions pay a dict lookup.  Drivers
         that bypass execute() (cluster local finish, worker task bodies)
         call this before _execute_to_page for history coverage; skipping it
-        only loses history, never correctness."""
+        only loses history, never correctness.  A plan seen for the first
+        time also has its scans' generators started (_warm_scans)."""
         hit = self._est_cache.get(id(root))
         if hit is None:
             from ..execution.history import (estimate_plan_rows,
@@ -876,6 +883,7 @@ class LocalExecutor:
             except Exception:
                 hit = ({}, {})  # estimation is advisory: run without it
             self._est_cache[id(root)] = hit
+            self._warm_scans(root)
         self._node_paths, self._node_ests = hit
 
     def plan_fingerprint(self, root: P.PlanNode) -> str:
@@ -919,6 +927,20 @@ class LocalExecutor:
         finally:
             # clean or error exit: no prefetch producer outlives the query
             self.close_producers()
+
+    def _warm_scans(self, root: P.PlanNode) -> None:
+        """Tell the connector of every scan in the plan that the scan is coming
+        (``warm_scan``, where a connector has it): the streams compile their
+        scans in execution order, a probe side's last, and a generator's first
+        compile is long enough to be worth starting beside the builds."""
+        stack = [root]
+        while stack:
+            n = stack.pop()
+            if isinstance(n, P.TableScan):
+                warm = getattr(self.catalogs.get(n.catalog), "warm_scan", None)
+                if warm is not None:
+                    warm(n.table, tuple(n.columns))
+            stack.extend(n.children)
 
     def execute_batched(self, node: P.PlanNode, runtimes) -> list:
         """Round 21 — continuous template batching: ONE fused execution of a
@@ -1447,7 +1469,7 @@ class LocalExecutor:
                 acc_exprs.append(arg)
                 acc_kinds.append(kind)
 
-        @_jit
+        @partial(_jit, site="agg.hash.step")
         def step(state, page, aux, stream=stream, node=node, key_types=key_types,
                  acc_exprs=acc_exprs, acc_kinds=acc_kinds):
             cols, nulls, valid = stream.transform(
@@ -1518,12 +1540,12 @@ class LocalExecutor:
                 state, cfg, key_vals, valid, inputs, acc_kinds, key_nulls
             )
 
-        @_jit
+        @partial(_jit, site="agg.direct.step")
         def dstep(state, page, aux, stream=stream):
             return body(state, *stream.transform(
                 page.columns, page.null_masks, page.valid_mask(), aux))
 
-        @_jit
+        @partial(_jit, site="agg.direct.batch")
         def bdstep(state, pages, live, aux, stream=stream):
             return body(state, *stream.transform(*_stack_pages(pages, live),
                                                  aux))
@@ -1623,7 +1645,7 @@ class LocalExecutor:
                 state, _ = jax.lax.scan(body, state, los)
                 return state
 
-            return _jit(run, donate_argnums=(0,))
+            return _jit(run, site="agg.scanfused.scan", donate_argnums=(0,))
 
         def cached_run(mode, insert):
             key = ("scanfused", id(node), mode)
@@ -1635,23 +1657,26 @@ class LocalExecutor:
                 self._agg_cache[key] = (node, run)
             return run
 
-        key_w = sum(np.dtype(t.dtype).itemsize + 1 for t in key_types)
-        acc_w = sum(np.dtype(dt).itemsize for dt, _ in acc_specs)
-        state_bytes = lambda cap: (cap + 1) * (8 + key_w + acc_w)
+        state_bytes = _group_state_bytes(key_types, acc_specs)
 
         if cfg is not None:
             if self.memory_pool.try_reserve(state_bytes(cfg.capacity),
                                             "group-by"):
                 try:
-                    run = cached_run(("direct", cfg),
-                                     lambda st, kv, kn, inp, v, cfg=cfg:
-                                     hashagg.direct_groupby_insert(
-                                         st, cfg, kv, v, inp, acc_kinds, kn))
-                    state = run(hashagg.direct_groupby_init(
-                        cfg, key_dtypes, acc_specs), los, auxes)
-                    if not bool(state.overflow):
-                        return self._finalize_groups(node, stream, state)
+                    with tracing.maybe_span("aggregate.direct",
+                                            slots=cfg.capacity):
+                        run = cached_run(("direct", cfg),
+                                         lambda st, kv, kn, inp, v, cfg=cfg:
+                                         hashagg.direct_groupby_insert(
+                                             st, cfg, kv, v, inp, acc_kinds, kn))
+                        state = run(hashagg.direct_groupby_init(
+                            cfg, key_dtypes, acc_specs), los, auxes)
+                        if not bool(state.overflow):
+                            return self._finalize_groups(node, stream, state)
+                    tracing.record_groupby(regrows=1)
                 finally:
+                    tracing.record_groupby(
+                        state_bytes=state_bytes(cfg.capacity))
                     self.memory_pool.free(state_bytes(cfg.capacity), "group-by")
             # stale stats / no memory: fall through to hash mode
 
@@ -1682,10 +1707,12 @@ class LocalExecutor:
                              hashagg.groupby_insert(st, kv, key_types, v, inp,
                                                     acc_kinds, kn))
             while True:
-                state = run(hashagg.groupby_init(capacity, key_dtypes,
-                                                 acc_specs), los, auxes)
-                if not bool(state.overflow):
-                    return self._finalize_groups(node, stream, state)
+                with tracing.maybe_span("aggregate.hash", slots=capacity):
+                    state = run(hashagg.groupby_init(capacity, key_dtypes,
+                                                     acc_specs), los, auxes)
+                    if not bool(state.overflow):
+                        return self._finalize_groups(node, stream, state)
+                tracing.record_groupby(regrows=1)
                 grown = capacity * 4
                 delta = state_bytes(grown) - state_bytes(capacity)
                 if grown > MAX_GROUP_CAPACITY or \
@@ -1694,6 +1721,7 @@ class LocalExecutor:
                 resv += delta
                 capacity = grown
         finally:
+            tracing.record_groupby(state_bytes=resv)
             self.memory_pool.free(resv, "group-by")
 
     def _run_percentile_aggregate(self, node: P.Aggregate):
@@ -2166,7 +2194,7 @@ class LocalExecutor:
                 state, _ = jax.lax.scan(body, state, los)
                 return state
 
-            run = _jit(run, donate_argnums=(0,))
+            run = _jit(run, site="agg.global.scan", donate_argnums=(0,))
             if cacheable:
                 self._agg_cache[key] = (node, run)
         state = run(_global_init_state(node), los, auxes)
@@ -2230,22 +2258,17 @@ class LocalExecutor:
         # the hash probe loop entirely (reference: the streaming aggregation
         # operator over pre-grouped input); the dense direct-index path still
         # wins when it applies, so this gates on cfg is None
+        state_bytes = _group_state_bytes(key_types, acc_specs)
         if cfg is None and self._streaming_agg_order(stream, node) is not None:
-            key_w0 = sum(np.dtype(t.dtype).itemsize + 1 for t in key_types)
-            acc_w0 = sum(np.dtype(dt).itemsize for dt, _ in acc_specs)
             return self._run_streaming_aggregate(
                 node, stream, key_types, acc_specs, acc_exprs, acc_kinds,
-                capacity, pages_once,
-                lambda cap, kw=key_w0, aw=acc_w0: (cap + 1) * (8 + kw + aw))
+                capacity, pages_once, state_bytes)
 
         # memory gate: group-by state is device-resident; if it cannot fit the
         # pool, go to partitioned passes (the HBM spill analog).  Reservation is
         # re-checked on every capacity growth.
-        key_w = sum(np.dtype(t.dtype).itemsize + 1 for t in key_types)
-        acc_w = sum(np.dtype(dt).itemsize for dt, _ in acc_specs)
         capacity = ceil_pow2(capacity)  # groupby_init allocates the rounded
         # size; reserving the raw request would under-account by up to 2x
-        state_bytes = lambda cap: (cap + 1) * (8 + key_w + acc_w)
         if cfg is not None and not self.memory_pool.try_reserve(
                 state_bytes(cfg.capacity), "group-by"):
             cfg = None  # direct table too large: try the (smaller) hash table
@@ -2255,9 +2278,10 @@ class LocalExecutor:
                 return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
             resv = {"bytes": state_bytes(capacity)}
 
+        peak = 0  # the largest reservation of this group-by (groupby_state_bytes)
         try:
-            while True:
-                if cfg is not None:
+            if cfg is not None:
+                with tracing.maybe_span("aggregate.direct", slots=cfg.capacity):
                     state = hashagg.direct_groupby_init(
                         cfg, tuple(t.dtype for t in key_types), acc_specs)
                     dstep, bdstep = self._direct_step(node, cfg, stream,
@@ -2269,16 +2293,19 @@ class LocalExecutor:
                             if live is None \
                             else bdstep(state, tuple(group), live, stream.aux)
                     if not bool(state.overflow):
-                        break
-                    # stale stats put keys out of range: hash mode
-                    self.memory_pool.free(resv["bytes"], "group-by")
-                    cfg, resv["bytes"] = None, 0
-                    if not self.memory_pool.try_reserve(state_bytes(capacity),
-                                                        "group-by"):
-                        return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
-                    resv["bytes"] = state_bytes(capacity)
-                    pages_once = stream.pages()
-                    continue
+                        return self._finalize_groups(node, stream, state)
+                # stale stats put keys out of range: hash mode, over the
+                # whole input again
+                tracing.record_groupby(regrows=1)
+                peak = resv["bytes"]
+                self.memory_pool.free(resv["bytes"], "group-by")
+                resv["bytes"] = 0
+                if not self.memory_pool.try_reserve(state_bytes(capacity),
+                                                    "group-by"):
+                    return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
+                resv["bytes"] = state_bytes(capacity)
+                pages_once = stream.pages()
+            with tracing.maybe_span("aggregate.hash", slots=capacity):
                 state = hashagg.groupby_init(
                     capacity, tuple(t.dtype for t in key_types), acc_specs
                 )
@@ -2290,11 +2317,11 @@ class LocalExecutor:
                 # fall back to partitioned passes (the HBM analog of the
                 # reference's SpillableHashAggregationBuilder)
                 if not bool(state.overflow):
-                    break
-                return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
-
-            return self._finalize_groups(node, stream, state)
+                    return self._finalize_groups(node, stream, state)
+            tracing.record_groupby(regrows=1)
+            return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
         finally:
+            tracing.record_groupby(state_bytes=max(peak, resv["bytes"]))
             self.memory_pool.free(resv["bytes"], "group-by")
 
     def _run_hash_inserts(self, node, stream, key_types, acc_exprs, acc_kinds,
@@ -2316,26 +2343,26 @@ class LocalExecutor:
                                for e in acc_exprs)
                 return keys, knulls, inputs, valid, jnp.sum(valid, dtype=jnp.int32)
 
-            @_jit
+            @partial(_jit, site="agg.hash.prepare")
             def prepare(page, aux, stream=stream):
                 return prep_body(*stream.transform(
                     page.columns, page.null_masks, page.valid_mask(), aux))
 
-            @_jit
+            @partial(_jit, site="agg.hash.prepare_batch")
             def bprepare(pages, live, aux, stream=stream):
                 # dispatch coalescing: K uniform pages stack inside the trace
                 # and the whole transform+staging runs as ONE dispatch
                 return prep_body(*stream.transform(
                     *_stack_pages(pages, live), aux))
 
-            @_jit
+            @partial(_jit, site="agg.hash.insert_compact")
             def insert_compact(state, keys, knulls, inputs, n, key_types=key_types,
                                acc_kinds=acc_kinds):
                 valid = jnp.arange(keys[0].shape[0], dtype=jnp.int32) < n
                 return hashagg.groupby_insert(state, keys, key_types, valid, inputs,
                                               acc_kinds, knulls)
 
-            @_jit
+            @partial(_jit, site="agg.hash.insert_masked")
             def insert_masked(state, keys, knulls, inputs, valid,
                               key_types=key_types, acc_kinds=acc_kinds):
                 return hashagg.groupby_insert(state, keys, key_types, valid, inputs,
@@ -2493,12 +2520,12 @@ class LocalExecutor:
                     accs.append(total[seg])  # per-row gather of its segment total
                 return tuple(kcols), tuple(knulls), tuple(accs), new
 
-            @_jit
+            @partial(_jit, site="agg.sorted.step")
             def pstep(page, aux, stream=stream):
                 return pstep_body(*stream.transform(
                     page.columns, page.null_masks, page.valid_mask(), aux))
 
-            @_jit
+            @partial(_jit, site="agg.sorted.batch")
             def bpstep(pages, live, aux, stream=stream):
                 # dispatch coalescing: the stacked group keeps scan row order,
                 # so clustering (group contiguity) holds across the K splits
@@ -2507,7 +2534,7 @@ class LocalExecutor:
                 return pstep_body(*stream.transform(
                     *_stack_pages(pages, live), aux))
 
-            @_jit
+            @partial(_jit, site="agg.sorted.merge")
             def mstep(state, kcols, knulls, accs, new,
                       key_types=key_types, merge_kinds=tuple(merge_kinds)):
                 return hashagg.groupby_insert(
@@ -2527,16 +2554,18 @@ class LocalExecutor:
         try:
             pages = pages_once
             while True:
-                state = hashagg.groupby_init(capacity, key_dtypes, acc_specs)
-                for group, live in _coalesced_batches(pages, self._batch()):
-                    kcols, knulls, accs, new = \
-                        pstep(group[0], stream.aux) if live is None \
-                        else bpstep(tuple(group), live, stream.aux)
-                    state = mstep(state, kcols, knulls, accs, new)
-                if not bool(state.overflow):
-                    return self._finalize_groups(node, stream, state)
+                with tracing.maybe_span("aggregate.sorted", slots=capacity):
+                    state = hashagg.groupby_init(capacity, key_dtypes, acc_specs)
+                    for group, live in _coalesced_batches(pages, self._batch()):
+                        kcols, knulls, accs, new = \
+                            pstep(group[0], stream.aux) if live is None \
+                            else bpstep(tuple(group), live, stream.aux)
+                        state = mstep(state, kcols, knulls, accs, new)
+                    if not bool(state.overflow):
+                        return self._finalize_groups(node, stream, state)
                 # merge-state overflow: grow and re-stream (rare — capacity is
                 # stats-sized upstream like the hash path)
+                tracing.record_groupby(regrows=1)
                 grown = ceil_pow2(capacity * 4)
                 delta = state_bytes(grown) - resv
                 if grown > MAX_GROUP_CAPACITY or \
@@ -2546,6 +2575,7 @@ class LocalExecutor:
                 capacity = grown
                 pages = stream.pages()
         finally:
+            tracing.record_groupby(state_bytes=resv)
             self.memory_pool.free(resv, "group-by")
 
     def _device_finalize(self, node: P.Aggregate):
@@ -2573,6 +2603,7 @@ class LocalExecutor:
         bucket = max(1 << max(n_groups - 1, 1).bit_length(), 64)
         keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
         tracing.record_compaction(state.capacity, bucket)
+        tracing.record_groupby(slots=state.capacity)
         nk = len(keys)
         dicts = tuple(stream.dicts[i] for i in node.keys) + tuple(None for _ in node.aggs)
 
@@ -2621,7 +2652,7 @@ class LocalExecutor:
 
         stream, key_types, acc_specs, acc_exprs, acc_kinds, _ = self._agg_compiled(node)
 
-        @_jit
+        @partial(_jit, site="agg.partitioned.route")
         def route(page, aux, stream=stream, node=node, parts=parts):
             cols, nulls, valid = stream.transform(
                 page.columns, page.null_masks, page.valid_mask(), aux)
@@ -2638,11 +2669,21 @@ class LocalExecutor:
                                   memory_pool=self.memory_pool,
                                   buffer_pool=self.buffer_pool, owner=self)
         try:
-            return self._consume_partitioned_agg(
-                node, stream, spill, parts, key_types, acc_specs, acc_exprs,
-                acc_kinds, route)
+            with tracing.maybe_span("aggregate.partitioned", parts=parts):
+                out = self._consume_partitioned_agg(
+                    node, stream, spill, parts, key_types, acc_specs, acc_exprs,
+                    acc_kinds, route)
         finally:
             spill.close()
+        if out is None:
+            # a partition still blew the ceiling: restart with more partitions
+            # (the one remaining source re-scan); THIS spill's buffers and
+            # reservations are freed first — the restart re-spools the whole
+            # input, and holding both doubles peak spill footprint in the one
+            # path that runs under memory pressure
+            tracing.record_groupby(regrows=1)
+            return self._run_aggregate_partitioned(node, parts * 4)
+        return out
 
     def _consume_partitioned_agg(self, node, stream, spill, parts, key_types,
                                  acc_specs, acc_exprs, acc_kinds, route):
@@ -2654,7 +2695,7 @@ class LocalExecutor:
         st["spill_partitions"] = parts
         st["spill_tiers"] = dict(spill.tier_bytes)
 
-        @_jit
+        @partial(_jit, site="agg.partitioned.insert")
         def insert(state, page, node=node, key_types=key_types,
                    acc_exprs=acc_exprs, acc_kinds=acc_kinds):
             cols, nulls, valid = page.columns, page.null_masks, page.valid_mask()
@@ -2665,6 +2706,7 @@ class LocalExecutor:
             return hashagg.groupby_insert(state, key_vals, key_types, valid,
                                           inputs, acc_kinds, key_nulls)
 
+        largest = 0  # slots of the largest partition table (not pool-reserved)
         pages_out, dicts = [], None
         for p in range(parts):
             # the spill pass counted this partition's rows EXACTLY: seed the
@@ -2693,15 +2735,11 @@ class LocalExecutor:
                         raise MemoryError(
                             f"aggregation exceeds {MAX_GROUP_CAPACITY} groups per "
                             f"partition even at {parts} partitions")
-                    # a partition still blew the ceiling: restart with more
-                    # partitions (the one remaining source re-scan).  Free
-                    # THIS spill's buffers/reservations first — the restart
-                    # re-spools the whole input, and holding both doubles
-                    # peak spill footprint in the one path that runs under
-                    # memory pressure.
-                    spill.close()
-                    return self._run_aggregate_partitioned(node, parts * 4)
+                    return None  # the caller restarts with more partitions
+                tracing.record_groupby(regrows=1)  # replays the partition
                 capacity *= 4
+            largest = max(largest, capacity)
+            tracing.record_groupby(partitioned_passes=1)
             page, dicts = self._finalize_groups(node, stream, state)
             pages_out.append(page)
             # consumed: release this partition's host reservation + disk file
@@ -2715,6 +2753,8 @@ class LocalExecutor:
         for p in pages_out:
             flat.extend(p.columns)
             flat.extend(p.null_masks)
+        tracing.record_groupby(
+            state_bytes=_group_state_bytes(key_types, acc_specs)(largest))
         flat = _host(flat, site="agg.stream.pull")
         w = len(node.schema.fields)
         host_pages = []
@@ -2746,7 +2786,7 @@ class LocalExecutor:
             return self._finish_global(node, stream, acc_exprs, acc_kinds,
                                        hit[1], hit[2])
 
-        @_jit
+        @partial(_jit, site="agg.global.step")
         def step(state, page, aux, stream=stream, acc_exprs=acc_exprs,
                  acc_kinds=acc_kinds):
             cols, nulls, valid = stream.transform(page.columns, page.null_masks,
@@ -2754,7 +2794,7 @@ class LocalExecutor:
             return _global_agg_update(state, cols, nulls, valid, acc_exprs,
                                       acc_kinds)
 
-        @_jit
+        @partial(_jit, site="agg.global.batch")
         def bstep(state, pages, live, aux, stream=stream, acc_exprs=acc_exprs,
                   acc_kinds=acc_kinds):
             # dispatch coalescing: fold a group of uniform pages in ONE
@@ -3072,8 +3112,10 @@ class LocalExecutor:
             span = cached["span"]
             table = cached["table"]
         else:
-            build_has_null, build_nonempty = _build_null_stats(build_page,
-                                                               node.right_keys)
+            build_has_null, build_rows = _build_key_stats(build_page,
+                                                          node.right_keys)
+            build_nonempty = build_rows > 0
+            tracing.record_join_build(build_rows)
             span = self._direct_join_span(build_page, node.right_keys,
                                           build_key_types)
             table = None
@@ -3103,6 +3145,7 @@ class LocalExecutor:
             cols, nulls, valid = up.transform(cols, nulls, valid, up_aux)
             keys = tuple(cols[i] for i in node.left_keys)
             if isinstance(table, DirectJoinTable):
+                table = stage_direct_table(table)
                 row_ids, matched = direct_probe(table, keys[0], valid)
             else:
                 row_ids, matched = probe(table, keys, build_key_types, valid)
@@ -4231,22 +4274,26 @@ def _dynamic_pruned_pages(probe_stream: _Stream, node, build_page: Page):
     return pages, kept
 
 
-def _build_null_stats(build_page: Page, key_channels):
-    """(build_has_null_key, build_nonempty) for null-aware anti joins — device
-    reductions, ONE batched scalar sync (pulling capacity-sized masks to host
-    costs megabytes)."""
+def _build_key_stats(build_page: Page, key_channels):
+    """(build_has_null_key, live build rows) — device reductions, ONE batched
+    scalar sync (pulling capacity-sized masks to host costs megabytes)."""
     if build_page.capacity == 0:
-        return False, False
+        return False, 0
     valid = build_page.valid_mask()
-    stats = [jnp.any(valid)]
+    stats = [jnp.sum(valid, dtype=jnp.int64)]
     for ch in key_channels:
         nm = build_page.null_masks[ch]
         if nm is not None:
             stats.append(jnp.any(nm & valid))
     got = _host(stats, site="join.build.nulls")
-    nonempty = bool(got[0])
     has_null = any(bool(x) for x in got[1:])
-    return has_null, nonempty
+    return has_null, int(got[0])
+
+
+def _build_null_stats(build_page: Page, key_channels):
+    """(build_has_null_key, build_nonempty) for null-aware anti joins."""
+    has_null, rows = _build_key_stats(build_page, key_channels)
+    return has_null, rows > 0
 
 
 def _null_aware_anti(node, anti_valid, nulls, build_has_null, build_nonempty):
@@ -4570,6 +4617,28 @@ def _values_page(node: P.Values) -> Page:
     for ci, f in enumerate(node.schema.fields):
         cols.append(jnp.asarray(np.array([r[ci] for r in node.rows]), f.type.dtype))
     return Page(node.schema, tuple(cols), tuple(None for _ in cols), None)
+
+
+def _group_state_bytes(key_types, acc_specs):
+    """cap -> device bytes of a group-by state of ``cap`` slots (and its sink):
+    the table word, each key with its null flag, each accumulator."""
+    key_w = sum(np.dtype(t.dtype).itemsize + 1 for t in key_types)
+    acc_w = sum(np.dtype(dt).itemsize for dt, _ in acc_specs)
+    return lambda cap: (cap + 1) * (8 + key_w + acc_w)
+
+
+def _split_base_rows(conn, table: str, splits) -> list:
+    """Base rows each split stands for, by the connector's own count (host
+    ints: ``row_count`` shared out over the split ranges; lineitem's ranges
+    are orders, at ``row_count``'s lines an order).  Zeros when the connector
+    does not say."""
+    if not (hasattr(conn, "row_count") and hasattr(conn, "table_bound")) \
+            or not all(hasattr(s, "lo") and hasattr(s, "hi") for s in splits):
+        return [0] * len(splits)
+    bound = max(int(conn.table_bound(table)), 1)
+    per = int(conn.row_count(table)) / bound
+    return [int(max(min(int(s.hi), bound) - int(s.lo), 0) * per)
+            for s in splits]
 
 
 def _page_bytes(page: Page) -> int:
@@ -4937,6 +5006,13 @@ def _narrow_pull_dtype(d):
     return None
 
 
+# a TopN of at most this many rows, over at most this many lane-rounds, is
+# selected by ops/arrays.first_rows and not sorted: a round reads every lane
+# twice a key, so 2^31 lane-rounds stay under a tenth of a second on a v5e
+TOPN_SELECT_MAX = 1024
+TOPN_SELECT_WORK = 1 << 31
+
+
 def _sort_page_device(page: Page, keys, dicts=None):
     """Device-side FULL sort: lexsort on device, then pull exactly the live
     rows — no dead lanes or pow2 padding, no validity mask (every fetched row
@@ -4981,12 +5057,14 @@ def _topn_page_device(page: Page, keys, count, dicts=None):
         if not k.ascending:
             c = ~c if jnp.issubdtype(c.dtype, jnp.integer) else -c
         lex.append(c)
-        # null placement outranks the value ordering for this key
-        ind = jnp.zeros(c.shape, jnp.int8) if nm is None \
-            else nm.astype(jnp.int8)
-        lex.append(-ind if k.nulls_first else ind)
+        if nm is not None:
+            # null placement outranks the value ordering for this key (a key
+            # without a mask has a constant indicator, which moves no row)
+            ind = nm.astype(jnp.int8)
+            lex.append(-ind if k.nulls_first else ind)
     valid = page.valid_mask()
-    lex.append(~valid)  # invalid lanes last — top-count rows are live ones
+    if page.valid is not None:
+        lex.append(~valid)  # invalid lanes last — top-count rows are live ones
     # count=None (full device sort): fetch exactly the live rows.  The live
     # count syncs through _host (counted, batched-API) and only AFTER every
     # rankability check above — a fallback to the host path must not pay a
@@ -4995,7 +5073,13 @@ def _topn_page_device(page: Page, keys, count, dicts=None):
     if all_live:
         count = int(_host([jnp.sum(valid, dtype=jnp.int64)],
                           site="sort.count")[0])
-    idx = jnp.lexsort(tuple(lex))[:count]
+    n = page.capacity
+    if not all_live and count <= TOPN_SELECT_MAX \
+            and count * n <= TOPN_SELECT_WORK \
+            and not any(jnp.issubdtype(c.dtype, jnp.floating) for c in lex):
+        idx = first_rows(tuple(lex), min(count, n))
+    else:
+        idx = jnp.lexsort(tuple(lex))[:count]
     nc = len(page.columns)
     # transfer-narrow dictionary-id columns (id bound known from the dict, no
     # sync); the schema dtype is restored host-side after the pull, so only

@@ -254,6 +254,17 @@ class DeviceBufferPool:
                 pass
         return max(self.result_budget() // 4, 1)
 
+    def page_entry_cap(self) -> int:
+        """Per-entry admission cap for scan pages, as the result tier has one:
+        a quarter of the budget.  An entry is one whole scan of one column
+        set, and several statements' column sets of one large table each fit
+        the budget alone but not together (TPC-H SF10 lineitem: 3.1 GB for
+        q3's four columns, 1.8 GB for q18's two, of 4.2 GB), so each would
+        evict the other's, the dimension scans and the join builds that every
+        statement shares, and a statement would meet another page shape every
+        time it ran.  A scan over the cap streams split by split, always."""
+        return max(self.budget() // 4, 1)
+
     @staticmethod
     def cacheable(conn) -> bool:
         """Only connectors whose page generation is deterministic for a given
@@ -333,6 +344,8 @@ class DeviceBufferPool:
         if faults.maybe_inject("cache_store", f"page.{key[2]}") == "deny":
             return False
         nbytes = _page_nbytes(page)
+        if nbytes > self.page_entry_cap():
+            return False
         return self._store(key, _Entry("page", key[1], key[2], page, nbytes),
                            self.PAGE_TAG)
 

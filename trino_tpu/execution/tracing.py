@@ -247,6 +247,20 @@ class QueryCounters:
     compactions: int = 0
     compact_lanes_in: int = 0
     compact_lanes_out: int = 0
+    # PR 27: how each group-by was sized and what it cost, host ints recorded
+    # where the executor decides (no sync): slots of the final state and the
+    # largest reservation of every group-by of the statement, overflows that
+    # cost a re-scan of the input (an in-loop rehash that replays one chunk is
+    # not one), Grace passes; rows inserted into join build tables when a
+    # stream is compiled (0 on a replay: the tables live in the stream) and
+    # base rows the connector generated for the statement (a scan served from
+    # a resident page generates none)
+    groupby_slots: int = 0
+    groupby_state_bytes: int = 0
+    groupby_regrows: int = 0
+    groupby_partitioned_passes: int = 0
+    join_build_rows: int = 0
+    rows_generated: int = 0
     # PR 25: the statement's wait states, seconds (each also a span of the
     # same name family: server.queued, batcher.wait, executor.checkout,
     # server.encode, server.deliver), recorded where the wait happens, and
@@ -304,7 +318,10 @@ class QueryCounters:
                    "plan_template_hits", "plan_template_misses",
                    "compiles", "adaptive_replans", "adaptive_holds",
                    "batched_requests", "compile_cache_misses",
-                   "compactions", "compact_lanes_in", "compact_lanes_out")
+                   "compactions", "compact_lanes_in", "compact_lanes_out",
+                   "groupby_slots", "groupby_state_bytes", "groupby_regrows",
+                   "groupby_partitioned_passes", "join_build_rows",
+                   "rows_generated")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
                      "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
@@ -545,6 +562,28 @@ def record_compaction(lanes_in: int, lanes_out: int) -> None:
         c.compactions += 1
         c.compact_lanes_in += lanes_in
         c.compact_lanes_out += lanes_out
+
+
+def record_groupby(slots: int = 0, state_bytes: int = 0, regrows: int = 0,
+                   partitioned_passes: int = 0) -> None:
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.groupby_slots += slots
+        c.groupby_state_bytes += state_bytes
+        c.groupby_regrows += regrows
+        c.groupby_partitioned_passes += partitioned_passes
+
+
+def record_join_build(rows: int) -> None:
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.join_build_rows += rows
+
+
+def record_rows_generated(rows: int) -> None:
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.rows_generated += rows
 
 
 def _attribute_extra(site: Optional[str], **extras) -> None:

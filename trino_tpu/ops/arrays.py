@@ -17,7 +17,9 @@ SQL-surface scale arrays run at (the columnar hot path stays span-only).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -152,6 +154,35 @@ def live_indices(valid, size: int):
     if size > n:
         idx = jnp.concatenate([idx, jnp.full((size - n,), n - 1, jnp.int32)])
     return idx, jnp.sum(valid, dtype=jnp.int32)
+
+
+@partial(jax.jit, static_argnums=(1,))  # compile-ok: module-level kernel; one program a (key dtypes, lanes, count)
+def first_rows(keys, count: int):
+    """Lane numbers of the first ``count`` rows in the order of
+    ``jnp.lexsort(keys)`` (last key most significant, ties in lane order), by
+    ``count`` rounds of a lexicographic arg-min: reductions only, no sort.  For
+    the TopN of a few rows: the TPU compiler takes one to two minutes over a
+    sort that carries a payload (126 s for the five-key lexsort of a cold
+    TPC-H SF10 q3's 113,513 groups, 30-60 s for one ``argsort``; PERF.md PR
+    27), and every new lane count is a new program.  Keys are integers or
+    bools (a NaN never equals the minimum it poisons)."""
+    n = keys[0].shape[0]
+    lanes = lax.iota(jnp.int32, n)
+    ordered = tuple(k.astype(jnp.int8) if k.dtype == bool else k
+                    for k in reversed(keys))
+
+    def pick(i, carry):
+        alive, out = carry
+        cand = alive
+        for k in ordered:
+            least = jnp.min(jnp.where(cand, k, jnp.iinfo(k.dtype).max))
+            cand = cand & (k == least)
+        j = jnp.min(jnp.where(cand, lanes, n))
+        return alive & (lanes != j), out.at[i].set(j)
+
+    _, out = lax.fori_loop(
+        0, count, pick, (jnp.ones((n,), bool), jnp.zeros((count,), jnp.int32)))
+    return out
 
 
 def gather_rows(a, idx):

@@ -205,6 +205,35 @@ def direct_build(lo: int, span: int, build_page, key_channel: int) -> DirectJoin
                            dup, lo)
 
 
+STAGE_SLOTS_MIN = 1 << 22  # direct tables of this many slots or more are staged
+
+
+def stage_direct_table(dt: DirectJoinTable) -> DirectJoinTable:
+    """A large direct table's arrays copied inside the program that probes it.
+
+    A gather straight from a long-lived HBM argument costs about 14.5 ns an
+    element on a v5e and runs at speed levels that follow where the allocator
+    happened to put the argument: q3 at SF10 sat at 8.0, 9.0, 9.2 or 11.5 s for
+    the life of a process, and re-placing the build arrays moved it (PERF.md,
+    PR 27).  The compiler prefetches most gather operands into its fast memory
+    space, but not every one (q3's probe step: five of six; q18's: seven of
+    fourteen).  A copy made in the program is a temporary whose place the
+    compiler assigns, the fast space where it fits, so the probes no longer
+    read what the heap's history placed: q3 6.90-6.94 s over nine placements.
+    The barriers keep the copy from being folded away or fused into the
+    gather.  A copy is one sequential pass (under a millisecond for 60 MB).
+    Tables below STAGE_SLOTS_MIN keep their programs as they were."""
+    if dt.occ.shape[0] < STAGE_SLOTS_MIN:
+        return dt
+
+    def stage(a):
+        zero = jax.lax.optimization_barrier(jnp.zeros((), a.dtype))
+        return jax.lax.optimization_barrier(
+            a | zero if a.dtype == jnp.bool_ else a + zero)
+
+    return jax.tree_util.tree_map(stage, dt)
+
+
 def direct_probe(dt: DirectJoinTable, key_col, valid):
     """(build_row_ids, matched) — one gather, no rounds."""
     span = dt.occ.shape[0] - 1

@@ -780,7 +780,19 @@ class CoordinatorServer:
                      "(live-lane index, then gathers)."),
                     ("compact_lanes_in", "Lanes read by row compactions."),
                     ("compact_lanes_out", "Lanes kept by row compactions "
-                     "(their output buckets).")):
+                     "(their output buckets)."),
+                    ("groupby_slots", "Slots of the group-by states that "
+                     "were finalized."),
+                    ("groupby_state_bytes", "Largest device reservation of "
+                     "each group-by, summed."),
+                    ("groupby_regrows", "Group-by overflows that cost a "
+                     "re-scan of the input."),
+                    ("groupby_partitioned_passes", "Grace passes of "
+                     "partitioned group-bys."),
+                    ("join_build_rows", "Rows inserted into join build "
+                     "tables (0 when a replay reuses its streams)."),
+                    ("rows_generated", "Base-table rows the connectors "
+                     "generated (resident pages generate none).")):
                 lines += [f"# HELP trino_tpu_{field}_total {what}",
                           f"# TYPE trino_tpu_{field}_total counter",
                           f"trino_tpu_{field}_total {getattr(ct, field, 0)}"]

@@ -88,6 +88,21 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"Compaction: {cp} compactions, "
                 f"{getattr(counters, 'compact_lanes_in', 0)} lanes in, "
                 f"{getattr(counters, 'compact_lanes_out', 0)} lanes out")
+        gs = getattr(counters, "groupby_slots", 0)
+        if gs:
+            # how the statement's group-bys were sized (PR 27): slots of the
+            # final states, the largest reservations, overflows that cost a
+            # re-scan, Grace passes
+            lines.append(
+                f"Group-by: {gs} slots, "
+                f"{getattr(counters, 'groupby_state_bytes', 0)} state bytes, "
+                f"{getattr(counters, 'groupby_regrows', 0)} regrows, "
+                f"{getattr(counters, 'groupby_partitioned_passes', 0)} "
+                "partitioned passes")
+        rg = getattr(counters, "rows_generated", 0)
+        jb = getattr(counters, "join_build_rows", 0)
+        if rg or jb:
+            lines.append(f"Scan: {rg} rows generated, {jb} join build rows")
         sp = getattr(counters, "spilled_bytes", 0)
         aq = getattr(counters, "admission_queued", 0)
         if sp or aq:
