@@ -141,11 +141,21 @@ def _lowered_ops(text):
 
 
 @pytest.mark.parametrize("site", ["jc_fn", "_compact_part",
-                                  "_compact_part_sized", "compact_groups"])
-def test_compaction_programs_hold_no_scatter(site):
+                                  "_compact_part_sized", "compact_groups",
+                                  "join.match"])
+def test_compaction_programs_hold_no_scatter(site, tpch_sf001):
     """A later edit or a JAX upgrade that brings a scatter (or a
     ``jnp.nonzero``, whose ``bincount`` is a scatter-add) back into a
-    compaction program fails here, not in a benchmark."""
+    compaction program fails here, not in a benchmark.  The match step of a
+    split join (PR 28) runs before every boundary's pack: a filter, one
+    gather, no sort and no scatter."""
+    if site == "join.match":
+        from test_split_join import lowered_steps
+
+        ops = _lowered_ops(lowered_steps(tpch_sf001)[site])
+        assert "gather" in ops and "sort" not in ops, ops
+        assert not [op for op in ops if "scatter" in op], ops
+        return
     n, size = 4096, 256
     i64 = jax.ShapeDtypeStruct((n,), jnp.int64)
     f64 = jax.ShapeDtypeStruct((n,), jnp.float64)

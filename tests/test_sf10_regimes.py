@@ -76,8 +76,8 @@ def _regime_staged(monkeypatch):
     monkeypatch.setattr(hashjoin, "STAGE_SLOTS_MIN", 1)  # SF10: orders' 15M slots pass 2^22
     staged = []
 
-    def counting(table):
-        out = hashjoin.stage_direct_table(table)
+    def counting(table, fields=None):
+        out = hashjoin.stage_direct_table(table, fields)
         staged.append(out is not table)
         return out
 

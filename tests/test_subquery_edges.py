@@ -154,7 +154,9 @@ def test_dynamic_filter_split_pruning(tpch_sf001, monkeypatch):
     assert n_splits > 10
     r = e.execute_sql("select count(*) c from lineitem where l_orderkey in "
                       "(select o_orderkey from orders where o_orderkey < 100)")
-    assert calls["n"] <= 2
+    # the kept splits, plus ONE page for the split join's first-page sample
+    # (PR 28: generated once as the join's stream is compiled)
+    assert calls["n"] <= 3
     r2 = e.execute_sql("select count(*) c from lineitem, orders "
                        "where l_orderkey = o_orderkey and o_orderkey < 100")
     assert r.columns[0][0] == r2.columns[0][0] > 0

@@ -845,22 +845,25 @@ def run_stream_splits(local: LocalExecutor, node, exchange_dir: str,
         jitted = stream.jitted()
         parts = []
         for split in splits:
-            page = si.conn.generate(split, list(si.scan_columns))
-            cols, nulls, valid = jitted(page)
-            got = _host([valid] + list(cols)
-                        + [n for n in nulls if n is not None],
-                        site="fte.stream.split")
-            v = got[0]
-            ncols = len(cols)
-            ccols = [c[v] for c in got[1:1 + ncols]]
-            rest = got[1 + ncols:]
-            cnulls = []
-            for n in nulls:
-                cnulls.append(None if n is None else rest.pop(0)[v])
-            if sink is not None:
-                sink(serialize_fragment_output(ccols, cnulls, stream.dicts))
-            else:
-                parts.append((ccols, cnulls))
+            # one split at a time THROUGH whatever sits between the scan and
+            # the stream's pages (a split join's match step and pack)
+            raw = si.conn.generate(split, list(si.scan_columns))
+            for page in si.pages_over(lambda raw=raw: iter((raw,)))():
+                cols, nulls, valid = jitted(page)
+                got = _host([valid] + list(cols)
+                            + [n for n in nulls if n is not None],
+                            site="fte.stream.split")
+                v = got[0]
+                ncols = len(cols)
+                ccols = [c[v] for c in got[1:1 + ncols]]
+                rest = got[1 + ncols:]
+                cnulls = []
+                for n in nulls:
+                    cnulls.append(None if n is None else rest.pop(0)[v])
+                if sink is not None:
+                    sink(serialize_fragment_output(ccols, cnulls, stream.dicts))
+                else:
+                    parts.append((ccols, cnulls))
             if tick is not None:
                 tick()  # split-boundary preemption point (fair scheduler)
         dicts = stream.dicts

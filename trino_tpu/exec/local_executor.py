@@ -34,11 +34,12 @@ from ..ops.hashing import ceil_pow2
 from ..ops.hashjoin import (DIRECT_JOIN_RANGE_MAX, DirectJoinTable,
                             DirectMultiJoinTable, JoinTable, MultiJoinTable,
                             build_insert, build_table_init, direct_build,
-                            direct_multi_build, direct_probe, direct_probe_slots,
+                            direct_match, direct_multi_build, direct_probe,
+                            direct_probe_slots,
                             expand_counts, multi_build, probe, probe_slots,
-                            stage_direct_table)
+                            stage_direct_table, GATHER_FIELDS, MATCH_FIELDS)
 from ..page import Field, Page, Schema
-from ..types import BIGINT, DOUBLE, BOOLEAN, DecimalType, Type
+from ..types import BIGINT, DOUBLE, BOOLEAN, INTEGER, DecimalType, Type
 from ..sql import plan as P
 from ..sql.ir import Call, Constant, Expr, FieldRef, evaluate, evaluate_predicate
 
@@ -425,10 +426,15 @@ class _ScanInfo:
     catalog: str = ""  # catalog/table identity: split-pruning replacements
     table: str = ""  # rebuild their page source through the executor's
     # cache-aware _scan_pages_source, which keys the buffer pool on them
-    replayable: bool = True  # False once a boundary (compaction) transformed the
-    # pages: column metadata stays valid for stats/ranges, but pruning must NOT
-    # rebuild pages from the splits (the downstream chain expects the
-    # transformed layout, not raw scan pages)
+    over: Optional[Callable] = None  # set once a boundary (a split join's
+    # compaction) sits between the scan and the stream's ``pages``: maps a raw
+    # page source over another split list to the stream's pages over it, so
+    # split pruning above the boundary still rebuilds the scan underneath it
+
+    def pages_over(self, raw_pages: Callable) -> Callable:
+        """The owning stream's page source rebuilt over ``raw_pages`` (a
+        pruned split list of the same scan)."""
+        return raw_pages if self.over is None else self.over(raw_pages)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -471,9 +477,11 @@ class _Stream:
     # no cross-group order promise).  Filters/projects/compaction preserve
     # row order, so the flag survives them; joins clear it.  Gates the
     # streaming aggregation, which needs exactly group contiguity.
-    compacted: bool = False  # a compaction boundary already shrank this chain's
-    # lanes to ~its estimated rows; a second boundary would pay materialization
-    # for no further reduction
+    compacted: bool = False  # the chain's first unique-key inner/semi join
+    # has decided where its compaction boundary sits: between that join's
+    # match and gather steps (_compacted_stream), or nowhere because its first
+    # page stayed dense.  Later joins of the chain run fused at the width
+    # that left
     traced_src: Optional[_TracedSrc] = None  # on-device regenerable provenance
     _jitted: Callable = None  # cached jit of transform applied to a Page
     _batch_jitted: Callable = None  # cached jit of transform over a STACKED
@@ -667,8 +675,15 @@ class LocalExecutor:
         # sweep and free via forget/GC.
         self._spills: list = []
 
-    def _batch(self) -> int:
-        """Effective dispatch-coalescing width (>=1; 1 = per-split)."""
+    def _batch(self, stream=None) -> int:
+        """Effective dispatch-coalescing width (>=1; 1 = per-split) for the
+        consumers of ``stream``'s pages.  Behind a split join's boundary
+        (``scan_info.over``) every page already is one coalesced group,
+        packed or dense: stacking K of those again would cost K times the
+        lanes for no saved launch, so their consumers take them singly."""
+        if stream is not None and stream.scan_info is not None \
+                and stream.scan_info.over is not None:
+            return 1
         b = self.dispatch_batch
         if b is None or int(b) <= 0:
             return _dispatch_batch_default()
@@ -1175,65 +1190,77 @@ class LocalExecutor:
             return page, dicts
         # streaming leaf reached directly (scan/filter/project/join-probe): materialize
         stream = self._compile_stream(node)
-        page = _concat_stream(stream, self._batch())
+        page = _concat_stream(stream, self._batch(stream))
         self._record(node, page, t0)
         return page, stream.dicts
 
     # -- page compaction at pipeline boundaries ------------------------------
-    def _compactable_fraction(self, node) -> bool:
-        """Should this streaming subtree's output be compacted before an
-        expensive consumer?  Gate on the CBO's estimated surviving fraction of
-        the scan's lanes (<= 1/8): compaction breaks operator fusion and
-        materializes the boundary, so it must only fire when the lane
-        reduction dwarfs that cost — a runtime-adaptive gate was measured to
-        2.5x-regress dense streams (Q3) via zero-reduction pipeline breaks."""
-        cur = node
-        while isinstance(cur, (P.Project, P.Filter)):
-            cur = cur.child
-        if not isinstance(cur, P.Join) or cur.est_rows is None:
-            return False
-        scan = cur
-        while not isinstance(scan, P.TableScan):
-            if isinstance(scan, P.Join):
-                scan = scan.left
-            elif isinstance(scan, (P.Project, P.Filter)):
-                scan = scan.child
-            else:
-                return False
-        conn = self.catalogs.get(scan.catalog)
-        if conn is None or not hasattr(conn, "row_count"):
-            return False
-        rows = float(conn.row_count(scan.table))
-        return float(cur.est_rows) <= rows / 8.0
+    def _compacted_stream(self, up: _Stream) -> Optional[_Stream]:
+        """The compaction boundary INSIDE a split join (_join_with_build): a
+        selective probe leaves most lanes invalid, and the fixed-shape fusion
+        model would drag every dead lane through the join's build-column
+        gathers and every later probe and insert.  ``up`` is the join's match
+        step (the upstream chain, then only what decides ``matched``).  Per
+        batch: run it as its own program, read the surviving-row count (one
+        4-byte pull), and pack the live rows into the smallest quantized
+        bucket (n/4, n/16, n/64) that holds them.  Buckets are pow2-quantized
+        so the downstream pipeline compiles at most a handful of shape
+        classes, and a batch that stays dense flows through untouched: the
+        width is decided by what each batch holds, not by an estimate.
+        Returns None when the boundary would not pack the scan's FIRST page
+        (one page through the match step as the stream is compiled): a join
+        that matches every lane gains nothing from a materialised boundary,
+        and split in two its gathers met a worse placement than fused (q18 at
+        SF10: 4-11 % slower, PERF.md PR 28), so the caller compiles it as one
+        step, as it always was.
+        Reference: operators emit DENSE pages after selective filters
+        (FilterAndProjectOperator) — compaction is where the reference gets
+        its selectivity win, re-planned for static shapes."""
+        from ..sql import ir as _ir
 
-    def _compacted_stream(self, up: _Stream) -> _Stream:
-        """Adaptive page compaction at a pipeline boundary (join probe, agg
-        input): upstream filters/selective joins leave most lanes invalid, but
-        the fixed-shape fusion model would drag every dead lane through all
-        downstream probes/inserts.  Per batch: run the upstream chain, read the
-        surviving-row count (one scalar sync), and gather valid rows into the
-        smallest quantized bucket (n/4, n/16, n/64) that holds them.  Buckets
-        are pow2-quantized so the downstream pipeline compiles at most a
-        handful of shape classes, and a batch that stays dense flows through
-        untouched.  Reference: operators emit DENSE pages after selective
-        filters (FilterAndProjectOperator) — compaction is where the reference
-        gets its selectivity win, re-planned for static shapes."""
         compact_jits: dict = {}
 
-        def pages(up=up, self=self):
-            run = up.jitted()
-            batch = self._batch()
-            brun = up.jitted_batch() if batch > 1 else None
-            for group, live in _coalesced_batches(up.pages(), batch):
-                cols, nulls, valid = run(group[0]) if live is None \
-                    else brun(group, live)
+        def counted(cols, nulls, valid):
+            return cols, nulls, valid, jnp.sum(valid, dtype=jnp.int32)
+
+        @partial(_jit, site="join.match")
+        def match(page, aux, params, up=up):
+            with _ir.bind_params(params):  # same contract as _Stream.jitted()
+                return counted(*up.transform(
+                    page.columns, page.null_masks, page.valid_mask(), aux))
+
+        @partial(_jit, site="join.match_batch")
+        def bmatch(pages, live, aux, params, up=up):
+            with _ir.bind_params(params):
+                return counted(*up.transform(*_stack_pages(pages, live), aux))
+
+        si = up.scan_info
+        if si.splits:  # (generated here, not pulled through the prefetcher)
+            page = si.conn.generate(si.splits[0], list(si.scan_columns))
+            if _page_batch_sig(page) is not None:
+                count = match(page, up.aux, _current_params())[3]
+                if int(_host([count], site="join.match.sample")[0]) \
+                        > page.capacity >> 2:
+                    return None
+
+        def pages(source=up.pages, up=up, self=self):
+            for group, live in _coalesced_batches(source(), self._batch()):
+                if live is None and _page_batch_sig(group[0]) is None:
+                    # an exact wide-decimal (object) column runs eagerly, an
+                    # empty page has nothing to pack
+                    yield Page(up.schema, *up.jitted()(group[0]))
+                    continue
+                cols, nulls, valid, count = \
+                    match(group[0], up.aux, _current_params()) if live is None \
+                    else bmatch(tuple(group), live, up.aux, _current_params())
                 n = int(valid.shape[0])
-                count = int(jnp.sum(valid))
+                count = int(_host([count], site="join.match.count")[0])
                 bucket = n
                 for sh in (6, 4, 2):  # smallest sufficient bucket wins
                     if count <= (n >> sh):
                         bucket = max(n >> sh, 1)
                         break
+                tracing.record_join_probe(n, bucket)
                 if bucket >= n:
                     yield Page(up.schema, cols, nulls, valid)
                     continue
@@ -1246,12 +1273,13 @@ class LocalExecutor:
                 tracing.record_compaction(n, bucket)
                 yield Page(up.schema, ccols, cnulls, cvalid)
 
-        si = up.scan_info
-        if si is not None:
-            si = dataclasses.replace(si, replayable=False)
+        # (no boundary sits under this one: only a chain that is not yet
+        # ``compacted`` gets one, so ``up.scan_info.over`` is None)
+        si = dataclasses.replace(si, over=lambda raw: partial(pages, raw))
         # compaction only re-packs live lanes — semantically a no-op for any
         # mask-respecting consumer — so traced regeneration stays valid: the
-        # upstream chain becomes a prior stage applied to raw pages
+        # match step becomes a prior stage applied to raw pages, without the
+        # pack
         tsrc = up.traced_src
         if tsrc is not None:
             tsrc = dataclasses.replace(tsrc, stages=tsrc.stages + (up,))
@@ -1346,10 +1374,9 @@ class LocalExecutor:
                 # prefetch / coalescing double buffer) AND stays buffer-pool
                 # aware — the pruned split list keys its own cache entry
                 psi = pruned[1]
-                pruned = (self._scan_pages_source(psi.conn, psi.catalog,
-                                                  psi.table, psi.splits,
-                                                  psi.scan_columns),
-                          psi)
+                pruned = (psi.pages_over(self._scan_pages_source(
+                    psi.conn, psi.catalog, psi.table, psi.splits,
+                    psi.scan_columns)), psi)
             pages, si = pruned if pruned is not None else (up.pages, up.scan_info)
             tsrc = up.traced_src
             if pruned is not None and tsrc is not None:
@@ -1363,7 +1390,7 @@ class LocalExecutor:
             # the pruned scan info when static pruning fired).
             rt = self._param_pruned_source(up, pred, si)
             if rt is not None:
-                pages = rt
+                pages = si.pages_over(rt)
                 tsrc = None  # split set varies per binding: no
                 # whole-scan traced regeneration
             return _Stream(up.schema, up.dicts, pages, transform, si, aux=up.aux,
@@ -1741,7 +1768,7 @@ class LocalExecutor:
                 raise NotImplementedError(
                     f"{s.kind} argument must be a plain column")
         stream = self._compile_stream(node.child)
-        page = _concat_stream(stream, self._batch())
+        page = _concat_stream(stream, self._batch(stream))
         n = page.capacity
         key_chs = list(node.keys)
         if n == 0:
@@ -2287,8 +2314,8 @@ class LocalExecutor:
                     dstep, bdstep = self._direct_step(node, cfg, stream,
                                                       key_types, acc_exprs,
                                                       acc_kinds)
-                    for group, live in _coalesced_batches(pages_once,
-                                                          self._batch()):
+                    for group, live in _coalesced_batches(
+                            pages_once, self._batch(stream)):
                         state = dstep(state, group[0], stream.aux) \
                             if live is None \
                             else bdstep(state, tuple(group), live, stream.aux)
@@ -2425,7 +2452,8 @@ class LocalExecutor:
                 resv["bytes"] += delta
                 state = hashagg.rehash(start_state, grown, tuple(acc_kinds))
 
-        for group, live in _coalesced_batches(pages_iter, self._batch()):
+        for group, live in _coalesced_batches(pages_iter,
+                                               self._batch(stream)):
             staged.append(prepare(group[0], stream.aux) if live is None
                           else bprepare(tuple(group), live, stream.aux))
             if len(staged) >= 4:
@@ -2556,7 +2584,8 @@ class LocalExecutor:
             while True:
                 with tracing.maybe_span("aggregate.sorted", slots=capacity):
                     state = hashagg.groupby_init(capacity, key_dtypes, acc_specs)
-                    for group, live in _coalesced_batches(pages, self._batch()):
+                    for group, live in _coalesced_batches(pages,
+                                                          self._batch(stream)):
                         kcols, knulls, accs, new = \
                             pstep(group[0], stream.aux) if live is None \
                             else bpstep(tuple(group), live, stream.aux)
@@ -2811,7 +2840,8 @@ class LocalExecutor:
 
     def _finish_global(self, node, stream, acc_exprs, acc_kinds, step, bstep):
         state = _global_init_state(node)
-        for group, live in _coalesced_batches(stream.pages(), self._batch()):
+        for group, live in _coalesced_batches(stream.pages(),
+                                               self._batch(stream)):
             page = group[0]
             if live is not None:
                 state = bstep(state, tuple(group), live, stream.aux)
@@ -2890,7 +2920,7 @@ class LocalExecutor:
         if len(node.right_keys) != 1:
             return None
         si = probe_stream.scan_info
-        if si is None or not si.replayable or not si.splits \
+        if si is None or not si.splits \
                 or not hasattr(si.splits[0], "table"):
             return None
         conn = si.conn
@@ -2956,7 +2986,7 @@ class LocalExecutor:
 
         st = self._node_stats(node)
         st["index_join_keys"] = len(keys)
-        repl = {"pages": pages,
+        repl = {"pages": si.pages_over(pages),
                 "scan_info": dataclasses.replace(si, splits=list(new_splits))}
         if probe_stream.traced_src is not None:
             repl["traced_src"] = None  # handle scans are host-fed
@@ -3053,9 +3083,9 @@ class LocalExecutor:
                     # compiled with (round-6 double buffer / HOST_DECODE
                     # decode overlap) and the kept split list keys its own
                     # buffer-pool entry
-                    pages_fn = self._scan_pages_source(
+                    pages_fn = psi.pages_over(self._scan_pages_source(
                         psi.conn, psi.catalog, psi.table, kept,
-                        psi.scan_columns)
+                        psi.scan_columns))
                 repl = {"pages": pages_fn, "_jitted": None,
                         "_batch_jitted": None}
                 if probe_stream.scan_info is not None:
@@ -3065,11 +3095,6 @@ class LocalExecutor:
                     repl["traced_src"] = dataclasses.replace(
                         probe_stream.traced_src, splits=tuple(kept))
                 probe_stream = dataclasses.replace(probe_stream, **repl)
-        if not probe_stream.compacted and self._compactable_fraction(node.left):
-            # probe cost scales with LANES: don't drag dead rows from upstream
-            # filters/joins through this join's probe rounds
-            probe_stream = self._compacted_stream(probe_stream)
-
         # memory gate: build-side state (columns + table/order layout) is
         # device-resident and pinned by the stream cache.  When it cannot fit the
         # pool, switch to the Grace-partitioned strategy (the HBM analog of the
@@ -3140,18 +3165,89 @@ class LocalExecutor:
             return self._compile_multi_join(node, build_page, build_dicts, probe_stream,
                                             build_key_types, span)
 
-        def transform(cols, nulls, valid, aux, up=probe_stream, node=node):
+        def probe_keys(cols, nulls, valid, aux, up=probe_stream, node=node):
             up_aux, table = aux
             cols, nulls, valid = up.transform(cols, nulls, valid, up_aux)
-            keys = tuple(cols[i] for i in node.left_keys)
+            return cols, nulls, valid, tuple(cols[i] for i in node.left_keys), table
+
+        def non_null_keys(matched, nulls, node=node):
+            for i in node.left_keys:  # NULL keys never match (SQL equi-join semantics)
+                if nulls[i] is not None:
+                    matched = matched & ~nulls[i]
+            return matched
+
+        dicts = (probe_stream.dicts + (None,) if node.kind == "mark"
+                 else probe_stream.dicts if semi
+                 else probe_stream.dicts + build_dicts)
+        # propagate probe-side scan provenance: downstream aggregations use it for
+        # row-bound table sizing, and further joins for dynamic split pruning
+        si = probe_stream.scan_info
+        if si is not None:
+            n_build = (1 if node.kind == "mark"
+                       else 0 if semi else len(build_page.columns))
+            si = dataclasses.replace(
+                si, columns=tuple(si.columns) + (None,) * n_build)
+
+        decided = probe_stream.compacted
+        if node.kind in ("inner", "semi") and si is not None and not decided:
+            # a probe stream still at scan width: match first, gather after.
+            # The match step is its own program: the upstream chain, then only
+            # what decides ``matched`` (for a direct table the one gather of
+            # ``occ``, carrying the slot; for a hashed one the probe loop,
+            # carrying the build row).  The boundary packs each batch to what
+            # survived (_compacted_stream); the gather step is the transform
+            # of the returned stream, so it fuses into whatever consumes it,
+            # and the chain's later joins run fused at the width the boundary
+            # left.  left/anti/mark joins keep their unmatched lanes and stay
+            # one step; so does a join whose first page the boundary would not
+            # pack (below).
+            def match_step(cols, nulls, valid, aux, node=node):
+                cols, nulls, valid, keys, table = probe_keys(cols, nulls, valid, aux)
+                if isinstance(table, DirectJoinTable):
+                    carry, matched = direct_match(
+                        stage_direct_table(table, MATCH_FIELDS), keys[0], valid)
+                else:
+                    carry, matched = probe(table, keys, build_key_types, valid)
+                valid = valid & non_null_keys(matched, nulls)
+                if node.kind == "semi":
+                    return cols, nulls, valid
+                return tuple(cols) + (carry,), tuple(nulls) + (None,), valid
+
+            def gather_step(cols, nulls, valid, table, node=node):
+                carry = cols[-1]
+                if isinstance(table, DirectJoinTable):
+                    table = stage_direct_table(table, GATHER_FIELDS)
+                    carry = table.rows[carry]
+                bcols, bnulls = _gather_build(table, carry, valid, node.kind)
+                return cols[:-1] + bcols, nulls[:-1] + bnulls, valid
+
+            mschema = probe_stream.schema if semi else Schema(
+                probe_stream.schema.fields + (Field("$carry", INTEGER),))
+            mdicts = probe_stream.dicts if semi else probe_stream.dicts + (None,)
+            packed = self._compacted_stream(_Stream(
+                mschema, mdicts, probe_stream.pages, match_step,
+                probe_stream.scan_info, aux=(probe_stream.aux, table),
+                traced_src=probe_stream.traced_src))
+            if packed is not None:
+                si = dataclasses.replace(si, over=packed.scan_info.over)
+                if semi:
+                    return dataclasses.replace(packed, schema=node.schema,
+                                               dicts=dicts, scan_info=si)
+                return dataclasses.replace(
+                    packed, schema=node.schema, dicts=dicts, scan_info=si,
+                    transform=gather_step, aux=table)
+            # the first page stayed dense: this chain has no selective join
+            # here, and runs fused as one step, its later joins too
+            decided = True
+
+        def transform(cols, nulls, valid, aux, node=node):
+            cols, nulls, valid, keys, table = probe_keys(cols, nulls, valid, aux)
             if isinstance(table, DirectJoinTable):
                 table = stage_direct_table(table)
                 row_ids, matched = direct_probe(table, keys[0], valid)
             else:
                 row_ids, matched = probe(table, keys, build_key_types, valid)
-            for i in node.left_keys:  # NULL keys never match (SQL equi-join semantics)
-                if nulls[i] is not None:
-                    matched = matched & ~nulls[i]
+            matched = non_null_keys(matched, nulls)
             if node.kind == "inner":
                 valid = valid & matched
             elif node.kind == "semi":
@@ -3170,21 +3266,8 @@ class LocalExecutor:
             out_nulls = tuple(nulls) + bnulls
             return out_cols, out_nulls, valid
 
-        dicts = (probe_stream.dicts + (None,) if node.kind == "mark"
-                 else probe_stream.dicts if semi
-                 else probe_stream.dicts + build_dicts)
-        # propagate probe-side scan provenance: downstream aggregations use it for
-        # row-bound table sizing, and further joins for dynamic split pruning
-        si = None
-        if probe_stream.scan_info is not None:
-            n_build = (1 if node.kind == "mark"
-                       else 0 if semi else len(build_page.columns))
-            si = dataclasses.replace(
-                probe_stream.scan_info,
-                columns=tuple(probe_stream.scan_info.columns) + (None,) * n_build)
         return _Stream(node.schema, dicts, probe_stream.pages, transform, si,
-                       aux=(probe_stream.aux, table),
-                       compacted=probe_stream.compacted,
+                       aux=(probe_stream.aux, table), compacted=decided,
                        traced_src=probe_stream.traced_src)
 
     def _compile_multi_join(self, node: P.Join, build_page, build_dicts, probe_stream,
@@ -3410,8 +3493,7 @@ class LocalExecutor:
         statically pass the pruned info so both passes compose."""
         if si is None:
             si = up.scan_info
-        if si is None or not si.replayable \
-                or not hasattr(si.conn, "split_range"):
+        if si is None or not hasattr(si.conn, "split_range"):
             return None
         from ..sql import ir as _ir
 
@@ -3543,7 +3625,7 @@ class LocalExecutor:
         if isinstance(node, (P.Aggregate, P.Sort, P.Limit, P.Output, P.Window)):
             return self._execute_to_page(node)
         stream = self._compile_stream(node)
-        return _concat_stream(stream, self._batch()), stream.dicts
+        return _concat_stream(stream, self._batch(stream)), stream.dicts
 
     def _direct_join_span(self, build_page: Page, key_channels, key_types):
         """(lo, span) when the build keys form a single dense integer range small
@@ -4161,7 +4243,7 @@ def _static_pruned_stream(up: _Stream, pred):
     via ConnectorMetadata.applyFilter / per-split TupleDomain stats).  Returns
     (pages, scan_info) with the pruned split list, or None when nothing prunes."""
     si = up.scan_info
-    if si is None or not si.replayable or not hasattr(si.conn, "split_range"):
+    if si is None or not hasattr(si.conn, "split_range"):
         return None
     from ..sql.domain_translator import (domain_to_split_pruner, extract_domains,
                                          split_conjuncts)
@@ -4198,7 +4280,7 @@ def _dynamic_pruned_pages(probe_stream: _Stream, node, build_page: Page):
     keys' value domain (inner/semi joins only — outer/anti joins must keep
     unmatched probe rows).  Returns None when no pruning is possible."""
     si = probe_stream.scan_info
-    if si is None or not si.replayable or not hasattr(si.conn, "split_range"):
+    if si is None or not hasattr(si.conn, "split_range"):
         return None
     exact_ok = build_page.capacity <= 65536
     bvalid = _host([build_page.valid_mask()],

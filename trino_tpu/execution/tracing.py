@@ -261,6 +261,13 @@ class QueryCounters:
     groupby_partitioned_passes: int = 0
     join_build_rows: int = 0
     rows_generated: int = 0
+    # PR 28: how often a split join's boundary engages (local_executor.
+    # _compacted_stream), host ints recorded where it yields a page, no sync:
+    # static lanes that entered a match step, and static lanes at which that
+    # join's build columns were then gathered (the pack's bucket, or the same
+    # width for a batch that stayed dense)
+    join_match_lanes: int = 0
+    join_gather_lanes: int = 0
     # PR 25: the statement's wait states, seconds (each also a span of the
     # same name family: server.queued, batcher.wait, executor.checkout,
     # server.encode, server.deliver), recorded where the wait happens, and
@@ -321,7 +328,7 @@ class QueryCounters:
                    "compactions", "compact_lanes_in", "compact_lanes_out",
                    "groupby_slots", "groupby_state_bytes", "groupby_regrows",
                    "groupby_partitioned_passes", "join_build_rows",
-                   "rows_generated")
+                   "rows_generated", "join_match_lanes", "join_gather_lanes")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
                      "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
@@ -562,6 +569,13 @@ def record_compaction(lanes_in: int, lanes_out: int) -> None:
         c.compactions += 1
         c.compact_lanes_in += lanes_in
         c.compact_lanes_out += lanes_out
+
+
+def record_join_probe(match_lanes: int, gather_lanes: int) -> None:
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.join_match_lanes += match_lanes
+        c.join_gather_lanes += gather_lanes
 
 
 def record_groupby(slots: int = 0, state_bytes: int = 0, regrows: int = 0,

@@ -29,7 +29,8 @@ from .hashing import EMPTY_KEY, ceil_pow2, pack_keys, probe_step, splitmix64
 
 __all__ = ["JoinTable", "build_table_init", "build_insert", "probe", "MAX_PROBES",
            "MultiJoinTable", "multi_build", "probe_slots", "expand_counts",
-           "DirectJoinTable", "direct_build", "direct_probe", "DirectMultiJoinTable",
+           "DirectJoinTable", "direct_build", "direct_match", "direct_probe",
+           "DirectMultiJoinTable",
            "direct_multi_build", "direct_probe_slots", "DIRECT_JOIN_RANGE_MAX"]
 
 MAX_PROBES = 64
@@ -208,8 +209,9 @@ def direct_build(lo: int, span: int, build_page, key_channel: int) -> DirectJoin
 STAGE_SLOTS_MIN = 1 << 22  # direct tables of this many slots or more are staged
 
 
-def stage_direct_table(dt: DirectJoinTable) -> DirectJoinTable:
-    """A large direct table's arrays copied inside the program that probes it.
+def stage_direct_table(dt: DirectJoinTable, fields=None) -> DirectJoinTable:
+    """A large direct table's arrays copied inside the program that gathers
+    from them.
 
     A gather straight from a long-lived HBM argument costs about 14.5 ns an
     element on a v5e and runs at speed levels that follow where the allocator
@@ -222,7 +224,11 @@ def stage_direct_table(dt: DirectJoinTable) -> DirectJoinTable:
     read what the heap's history placed: q3 6.90-6.94 s over nine placements.
     The barriers keep the copy from being folded away or fused into the
     gather.  A copy is one sequential pass (under a millisecond for 60 MB).
-    Tables below STAGE_SLOTS_MIN keep their programs as they were."""
+    Tables below STAGE_SLOTS_MIN keep their programs as they were.
+
+    ``fields`` names the arrays this program gathers from (default: all of
+    them).  A split join's match step reads ``MATCH_FIELDS`` and its gather
+    step ``GATHER_FIELDS``: neither copies what the other reads."""
     if dt.occ.shape[0] < STAGE_SLOTS_MIN:
         return dt
 
@@ -231,18 +237,31 @@ def stage_direct_table(dt: DirectJoinTable) -> DirectJoinTable:
         return jax.lax.optimization_barrier(
             a | zero if a.dtype == jnp.bool_ else a + zero)
 
-    return jax.tree_util.tree_map(stage, dt)
+    if fields is None:
+        return jax.tree_util.tree_map(stage, dt)
+    return dataclasses.replace(dt, **{
+        f: jax.tree_util.tree_map(stage, getattr(dt, f)) for f in fields})
 
 
-def direct_probe(dt: DirectJoinTable, key_col, valid):
-    """(build_row_ids, matched) — one gather, no rounds."""
+MATCH_FIELDS = ("occ",)  # what direct_match gathers from
+GATHER_FIELDS = ("rows", "build_columns", "build_null_masks")
+
+
+def direct_match(dt: DirectJoinTable, key_col, valid):
+    """(slot, matched): the half of direct_probe that decides the match, one
+    gather (of ``occ``).  ``slot`` is clipped into the table, so
+    ``dt.rows[slot]`` is the build row of every matched lane."""
     span = dt.occ.shape[0] - 1
     slot = (key_col.astype(jnp.int64) - dt.lo).astype(jnp.int32)
     inr = (slot >= 0) & (slot < span)
     cslot = jnp.clip(slot, 0, span - 1)
-    matched = valid & inr & dt.occ[cslot]
-    row_ids = jnp.where(matched, dt.rows[cslot], 0)
-    return row_ids, matched
+    return cslot, valid & inr & dt.occ[cslot]
+
+
+def direct_probe(dt: DirectJoinTable, key_col, valid):
+    """(build_row_ids, matched) — two gathers, no rounds."""
+    cslot, matched = direct_match(dt, key_col, valid)
+    return jnp.where(matched, dt.rows[cslot], 0), matched
 
 
 @jax.tree_util.register_pytree_node_class

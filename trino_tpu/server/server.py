@@ -792,7 +792,11 @@ class CoordinatorServer:
                     ("join_build_rows", "Rows inserted into join build "
                      "tables (0 when a replay reuses its streams)."),
                     ("rows_generated", "Base-table rows the connectors "
-                     "generated (resident pages generate none).")):
+                     "generated (resident pages generate none)."),
+                    ("join_match_lanes", "Lanes that entered the match step "
+                     "of a split join."),
+                    ("join_gather_lanes", "Lanes at which split joins then "
+                     "gathered their build columns.")):
                 lines += [f"# HELP trino_tpu_{field}_total {what}",
                           f"# TYPE trino_tpu_{field}_total counter",
                           f"trino_tpu_{field}_total {getattr(ct, field, 0)}"]

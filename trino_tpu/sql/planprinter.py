@@ -88,6 +88,13 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"Compaction: {cp} compactions, "
                 f"{getattr(counters, 'compact_lanes_in', 0)} lanes in, "
                 f"{getattr(counters, 'compact_lanes_out', 0)} lanes out")
+        jm = getattr(counters, "join_match_lanes", 0)
+        if jm:
+            # split joins (PR 28): lanes that entered a match step, and lanes
+            # at which the build columns were then gathered
+            lines.append(
+                f"Join probe: {jm} lanes matched, "
+                f"{getattr(counters, 'join_gather_lanes', 0)} lanes gathered")
         gs = getattr(counters, "groupby_slots", 0)
         if gs:
             # how the statement's group-bys were sized (PR 27): slots of the
