@@ -17,6 +17,12 @@ but its code, and the compile cache is whatever ``trino_tpu/__init__.py`` placed
 
 Earlier lines are facts for CHANGES.md ("one run, not a benchmark"); the LAST line
 of stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}``.
+
+This is a smoke, not a measure: ``python -m benchmark.run`` (BENCHMARK.json,
+benchmark/README.md) is the yardstick.  The file stays, both phases, until the
+four-chip mesh has a benchmark cell of its own (PERF.md section 7, ROADMAP R1):
+``--mesh`` is the only four-chip path until then.  The five statement texts and
+their pandas twins below are also what ``scripts/query_counters.py`` traces.
 """
 
 import argparse
@@ -25,15 +31,193 @@ import os
 import sys
 import time
 
+import numpy as np
+
 SERVED = ("q1", "q3", "q4", "q9", "q18")
 MESHED = ("q1", "q3", "q18")
-# oracle columns that bench.py's pandas twins leave as scaled-decimal ints (x100)
+# oracle columns that the pandas twins leave as scaled-decimal ints (x100)
 ORACLE_SCALE = {"q18": {"o_totalprice": 100.0, "l_quantity": 100.0}}
 # where the pandas twin's column order is not the statement's SELECT order
 ORACLE_ORDER = {"q3": ["l_orderkey", "revenue", "o_orderdate", "o_shippriority"]}
 # avg(decimal(p,2)) is a decimal(p,2) in SQL and an exact float mean in pandas:
 # those columns agree to half a unit of the last decimal place
 ORACLE_DECIMALS = {"q1": {"avg_qty": 2, "avg_pr": 2, "avg_dc": 2}}
+
+
+QUERIES = {
+    "q1": """
+    select l_returnflag, l_linestatus, sum(l_quantity) as sum_qty,
+           sum(l_extendedprice) as sum_base_price,
+           sum(l_extendedprice * (1 - l_discount)) as sum_disc_price,
+           sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) as sum_charge,
+           avg(l_quantity) as avg_qty, avg(l_extendedprice) as avg_price,
+           avg(l_discount) as avg_disc, count(*) as count_order
+    from lineitem where l_shipdate <= date '1998-12-01' - interval '90' day
+    group by l_returnflag, l_linestatus order by l_returnflag, l_linestatus""",
+    "q3": """
+    select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
+           o_orderdate, o_shippriority
+    from customer, orders, lineitem
+    where c_mktsegment = 'BUILDING' and c_custkey = o_custkey
+      and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15'
+      and l_shipdate > date '1995-03-15'
+    group by l_orderkey, o_orderdate, o_shippriority
+    order by revenue desc, o_orderdate limit 10""",
+    "q4": """
+    select o_orderpriority, count(*) as order_count from orders
+    where o_orderdate >= date '1993-07-01'
+      and o_orderdate < date '1993-07-01' + interval '3' month
+      and exists (select 1 from lineitem where l_orderkey = o_orderkey
+                  and l_commitdate < l_receiptdate)
+    group by o_orderpriority order by o_orderpriority""",
+    "q9": """
+    select nation, o_year, sum(amount) as sum_profit from (
+      select n_name as nation, extract(year from o_orderdate) as o_year,
+        l_extendedprice * (1 - l_discount) - ps_supplycost * l_quantity as amount
+      from part, supplier, lineitem, partsupp, orders, nation
+      where s_suppkey = l_suppkey and ps_suppkey = l_suppkey and ps_partkey = l_partkey
+        and p_partkey = l_partkey and o_orderkey = l_orderkey
+        and s_nationkey = n_nationkey and p_name like '%green%') as profit
+    group by nation, o_year order by nation, o_year desc""",
+    "q18": """
+    select c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice, sum(l_quantity)
+    from customer, orders, lineitem
+    where o_orderkey in (select l_orderkey from lineitem group by l_orderkey
+                         having sum(l_quantity) > 300)
+      and c_custkey = o_custkey and o_orderkey = l_orderkey
+    group by c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+    order by o_totalprice desc, o_orderdate limit 100""",
+}
+
+# the columns the pandas twins read, per table: only these are pulled to the host
+ORACLE_COLUMNS = {
+    "lineitem": ["l_returnflag", "l_linestatus", "l_quantity", "l_extendedprice",
+                 "l_discount", "l_tax", "l_shipdate", "l_orderkey", "l_partkey",
+                 "l_suppkey", "l_commitdate", "l_receiptdate"],
+    "customer": ["c_custkey", "c_mktsegment", "c_name"],
+    "orders": ["o_orderkey", "o_custkey", "o_orderdate", "o_shippriority",
+               "o_totalprice", "o_orderpriority"],
+    "part": ["p_partkey", "p_name"],
+    "supplier": ["s_suppkey", "s_nationkey"],
+    "partsupp": ["ps_partkey", "ps_suppkey", "ps_supplycost"],
+    "nation": ["n_nationkey", "n_name"],
+}
+
+
+class _HostTables:
+    """Lazy, cached host-side copies of the pandas twins' input columns."""
+
+    def __init__(self, conn):
+        self.conn = conn
+        self._cache: dict = {}
+
+    def __getitem__(self, t):
+        import pandas as pd
+
+        if t in self._cache:
+            return self._cache[t]
+        conn = self.conn
+        dicts = conn.dictionaries(t)
+        names = ORACLE_COLUMNS[t]
+        # ONE generate per split for every column (one compiled program per
+        # table, not one per column)
+        parts: dict = {name: [] for name in names}
+        for sp in conn.splits(t):
+            page = conn.generate(sp, list(names))
+            valid = np.asarray(page.valid_mask())
+            for name in names:
+                parts[name].append(np.asarray(page.column(name))[valid])
+        cols = {}
+        for name in names:
+            arr = np.concatenate(parts[name])
+            d = dicts.get(name)
+            if d is not None:
+                arr = d.decode(arr)
+            cols[name] = arr
+        df = pd.DataFrame(cols)
+        self._cache[t] = df
+        return df
+
+
+def cpu_q1(T):
+    df = T["lineitem"]
+    cutoff = (np.datetime64("1998-12-01") - np.timedelta64(90, "D")
+              - np.datetime64("1970-01-01")).astype(np.int64)
+    m = df[df["l_shipdate"].to_numpy() <= cutoff]
+    disc = m["l_discount"].to_numpy() / 100.0
+    tax = m["l_tax"].to_numpy() / 100.0
+    price = m["l_extendedprice"].to_numpy() / 100.0
+    g = m.assign(dp=price * (1 - disc), ch=price * (1 - disc) * (1 + tax),
+                 qty=m["l_quantity"].to_numpy() / 100.0, pr=price, dc=disc)
+    r = g.groupby(["l_returnflag", "l_linestatus"]).agg(
+        sum_qty=("qty", "sum"), sum_base=("pr", "sum"), sum_dp=("dp", "sum"),
+        sum_ch=("ch", "sum"), avg_qty=("qty", "mean"), avg_pr=("pr", "mean"),
+        avg_dc=("dc", "mean"), cnt=("dp", "size")).reset_index()
+    return r.sort_values(["l_returnflag", "l_linestatus"])
+
+
+def cpu_q3(T):
+    c = T["customer"]; o = T["orders"]; l = T["lineitem"]
+    cutoff = (np.datetime64("1995-03-15") - np.datetime64("1970-01-01")).astype(np.int64)
+    c2 = c[c["c_mktsegment"] == "BUILDING"][["c_custkey"]]
+    o2 = o[o["o_orderdate"].to_numpy() < cutoff][
+        ["o_orderkey", "o_custkey", "o_orderdate", "o_shippriority"]]
+    l2 = l[l["l_shipdate"].to_numpy() > cutoff][
+        ["l_orderkey", "l_extendedprice", "l_discount"]]
+    j = o2.merge(c2, left_on="o_custkey", right_on="c_custkey")
+    j = l2.merge(j, left_on="l_orderkey", right_on="o_orderkey")
+    rev = (j["l_extendedprice"].to_numpy() / 100.0) * (1 - j["l_discount"].to_numpy() / 100.0)
+    j = j.assign(revenue=rev)
+    r = j.groupby(["l_orderkey", "o_orderdate", "o_shippriority"])["revenue"].sum().reset_index()
+    return r.sort_values(["revenue", "o_orderdate"], ascending=[False, True]).head(10)
+
+
+def cpu_q4(T):
+    o = T["orders"]; l = T["lineitem"]
+    lo = (np.datetime64("1993-07-01") - np.datetime64("1970-01-01")).astype(np.int64)
+    hi = (np.datetime64("1993-10-01") - np.datetime64("1970-01-01")).astype(np.int64)
+    od = o["o_orderdate"].to_numpy()
+    o2 = o[(od >= lo) & (od < hi)]
+    late = l[l["l_commitdate"].to_numpy() < l["l_receiptdate"].to_numpy()]
+    keys = np.unique(late["l_orderkey"].to_numpy())
+    m = o2[np.isin(o2["o_orderkey"].to_numpy(), keys)]
+    r = m.groupby("o_orderpriority").size().reset_index(name="order_count")
+    return r.sort_values("o_orderpriority")
+
+
+def cpu_q9(T):
+    p = T["part"]; s = T["supplier"]; l = T["lineitem"]
+    ps = T["partsupp"]; o = T["orders"]; n = T["nation"]
+    p2 = p[p["p_name"].astype(str).str.contains("green")][["p_partkey"]]
+    j = l.merge(p2, left_on="l_partkey", right_on="p_partkey")
+    j = j.merge(s[["s_suppkey", "s_nationkey"]], left_on="l_suppkey", right_on="s_suppkey")
+    j = j.merge(ps[["ps_partkey", "ps_suppkey", "ps_supplycost"]],
+                left_on=["l_partkey", "l_suppkey"], right_on=["ps_partkey", "ps_suppkey"])
+    j = j.merge(o[["o_orderkey", "o_orderdate"]], left_on="l_orderkey", right_on="o_orderkey")
+    j = j.merge(n[["n_nationkey", "n_name"]], left_on="s_nationkey", right_on="n_nationkey")
+    amount = (j["l_extendedprice"].to_numpy() / 100.0) * (1 - j["l_discount"].to_numpy() / 100.0) \
+        - (j["ps_supplycost"].to_numpy() / 100.0) * (j["l_quantity"].to_numpy() / 100.0)
+    year = (j["o_orderdate"].to_numpy().astype("datetime64[D]")).astype("datetime64[Y]").astype(int) + 1970
+    j = j.assign(amount=amount, o_year=year)
+    r = j.groupby(["n_name", "o_year"])["amount"].sum().reset_index()
+    return r.sort_values(["n_name", "o_year"], ascending=[True, False])
+
+
+def cpu_q18(T):
+    c = T["customer"]; o = T["orders"]; l = T["lineitem"]
+    qty = l.groupby("l_orderkey")["l_quantity"].sum()
+    big = qty[qty > 30000].index  # l_quantity is a scaled decimal (x100)
+    o2 = o[o["o_orderkey"].isin(big)]
+    j = o2.merge(c[["c_custkey", "c_name"]], left_on="o_custkey", right_on="c_custkey")
+    j = j.merge(l[["l_orderkey", "l_quantity"]], left_on="o_orderkey", right_on="l_orderkey")
+    r = j.groupby(["c_name", "c_custkey", "o_orderkey", "o_orderdate", "o_totalprice"])[
+        "l_quantity"].sum().reset_index()
+    return r.sort_values(["o_totalprice", "o_orderdate"],
+                         ascending=[False, True]).head(100)
+
+
+CPU_QUERIES = {"q1": cpu_q1, "q3": cpu_q3, "q4": cpu_q4, "q9": cpu_q9,
+               "q18": cpu_q18}
 
 
 class SmokeFailure(AssertionError):
@@ -47,7 +231,6 @@ def _say(**facts) -> None:
 def _column(values, scale: float = 1.0):
     """One result column in comparable form: strings stay strings, everything
     else becomes float64 (dates as days since the epoch)."""
-    import numpy as np
     import pandas as pd
 
     vals = list(values)
@@ -65,9 +248,7 @@ def _column(values, scale: float = 1.0):
 
 def check_answer(name: str, got, want, what: str) -> None:
     """Positional, order-sensitive comparison of an engine answer (pandas frame)
-    with the pandas oracle's frame — every bench statement has an ORDER BY."""
-    import numpy as np
-
+    with the pandas oracle's frame — every statement has an ORDER BY."""
     want = want[ORACLE_ORDER.get(name, list(want.columns))]
     if got.shape != want.shape:
         raise SmokeFailure(f"{name} {what}: shape {got.shape} != oracle {want.shape}")
@@ -86,14 +267,12 @@ def check_answer(name: str, got, want, what: str) -> None:
 
 
 def _kernel_facts() -> dict:
-    from trino_tpu.exec.local_executor import _scan_fused_enabled
     from trino_tpu.ops import pallas_kernels as pk
 
     on = pk.use_pallas()
     return {"use_pallas": on, "interpret": pk.pallas_interpret(),
             "hash_probe_insert_capacities": [2, pk.PALLAS_TABLE_MAX] if on else None,
-            "compact_out_rows_max": pk.COMPACT_OUT_MAX if on else None,
-            "scan_fused": _scan_fused_enabled()}
+            "compact_out_rows_max": pk.COMPACT_OUT_MAX if on else None}
 
 
 def _cache_files() -> int:
@@ -112,7 +291,6 @@ def served_phase(engine, oracle, on_tpu: bool) -> None:
     """q1/q3/q4/q9/q18 through Client.execute -> POST /v1/statement, each once
     cold and twice warm, every answer against the pandas oracle; warm runs
     must not compile."""
-    import bench
     import jax
     from trino_tpu.server.client import Client
     from trino_tpu.server.server import CoordinatorServer
@@ -122,9 +300,9 @@ def served_phase(engine, oracle, on_tpu: bool) -> None:
     try:
         client = Client(srv.url, catalog="tpch")
         for name in SERVED:
-            sql = bench.QUERIES[name]
+            sql = QUERIES[name]
             t0 = time.perf_counter()
-            want = bench.CPU_QUERIES[name](oracle)
+            want = CPU_QUERIES[name](oracle)
             oracle_s = round(time.perf_counter() - t0, 2)
 
             def run(what):
@@ -180,7 +358,6 @@ def mesh_phase(engine, oracle, on_tpu: bool) -> None:
     """q1/q3/q18 over worker_mesh() of every device (one process, SPMD), each
     against the pandas oracle and the one-chip answer; every device must hold
     bytes after the scans, and the per-worker shard stats are printed."""
-    import bench
     import jax
     from trino_tpu.parallel.mesh import worker_mesh
 
@@ -190,8 +367,8 @@ def mesh_phase(engine, oracle, on_tpu: bool) -> None:
     mesh = worker_mesh(n)
     session = engine.create_session("tpch")
     for name in MESHED:
-        sql = bench.QUERIES[name]
-        want = bench.CPU_QUERIES[name](oracle)
+        sql = QUERIES[name]
+        want = CPU_QUERIES[name](oracle)
         t0 = time.perf_counter()
         local = engine.execute_sql(sql, session).to_pandas()
         local_s = time.perf_counter() - t0
@@ -246,7 +423,6 @@ def main(argv=None) -> int:
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
 
-    import bench
     from trino_tpu import Engine
     from trino_tpu.connectors.tpch import TpchConnector
 
@@ -260,7 +436,7 @@ def main(argv=None) -> int:
     conn = TpchConnector(sf=sf, split_rows=1 << 21)
     engine = Engine()
     engine.register_catalog("tpch", conn)
-    oracle = bench._HostTables(conn)
+    oracle = _HostTables(conn)
     if args.mesh:
         mesh_phase(engine, oracle, on_tpu)
     else:

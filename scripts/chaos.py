@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Standalone chaos matrix: the tests/test_chaos.py scenarios as a capture
 artifact.  Prints ONE JSON line — always, even on crash (finally block) —
-with per-scenario outcomes and the leak-check verdicts, same contract as
-bench.py, so a chaos pass on real hardware is one command (not yet run on
-the chip).
+with per-scenario outcomes and the leak-check verdicts, so a chaos pass on
+real hardware is one command (not yet run on the chip).
 
 Env knobs:
     CHAOS_SF       TPC-H scale factor (default 0.1 — CPU-box friendly)
@@ -38,12 +37,9 @@ def main() -> int:
                "unit": "fraction", "sf": sf, "scenarios": []}
     rc = 1
     try:
-        from benchenv import env_info
-
-        payload["env"] = env_info()
-    except Exception:
-        pass
-    try:
+        dev = jax.devices()[0]
+        payload["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                             "count": len(jax.devices())}
         from trino_tpu import Engine
         from trino_tpu.connectors.tpch import TpchConnector
         from trino_tpu.execution import faults

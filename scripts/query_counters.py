@@ -2,7 +2,7 @@
 """Per-query device-boundary counter trace: the tool that derives (and
 re-derives) the budget numbers pinned in tests/test_query_budgets.py.
 
-Runs the TPC-H north-star queries (bench.py's QUERIES) through the engine
+Runs the TPC-H north-star queries (chip_smoke.py's QUERIES) through the engine
 twice — cold (plan + XLA compile) and warm (cached plan, compiled pipelines)
 — and prints one JSON line per query with the QueryCounters snapshot of each
 run: device_dispatches, host_transfers, host_bytes_pulled.
@@ -15,7 +15,7 @@ host->device launch bill and its pulled bytes are its transfer bill.  To re-deri
 and copy the warm numbers (with the headroom noted in the test) into
 tests/test_query_budgets.py.  TRACE_SF / TRACE_QUERIES / TRACE_SPLIT_ROWS
 override the scale factor (default 1, matching the tests), query subset, and
-split size (default 1<<21, matching bench.py).
+split size (default 1<<21, matching chip_smoke.py).
 """
 
 import json
@@ -41,7 +41,7 @@ jax.config.update("jax_enable_x64", True)
 def main():
     import argparse
 
-    from bench import QUERIES
+    from chip_smoke import QUERIES
     from trino_tpu import Engine
     from trino_tpu.connectors.tpch import TpchConnector
 
@@ -62,8 +62,7 @@ def main():
                          "in tests/test_query_budgets.py pin the EXECUTE "
                          "path and their fixture forces the tier off; a "
                          "warm run with the tier on costs 0 dispatches "
-                         "(that's bench_serve.py's measurement, not this "
-                         "one's)")
+                         "(not what this tool measures)")
     ap.add_argument("--prepared", action="store_true",
                     help="trace the PREPARE/EXECUTE point-lookup class "
                          "instead of the TPC-H set: cold (template "
@@ -285,7 +284,7 @@ def _trace_distributed(engine, sf, split_rows, names, QUERIES, show_sites,
     modes (device-resident vs host spool).  The warm device rows — total
     dist.* site bytes and the per-site table — are what
     tests/test_distributed_budgets.py pins; the spool:device byte ratio is
-    the exchange-elimination factor bench.py --distributed reports."""
+    the exchange-elimination factor."""
     from trino_tpu.exec.distributed import DistributedExecutor
     from trino_tpu.parallel.mesh import worker_mesh
     from trino_tpu.sql.frontend import compile_sql

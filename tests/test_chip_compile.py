@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS, SingleDeviceS
 
 from trino_tpu.ops import pallas_kernels as pk
 
-PAGE_ROWS = 1 << 21  # chip_smoke.py's / bench.py's split_rows at SF1
+PAGE_ROWS = 1 << 21  # chip_smoke.py's and the benchmark's split_rows
 
 
 @pytest.fixture(scope="module")

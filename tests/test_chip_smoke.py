@@ -74,28 +74,3 @@ def test_cache_dir_default_is_fixed_inside_the_checkout():
     assert os.path.basename(d) == ".jax_cache" or os.path.basename(d).startswith("cpu-")
     with open("/proc/sys/kernel/random/boot_id") as f:
         assert f.read().strip()[:8] not in d
-
-
-@pytest.mark.parametrize("sql,rc", [(None, 0), ("select no_such_column from lineitem", 1)])
-def test_bench_exit_code_follows_its_queries(sql, rc, monkeypatch, capsys):
-    """bench.py still prints its one JSON line when a query raised, and then
-    exits non-zero (it used to exit 0 whatever happened)."""
-    import signal
-
-    import bench
-
-    monkeypatch.setattr(bench, "SF", 0.01)
-    monkeypatch.setattr(bench, "RUNS", 1)
-    monkeypatch.setenv("BENCH_QUERIES", "q4")
-    monkeypatch.setenv("TRINO_TPU_RESULT_CACHE", "0")  # main() would setdefault it
-    if sql is not None:
-        monkeypatch.setitem(bench.QUERIES, "q4", sql)
-    saved = {s: signal.getsignal(s) for s in (signal.SIGTERM, signal.SIGALRM)}
-    try:
-        assert bench.main([]) == rc
-    finally:  # main() leaves both ignored for its own exit
-        for s, h in saved.items():
-            signal.signal(s, h)
-    payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert ("failed" in payload) == bool(rc)
-    assert payload.get("failed", ["q4"]) == ["q4"]

@@ -354,7 +354,7 @@ def test_stalled_worker_marked_degraded_and_unscheduled(tmp_path):
         coord.stop()
 
 
-def test_speculative_execution_of_stragglers(tmp_path):
+def test_speculative_execution_of_stragglers(tmp_path, monkeypatch):
     """Once every task is dispatched, a straggler re-dispatches to another
     worker; first-commit-wins dedup makes the duplicate harmless and the
     query finishes at the fast worker's pace (reference: the FTE scheduler's
@@ -377,9 +377,18 @@ def test_speculative_execution_of_stragglers(tmp_path):
         # 20s straggler cost: big enough that "the query finished in well
         # under one straggler" stays unambiguous on a loaded 1-core box
         # (wall-clock margins below that were flaky under background load)
-        orig = w2.local._agg_compiled
-        w2.local._agg_compiled = lambda node, _o=orig: (time.sleep(20),
-                                                        _o(node))[1]
+        # EVERY executor of the slow worker: it keeps a pool of them, and a
+        # second one exists whenever two of its tasks overlapped in the warm-up
+        from trino_tpu.exec.local_executor import LocalExecutor
+
+        orig = LocalExecutor._agg_compiled
+
+        def straggle(self, node):
+            if self.memory_pool is w2.memory_pool:
+                time.sleep(20)
+            return orig(self, node)
+
+        monkeypatch.setattr(LocalExecutor, "_agg_compiled", straggle)
         t0 = time.time()
         got = coord.execute_sql(Q).rows()
         elapsed = time.time() - t0

@@ -1,9 +1,8 @@
 """Dispatch coalescing (TRINO_TPU_DISPATCH_BATCH / SET SESSION dispatch_batch):
 batched multi-split execution must be a pure dispatch-count optimization —
-byte-identical results, identical page generation (once per split; the failed
-scan-fused path's on-device REGENERATION must never silently come back), and a
-visible `coalesced_splits` counter.  batch=1 is the exact-old-behavior escape
-hatch.
+byte-identical results, identical page generation (once per split, never
+regenerated on the device), and a visible `coalesced_splits` counter.  batch=1
+is the exact-old-behavior escape hatch.
 
 Scale here is tiny but split-RICH (sf=0.02, split_rows=1<<11 -> ~100 lineitem
 splits): coalescing coverage comes from split count, not data volume.
@@ -91,8 +90,8 @@ def test_warm_dispatch_reduction(ab_engine, name):
 def test_pages_generated_once_per_split():
     """Coalescing stacks pages the connector already produced — the page
     generation count per split must not change with the batch width (guards
-    against resurrecting scan-fused regeneration, and against a batcher that
-    drops or duplicates splits)."""
+    against on-device regeneration, and against a batcher that drops or
+    duplicates splits)."""
     def run(batch):
         e = Engine()
         conn = TpchConnector(sf=0.01, split_rows=SPLIT_ROWS)

@@ -42,7 +42,7 @@ SF = 0.02
 SPLIT_ROWS = 1 << 12
 
 # inlined (budget-suite convention: the ceilings must not drift with a
-# benchmark edit) — text matches bench.py's QUERIES
+# benchmark edit) — text matches chip_smoke.py's QUERIES
 QUERIES = {
     "q3": """
     select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,

@@ -232,13 +232,9 @@ def test_fte_retries_real_connector_failures(tmp_path):
         assert ex.execute(plan).rows() == expected
     finally:
         del conn.generate
-    # without fault tolerance the same flake kills the query (the scan-fused
-    # path regenerates on device without touching conn.generate — disable it
-    # so the plain executor actually walks the flaky page source)
+    # without fault tolerance the same flake kills the query
     conn.generate = _FlakyGenerate(conn, lambda: OSError("simulated io loss"), 2)
     plain = LocalExecutor(ex.catalogs)
-    plain._run_aggregate_scan_fused = lambda *a, **k: None
-    plain._run_global_scan_fused = lambda *a, **k: None
     try:
         with pytest.raises(OSError):
             plain.execute(plan)

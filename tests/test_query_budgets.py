@@ -55,8 +55,8 @@ import pytest
 from trino_tpu import Engine
 from trino_tpu.connectors.tpch import TpchConnector
 
-# the bench.py north-star queries (inlined: importing bench.py re-points the
-# process-wide XLA compile cache, which tests keep session-private)
+# the north-star queries (inlined: the ceilings must not drift with an edit of
+# chip_smoke.py's texts)
 QUERIES = {
     "q1": """
     select l_returnflag, l_linestatus, sum(l_quantity) as sum_qty,

@@ -375,7 +375,7 @@ def test_off_by_default_without_env(monkeypatch):
     e.execute_sql(Q_POINT, s)
     c = e.last_query_counters
     # unset env = tier off on EVERY backend: no lookups, no stores — the
-    # warm path keeps executing (bench.py and the budget suite depend on it)
+    # warm path keeps executing (the benchmark and the budget suite depend on it)
     assert c.result_cache_hits == 0 and c.result_cache_misses == 0
     assert c.device_dispatches > 0
     assert e.buffer_pool.info()["result_entries"] == 0

@@ -25,12 +25,6 @@ def seng(monkeypatch):
         return orig(self, *a, **k)
 
     monkeypatch.setattr(LE.LocalExecutor, "_run_streaming_aggregate", counting)
-    # the scan-fused whole-pipeline path outranks streaming aggregation for
-    # traced sources at this scale; disable it so these tests keep exercising
-    # the streaming machinery (its scaling niche: group counts beyond the
-    # fused path's table ceiling)
-    monkeypatch.setattr(LE.LocalExecutor, "_run_aggregate_scan_fused",
-                        lambda self, *a, **k: None)
     e = Engine()
     e.register_catalog("tpch", TpchConnector(sf=0.5, split_rows=1 << 17))
     return e, e.create_session("tpch"), calls

@@ -122,21 +122,11 @@ def test_constant_join_key(engine):
     assert r.columns[0][0] == 25
 
 
-def test_dynamic_filter_split_pruning(tpch_sf001, monkeypatch):
-    """Inner/semi joins prune probe splits outside the build-key domain.
-    The scan-fused paths regenerate on device without calling conn.generate,
-    so they are disabled here — this test observes pruning through the
-    page-loop machinery's generate calls (the fused path consumes the same
-    pruned split list; test_scan_fused covers it)."""
+def test_dynamic_filter_split_pruning(tpch_sf001):
+    """Inner/semi joins prune probe splits outside the build-key domain,
+    observed through the page loop's generate calls."""
     from trino_tpu import Engine
     from trino_tpu.connectors.tpch import TpchConnector
-    import trino_tpu.exec.local_executor as LE
-
-    monkeypatch.setattr(LE.LocalExecutor, "_run_aggregate_scan_fused",
-                        lambda self, *a, **k: None)
-    monkeypatch.setattr(LE.LocalExecutor, "_run_global_scan_fused",
-                        lambda self, *a, **k: None)
-    monkeypatch.setattr(LE, "_concat_traced", lambda stream: None)
 
     conn = TpchConnector(sf=0.01, split_rows=1 << 12)
     e = Engine()

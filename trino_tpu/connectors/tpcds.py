@@ -1285,8 +1285,7 @@ class TpcdsConnector:
         """Equal-size split ranges (one XLA shape class per table scan; the
         trailing overshoot past ``row_count`` is masked via the page's valid
         mask — same contract as the TPC-H connector, which is what lets the
-        scan-fused and shard_map paths drive every split through one traced
-        program)."""
+        shard_map path drive every split through one traced program)."""
         n = self.row_count(table)
         step = min(self.split_rows, max(n, 1))
         nsplits = -(-n // step)
@@ -1314,7 +1313,7 @@ class TpcdsConnector:
 
     def generate_traced(self, table: str, lo, length: int, columns):
         """Trace-time generation with traced ``lo`` and static ``length`` (the
-        scan-fused / in-shard_map sharded scan contract shared with
+        in-shard_map sharded scan contract shared with
         TpchConnector.generate_traced): returns (cols tuple, valid)."""
         all_cols = GENERATORS[table](self.sf, lo, length)
         schema = SCHEMAS[table]

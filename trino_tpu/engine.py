@@ -56,10 +56,7 @@ class Engine:
         # point SELECT) performs zero parse/analyze/plan work and zero
         # re-compilation: its cost is the dispatches themselves.
         self._template_cache: dict = {}
-        import os as _os
-
-        self.plan_templates_enabled = _os.environ.get(
-            "TRINO_TPU_PLAN_TEMPLATES", "1") not in ("0", "false", "no")
+        self.plan_templates_enabled = True
         self._executor_pool: list = []  # idle executors (checkout/release)
         self._all_executors: list = []  # every live executor (invalidation)
         self._executor_sem = None  # lazy: created at first checkout
@@ -104,13 +101,13 @@ class Engine:
         self.tracer = Tracer()
         # device-boundary accounting (exec/local_executor counters): lifetime
         # totals for the /v1/metrics Prometheus surface plus the most recent
-        # local plan execution's snapshot (what EXPLAIN ANALYZE and bench.py
-        # report per query)
+        # local plan execution's snapshot (what EXPLAIN ANALYZE and the
+        # benchmark report per query)
         self.counters_total = QueryCounters()
         self.last_query_counters = QueryCounters()
         # span-tree summary of the most recent statement (execute_sql paths):
         # {"query_id", "root_span_s", "spans": [span_dict...]} — the payload
-        # behind GET /v1/query/{id}/trace and bench.py's per_query trace
+        # behind GET /v1/query/{id}/trace
         self.last_query_trace: dict = {}
         # live in-flight introspection (round 8): every _jit dispatch / _host
         # pull / split-generation / exchange segment holds a registry entry
@@ -170,8 +167,7 @@ class Engine:
         # one CONFIRMED template into ONE batched device program
         # (LocalExecutor.execute_batched) with per-request result demux.
         # Zero cost when idle — the first request on an idle lane runs the
-        # unmodified serial path.  TRINO_TPU_TEMPLATE_BATCH=0 disables;
-        # TRINO_TPU_BATCH_WINDOW_MS / TRINO_TPU_BATCH_MAX size the window.
+        # unmodified serial path.  TRINO_TPU_TEMPLATE_BATCH=0 disables.
         from .execution.batcher import TemplateBatcher
 
         self.template_batcher = TemplateBatcher()
@@ -1259,7 +1255,7 @@ class Engine:
 
     def _finish_query_trace(self, qid: str, queued_s: float = 0.0):
         """Publish the finished span tree as ``last_query_trace`` (span
-        summaries — what bench.py and GET /v1/query/{id}/trace surface)
+        summaries — what GET /v1/query/{id}/trace surfaces)
         plus its wall-clock decomposition (``wall_breakdown``: root-span
         window attributed to named buckets — plan / split generation / h2d /
         device dispatch / host pull / exchange wait / admission queue /
@@ -2571,6 +2567,7 @@ def _plan_shape_props(sess) -> tuple:
     re-plans if the process default changes between sessions."""
     props = getattr(sess, "properties", None) or {}
     return (str(props.get("join_distribution_type", "AUTOMATIC")).upper(),
+            int(props.get("group_by_capacity") or 0),
             _effective_dispatch_batch(sess),
             _effective_adaptive(sess))
 

@@ -512,7 +512,8 @@ class DistributedExecutor:
         # blocking consumers (sort shard, window partition, final-agg merge,
         # stream materialize) read sharded device buffers directly — per-batch
         # host traffic is scalar cursor/overflow flags.  =0 restores the
-        # round-17 host spool (the A/B half bench.py --distributed prices).
+        # round-17 host spool (the A/B half that
+        # scripts/query_counters.py --distributed prices).
         if device_exchange is None:
             device_exchange = os.environ.get(
                 "TRINO_TPU_DEVICE_EXCHANGE", "1") != "0"

@@ -244,8 +244,8 @@ def _dispatch_batch_default() -> int:
     """Engine-wide dispatch-coalescing width: how many shape-uniform scan
     splits fold into ONE device dispatch.  Each dispatch is a host-side
     launch, so batch K divides the per-split dispatch bill by ~K with
-    zero regeneration cost (pages are still produced once per split — the
-    lesson of the failed scan-fused path, which re-generated on device).
+    zero regeneration cost: pages are still produced once per split (a whole
+    scan fused into one program regenerated them, and lost on the chip).
     ``TRINO_TPU_DISPATCH_BATCH=1`` restores exact per-split behavior; the
     ``dispatch_batch`` session property overrides per query (and rides the
     plan-cache key via engine._plan_shape_props)."""
@@ -437,25 +437,6 @@ class _ScanInfo:
         return raw_pages if self.over is None else self.over(raw_pages)
 
 
-@dataclasses.dataclass(frozen=True)
-class _TracedSrc:
-    """Trace-time provenance of a stream's pages: when present, every page the
-    stream yields equals ``conn.generate_traced(table, split.lo, length, cols)``
-    pushed through ``stages`` (prior pipeline boundaries, e.g. a compaction
-    whose packing is semantically a no-op) and then the stream's own transform.
-    Sinks that see this can run the ENTIRE scan inside one ``lax.scan`` over
-    split offsets — O(1) host dispatches instead of O(splits), the difference
-    between launch-bound and compute-bound (reference
-    analog: the zero-per-page scheduler cost of operator/Driver.java:372-481)."""
-
-    conn: object
-    table: str
-    splits: tuple  # uniform-length split ranges (post static/dynamic pruning)
-    scan_cols: tuple  # column names generate_traced must produce
-    stages: tuple = ()  # prior _Streams whose (transform, aux) apply in order
-    # BEFORE the owning stream's transform (aux always passed as jit arguments)
-
-
 @dataclasses.dataclass
 class _Stream:
     """A streaming pipeline segment: a source of raw pages + a fused transform.
@@ -482,14 +463,11 @@ class _Stream:
     # match and gather steps (_compacted_stream), or nowhere because its first
     # page stayed dense.  Later joins of the chain run fused at the width
     # that left
-    traced_src: Optional[_TracedSrc] = None  # on-device regenerable provenance
     _jitted: Callable = None  # cached jit of transform applied to a Page
     _batch_jitted: Callable = None  # cached jit of transform over a STACKED
     # group of uniform pages (dispatch coalescing; retraces per group arity)
     _bindings_jitted: Callable = None  # cached jit of transform vmapped over
     # a BINDINGS batch (round 21: one dispatch serves R template requests)
-    _fused_cache: dict = dataclasses.field(default_factory=dict)  # compiled
-    # whole-scan artifacts (fused concat passes), keyed by shape class
 
     def jitted(self):
         """Jit-compiled page->(cols,nulls,valid) function, cached on the stream so
@@ -1276,17 +1254,9 @@ class LocalExecutor:
         # (no boundary sits under this one: only a chain that is not yet
         # ``compacted`` gets one, so ``up.scan_info.over`` is None)
         si = dataclasses.replace(si, over=lambda raw: partial(pages, raw))
-        # compaction only re-packs live lanes — semantically a no-op for any
-        # mask-respecting consumer — so traced regeneration stays valid: the
-        # match step becomes a prior stage applied to raw pages, without the
-        # pack
-        tsrc = up.traced_src
-        if tsrc is not None:
-            tsrc = dataclasses.replace(tsrc, stages=tsrc.stages + (up,))
         return _Stream(up.schema, up.dicts, pages,
                        lambda c, n, v, aux: (c, n, v), si,
-                       clustered_by=up.clustered_by, compacted=True,
-                       traced_src=tsrc)
+                       clustered_by=up.clustered_by, compacted=True)
 
     # -- streaming segment compilation ---------------------------------------
     def _subtree_overridden(self, node) -> bool:
@@ -1347,16 +1317,9 @@ class LocalExecutor:
                            table=node.table)
             clustered = tuple(conn.clustered_by(node.table)) \
                 if hasattr(conn, "clustered_by") else ()
-            tsrc = None
-            if (hasattr(conn, "generate_traced")
-                    and not getattr(conn, "HOST_DECODE", False) and splits
-                    and all(hasattr(s, "lo") and hasattr(s, "hi") for s in splits)
-                    and len({s.hi - s.lo for s in splits}) == 1):
-                tsrc = _TracedSrc(conn, node.table, tuple(splits),
-                                  tuple(node.columns))
             return _Stream(node.schema, dicts, pages,
                            lambda c, n, v, aux: (c, n, v), si,
-                           clustered_by=clustered, traced_src=tsrc)
+                           clustered_by=clustered)
 
         if isinstance(node, P.Filter):
             up = self._compile_stream(node.child)
@@ -1378,9 +1341,6 @@ class LocalExecutor:
                     psi.conn, psi.catalog, psi.table, psi.splits,
                     psi.scan_columns)), psi)
             pages, si = pruned if pruned is not None else (up.pages, up.scan_info)
-            tsrc = up.traced_src
-            if pruned is not None and tsrc is not None:
-                tsrc = dataclasses.replace(tsrc, splits=tuple(si.splits))
             # bind-time split pruning (plan templates): a Parameter in the
             # predicate carries no plan-time value, so static pruning above
             # cannot see it — prune per EXECUTION from the bound values, or
@@ -1391,11 +1351,8 @@ class LocalExecutor:
             rt = self._param_pruned_source(up, pred, si)
             if rt is not None:
                 pages = si.pages_over(rt)
-                tsrc = None  # split set varies per binding: no
-                # whole-scan traced regeneration
             return _Stream(up.schema, up.dicts, pages, transform, si, aux=up.aux,
-                           clustered_by=up.clustered_by, compacted=up.compacted,
-                           traced_src=tsrc)
+                           clustered_by=up.clustered_by, compacted=up.compacted)
 
         if isinstance(node, P.Project):
             up = self._compile_stream(node.child)
@@ -1424,8 +1381,7 @@ class LocalExecutor:
                     up.scan_info.columns[e.index] if isinstance(e, FieldRef) else None
                     for e in node.exprs))
             return _Stream(node.schema, dicts, up.pages, transform, si, aux=up.aux,
-                           clustered_by=up.clustered_by, compacted=up.compacted,
-                           traced_src=up.traced_src)
+                           clustered_by=up.clustered_by, compacted=up.compacted)
 
         if isinstance(node, P.Join):
             return self._compile_join(node)
@@ -1581,36 +1537,6 @@ class LocalExecutor:
             self._agg_cache[("direct", id(node), cfg)] = (node, dstep, bdstep)
         return dstep, bdstep
 
-    # -- scan-fused aggregation ----------------------------------------------
-    def _traced_chain(self, stream):
-        if not _scan_fused_enabled():
-            return None
-        return self._traced_chain_always(stream)
-
-    def _traced_chain_always(self, stream):
-        """(chain_fn, split_offsets, stage_auxes) for a traced-regenerable
-        stream, or None.  chain_fn(lo, auxes) regenerates one split's raw page
-        on device and pushes it through every pipeline stage — pure, so a
-        ``lax.scan`` over the offsets runs the WHOLE scan in one dispatch.
-        Stage aux pytrees are jit ARGUMENTS (the no-closed-over-aux rule)."""
-        ts = stream.traced_src
-        if ts is None or not ts.splits:
-            return None
-        stages = ts.stages + (stream,)
-        length = int(ts.splits[0].hi - ts.splits[0].lo)
-        los = jnp.asarray([int(s.lo) for s in ts.splits], jnp.int64)
-        auxes = tuple(st.aux for st in stages)
-
-        def chain(lo, auxes, ts=ts, stages=stages, length=length):
-            cols, valid = ts.conn.generate_traced(ts.table, lo, length,
-                                                  ts.scan_cols)
-            nulls = tuple(None for _ in cols)
-            for st, aux in zip(stages, auxes):
-                cols, nulls, valid = st.transform(cols, nulls, valid, aux)
-            return cols, nulls, valid
-
-        return chain, los, auxes
-
     def _agg_capacity_estimate(self, stream, node, key_ranges):
         """Upper-bound estimate of group count from static key ranges and the
         source table's row bound (reference: stats-driven GroupByHash
@@ -1632,124 +1558,6 @@ class LocalExecutor:
             bound = int(si.conn.row_count(si.splits[0].table))
             est = bound if est is None else min(est, bound)
         return est
-
-    def _run_aggregate_scan_fused(self, node, stream, key_types, acc_specs,
-                                  acc_exprs, acc_kinds):
-        """Whole-scan grouped aggregation in ONE device dispatch: generate →
-        transform (filters/projects/single-match join probes) → group insert,
-        all inside a ``lax.scan`` over split offsets.  The per-page loop pays
-        a host-side launch per dispatch; this path pays one.  Growth cannot happen mid-scan (static shapes), so
-        the table is pre-sized from stats and overflow re-runs the scan at 4x —
-        regeneration is device compute, far cheaper than O(splits) dispatches.
-        Returns None when the stream is not traced-regenerable."""
-        traced = self._traced_chain(stream)
-        if traced is None:
-            return None
-        chain, los, auxes = traced
-        key_dtypes = tuple(t.dtype for t in key_types)
-        key_ranges = self._key_ranges(stream, node)
-        cfg = None
-        if all(r is not None for r in key_ranges):
-            try:
-                _, onulls, _ = jax.eval_shape(chain, jnp.int64(0), auxes)
-            except Exception:
-                return None
-            key_nullable = tuple(onulls[i] is not None for i in node.keys)
-            cfg = hashagg.direct_config(key_ranges, key_nullable)
-
-        cacheable = self._agg_cacheable(node)
-
-        def make_run(insert):
-            def run(state, los, auxes, insert=insert):
-                def body(st, lo):
-                    cols, nulls, valid = chain(lo, auxes)
-                    key_vals = tuple(cols[i] for i in node.keys)
-                    key_nulls = tuple(nulls[i] for i in node.keys)
-                    inputs = [(None, None) if e is None
-                              else evaluate(e, cols, nulls) for e in acc_exprs]
-                    return insert(st, key_vals, key_nulls, inputs, valid), None
-
-                state, _ = jax.lax.scan(body, state, los)
-                return state
-
-            return _jit(run, site="agg.scanfused.scan", donate_argnums=(0,))
-
-        def cached_run(mode, insert):
-            key = ("scanfused", id(node), mode)
-            hit = self._agg_cache.get(key) if cacheable else None
-            if hit is not None:
-                return hit[1]
-            run = make_run(insert)
-            if cacheable:
-                self._agg_cache[key] = (node, run)
-            return run
-
-        state_bytes = _group_state_bytes(key_types, acc_specs)
-
-        if cfg is not None:
-            if self.memory_pool.try_reserve(state_bytes(cfg.capacity),
-                                            "group-by"):
-                try:
-                    with tracing.maybe_span("aggregate.direct",
-                                            slots=cfg.capacity):
-                        run = cached_run(("direct", cfg),
-                                         lambda st, kv, kn, inp, v, cfg=cfg:
-                                         hashagg.direct_groupby_insert(
-                                             st, cfg, kv, v, inp, acc_kinds, kn))
-                        state = run(hashagg.direct_groupby_init(
-                            cfg, key_dtypes, acc_specs), los, auxes)
-                        if not bool(state.overflow):
-                            return self._finalize_groups(node, stream, state)
-                    tracing.record_groupby(regrows=1)
-                finally:
-                    tracing.record_groupby(
-                        state_bytes=state_bytes(cfg.capacity))
-                    self.memory_pool.free(state_bytes(cfg.capacity), "group-by")
-            # stale stats / no memory: fall through to hash mode
-
-        if self._streaming_agg_order(stream, node) is not None:
-            est = self._agg_capacity_estimate(stream, node, key_ranges)
-            if est is None or 2 * est > MAX_GROUP_CAPACITY:
-                # clustered input with a huge/unknown group count: the
-                # streaming (sorted) aggregation's bounded merge state scales
-                # past any hash-table ceiling — let it take the query
-                return None
-
-        capacity = node.capacity or DEFAULT_GROUP_CAPACITY
-        if not node.capacity:
-            est = self._agg_capacity_estimate(stream, node, key_ranges)
-            if est is not None:
-                # a higher cap than the page-loop path (1<<20): an overflow
-                # here costs a full re-scan + recompile, so undershoot is the
-                # expensive direction
-                target = 1 << max(2 * est - 1, 1).bit_length()
-                capacity = max(capacity, min(target, 1 << 24))
-        capacity = ceil_pow2(capacity)
-        if not self.memory_pool.try_reserve(state_bytes(capacity), "group-by"):
-            return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
-        resv = state_bytes(capacity)
-        try:
-            run = cached_run("hash",
-                             lambda st, kv, kn, inp, v:
-                             hashagg.groupby_insert(st, kv, key_types, v, inp,
-                                                    acc_kinds, kn))
-            while True:
-                with tracing.maybe_span("aggregate.hash", slots=capacity):
-                    state = run(hashagg.groupby_init(capacity, key_dtypes,
-                                                     acc_specs), los, auxes)
-                    if not bool(state.overflow):
-                        return self._finalize_groups(node, stream, state)
-                tracing.record_groupby(regrows=1)
-                grown = capacity * 4
-                delta = state_bytes(grown) - state_bytes(capacity)
-                if grown > MAX_GROUP_CAPACITY or \
-                        not self.memory_pool.try_reserve(delta, "group-by"):
-                    return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
-                resv += delta
-                capacity = grown
-        finally:
-            tracing.record_groupby(state_bytes=resv)
-            self.memory_pool.free(resv, "group-by")
 
     def _run_percentile_aggregate(self, node: P.Aggregate):
         """approx_percentile via exact sort-based selection: one device
@@ -2199,41 +2007,6 @@ class LocalExecutor:
         dicts = tuple(stream.dicts[i] for i in key_chs) + tuple(agg_dicts)
         return Page(node.schema, tuple(arrays), tuple(nulls), None), dicts
 
-    def _run_global_scan_fused(self, node, stream, acc_exprs, acc_kinds):
-        """Ungrouped-aggregation variant of the scan-fused path: the
-        accumulator tuple is the scan carry."""
-        traced = self._traced_chain(stream)
-        if traced is None:
-            return None
-        chain, los, auxes = traced
-        cacheable = self._agg_cacheable(node)
-        key = ("globalfused", id(node))
-        hit = self._agg_cache.get(key) if cacheable else None
-        if hit is not None:
-            run = hit[1]
-        else:
-            def run(state, los, auxes):
-                def body(st, lo):
-                    cols, nulls, valid = chain(lo, auxes)
-                    return _global_agg_update(st, cols, nulls, valid,
-                                              acc_exprs, acc_kinds), None
-
-                state, _ = jax.lax.scan(body, state, los)
-                return state
-
-            run = _jit(run, site="agg.global.scan", donate_argnums=(0,))
-            if cacheable:
-                self._agg_cache[key] = (node, run)
-        state = run(_global_init_state(node), los, auxes)
-        # ONE batched pull for every accumulator scalar (serial np.asarray
-        # would pay one blocking sync per accumulator)
-        acc_cols = [a[None] for a in _host(list(state),
-                                           site="agg.global.accs")]
-        out_cols, out_nulls = _finalize_aggs(node.aggs, acc_cols, 1)
-        arrays = [np.asarray(c) for c in out_cols]  # host-ok: post-_host finalize
-        page = Page(node.schema, tuple(arrays), tuple(out_nulls), None)
-        return page, tuple(None for _ in node.aggs)
-
     def _run_aggregate(self, node: P.Aggregate):
         if any(s.kind in P.SORTED_AGG_KINDS for s in node.aggs):
             return self._run_percentile_aggregate(node)
@@ -2241,11 +2014,6 @@ class LocalExecutor:
         capacity = node.capacity or DEFAULT_GROUP_CAPACITY
         if not node.keys:
             return self._run_global_aggregate(node, stream, acc_exprs, acc_kinds)
-
-        fused = self._run_aggregate_scan_fused(node, stream, key_types,
-                                               acc_specs, acc_exprs, acc_kinds)
-        if fused is not None:
-            return fused
 
         # direct-indexed fast path: slot = packed key when static ranges are narrow
         # (reference: BigintGroupByHash, operator/GroupByHash.java:90-99)
@@ -2806,9 +2574,6 @@ class LocalExecutor:
 
     def _run_global_aggregate(self, node, stream, acc_exprs, acc_kinds):
         """Ungrouped aggregation (reference: AggregationOperator) — pure jnp reductions."""
-        fused = self._run_global_scan_fused(node, stream, acc_exprs, acc_kinds)
-        if fused is not None:
-            return fused
         cacheable = self._agg_cacheable(node)
         hit = self._agg_cache.get(("global", id(node))) if cacheable else None
         if hit is not None:
@@ -2986,11 +2751,9 @@ class LocalExecutor:
 
         st = self._node_stats(node)
         st["index_join_keys"] = len(keys)
-        repl = {"pages": si.pages_over(pages),
-                "scan_info": dataclasses.replace(si, splits=list(new_splits))}
-        if probe_stream.traced_src is not None:
-            repl["traced_src"] = None  # handle scans are host-fed
-        return dataclasses.replace(probe_stream, **repl)
+        return dataclasses.replace(
+            probe_stream, pages=si.pages_over(pages),
+            scan_info=dataclasses.replace(si, splits=list(new_splits)))
 
     def _build_cache_key(self, node: P.Join):
         """Buffer-pool key for this join's build fragment, or None when the
@@ -3091,9 +2854,6 @@ class LocalExecutor:
                 if probe_stream.scan_info is not None:
                     repl["scan_info"] = dataclasses.replace(
                         probe_stream.scan_info, splits=list(kept))
-                if probe_stream.traced_src is not None:
-                    repl["traced_src"] = dataclasses.replace(
-                        probe_stream.traced_src, splits=tuple(kept))
                 probe_stream = dataclasses.replace(probe_stream, **repl)
         # memory gate: build-side state (columns + table/order layout) is
         # device-resident and pinned by the stream cache.  When it cannot fit the
@@ -3226,8 +2986,7 @@ class LocalExecutor:
             mdicts = probe_stream.dicts if semi else probe_stream.dicts + (None,)
             packed = self._compacted_stream(_Stream(
                 mschema, mdicts, probe_stream.pages, match_step,
-                probe_stream.scan_info, aux=(probe_stream.aux, table),
-                traced_src=probe_stream.traced_src))
+                probe_stream.scan_info, aux=(probe_stream.aux, table)))
             if packed is not None:
                 si = dataclasses.replace(si, over=packed.scan_info.over)
                 if semi:
@@ -3267,8 +3026,7 @@ class LocalExecutor:
             return out_cols, out_nulls, valid
 
         return _Stream(node.schema, dicts, probe_stream.pages, transform, si,
-                       aux=(probe_stream.aux, table), compacted=decided,
-                       traced_src=probe_stream.traced_src)
+                       aux=(probe_stream.aux, table), compacted=decided)
 
     def _compile_multi_join(self, node: P.Join, build_page, build_dicts, probe_stream,
                             build_key_types, span=None) -> _Stream:
@@ -3688,23 +3446,8 @@ class LocalExecutor:
 # -- helpers ------------------------------------------------------------------------------
 
 
-def _scan_fused_enabled() -> bool:
-    """Scan-fused paths trade RE-GENERATING the scan on device for collapsing
-    host dispatches.  OFF by default on every backend: on the CPU backend
-    generation IS the dominant cost, and on the chip the fused paths keep the
-    tables out of the HBM page cache (every statement regenerates its scans)
-    and lost both times they were measured — SF1 q3 warm 13.3 s fused vs
-    2.93 s per-split (2026-08-01, an access path that is gone) and, directly
-    attached, 13.5 s fused (PR 22, PERF.md section 6).  TRINO_TPU_SCAN_FUSED=1
-    turns them on (tests/test_scan_fused.py, chip A/Bs)."""
-    import os
-
-    return os.environ.get("TRINO_TPU_SCAN_FUSED", "0") not in ("0", "false", "no")
-
-
 def _global_agg_update(state, cols, nulls, valid, acc_exprs, acc_kinds):
-    """One page folded into the ungrouped-aggregation accumulator tuple — the
-    shared body of the per-page step and the scan-fused whole-scan runner."""
+    """One page folded into the ungrouped-aggregation accumulator tuple."""
     out = []
     for st, e, kind in zip(state, acc_exprs, acc_kinds):
         if kind == "count_star":
@@ -4031,81 +3774,6 @@ def _compact_part_sized(cols, nulls, valid, size: int):
         + (jnp.arange(size, dtype=jnp.int32) < live,)
 
 
-def _concat_traced(stream: _Stream):
-    """Whole-scan materialization for traced-regenerable streams in two device
-    dispatches + one scalar sync: a counting ``lax.scan`` sizes the output, a
-    filling scan packs every split's surviving rows into one buffer.  The
-    page-loop version pays ~2 dispatches and a chunked sync per split.
-    Regenerating the scan twice is deliberate: it trades device compute for
-    host dispatches (whether that wins on the chip is not yet measured)."""
-    ts = stream.traced_src
-    if ts is None or not ts.splits or not _scan_fused_enabled():
-        return None
-    stages = ts.stages + (stream,)
-    length = int(ts.splits[0].hi - ts.splits[0].lo)
-    los = jnp.asarray([int(s.lo) for s in ts.splits], jnp.int64)
-    auxes = tuple(st.aux for st in stages)
-
-    def chain(lo, auxes):
-        cols, valid = ts.conn.generate_traced(ts.table, lo, length,
-                                              ts.scan_cols)
-        nulls = tuple(None for _ in cols)
-        for st, aux in zip(stages, auxes):
-            cols, nulls, valid = st.transform(cols, nulls, valid, aux)
-        return cols, nulls, valid
-
-    key = ("concat", length, tuple(id(st) for st in stages))
-    arts = stream._fused_cache.get(key)
-    if arts is None:
-        try:
-            cshapes, nshapes, _ = jax.eval_shape(chain, jnp.int64(0), auxes)
-        except Exception:
-            return None
-        col_dtypes = tuple(c.dtype for c in cshapes)
-        has_null = tuple(n is not None for n in nshapes)
-
-        @_jit
-        def count_pass(los, auxes):
-            def body(tot, lo):
-                _, _, valid = chain(lo, auxes)
-                return tot + jnp.sum(valid, dtype=jnp.int64), None
-
-            tot, _ = jax.lax.scan(body, jnp.int64(0), los)
-            return tot
-
-        def fill_pass(los, auxes, total, cap):
-            def body(carry, lo):
-                off, bufs, nbufs = carry
-                cols, nulls, valid = chain(lo, auxes)
-                pos = jnp.cumsum(valid) - 1
-                dst = jnp.where(valid, off + pos, cap)  # invalid -> sink slot
-                bufs = tuple(b.at[dst].set(c) for b, c in zip(bufs, cols))
-                nbufs = tuple(nb if nb is None else nb.at[dst].set(m)
-                              for nb, m in zip(nbufs, nulls))
-                return (off + jnp.sum(valid, dtype=jnp.int64), bufs, nbufs), None
-
-            bufs0 = tuple(jnp.zeros((cap + 1,), d) for d in col_dtypes)
-            nbufs0 = tuple(jnp.zeros((cap + 1,), bool) if h else None
-                           for h in has_null)
-            (_, bufs, nbufs), _ = jax.lax.scan(
-                body, (jnp.int64(0), bufs0, nbufs0), los)
-            valid = jnp.arange(cap) < total
-            return (tuple(b[:cap] for b in bufs),
-                    tuple(None if nb is None else nb[:cap] for nb in nbufs),
-                    valid)
-
-        arts = (count_pass, _jit(fill_pass, static_argnums=(3,)))
-        stream._fused_cache[key] = arts
-    count_pass, fill_pass = arts
-    total = int(count_pass(los, auxes))
-    if total == 0:
-        cols = tuple(jnp.zeros((0,), f.type.dtype) for f in stream.schema.fields)
-        return Page(stream.schema, cols, tuple(None for _ in cols), None)
-    cap = max(1 << max(total - 1, 1).bit_length(), 1024)
-    cols, nulls, valid = fill_pass(los, auxes, jnp.int64(total), cap)
-    return Page(stream.schema, cols, nulls, valid)
-
-
 def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
     """Materialize a streaming segment into a single device page (compacted).
 
@@ -4115,9 +3783,6 @@ def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
     pages stay in worker memory between operators).  ``batch``>1 coalesces shape-uniform pages: each group
     of K splits runs its transform in ONE dispatch (and its compaction and
     live-count sync amortize K-fold with it)."""
-    fused = _concat_traced(stream)
-    if fused is not None:
-        return fused
     step = stream.jitted()
     bstep = stream.jitted_batch() if batch > 1 else None
     parts = []

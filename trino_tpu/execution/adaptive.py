@@ -65,7 +65,6 @@ uncorrected executions before the statement is reconsidered.
 from __future__ import annotations
 
 import hashlib
-import os
 import threading
 from collections import OrderedDict
 from typing import Optional
@@ -96,14 +95,6 @@ MAX_DISPATCH_BATCH = 16
 _RATIO_CAP = 10.0  # win model: beyond 10x the extra ratio buys nothing
 
 
-def _env_float(name: str, default: float) -> float:
-    try:
-        v = os.environ.get(name, "")
-        return float(v) if v != "" else default
-    except ValueError:
-        return default
-
-
 def _pow2_at_least(n: float) -> int:
     return 1 << max(int(n) - 1, 1).bit_length()
 
@@ -130,12 +121,9 @@ class AdaptiveAdvisor:
                  price_scale: float = 1.0):
         self.history = history
         self.compile_log = compile_log
-        self.threshold = threshold if threshold is not None else _env_float(
-            "TRINO_TPU_ADAPTIVE_THRESHOLD", ADAPTIVE_THRESHOLD)
-        self.horizon = horizon if horizon is not None else _env_float(
-            "TRINO_TPU_ADAPTIVE_HORIZON", DEFAULT_HORIZON)
-        self.cooldown = cooldown if cooldown is not None else int(_env_float(
-            "TRINO_TPU_ADAPTIVE_COOLDOWN", DEFAULT_COOLDOWN))
+        self.threshold = ADAPTIVE_THRESHOLD if threshold is None else threshold
+        self.horizon = DEFAULT_HORIZON if horizon is None else horizon
+        self.cooldown = DEFAULT_COOLDOWN if cooldown is None else cooldown
         # test/ops hook: multiplies the compile price in the comparison
         # (0.0 = re-plan whenever material, large = always hold)
         self.price_scale = price_scale

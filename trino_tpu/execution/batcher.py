@@ -13,8 +13,8 @@ coalesce into one dispatch (the per-REQUEST analog of the round-6 per-split
   untouched by construction);
 - requests arriving while the lane is busy QUEUE; when the leader finishes
   it hands the lane to the first queued member, which becomes the DRIVER:
-  it sleeps the gather window (TRINO_TPU_BATCH_WINDOW_MS), drains up to
-  TRINO_TPU_BATCH_MAX members, and runs ONE fused execution
+  it sleeps the gather window (``DEFAULT_WINDOW_MS``), drains up to
+  ``DEFAULT_MAX_BATCH`` members, and runs ONE fused execution
   (LocalExecutor.execute_batched) whose per-lane results resolve every
   member;
 - a whole-batch failure (BatchUnsupported, a device fault) re-runs EVERY
@@ -45,18 +45,8 @@ __all__ = ["TemplateBatcher"]
 LEADER_EXIT_HOOK = None
 
 
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
-
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
+DEFAULT_WINDOW_MS = 2.0  # a driver's gather window
+DEFAULT_MAX_BATCH = 16  # members one fused execution takes
 
 
 class _Member:
@@ -88,10 +78,10 @@ class TemplateBatcher:
     surface (/v1/metrics template-batch counters + size histogram)."""
 
     def __init__(self, window_ms=None, max_batch=None, enabled=None):
-        self.window_s = (_env_float("TRINO_TPU_BATCH_WINDOW_MS", 2.0)
-                         if window_ms is None else float(window_ms)) / 1000.0
-        self.max_batch = max(_env_int("TRINO_TPU_BATCH_MAX", 16)
-                             if max_batch is None else int(max_batch), 1)
+        self.window_s = (DEFAULT_WINDOW_MS if window_ms is None
+                         else float(window_ms)) / 1000.0
+        self.max_batch = max(DEFAULT_MAX_BATCH if max_batch is None
+                             else int(max_batch), 1)
         if enabled is None:
             enabled = os.environ.get("TRINO_TPU_TEMPLATE_BATCH", "1") \
                 not in ("0", "false", "no")

@@ -32,7 +32,7 @@ Two tiers, one LRU:
   metrics gauges and the leak checks see them next to the device tiers.
   The tier has its OWN byte budget (``TRINO_TPU_RESULT_CACHE``; unset = 0
   everywhere — results are host memory, there is no HBM fraction to steal,
-  and bench.py must keep measuring the execute path unless a capture
+  and a measurement must keep timing the execute path unless it
   explicitly opts in) and a per-entry size cap
   (``TRINO_TPU_RESULT_CACHE_MAX_ENTRY``, default budget/4).  Admission
   policy (deterministic plans only, no volatile functions, cacheable
@@ -89,8 +89,8 @@ def result_cache_budget() -> int:
     disables), unset = 0 on EVERY backend.  Unlike the page tier there is no
     accelerator default: result entries live in host RAM (no HBM fraction to
     derive a default from) and an implicit default would silently turn
-    bench.py's warm runs into cache hits — serving deployments opt in
-    explicitly."""
+    a benchmark's replayed statements into cache hits — serving deployments
+    opt in explicitly."""
     import os
 
     raw = os.environ.get("TRINO_TPU_RESULT_CACHE")

@@ -137,8 +137,8 @@ def read_flight_dir(path: str) -> list:
                         continue  # torn tail of a dead process
         except OSError:
             continue
-    # several recorders may share one directory (bench_serve's two engines,
-    # chaos's second engine): name order interleaves instances, recording
+    # several recorders may share one directory (two engines of one process,
+    # as in scripts/chaos.py): name order interleaves instances, recording
     # time is the one global order.  Stable sort keeps in-file append order
     # for ties.
     out.sort(key=lambda r: r.get("recorded_at") or 0.0)
@@ -173,9 +173,10 @@ class FlightRecorder:
         self._segment: Optional[str] = None  # active segment file path
         self._segment_bytes = 0
         # per-instance segment namespace: several recorders legitimately
-        # share one TRINO_TPU_FLIGHT_DIR (bench_serve builds two engines,
-        # chaos a second one) — identical names would make one instance's
-        # eviction delete another's ACTIVE segment and silently lose records
+        # share one TRINO_TPU_FLIGHT_DIR (every engine of a process, and
+        # every process given the directory) — identical names would make one
+        # instance's eviction delete another's ACTIVE segment and silently
+        # lose records
         self._instance = f"{os.getpid():08x}{uuid.uuid4().hex[:6]}"
 
     @property

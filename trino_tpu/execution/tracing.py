@@ -971,7 +971,7 @@ class CompileLog:
 
     Recompile-storm detection: ONE compiled stream (a single _jit wrapper,
     identified by the ``wrapper`` token) compiling more than
-    ``TRINO_TPU_COMPILE_STORM_SIGS`` (default 8) DISTINCT argument
+    ``DEFAULT_STORM_SIGNATURES`` (8) DISTINCT argument
     signatures WITHIN ONE STATEMENT is a storm — shape churn (non-uniform
     splits defeating coalescing, un-quantized size buckets) multiplying
     cold-compile cost — and logs ONE named warning pointing at the
@@ -999,8 +999,7 @@ class CompileLog:
         self.max_records = max_records if max_records is not None \
             else _env_int("TRINO_TPU_COMPILE_LOG", DEFAULT_COMPILE_LOG_RECORDS)
         self.storm_sigs = storm_sigs if storm_sigs is not None \
-            else _env_int("TRINO_TPU_COMPILE_STORM_SIGS",
-                          DEFAULT_STORM_SIGNATURES)
+            else DEFAULT_STORM_SIGNATURES
         self._lock = threading.Lock()
         from collections import deque
 

@@ -120,14 +120,14 @@ INSERT_BLOCK = 1024
 COMPACT_BLOCK = 1024
 TABLE_TILE = 1024
 
-_FORCE: bool | None = None  # tests/bench override; trace-time, like the env
+_FORCE: bool | None = None  # tests' override; trace-time, like the env
 
 
 def force(mode: bool | None) -> None:
-    """Test/bench override for `use_pallas()` (None = back to env/backend).
+    """Test override for `use_pallas()` (None = back to env/backend).
     TRACE-time only: never flip it across calls of one jitted callable —
-    build a fresh jit per mode (bench_micro's *_ab kernels) or
-    `jax.clear_caches()` first (tests/test_pallas_kernels.py)."""
+    build a fresh jit per mode or `jax.clear_caches()` first
+    (tests/test_pallas_kernels.py)."""
     global _FORCE
     _FORCE = mode
 

@@ -1054,8 +1054,6 @@ class ClusterCoordinator:
         # dedup absorbs stragglers from the earlier attempt (reference:
         # HttpPageBufferClient + DeduplicatingDirectExchangeBuffer).
         self.stream_exchange = stream_exchange
-        self.fanout_stream = _os.environ.get(
-            "TRINO_TPU_FANOUT_STREAM", "1") != "0"  # kill-switch / A-B knob
         self._stream_pending: dict = {}  # id(plan node) -> substituted frag
         self._stream_producers: dict = {}  # task_id -> replay record
         self.streamed_tasks = 0  # observability: producers launched streaming
@@ -2134,11 +2132,8 @@ class ClusterCoordinator:
             // self.splits_per_task
         base_sources = None
         if fanout is not None and self._collect_pending(fanout, spooled):
-            if self.fanout_stream:
-                base_sources = self._stream_fanout_sources(
-                    fanout, spooled, exchange_dir, n_readers=n_tasks)
-            else:
-                self._materialize_pending(fanout, spooled, exchange_dir)
+            base_sources = self._stream_fanout_sources(
+                fanout, spooled, exchange_dir, n_readers=n_tasks)
         tasks = []
         for i in range(n_tasks):
             tid = self._next_tid()
@@ -2194,16 +2189,6 @@ class ClusterCoordinator:
                                                  child_sources)
             sources[tid] = url
         return sources
-
-    def _materialize_pending(self, node, spooled, exchange_dir) -> None:
-        """Run each directly-pending child fragment to a SPOOLED output (the
-        fanout-stream kill-switch path: multiple readers share the durable
-        copy); the child's own pending descendants still stream into it."""
-        for c in self._collect_pending(node, spooled):
-            frag = self._stream_pending.pop(id(c))
-            srcs = self._dispatch_stream_tree(c, spooled, exchange_dir)
-            tid = spooled[id(c)][0][0]
-            self._run_single_task(frag, exchange_dir, tid=tid, sources=srcs)
 
     def _stream_fanout_sources(self, node, spooled, exchange_dir,
                                n_readers: int) -> dict:

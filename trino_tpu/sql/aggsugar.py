@@ -219,7 +219,7 @@ def _stats2_rewrite(name: str, y: A.Node, x: A.Node) -> A.Node:
     pairwise-non-null rows + a finalize expression (reference:
     operator/aggregation/ CovarianceAggregation / RegressionAggregation /
     CorrelationAggregation keep the same running moments in their state; on
-    TPU the moments are plain sum/count aggregates the scan-fused partial
+    TPU the moments are plain sum/count aggregates the partial-aggregation
     machinery already distributes, and the finalize is a scalar expression).
 
     Signature order matches the reference: f(y, x) — y dependent, x

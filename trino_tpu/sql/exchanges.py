@@ -167,6 +167,9 @@ def resolve_distributions(plan: P.PlanNode, catalogs: dict,
     - ``capacity`` / ``grace_parts``: Aggregate hash-table capacity and
       Grace partition seeds from observed group counts.
 
+    The ``group_by_capacity`` session property sets the initial capacity of
+    every Aggregate that has no such correction.
+
     The chain walk here mirrors ``history.plan_node_paths`` (pre-order,
     child-index chains from root "0") by construction — the corrections'
     addresses are those paths."""
@@ -184,7 +187,8 @@ def resolve_distributions(plan: P.PlanNode, catalogs: dict,
         path = f"{type(node).__name__}#{chain}"
         fact = rows_facts.get(path)
         if isinstance(node, P.Aggregate):
-            cap = int(cap_facts.get(path) or 0)
+            cap = int(cap_facts.get(path)
+                      or (props or {}).get("group_by_capacity") or 0)
             gp = int(grace_facts.get(path) or 0)
             if (cap and cap != node.capacity) \
                     or (gp and gp != node.grace_parts):
