@@ -22,6 +22,11 @@ Measured trace the ceilings derive from (2026-08-06, jax 0.7 CPU mesh):
         warm spool:  dist bytes 23522761 (2516x)
     q18 warm device: dist bytes 563, pulled 598
         warm spool:  dist bytes 33887208 (60190x)
+        (PR 30, re-measured through this file's own _warm_run: q18's semi-join
+        moved under orders, inside the first join's build fragment
+        (PushSemiJoinThroughJoin): dist bytes 554, pulled 592 where the parent
+        read 563 and 619: one build fewer is sized and null-checked at the top
+        level, and the split join's 4-byte count is new.  Ceilings unchanged.)
 
 Ceilings sit at ~2x measured for group-count headroom.  A failure means a
 bulk pull crept back into the mesh path — fix the path, don't bump the

@@ -135,10 +135,17 @@ def test_served_answers_match_the_reference(regime, name, params, monkeypatch, h
     if "staged" in want_regime and len(want):  # the probes were traced with staged tables
         assert want_regime["staged"] and all(want_regime["staged"])
 
-    # the pool: lineitem is over the per-entry cap, orders and customer are resident
+    # the pool: lineitem is over the per-entry cap, orders and customer are resident.
+    # Since PR 30 q18's semi-join is the filter of ORDERS (PushSemiJoinThroughJoin:
+    # orders is its probe side, inside the first join's build), so where its build
+    # comes out empty dynamic filtering prunes every split of orders and orders is
+    # never scanned, nor admitted
     info = engine.buffer_pool.info()
     assert "tpch.lineitem" not in info["per_table"], info["per_table"]
-    assert {"tpch.orders", "tpch.customer"} <= set(info["per_table"]), info["per_table"]
+    resident = {"tpch.customer"} | ({"tpch.orders"} if name == "q3" or len(want) else set())
+    assert resident <= set(info["per_table"]), info["per_table"]
+    if not resident >= {"tpch.orders"}:
+        assert "tpch.orders" not in info["per_table"], info["per_table"]
     assert info["bytes"] <= POOL_BYTES
     # the cold run: every scan of lineitem generated it, the builds were made (where
     # q18's semi-join build comes out empty, dynamic filtering prunes every split of
@@ -155,9 +162,16 @@ def test_served_answers_match_the_reference(regime, name, params, monkeypatch, h
             assert cold.groupby_regrows == want_regime["regrows"]
         assert cold.groupby_slots >= 15_000
     # the replay: the compiled streams hold the build tables (q18's inner group-by
-    # is the semi-join's build side), the probe side is generated again
+    # is the semi-join's build side), the probe side is generated again: all of it in
+    # q3; in q18 the splits that can hold one of the few orders that passed the
+    # semi-join (the first join's build is now a handful of keys, so
+    # ``_dynamic_pruned_pages`` takes their exact set and skips the splits with none)
     _, warm, warm_spans = runs[-1]
     assert warm.compiles == 0 and warm.join_build_rows == 0
-    assert warm.rows_generated == probes * LINEITEM_ROWS
+    if name == "q3":
+        assert warm.rows_generated == probes * LINEITEM_ROWS
+    else:
+        one_split = LINEITEM_ROWS // len(conn.splits("lineitem"))
+        assert probes * one_split <= warm.rows_generated <= probes * LINEITEM_ROWS
     assert warm.page_cache_hits == 0
     assert warm_spans == ["aggregate.hash"]

@@ -10,6 +10,7 @@ say which it took.
 """
 
 import functools
+import os
 import re
 import urllib.request
 
@@ -18,6 +19,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from benchmark.harness.loader import _load_module
 from trino_tpu import Engine
 from trino_tpu.connectors.memory import MemoryConnector
 from trino_tpu.connectors.tpch import TpchConnector
@@ -39,6 +41,12 @@ Q18_CHAIN = """
     select o_orderkey, o_totalprice, sum(l_quantity) q, count(*) n
     from lineitem, orders where l_orderkey = o_orderkey
     group by o_orderkey, o_totalprice order by o_orderkey"""
+# the benchmark's q18 (benchmark/statements/q18.py) at a QUANTITY that keeps a
+# few orders at SF0.01: since PR 30 its IN-subquery filters orders inside the
+# first join's build, so lineitem's first probe is the selective one
+Q18_SEMI, _ = _load_module(os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "statements", "q18.py"),
+    "q18").render({"quantity": 250})
 SEMI = """
     select l_orderkey, count(*) n from lineitem
     where l_orderkey in (select o_orderkey from orders
@@ -85,6 +93,18 @@ def _oracle_q18(T):
         q=("l_quantity", "sum"), n=("l_quantity", "size")).sort_values("o_orderkey")
 
 
+def _oracle_q18_semi(T):
+    li, o, c = T["lineitem"], T["orders"], T["customer"]
+    qty = li.groupby("l_orderkey").l_quantity.sum()
+    j = o[o.o_orderkey.isin(qty[qty > 250].index)] \
+        .merge(c, left_on="o_custkey", right_on="c_custkey") \
+        .merge(li, left_on="o_orderkey", right_on="l_orderkey")
+    g = j.groupby(["c_name", "c_custkey", "o_orderkey", "o_orderdate",
+                   "o_totalprice"], as_index=False).agg(q=("l_quantity", "sum"))
+    return g.sort_values(["o_totalprice", "o_orderdate"],
+                         ascending=[False, True]).head(100)
+
+
 def _oracle_dense_later(T):
     o = T["orders"]
     j = T["lineitem"].merge(o[(o.o_orderkey >= 7500) | (o.o_orderkey == 1)],
@@ -122,6 +142,8 @@ CASES = {
     "q3_chain": (Q3_CHAIN, _oracle_q3, "packed"),     # selective first join
     # every live lane of the first page matches: compiled as ONE step, as ever
     "q18_chain": (Q18_CHAIN, _oracle_q18, "fused"),
+    # the semi-join sits under orders (PushSemiJoinThroughJoin): few lanes match
+    "q18_semi": (Q18_SEMI, _oracle_q18_semi, "packed"),
     # sparse first batch, dense later ones: those pass the boundary unpacked
     "dense_later": (DENSE_LATER, _oracle_dense_later, "mixed"),
     "semi": (SEMI, _oracle_semi, "packed"),

@@ -573,6 +573,51 @@ class PushFilterThroughJoin(Rule):
         return P.Filter(out, _and_all(keep)) if keep else out
 
 
+class PushSemiJoinThroughJoin(Rule):
+    """Move a filtering semi-join onto the input of the join below it that
+    owns its key channels (reference: PredicatePushDown's visitSemiJoin /
+    the semi-join-as-filter reading of TransformFilteringSemiJoinToInnerJoin):
+    SemiJoin(Join(a, b), s) on a's channels -> Join(SemiJoin(a, s), b), and
+    on b's channels of an inner join -> Join(a, SemiJoin(b, s)).
+
+    A filtering semi-join is a predicate over its probe's key channels and the
+    build's key set only: a row passes where IN is TRUE, and a NULL key or an
+    UNKNOWN never passes, at whichever level it is asked.  So it commutes with
+    the join exactly as a single-side conjunct does in PushFilterThroughJoin,
+    under the same side table (the probe side of inner/left/anti, the build
+    side of inner only: an outer join's NULL-extended build rows do not exist
+    below it), less the probe side of a semi-join: two filtering semi-joins
+    over one input would pass each other for ever, and neither gains by it.
+    The lower join's schema is unchanged (a semi-join adds no channel), so
+    nothing above is remapped.  On a build side the semi-join becomes part of
+    the build, made once; on a probe side the lower join's first probe is the
+    selective one, which the executor's match-then-gather boundary packs.
+    Not ``mark`` (it adds a channel), not ``anti`` (NOT IN's null rules), not
+    a semi-join with a residual filter, not keys from both sides.  Each
+    application lowers the semi-join by one join, so it ends."""
+
+    pattern = (P.Join,)
+
+    def apply(self, node, memo):
+        keys = node.left_keys
+        if node.kind != "semi" or node.filter is not None or not keys:
+            return None
+        join = memo.resolve(node.left)
+        if not isinstance(join, P.Join):
+            return None
+        n_left = len(memo.resolve(join.left).schema.fields)
+        if max(keys) < n_left and join.kind in ("inner", "left", "anti"):
+            semi = dataclasses.replace(node, left=join.left,
+                                       schema=join.left.schema)
+            return dataclasses.replace(join, left=semi)
+        if min(keys) >= n_left and join.kind == "inner":
+            semi = dataclasses.replace(
+                node, left=join.right, schema=join.right.schema,
+                left_keys=tuple(k - n_left for k in keys))
+            return dataclasses.replace(join, right=semi)
+        return None
+
+
 class PushFilterThroughAggregate(Rule):
     """Conjuncts over GROUP BY key channels filter the groups' input rows
     identically (reference: iterative/rule/PushPredicateThroughProjectIntoRowNumber
@@ -1182,7 +1227,8 @@ DEFAULT_RULES = (MergeFilters(), MergeLimits(), EliminateLimitZero(),
                  MergeUnions(), PushLimitThroughUnion(),
                  RemoveRedundantLimit(),
                  # round-5 expansion (VERDICT item 4): pushdown + folding
-                 PushFilterThroughJoin(), PushFilterThroughAggregate(),
+                 PushFilterThroughJoin(), PushSemiJoinThroughJoin(),
+                 PushFilterThroughAggregate(),
                  PushFilterThroughWindow(), PushFilterThroughUnion(),
                  PushFilterThroughSort(), PropagateEmptyUnary(),
                  EliminateEmptyJoin(), DropEmptyUnionInputs(),
