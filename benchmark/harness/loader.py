@@ -71,9 +71,18 @@ class Cell:
         check_name(config["name"], "config")
         self.config = _read_json(os.path.join(root, config["file"]))
         self.config["name"] = config["name"]
+        # what the harness builds the deployment from (run.Bench); a file that leaves a
+        # key out describes one TpchConnector under the catalog "tpch" on one chip
+        self.config.setdefault("connector", "tpch")
+        self.config.setdefault("catalog", self.config["connector"])
+        if self.config.setdefault("chips", 1) != self.chips:
+            raise BenchmarkError(
+                f"workload {workload!r} asks for {self.chips} chips, its configuration "
+                f"{config['name']!r} describes a deployment on {self.config['chips']}")
         check_name(entry["traffic"], "traffic")
         self.traffic = _read_json(os.path.join(self.bench_dir, "traffic", entry["traffic"] + ".json"))
         self.traffic["name"] = entry["traffic"]
+        self.traffic.setdefault("statement_timeout_s", 300)
         self.statements = {}
         for slot in self.traffic["slots"]:
             name = check_name(slot, "statement")

@@ -8,9 +8,11 @@ cycle, each in an order drawn from the seed as TPC-H's query streams are, and a 
 started only while the last round's seconds still fit into the window, so every run
 does the same statements and none measures past ``--seconds``), ``params`` per statement
 (``fixed``: the statement's VALIDATION values, a replay of one text; ``fresh``: a draw
-from its substitution ranges for every execution) and ``poll_interval``.  Every client
-draws from its own ``random.Random`` seeded from ``--seed`` and the client's index, so the
-same seed gives the same statements whatever the threads do.
+from its substitution ranges for every execution), ``poll_interval`` and
+``statement_timeout_s`` (what the client gives one statement).  Clients are opened on the
+configuration's ``catalog``.  Every client draws from its own ``random.Random`` seeded
+from ``--seed`` and the client's index, so the same seed gives the same statements
+whatever the threads do.
 """
 
 import contextlib
@@ -39,10 +41,10 @@ def client_rng(seed, client, phase):
     return random.Random(f"{seed}/{phase}/{client}")
 
 
-def execute(client, statement, name, p, engine=None, annotate=False):
+def execute(client, statement, name, p, timeout_s, engine=None, annotate=False):
     """One statement from ``Client.execute`` call to the last page of its answer, as a
-    record.  ``engine`` (single-client cells only: the engine's last-statement facts
-    are shared state) adds the statement's own counters."""
+    record; the client gives it ``timeout_s``.  ``engine`` (single-client cells only: the
+    engine's last-statement facts are shared state) adds the statement's own counters."""
     sql, bound = statement.render(p)
     scope = contextlib.nullcontext()
     if annotate:
@@ -54,7 +56,7 @@ def execute(client, statement, name, p, engine=None, annotate=False):
         rec["t0"] = time.perf_counter()
         for attempt in (0, 1):
             try:
-                res = client.execute(sql, timeout=300.0, params=bound)
+                res = client.execute(sql, timeout=timeout_s, params=bound)
                 rec["columns"], rec["rows"] = res.column_names, res.rows
                 rec["error"] = None
                 break
@@ -92,7 +94,7 @@ def closed_loop(url, cell, seed, seconds, phase, engine=None, annotate=False, sl
     single = engine if clients == 1 else None
 
     def run(k, stop_at):
-        client = RecordingClient(url, catalog="tpch",
+        client = RecordingClient(url, catalog=cell.config["catalog"],
                                  poll_interval=traffic.get("poll_interval", 0.05))
         rng = client_rng(seed, k, phase)
         i, order, round_t0 = k * stride, list(slots), None
@@ -110,7 +112,8 @@ def closed_loop(url, cell, seed, seconds, phase, engine=None, annotate=False, sl
             statement = cell.statements[name]
             p = statement.VALIDATION if traffic["params"][name] == "fixed" \
                 else statement.params(rng, cell.config)
-            rec = execute(client, statement, name, p, engine=single, annotate=annotate)
+            rec = execute(client, statement, name, p, traffic["statement_timeout_s"],
+                          engine=single, annotate=annotate)
             rec["client"] = k
             with lock:
                 records.append(rec)

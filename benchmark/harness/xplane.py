@@ -73,8 +73,9 @@ def _host_activity(spans, a, z):
 
 
 def reduce_trace(path, cpu_stand_in=False, top=10):
-    """-> {"busy_s", "window_s", "devices", "device_ops": [[name, s]...],
-    "idle_gaps": [[name, s]...]}; ``busy_s`` is the mean over devices."""
+    """-> {"busy_s", "busy_s_by_device", "window_s", "devices", "device_ops": [[name, s]...],
+    "idle_gaps": [[name, s]...]}; ``busy_s`` is the mean over devices, ``busy_s_by_device``
+    each device's own in the order of the trace's planes."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(path)
@@ -109,5 +110,6 @@ def reduce_trace(path, cpu_stand_in=False, top=10):
     def ranked(d):
         return [[k, v / n / 1e9] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:top]]
 
-    return {"busy_s": sum(busy) / n / 1e9, "window_s": (hi - lo) / 1e9, "devices": n,
+    return {"busy_s": sum(busy) / n / 1e9, "busy_s_by_device": [b / 1e9 for b in busy],
+            "window_s": (hi - lo) / 1e9, "devices": n,
             "device_ops": ranked(ops), "idle_gaps": ranked(gaps)}

@@ -3,19 +3,24 @@
     python -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
     python -m benchmark.run --workload <name> --seed 7 --seconds 3 --trace 0 --rehearse
 
-ONE process, the only one to touch JAX.  It reads the cell from BENCHMARK.json, builds
-Engine + TpchConnector + CoordinatorServer with the program's defaults (no TRINO_TPU_*
-switch is set here), warms up the cell's statements (set-up, reported as ``setup_s``),
-drives the cell's traffic over POST /v1/statement for ``--seconds``, and only then
-pulls the reference's columns to the host and compares every checked answer.  Earlier
-lines of stdout are facts as JSON; the LAST line is the contract's result object.
+ONE process, the only one to touch JAX.  It reads the cell from BENCHMARK.json and
+builds the deployment that the cell's configuration file describes, with the program's
+defaults (no TRINO_TPU_* switch is set here): the ``connector`` (CONNECTORS) at ``sf``
+and ``split_rows``, registered under ``catalog`` in one Engine behind one
+CoordinatorServer; with ``chips`` over 1 the engine runs every statement on
+``worker_mesh(chips)``.  It warms up the cell's statements (set-up, reported as
+``setup_s``), drives the cell's traffic over POST /v1/statement for ``--seconds``, and
+only then pulls the reference's columns to the host and compares every checked answer.
+Earlier lines of stdout are facts as JSON; the LAST line is the contract's result object.
 
 Without ``--rehearse`` a backend that is not a TPU, or one with fewer chips than the
 cell asks for, is a non-zero exit with no result line.  ``--rehearse`` runs the same
-control flow on the CPU backend at the config's ``rehearse_sf`` and never names a TPU.
+control flow on the CPU backend at the config's ``rehearse_sf`` (on as many host devices
+as the cell has chips) and never names a TPU.
 """
 
 import argparse
+import importlib
 import json
 import os
 import random
@@ -30,6 +35,9 @@ from benchmark.harness.loader import BenchmarkError, Cell  # noqa: E402
 
 WARM_RUNS_MAX = 4
 TRACE_SAMPLE = 40
+# a configuration's ``connector`` -> the program's class; each takes (sf=, split_rows=)
+CONNECTORS = {"tpch": "trino_tpu.connectors.tpch.TpchConnector",
+              "tpcds": "trino_tpu.connectors.tpcds.TpcdsConnector"}
 
 
 def say(**facts):
@@ -64,6 +72,34 @@ class Context:
                 if r["error"] is None and (name is None or r["name"] == name)]
 
 
+def engine_on_mesh(chips):
+    """The program's engine with every statement on ``worker_mesh(chips)``.  The mesh is
+    an argument of ``execute_sql`` that the served path never passes, and the constructor
+    takes none (PERF.md section 7), so the default is set here: a caller that names
+    neither ``distributed`` nor ``mesh`` gets both, as a deployment on a mesh would."""
+    from trino_tpu import Engine
+    from trino_tpu.parallel.mesh import worker_mesh
+
+    class MeshEngine(Engine):
+        def execute_sql(self, sql, session=None, distributed=None, mesh=None, **kwargs):
+            if distributed is None and mesh is None:
+                distributed, mesh = True, self.mesh
+            return super().execute_sql(sql, session, bool(distributed), mesh, **kwargs)
+
+    engine = MeshEngine()
+    engine.mesh = worker_mesh(chips)
+    return engine
+
+
+def connector_class(config):
+    """The program's connector class that the configuration names."""
+    if config["connector"] not in CONNECTORS:
+        raise BenchmarkError(f"configuration {config['name']!r} names the connector "
+                             f"{config['connector']!r}: one of {sorted(CONNECTORS)}")
+    module, cls = CONNECTORS[config["connector"]].rsplit(".", 1)
+    return getattr(importlib.import_module(module), cls)
+
+
 class Bench:
     def __init__(self, cell, rehearse):
         import jax
@@ -83,15 +119,14 @@ class Bench:
             cell.peak(dev.device_kind)  # an unknown device kind is an error, not a default
 
         from trino_tpu import Engine
-        from trino_tpu.connectors.tpch import TpchConnector
         from trino_tpu.server.server import CoordinatorServer
 
         cfg = cell.config
         if rehearse:
             cfg["sf"] = cfg["rehearse_sf"]
-        self.conn = TpchConnector(sf=cfg["sf"], split_rows=cfg["split_rows"])
-        self.engine = Engine()
-        self.engine.register_catalog("tpch", self.conn)
+        self.conn = connector_class(cfg)(sf=cfg["sf"], split_rows=cfg["split_rows"])
+        self.engine = Engine() if cell.chips == 1 else engine_on_mesh(cell.chips)
+        self.engine.register_catalog(cfg["catalog"], self.conn)
         self.server = CoordinatorServer(self.engine, port=0)
         self.server.start()
         self.setup_records = []
@@ -102,6 +137,11 @@ class Bench:
     def close(self):
         self.server.stop()
 
+    def _client(self):
+        from benchmark.harness import loop
+
+        return loop.RecordingClient(self.server.url, catalog=self.cell.config["catalog"])
+
     # -- set-up --------------------------------------------------------------------
     def setup(self, seed):
         """Each of the cell's statements with the seed's first parameter draw until a
@@ -109,7 +149,7 @@ class Bench:
         then the traffic's warm burst, if it asks for one."""
         from benchmark.harness import loop
 
-        client = loop.RecordingClient(self.server.url, catalog="tpch")
+        client = self._client()
         rng = loop.client_rng(seed, 0, "setup")
         for name, statement in self.cell.statements.items():
             p = statement.VALIDATION if self.cell.traffic["params"][name] == "fixed" \
@@ -117,7 +157,8 @@ class Bench:
             runs, lookups, build_s = [], [], []
             for _ in range(WARM_RUNS_MAX):
                 before = self._build_lookups()
-                rec = loop.execute(client, statement, name, p, engine=self.engine)
+                rec = loop.execute(client, statement, name, p,
+                                   self.cell.traffic["statement_timeout_s"], engine=self.engine)
                 runs.append(rec)
                 lookups.append(self._build_lookups() - before)
                 build_s.append(self._build_seconds())
@@ -142,8 +183,8 @@ class Bench:
                     f"burst{i}.{attempt}", slots=burst.get("slots"))
                 self.setup_records.extend(records)
                 compiles = self.engine.counters_total.compiles - before
-                say(setup="burst", slots=burst.get("slots", "the mix"), statements=len(records),
-                    compiles=compiles)
+                say(setup="burst", slots=burst.get("slots", "the mix"), attempt=attempt,
+                    statements=len(records), compiles=compiles)
                 if not compiles:
                     break
         self.setup_s = time.perf_counter() - _T0
@@ -186,10 +227,11 @@ class Bench:
         ctx.pool = {k: pool1[k] - v for k, v in pool0.items()
                     if isinstance(v, (int, float)) and not isinstance(v, bool)}
         stats_ = self.jax.devices()[0].memory_stats() or {}
-        self.device["memory_peak_bytes"] = max(
-            ((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in self.jax.devices()[:self.cell.chips]), default=0)
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                 for d in self.jax.devices()[:self.cell.chips]]
+        self.device["memory_peak_bytes"] = max(peaks, default=0)
         say(memory={"peak_bytes_in_use": self.device["memory_peak_bytes"],
+                    "peak_bytes_in_use_by_device": peaks,
                     "bytes_limit": stats_.get("bytes_limit"),
                     "page_cache_budget_bytes": pool1.get("budget_bytes"),
                     "page_cache_bytes": pool1.get("bytes"), "evictions": pool1.get("evictions"),
@@ -207,9 +249,7 @@ class Bench:
         """Client seconds minus the server's root span, for a sample of each class,
         fetched after the window from GET /v1/query/{id}/trace (the server keeps the
         last hundred statements)."""
-        from benchmark.harness import loop
-
-        client = loop.RecordingClient(self.server.url, catalog="tpch")
+        client = self._client()
         for name in self.cell.statements:
             for rec in ctx.completed(name)[-TRACE_SAMPLE:]:
                 try:
@@ -303,12 +343,18 @@ def main(argv=None):
     args = ap.parse_args(argv)
     try:
         cell = Cell(args.workload)
+        if args.rehearse and cell.chips > 1 \
+                and "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+            # the CPU backend has one device unless asked, and is asked before JAX starts
+            os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_"
+                                       f"platform_device_count={cell.chips}").strip()
         bench = Bench(cell, args.rehearse)
     except BenchmarkError as e:
         print(f"benchmark.run: {e}", file=sys.stderr)
         return 2
     try:
         say(workload=cell.name, config=cell.config["name"], traffic=cell.traffic["name"],
+            connector=cell.config["connector"], catalog=cell.config["catalog"],
             sf=cell.config["sf"], seed=args.seed, device=bench.device,
             cache_dir=bench.jax.config.jax_compilation_cache_dir)
         bench.setup(args.seed)
@@ -330,6 +376,12 @@ def main(argv=None):
     return 0
 
 
+def _seconds_of(records):
+    seconds = [r["seconds"] for r in records]
+    return {"n": len(seconds), "median_s": stats.median(seconds),
+            "mean_s": sum(seconds) / len(seconds), "max_s": max(seconds)}
+
+
 def report(bench, ctx, checked, failed, setup_failed, trace):
     cell = bench.cell
     window_compiles = ctx.counters.get("compiles", 0)
@@ -339,9 +391,11 @@ def report(bench, ctx, checked, failed, setup_failed, trace):
         statements_in_window=len(ctx.records), statements_compared=len(checked),
         setup_statements_compared=len(bench.setup_records), setup_failed=setup_failed,
         window_s=ctx.window_s, window_compiles=window_compiles,
-        by_statement={n: {"n": len(ctx.completed(n)),
-                          "median_s": stats.median([r["seconds"] for r in ctx.completed(n)])}
+        by_statement={n: _seconds_of(ctx.completed(n))
                       for n in cell.statements if ctx.completed(n)},
+        # where in the window the slowest statements fell: [position, name, seconds]
+        slowest=sorted(([i, r["name"], r["seconds"]] for i, r in enumerate(ctx.records)),
+                       key=lambda x: -x[2])[:3],
         lost=sum(r["lost"] for r in ctx.records),
         result_cache_hits=ctx.counters.get("result_cache_hits", 0),
         device_dispatches=ctx.counters.get("device_dispatches", 0))
@@ -360,6 +414,7 @@ def report(bench, ctx, checked, failed, setup_failed, trace):
               "metrics": metrics_of(ctx, entries), "device": device}
     if trace:
         device["busy_s"], device["window_s"] = ctx.trace["busy_s"], ctx.trace["window_s"]
+        device["busy_s_by_device"] = ctx.trace["busy_s_by_device"]
         result["breakdown"] = {"device_ops": ctx.trace["device_ops"],
                                "idle_gaps": ctx.trace["idle_gaps"]}
     return result

@@ -1,28 +1,9 @@
 import json
 import os
-import shutil
 
 import pytest
 
-from benchmark.harness.loader import ROOT, BenchmarkError, Cell, check_name, check_unit
-
-
-@pytest.fixture
-def copy(tmp_path):
-    """BENCHMARK.json and benchmark/ (without its tests) in a temporary directory."""
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    return tmp_path
-
-
-def edit(root, fn):
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        bench = json.load(f)
-    fn(bench)
-    with open(path, "w") as f:
-        json.dump(bench, f)
+from benchmark.harness.loader import BenchmarkError, Cell, check_name, check_unit
 
 
 @pytest.mark.parametrize("name", ["a b", "a,b", "a/b", "", "-x", "x" * 65, "qμ"])
@@ -44,9 +25,12 @@ def test_good_names_and_units():
         assert check_unit(unit, "m") == unit
 
 
-@pytest.mark.parametrize("cell", ["sf1_joins", "sf10_scan", "sf1_dashboard"])
+@pytest.mark.parametrize("cell", ["sf1_joins", "sf10_scan", "sf1_dashboard", "sf10_joins"])
 def test_every_cell_of_the_repo_loads(cell):
     c = Cell(cell)
+    # the deployment the harness builds is written out in every file of the repo
+    assert (c.config["connector"], c.config["catalog"], c.config["chips"]) == ("tpch", "tpch", 1)
+    assert c.traffic["statement_timeout_s"] == 300
     names = {m["name"] for m in c.end_to_end}
     assert "setup_s" in names and len(names) >= 2
     assert c.per_layer and all(callable(m["read"]) for m in c.per_layer + c.end_to_end)
@@ -55,7 +39,7 @@ def test_every_cell_of_the_repo_loads(cell):
         assert st.TABLES and callable(st.reference) and isinstance(st.VALIDATION, dict)
 
 
-def test_a_bad_metric_name_or_unit_in_benchmark_json_is_refused(copy):
+def test_a_bad_metric_name_or_unit_in_benchmark_json_is_refused(copy, edit):
     edit(copy, lambda b: b["per_layer"].append(
         {"name": "bad name", "unit": "s", "better": "lower", "source": "host_clock",
          "layer": "x", "moves": "setup_s"}))
@@ -66,7 +50,7 @@ def test_a_bad_metric_name_or_unit_in_benchmark_json_is_refused(copy):
         Cell("sf1_joins", root=str(copy))
 
 
-def test_a_workload_naming_a_missing_file_is_refused(copy):
+def test_a_workload_naming_a_missing_file_is_refused(copy, edit):
     edit(copy, lambda b: b["workloads"].append(
         {"name": "ghost", "config": "tpch_sf1_1chip", "traffic": "no_such_mix", "chips": 1,
          "why": "x"}))
@@ -76,7 +60,7 @@ def test_a_workload_naming_a_missing_file_is_refused(copy):
         Cell("nowhere", root=str(copy))
 
 
-def test_a_later_pr_adds_one_of_each_as_new_files_and_entries(copy):
+def test_a_later_pr_adds_one_of_each_as_new_files_and_entries(copy, edit):
     """A statement, a traffic mix, a configuration, a per-layer metric and a cell: new
     files and new BENCHMARK.json entries, no edit to a file that was there."""
     before = {p: os.path.getmtime(os.path.join(dp, p)) for dp, _, fs in os.walk(copy / "benchmark")
@@ -130,3 +114,27 @@ def test_an_unknown_device_kind_has_no_peaks():
     assert cell.peak("TPU v5 lite")["hbm_gb_per_s"] == 819
     with pytest.raises(BenchmarkError, match="no peaks"):
         cell.peak("TPU v9")
+
+
+def test_a_file_that_leaves_the_deployment_keys_out_describes_todays(copy):
+    path = copy / "benchmark" / "configs" / "tpch_sf1_1chip.json"
+    config = json.loads(path.read_text())
+    for key in ("connector", "catalog", "chips"):
+        del config[key]
+    path.write_text(json.dumps(config))
+    traffic = copy / "benchmark" / "traffic" / "joins_stream.json"
+    traffic.write_text(json.dumps({k: v for k, v in json.loads(traffic.read_text()).items()
+                                   if k != "statement_timeout_s"}))
+    cell = Cell("sf1_joins", root=str(copy))
+    assert (cell.config["connector"], cell.config["catalog"], cell.config["chips"]) \
+        == ("tpch", "tpch", 1)
+    assert cell.traffic["statement_timeout_s"] == 300
+    config.update(connector="tpcds")
+    path.write_text(json.dumps(config))
+    assert Cell("sf1_joins", root=str(copy)).config["catalog"] == "tpcds"
+
+
+def test_chips_of_the_configuration_and_of_the_cell_have_to_agree(copy, edit):
+    edit(copy, lambda b: b["workloads"][0].update(chips=4))
+    with pytest.raises(BenchmarkError, match="asks for 4 chips.*describes a deployment on 1"):
+        Cell("sf1_joins", root=str(copy))

@@ -9,15 +9,17 @@ STEP = 0.05
 
 
 def cell(order, slots=("a", "b", "c")):
-    traffic = {"clients": 1, "slots": list(slots), "params": {s: "fixed" for s in slots}}
+    traffic = {"clients": 1, "slots": list(slots), "params": {s: "fixed" for s in slots},
+               "statement_timeout_s": 7}
     if order:
         traffic["order"] = order
-    return SimpleNamespace(traffic=traffic, config={},
+    return SimpleNamespace(traffic=traffic, config={"catalog": "elsewhere"},
                            statements={s: SimpleNamespace(VALIDATION={}) for s in slots})
 
 
 def drive(monkeypatch, order, seed, seconds):
-    def fake(client, statement, name, p, engine=None, annotate=False):
+    def fake(client, statement, name, p, timeout_s, engine=None, annotate=False):
+        assert timeout_s == 7 and client.catalog == "elsewhere"  # the cell's files say both
         t0 = time.perf_counter()
         time.sleep(STEP)
         t1 = time.perf_counter()
@@ -47,3 +49,18 @@ def test_the_first_round_runs_even_where_it_does_not_fit(monkeypatch):
 def test_a_cycle_closes_with_the_statement_in_flight(monkeypatch):
     names, window_s = drive(monkeypatch, None, 5, 3.5 * STEP)
     assert names == ["a", "b", "c", "a"] and window_s >= 3.5 * STEP
+
+
+def test_the_statement_timeout_reaches_the_client():
+    calls = []
+
+    class Client:
+        last_id = "q1"
+
+        def execute(self, sql, timeout, params):
+            calls.append((sql, timeout, params))
+            return SimpleNamespace(column_names=["c"], rows=[[1]])
+
+    statement = SimpleNamespace(render=lambda p: ("select 1", None))
+    rec = loop.execute(Client(), statement, "one", {}, 900)
+    assert calls == [("select 1", 900, None)] and rec["error"] is None and rec["rows"] == [[1]]

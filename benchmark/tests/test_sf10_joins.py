@@ -24,7 +24,7 @@ LISTED = {"plan_ms.olap", "window_compiles.olap", "compile_misses.olap",
           "dispatches_per_stmt.olap", "page_cache_hit_share.olap",
           "build_cache_lookups_per_stmt.olap", "device_busy_s_per_stmt.olap", "q3_s.olap",
           "q18_s.olap", "host_pull_s_per_stmt.olap", "dispatch_s_per_stmt.olap",
-          "host_other_s_per_stmt.olap"} | set(NEW_METRICS)
+          "host_other_s_per_stmt.olap", "join_gather_lane_share.olap"} | set(NEW_METRICS)
 # nothing to read on the CPU backend, by design: the page cache is off there (its
 # budget is 0, so no lookup is made), and the stand-in trace has no device plane
 NONE_ON_CPU = {"page_cache_hit_share.olap", "device_busy_s_per_stmt.olap"}
@@ -46,7 +46,7 @@ def test_the_new_entries_load_and_pass_the_name_and_unit_checks():
     assert traffic.pop("why") and traffic.pop("name") == "joins_sf10_stream"
     assert traffic == {"loop": "closed", "clients": 1, "slots": ["q3", "q18"],
                        "order": "seeded_rounds", "params": {"q3": "fixed", "q18": "fixed"},
-                       "check": "all", "trace_seconds": 5}
+                       "check": "all", "statement_timeout_s": 300, "trace_seconds": 5}
     assert {m["name"] for m in cell.end_to_end} == {"stmt_s.geomean", "setup_s"}
     assert {m["name"] for m in cell.per_layer} == LISTED
     config = next(c for c in bench["configs"] if c["name"] == "tpch_sf10_joins_1chip")

@@ -16,10 +16,11 @@ def test_reduction_of_the_recorded_v5e_trace():
     calls each on one TPU v5e, with sleeps between them (record_small_trace.py)."""
     r = xplane.reduce_trace(SMALL)
     assert r["devices"] == 1
-    # six executions of about 11.8 us each, four of them inside the annotated window's clock
-    assert 0 < r["busy_s"] < 1e-4
-    assert 0.02 < r["window_s"] < 0.03
-    assert r["busy_s"] < r["window_s"]
+    # six executions of about 11.8 us each, four of them inside the annotated window's clock:
+    # the numbers the reduction gave before it listed each device's own busy seconds
+    assert (r["busy_s"], r["window_s"]) == (4.7374e-05, 0.024291369)
+    assert r["busy_s_by_device"] == [r["busy_s"]]
+    assert r["device_ops"][0] == ["convolution_reduce_fusion", 4.7309e-05]
     ops = dict(r["device_ops"])
     assert "convolution_reduce_fusion" in ops
     assert sum(ops.values()) >= r["busy_s"]
@@ -38,3 +39,22 @@ def test_a_trace_without_device_operations_is_an_error():
     assert any(p.name.startswith(xplane.DEVICE_PLANE) for p in data.planes)
     with pytest.raises(FileNotFoundError):
         xplane.find_trace(os.path.dirname(__file__))
+
+
+def test_busy_seconds_are_averaged_over_the_devices_and_listed_for_each(monkeypatch):
+    """Two device planes, one busy twice as long as the other."""
+    from types import SimpleNamespace as NS
+
+    def plane(name, *events):
+        return NS(name=name, lines=[NS(name="XLA Ops", events=[
+            NS(name="fusion = f32[]", start_ns=a, duration_ns=d) for a, d in events])])
+
+    data = NS(planes=[plane("/device:TPU:0", (0, 400), (600, 400)),
+                      plane("/device:TPU:1", (100, 400)), NS(name="/host:CPU", lines=[])])
+    import jax
+
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file", staticmethod(lambda path: data))
+    r = xplane.reduce_trace("two.xplane.pb")
+    assert r["devices"] == 2 and r["window_s"] == 1000e-9
+    assert r["busy_s_by_device"] == [800e-9, 400e-9] and r["busy_s"] == 600e-9
+    assert dict(r["idle_gaps"]) == {"between statements": pytest.approx(400e-9)}
