@@ -7,6 +7,7 @@ trino_tpu.server): parse → analyze → plan → optimize → execute.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import re
@@ -23,6 +24,7 @@ _WALL_COUNTERS = (("plan", "wall_plan_s"),
                   ("h2d", "wall_h2d_s"),
                   ("device_dispatch", "wall_dispatch_s"),
                   ("host_pull", "wall_host_pull_s"),
+                  ("exchange_wait", "wall_exchange_wait_s"),
                   ("unattributed", "wall_unattributed_s"))
 
 
@@ -38,8 +40,18 @@ class Session:
 
 
 class Engine:
-    def __init__(self):
+    def __init__(self, mesh=None):
         self.catalogs: dict = {}
+        # a deployment on a worker mesh (parallel.mesh.worker_mesh(n)): every
+        # statement whose caller names neither ``distributed`` nor ``mesh``
+        # runs on this mesh's executor.  None: the one-chip engine.
+        self.mesh = mesh
+        # mesh -> its DistributedExecutor.  One per mesh, made at the first
+        # statement on it and kept: compiled fragments, join tables and the
+        # ladder rung that last held outlive the statement, as a pooled
+        # LocalExecutor's caches do.  Its caches are single-statement state,
+        # so ``ex.statement_lock`` serializes the statements on one mesh.
+        self._mesh_executors: dict = {}
         self.sql_routines: dict = {}  # name -> (params, return_type, body AST)
         self.views: dict = {}  # name -> (column_aliases, Select AST); reference:
         # coordinator-only DataDefinitionTask CreateViewTask (execution/CreateViewTask.java)
@@ -212,6 +224,7 @@ class Engine:
             # re-pool an executor retired by this invalidation
             self._executor_pool = []
             self._all_executors = []
+            self._mesh_executors = {}
         self._fte_executor = None
         bp = getattr(self, "buffer_pool", None)
         if bp is not None:
@@ -290,7 +303,7 @@ class Engine:
         executables + device arrays, so dropping only the cache entry would
         leak a full compiled copy per replan (same reasoning as
         _invalidate)."""
-        for ex in self._all_executors:
+        for ex in self._all_executors + list(self._mesh_executors.values()):
             ex.forget_plan(plan)
         fte = getattr(self, "_fte_executor", None)
         if fte is not None:
@@ -776,20 +789,23 @@ class Engine:
             finally:
                 self._account_counters(counters)
         if distributed:
-            from .exec.distributed import DistributedExecutor
-
-            ex = DistributedExecutor(self.catalogs, mesh=mesh,
-                                     dispatch_batch=dispatch_batch)
-            try:
-                result = ex.execute(plan)
-                # clean completion only: fold per-exchange shard skew into
-                # the plan-history store under the same structural node
-                # paths the cardinality actuals use (round 20 — record and
-                # expose; the advisor's consumer lands later)
-                self._record_shard_skew(plan, ex)
-                return result
-            finally:
-                self._account_counters(ex.counters)
+            with self._mesh_executor(mesh) as ex:
+                ex.local.dispatch_batch = dispatch_batch
+                ex.local.page_cache = page_cache
+                try:
+                    result = ex.execute(plan)
+                    # clean completion only: fold per-exchange shard skew
+                    # into the plan-history store under the same structural
+                    # node paths the cardinality actuals use (round 20 —
+                    # record and expose; the advisor's consumer lands later)
+                    self._record_shard_skew(plan, ex)
+                    return result
+                finally:
+                    ex.local.dispatch_batch = None
+                    ex.local.page_cache = None
+                    if not cache:
+                        ex.forget_plan(plan)  # a plan nobody will replay
+                    self._account_counters(ex.counters)
         from .exec.local_executor import LocalExecutor
 
         if not cache:
@@ -1083,6 +1099,29 @@ class Engine:
             self._all_executors.append(ex)
             return ex
 
+    @contextlib.contextmanager
+    def _mesh_executor(self, mesh):
+        """The engine's executor for ``mesh`` (None: the engine's own mesh,
+        else the default worker mesh), made once and held for one statement
+        at a time; a second concurrent mesh statement waits here, and the
+        wait is its ``executor.checkout`` span and ``executor_wait_s``."""
+        from .execution import tracing
+
+        mesh = mesh if mesh is not None else self.mesh
+        with self._init_lock:
+            ex = self._mesh_executors.get(mesh)
+            if ex is None:
+                from .exec.distributed import DistributedExecutor
+
+                ex = self._mesh_executors[mesh] = DistributedExecutor(
+                    self.catalogs, mesh=mesh, buffer_pool=self.buffer_pool)
+        with tracing.wait_span("executor.checkout", "executor_wait_s"):
+            ex.statement_lock.acquire()
+        try:
+            yield ex
+        finally:
+            ex.statement_lock.release()
+
     def _release_executor(self, ex) -> None:
         # belt-and-braces: execute() already sweeps its prefetch producers on
         # every exit, but an executor going back into circulation (where the
@@ -1097,7 +1136,7 @@ class Engine:
         self._executor_sem.release()
 
     def execute_sql(self, sql: str, session: Optional[Session] = None,
-                    distributed: bool = False, mesh=None,
+                    distributed: Optional[bool] = None, mesh=None,
                     fault_tolerant: bool = False,
                     parameters: Optional[list] = None):
         """Statement entry point (reference: DispatchManager.createQuery:176 ->
@@ -1116,6 +1155,11 @@ class Engine:
         from .execution import tracing
         from .execution.query_state import QueryState, QueryStateMachine
 
+        if distributed is None:
+            # an engine on a mesh runs a statement there unless told otherwise
+            distributed = self.mesh is not None and mesh is None \
+                and not fault_tolerant
+        distributed = bool(distributed)
         # where the statement's queue phase starts: where the front end says
         # it accepted it (the server's POST), else here
         accepted = tracing.take_accepted() or (time.perf_counter(), {})
@@ -2230,20 +2274,22 @@ class Engine:
             # execution mode — mesh / coordinator / local with the fallback
             # reason (VERDICT r3 weak #3: fallbacks must be visible, reference:
             # sql/planner/planprinter fragment output)
-            from .exec.distributed import DistributedExecutor
-
-            ex = DistributedExecutor(self.catalogs, mesh=mesh)
-            t0 = time.perf_counter()
-            res = ex.execute(plan)
-            dt = time.perf_counter() - t0
-            self._account_counters(ex.counters)
-            self._record_shard_skew(plan, ex)
-            lines = format_plan(plan, counters=ex.counters,
-                                skew=ex.skew_by_node).split("\n")
-            lines += ["", "Fragment execution (distributed run):"]
-            for label, mode, reason in ex.exec_trace:
-                note = f" — {reason}" if reason else ""
-                lines += [f"  [{mode}] {label}{note}"]
+            with self._mesh_executor(mesh) as ex:
+                t0 = time.perf_counter()
+                try:
+                    res = ex.execute(plan)
+                finally:
+                    # this plan was made for the EXPLAIN and is never replayed
+                    ex.forget_plan(plan)
+                dt = time.perf_counter() - t0
+                self._account_counters(ex.counters)
+                self._record_shard_skew(plan, ex)
+                lines = format_plan(plan, counters=ex.counters,
+                                    skew=ex.skew_by_node).split("\n")
+                lines += ["", "Fragment execution (distributed run):"]
+                for label, mode, reason in ex.exec_trace:
+                    note = f" — {reason}" if reason else ""
+                    lines += [f"  [{mode}] {label}{note}"]
             lines += [f"-- executed in {dt * 1000:.1f} ms, {len(res)} output rows"]
         elif ast.analyze:
             from .exec.local_executor import LocalExecutor

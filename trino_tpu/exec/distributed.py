@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 import time
 from functools import partial
 from typing import Callable, Optional
@@ -36,7 +37,9 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from ..execution import faults
-from ..execution.tracing import maybe_span, record_shard_stats
+from ..execution.tracing import (maybe_span, record_join_build,
+                                 record_mesh_fragment, record_page_cache,
+                                 record_rows_generated, record_shard_stats)
 from ..ops import hashagg
 from ..ops.arrays import append_rows, compact_rows
 from ..ops.exchange import bucketize, exchange_all_to_all, partition_ids
@@ -52,7 +55,7 @@ from .local_executor import (DEFAULT_GROUP_CAPACITY, MAX_GROUP_CAPACITY, LocalEx
                              _accumulators_for, _build_null_stats,
                              _compact_part, _finalize_aggs, _gather_build, _limit_page,
                              _materialize, _null_aware_anti, _page_to_device,
-                             _sort_page, _window_spec_dicts)
+                             _sort_page, _split_base_rows, _window_spec_dicts)
 
 
 def _route_rows(cols, nulls, valid, pid, n_parts: int, bucket: int, axis_name):
@@ -117,6 +120,46 @@ _MERGE_KIND = {"sum": "sum", "count": "sum", "count_star": "sum", "min": "min",
                # two-limb partial sums merge by PLAIN addition (the limbs are
                # already split; splitting again would corrupt them)
                "sum_hi32": "sum", "sum_lo32": "sum"}
+
+
+# a batch whose live rows fit a 16th of its lanes is packed before the group-by
+# insert: a TPU scatter costs by WIDTH, sink writes included (the local
+# executor's _run_hash_inserts makes the same cut, from a pulled count)
+_SPARSE_INSERT_SHIFT = 4
+# the merge's fresh table: a direct-indexed state can be a handful of slots
+_MERGE_MIN_SLOTS = 64
+
+
+def _groupby_insert_live(state, key_vals, key_types, valid, inputs, acc_kinds):
+    """``hashagg.groupby_insert`` over one worker's batch, at the width of its
+    live rows where they are few.  After a selective join (q3 keeps a 200th
+    of the lanes its probe exchange receives) every scatter of the insert
+    (one a key column and an accumulator, 74-290 ns a LANE) would run at
+    full width; ``compact_rows`` is a sort and gathers.  The choice is a
+    ``lax.cond`` on the live count, inside the step: no pull, no second
+    program, and a dense batch pays one reduction."""
+    n = valid.shape[0]
+    bucket = n >> _SPARSE_INSERT_SHIFT
+    if bucket < 1024:
+        return hashagg.groupby_insert(state, key_vals, key_types, valid, inputs,
+                                      acc_kinds)
+    flat = tuple(key_vals) + tuple(x for vn in inputs for x in vn)
+    nk = len(key_vals)
+
+    def sparse(state):
+        packed, total = compact_rows(flat, valid, bucket)
+        live = jnp.arange(bucket, dtype=jnp.int32) < total
+        rest = packed[nk:]
+        cinputs = [(rest[2 * i], rest[2 * i + 1]) for i in range(len(inputs))]
+        return hashagg.groupby_insert(state, packed[:nk], key_types, live,
+                                      cinputs, acc_kinds)
+
+    def dense(state):
+        return hashagg.groupby_insert(state, key_vals, key_types, valid, inputs,
+                                      acc_kinds)
+
+    return jax.lax.cond(jnp.sum(valid, dtype=jnp.int32) <= bucket, sparse,
+                        dense, state)
 
 
 def _eval_project(exprs, cols, nulls, shape):
@@ -272,9 +315,10 @@ def _multi_probe_expand(node, mt, build_key_types, cols, nulls, valid,
 
 
 def _slice_batch(batch_g):
-    """Per-worker slice of a scan-batch pytree inside a shard_map body: for
-    traced scans the batch is a [W] offset vector (slice = scalar lo), for
-    host-fed scans a (cols, nulls, valid) pytree of [W, cap] arrays."""
+    """Per-worker slice of a scan-batch pytree inside a shard_map body: a
+    generator scan's batch is a resident (cols, valid) pytree of [W, lanes]
+    arrays (_ShardedScan), a host-fed scan's a (cols, nulls, valid) pytree
+    of [W, cap] arrays."""
     return jax.tree.map(lambda x: x[0], batch_g)
 
 
@@ -371,6 +415,117 @@ class _HostFedBatches:
         return (tuple(cols), tuple(nulls), valid)
 
 
+class _ShardedScan:
+    """A generator connector's table scan, resident on the mesh: batch b is a
+    (cols, valid) pytree of [W, lanes] arrays sharded on the worker axis
+    (split b*W+d is chip d's row), generated by ONE jitted shard_map program
+    a table and column set and consumed by the fragment steps as an
+    argument.  The whole scan is one entry of the engine's page cache,
+    accounted at the bytes ONE chip holds (a W-th of it: the budget is a
+    chip's), so it is looked up once a statement, generated only when the
+    pool does not hold it (first run, after an eviction or invalidation),
+    and streamed batch by batch, never pinned, when the pool is off or the
+    entry passes its cap.  The same sequence protocol as _HostFedBatches:
+    retry ladders and capacity growths re-iterate it.  What it holds is
+    single-statement state: ``release`` at the statement's end."""
+
+    def __init__(self, ex, conn, catalog, table, columns, splits):
+        self.ex, self.conn = ex, conn
+        self.catalog, self.table, self.columns = catalog, table, tuple(columns)
+        W = ex.n_workers
+        self.rows = splits[0].hi - splits[0].lo  # lanes a worker a batch
+        self._n = len(splits) // W
+        self._lo = [np.asarray([splits[b * W + d].lo for d in range(W)],  # host-ok: split list
+                               dtype=np.int64) for b in range(self._n)]
+        base = _split_base_rows(conn, table, splits)
+        self._base_rows = [sum(base[b * W:(b + 1) * W]) for b in range(self._n)]
+        self._splits = splits
+        self._held = None    # the resident batches, while a statement runs
+        self._acc = None     # batches gathered for the pool, in order
+        self._looked = False
+
+    def __len__(self):
+        return self._n
+
+    def __iter__(self):
+        return (self[i] for i in range(self._n))
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            lo, hi, st = i.indices(self._n)
+            assert st == 1 and hi == self._n, "only tail slices are used"
+            return [self[j] for j in range(lo, hi)]
+        if i < 0 or i >= self._n:
+            raise IndexError(i)
+        self._lookup()
+        if self._held is not None:
+            return self._held[i]
+        batch = self._generate(i)
+        if self._acc is not None and i == len(self._acc):
+            self._gather(batch)
+        return batch
+
+    def release(self):
+        self._held = self._acc = None
+        self._looked = False
+
+    def _key(self):
+        bp = self.ex.buffer_pool
+        if not self._n or bp is None or not self.ex.local._page_cache_on() \
+                or not bp.cacheable(self.conn):
+            return None
+        key = bp.page_key(self.catalog, self.conn, self.table, self._splits,
+                          self.columns)
+        # not a Page: a local scan of the same splits must never read it
+        return key[:3] + (("mesh", self.ex.n_workers) + key[3],) + key[4:]
+
+    def _lookup(self):
+        if self._looked:
+            return
+        self._looked = True
+        self.ex._scans.append(self)
+        key = self._key()
+        if key is None:
+            return
+        site = f"dist.scan.{self.table}.cache"
+        hit = self.ex.buffer_pool.get_page(key)
+        if hit is not None:
+            self._held, nbytes = hit
+            record_page_cache(hits=1, bytes_saved=nbytes, site=site)
+            return
+        record_page_cache(misses=1, site=site)
+        self._acc = []
+
+    def _generate(self, i):
+        gen = self.ex._scan_generator(self.conn, self.table, self.columns,
+                                      self.rows)
+        faults.maybe_inject("generate", f"scan.{self.table}")
+        with maybe_span("split-generation", table=self.table, batch=i):
+            lo = jax.device_put(self._lo[i], self.ex._sharded)  # device-ok: mesh-sharded placement
+            batch = gen(lo)
+        record_rows_generated(self._base_rows[i])
+        return batch
+
+    def _gather(self, batch):
+        """One more batch of a scan the pool does not hold yet; the last one
+        stores the entry.  A scan whose chip's share passes the entry cap
+        pins nothing, as the local path's does not."""
+        bp = self.ex.buffer_pool
+        per_chip = sum(int(a.nbytes) for a in jax.tree.leaves(batch)) \
+            // self.ex.n_workers
+        if per_chip * self._n > bp.page_entry_cap():
+            self._acc = None
+            return
+        self._acc.append(batch)
+        if len(self._acc) < self._n:
+            return
+        self._held, self._acc = tuple(self._acc), None
+        try:
+            bp.put_page(self._key(), self._held, nbytes=per_chip * self._n)
+        except Exception:
+            pass  # uncached, not failed; the next statement regenerates
+
+
 def _collation_luts(sort_keys, fields, dicts):
     """id -> collation-rank LUTs for dictionary-encoded sort keys: ids are
     assigned in insertion order, so device sorts must compare decoded-value
@@ -435,7 +590,7 @@ def _stack_shards(per_cols, per_nulls, counts, fields):
     return tuple(cols_g), tuple(nulls_g), valid_g, nmax
 
 
-def _page_from_shards(schema, cols_g, nulls_g, counts):
+def _page_from_shards(schema, cols_g, nulls_g, counts, keep=None):
     """Reassemble [W, nmax] shard results into one flat page: worker w
     contributes its counts[w] head rows, workers concatenated in mesh order.
 
@@ -443,7 +598,9 @@ def _page_from_shards(schema, cols_g, nulls_g, counts):
     compaction over the flattened [W*nmax] layout — compact_rows keeps
     arrival order, so the result is byte-identical to the host concat) and
     the page never round-trips.  Mixed/host shards take the host concat,
-    staged back through ``_page_to_device`` (counted, injectable H2D)."""
+    staged back through ``_page_to_device`` (counted, injectable H2D).
+    ``keep(what, make)`` is the caller's fragment store for the jitted
+    concat (its shape facts are the key)."""
     W = len(counts)
     cols_l, nulls_l = list(cols_g), list(nulls_g)
     if cols_l and all(isinstance(a, jax.Array) for a in cols_l + nulls_l):
@@ -459,8 +616,10 @@ def _page_from_shards(schema, cols_g, nulls_g, counts):
             packed, _ = compact_rows(arrs, valid, max(total, 1))
             return packed[:len(cols_t)], packed[len(cols_t):]
 
-        out_cols, out_nulls = _jit(concat, site="dist.shards.concat")(
-            tuple(cols_l), tuple(nulls_l), counts_t)
+        make = partial(_jit, concat, site="dist.shards.concat")
+        fn = make() if keep is None else keep(
+            ("shards.concat", total, nmax, len(cols_l)), make)
+        out_cols, out_nulls = fn(tuple(cols_l), tuple(nulls_l), counts_t)
         if total == 0:
             # compact_rows needs out_len >= 1; trim the placeholder row
             out_cols = tuple(c[:0] for c in out_cols)
@@ -500,13 +659,30 @@ class _DStream:
 
 class DistributedExecutor:
     """Executes plans SPMD across the mesh; falls back to LocalExecutor for blocking
-    sub-plans (join build sides, small inputs)."""
+    sub-plans (join build sides, small inputs).
+
+    It outlives a statement (the engine keeps one a mesh): what a plan node
+    compiled to is KEPT under the node's identity with a strong reference,
+    as LocalExecutor._stream_cache keeps its streams: the _DStream with its
+    join tables in ``aux``, the jitted shard_map steps over it, the build
+    side's page and facts, the rung of _EXCHANGE_LADDER and the group-by
+    capacity that last held.  A replay of one plan compiles nothing, builds
+    nothing and pulls only the flags its exchanges need.  ``forget_plan``
+    (the engine's version-stale path) and the engine's ``_invalidate`` (which
+    drops the executor) are what forgets.  All of it is single-statement
+    state: the engine runs one statement at a time under ``statement_lock``."""
 
     def __init__(self, catalogs: dict, mesh=None, partition_threshold: int = 1 << 17,
-                 dispatch_batch=None, device_exchange=None):
+                 device_exchange=None, buffer_pool=None):
         self.catalogs = catalogs
         self.mesh = mesh if mesh is not None else worker_mesh()
         self.n_workers = self.mesh.devices.size
+        self._sharded = NamedSharding(self.mesh, PS(WORKER_AXIS))
+        self.statement_lock = threading.Lock()
+        # the engine's device buffer pool: resident sharded scans are entries
+        # of its page tier, and the embedded LocalExecutor's build sides read
+        # and store their scans and tables there like any pooled executor's
+        self.buffer_pool = buffer_pool
         # device-resident exchange (round 18): routed rows append into carried
         # [W, cap] device receive buffers INSIDE the routing shard_map and the
         # blocking consumers (sort shard, window partition, final-agg merge,
@@ -518,22 +694,25 @@ class DistributedExecutor:
             device_exchange = os.environ.get(
                 "TRINO_TPU_DEVICE_EXCHANGE", "1") != "0"
         self.device_exchange = bool(device_exchange)
-        self.local = LocalExecutor(catalogs)
-        # session dispatch-coalescing width threads into the fallback local
-        # executor: blocking sub-plans (join builds, small fragments) coalesce
-        # their per-split dispatches exactly like a purely local query.  The
-        # SPMD paths are already whole-mesh batched (one dispatch per batch of
-        # W splits), so only the local side needs the knob.
-        self.local.dispatch_batch = dispatch_batch
+        # blocking sub-plans (join builds, small fragments) run here; the
+        # engine sets its per-query knobs (dispatch_batch, page_cache) for the
+        # statement, as it does on a pooled executor.  The SPMD paths are
+        # already whole-mesh batched (one dispatch per batch of W splits)
+        self.local = LocalExecutor(catalogs, buffer_pool=buffer_pool)
         # build sides at/above this row count join PARTITIONED (all-to-all probe
         # exchange) instead of broadcast (reference: DetermineJoinDistributionType's
         # size-based choice, iterative/rule/DetermineJoinDistributionType.java:51)
         self.partition_threshold = partition_threshold
+        self._rung = 0
         self._probe_factor, self._expand_factor = _EXCHANGE_LADDER[0]
-        # per-execute build artifacts (pages, join tables) keyed by plan-node
-        # id: the retry ladder recompiles only the probe side — build-side
-        # local execution and the build-exchange compile are rung-invariant
-        self._build_cache: dict = {}
+        # (id(node), what...) -> (node, value): everything kept for a plan
+        # node (class docstring).  Build artifacts (pages, join tables, the
+        # facts pulled from them) are rung-invariant: the retry ladder
+        # recompiles only the probe side
+        self._kept: dict = {}
+        # (catalog, table, columns, lanes) -> the jitted shard_map generator
+        self._generators: dict = {}
+        self._scans: list = []  # _ShardedScans this statement touched
         self.exec_trace: list = []
         self._decline_reason = None
         # per-query device-boundary counters: mesh dispatches/pulls record
@@ -553,7 +732,6 @@ class DistributedExecutor:
     def execute(self, node: P.PlanNode) -> MaterializedResult:
         from ..execution import tracing
 
-        self._build_cache = {}
         self.exec_trace = []  # [(node label, mode, reason)] — runtime truth of
         # which fragments ran on the mesh vs fell back (VERDICT r3 weak #3:
         # silent local fallback); EXPLAIN ANALYZE prints it
@@ -568,6 +746,79 @@ class DistributedExecutor:
             # blocking sub-plans run on the embedded LocalExecutor, which may
             # start prefetch producers: stop them on error paths too
             self.local.close_producers()
+            # the resident batches stay the pool's to evict between statements
+            for scan in self._scans:
+                scan.release()
+            self._scans = []
+
+    # ------------------------------------------------------------ what is kept
+    def _held(self, node, what: tuple):
+        """The kept (node, value) entry of (node, what), or None."""
+        hit = self._kept.get((id(node),) + what)
+        return hit if hit is not None and hit[0] is node else None
+
+    def _keep(self, node, what: tuple, make):
+        """``make()`` once for (node, what), then the kept value."""
+        hit = self._held(node, what)
+        if hit is None:
+            hit = self._kept[(id(node),) + what] = (node, make())
+        return hit[1]
+
+    def _fragment(self, node) -> Optional[_DStream]:
+        """The compiled stream of ``node`` at the current rung, kept; None
+        (with its reason) when it has no distributable scan spine.  THE
+        lookup the fragment counters and the ``mesh.fragment`` span read:
+        one a consumer's attempt."""
+        what = ("stream", self._rung)
+        hit = self._held(node, what) is not None
+        with maybe_span("mesh.fragment", hit=hit, node=type(node).__name__):
+            record_mesh_fragment(hit)
+            stream, reason = self._keep(node, what, lambda: (
+                self._compile_stream(node), self._decline_reason))
+        if stream is None and self._decline_reason is None:
+            self._decline_reason = reason
+        return stream
+
+    def _step(self, node, what: tuple, make):
+        """A jitted step over ``node``'s fragment at the current rung."""
+        return self._keep(node, what + (self._rung,), make)
+
+    def forget_plan(self, plan: P.PlanNode) -> None:
+        """Drop what is kept for a plan the engine is replacing or will not
+        replay (LocalExecutor.forget_plan's contract)."""
+        ids = set()
+
+        def walk(n):
+            ids.add(id(n))
+            for c in n.children:
+                walk(c)
+
+        walk(plan)
+        for key in [k for k in list(self._kept) if k[0] in ids]:
+            self._kept.pop(key, None)
+        self.local.forget_plan(plan)
+
+    def _scan_generator(self, conn, table, columns, rows):
+        """The one generator program of a table and column set: every worker
+        generates its split of ``rows`` lanes at its own offset."""
+        key = (id(conn), table, tuple(columns), rows)
+        hit = self._generators.get(key)
+        if hit is not None and hit[0] is conn:
+            return hit[1]
+
+        @partial(shard_map, mesh=self.mesh, in_specs=PS(WORKER_AXIS),
+                 out_specs=PS(WORKER_AXIS))
+        def generate(lo_g):
+            cols, valid = conn.generate_traced(table, lo_g[0], rows, columns)
+            if valid is None:
+                valid = jnp.ones(cols[0].shape, bool)
+            # a constant mask is unvarying: derive the worker axis from lo
+            valid = valid | (lo_g[0] < 0)
+            return tuple(c[None] for c in cols), valid[None]
+
+        fn = _jit(generate, site="dist.scan.generate")
+        self._generators[key] = (conn, fn)
+        return fn
 
     def _decline(self, node, reason: str):
         """Record why a fragment cannot compile for the mesh (deepest cause
@@ -606,20 +857,25 @@ class DistributedExecutor:
         return rec
 
     # ---------------------------------------------------------------- retries
-    def _retry_exchange(self, run_once):
+    def _retry_exchange(self, node, run_once):
         """The overflow side-channel's host half: run a compiled fragment; when
         any worker reports an exchange/expansion bucket overflow, climb the
         ladder (bigger buckets) and re-run from scratch — the same
-        grow-and-retry pattern as aggregation capacity growth.  Returns the
+        grow-and-retry pattern as aggregation capacity growth.  The climb
+        STARTS at the rung that last held for ``node`` (kept: a replay does
+        not overflow its way up again) and is not capped there.  Returns the
         result, or None when the fragment is not distributable (caller falls
         back to local)."""
-        for pf, ef in _EXCHANGE_LADDER:
-            self._probe_factor, self._expand_factor = pf, ef
+        start = self._keep(node, ("rung",), lambda: [0])
+        for rung in range(start[0], len(_EXCHANGE_LADDER)):
+            self._rung = rung
+            self._probe_factor, self._expand_factor = _EXCHANGE_LADDER[rung]
             out = run_once()
             if out is None:
                 return None
             result, oflow = out
             if not oflow:
+                start[0] = rung
                 return result
         return None  # pathological expansion: let the local executor handle it
 
@@ -649,13 +905,13 @@ class DistributedExecutor:
                 # ordered merge (reference: TopNOperator per task +
                 # MergeOperator at the gather stage)
                 def once(node=node):
-                    stream = self._compile_stream(node.child.child)
+                    stream = self._fragment(node.child.child)
                     if stream is None:
                         return None
                     return self._run_topn(stream, node.child.keys, node.count,
                                           node=node)
 
-                out = self._retry_exchange(once)
+                out = self._retry_exchange(node, once)
                 if out is not None:
                     self._trace(node, "mesh")
                     return out
@@ -691,12 +947,12 @@ class DistributedExecutor:
                     parts[0][1])
 
         def once(node=node):
-            stream = self._compile_stream(node)
+            stream = self._fragment(node)
             if stream is None:
                 return None
             return self._materialize_dstream(stream, node=node)
 
-        out = self._retry_exchange(once)
+        out = self._retry_exchange(node, once)
         if out is not None:
             self._trace(node, "mesh")
             return out
@@ -755,23 +1011,17 @@ class DistributedExecutor:
 
                 return _DStream(node.schema, dicts, batches, host_scan_fn,
                                 lambda c, n, v, aux: (c, n, v, _false(v)))
-            splits = conn.splits(node.table, n_hint=self.n_workers)
-            step = splits[0].hi - splits[0].lo
-            n_batches = len(splits) // self.n_workers
-            lo_batches = [
-                np.asarray([splits[b * self.n_workers + d].lo  # host-ok: split list
-                            for d in range(self.n_workers)], dtype=np.int64)
-                for b in range(n_batches)
-            ]
+            # resident sharded scan: the steps read [W, lanes] column batches
+            # as arguments; generation is a program of its own (_ShardedScan)
+            scan = _ShardedScan(self, conn, node.catalog, node.table,
+                                node.columns,
+                                conn.splits(node.table, n_hint=self.n_workers))
 
-            def scan_fn(lo, conn=conn, node=node, step=step):
-                cols, valid = conn.generate_traced(node.table, lo, step, node.columns)
-                nulls = tuple(None for _ in cols)
-                if valid is None:
-                    valid = jnp.ones(cols[0].shape, bool)
-                return cols, nulls, valid
+            def scan_fn(batch_w):
+                cols, valid = batch_w
+                return tuple(cols), tuple(None for _ in cols), valid
 
-            return _DStream(node.schema, dicts, lo_batches, scan_fn,
+            return _DStream(node.schema, dicts, scan, scan_fn,
                             lambda c, n, v, aux: (c, n, v, _false(v)))
 
         if isinstance(node, P.Filter):
@@ -807,31 +1057,40 @@ class DistributedExecutor:
             up = self._compile_stream(node.left)
             if up is None:
                 return None
-            # build side: local (blocking) execution, cached across ladder rungs
-            hit = self._build_cache.get(("page", id(node)))
-            if hit is None:
-                hit = self.local._execute_to_page_streamed(node.right)
-                self._build_cache[("page", id(node))] = hit
-            build_page, build_dicts = hit
             build_key_types = tuple(node.right.schema.fields[i].type for i in node.right_keys)
-            if build_page.capacity == 0:
-                # empty build joins flow through the normal probe path against a
-                # tiny all-invalid table: inner/semi match nothing, left/anti
-                # keep every probe row (round-1 VERDICT weak #3: this shape
-                # silently fell back to local)
-                build_page = _pad_page(build_page, 16)
-            multi = _has_duplicate_keys(build_page, node.right_keys,
-                                        build_key_types,
-                                        device=self.device_exchange)
-            # NOT IN 3VL facts, host-side (shared with the local executor's
-            # null-aware anti: _build_null_stats / _null_aware_anti)
-            build_null_stats = _build_null_stats(build_page, node.right_keys)
+
+            def build(node=node):
+                # build side: local (blocking) execution, kept with the facts
+                # pulled from it (each is a dispatch or a pull) across ladder
+                # rungs and statements
+                build_page, build_dicts = \
+                    self.local._execute_to_page_streamed(node.right)
+                if build_page.capacity == 0:
+                    # empty build joins flow through the normal probe path
+                    # against a tiny all-invalid table: inner/semi match
+                    # nothing, left/anti keep every probe row (round-1
+                    # VERDICT weak #3: this shape silently fell back to local)
+                    build_page = _pad_page(build_page, 16)
+                multi = _has_duplicate_keys(build_page, node.right_keys,
+                                            build_key_types,
+                                            device=self.device_exchange)
+                # NOT IN 3VL facts, host-side (shared with the local
+                # executor's null-aware anti: _build_null_stats /
+                # _null_aware_anti)
+                build_null_stats = _build_null_stats(build_page,
+                                                     node.right_keys)
+                n_build = int(_host([jnp.sum(build_page.valid_mask(),
+                                             dtype=jnp.int64)],
+                                    site="dist.join.buildsize")[0])
+                record_join_build(n_build)
+                return (build_page, build_dicts, multi, build_null_stats,
+                        n_build)
+
+            build_page, build_dicts, multi, build_null_stats, n_build = \
+                self._keep(node, ("build",), build)
             # distribution: the planner's stats-driven hint (CBO,
             # DetermineJoinDistributionType) decides when present; AUTOMATIC
             # plans ('replicated' hint) fall back to the actual build size
-            n_build = int(_host([jnp.sum(build_page.valid_mask(),
-                                         dtype=jnp.int64)],
-                                site="dist.join.buildsize")[0])
             hint = getattr(node, "distribution", "replicated")
             partitioned = (hint == "partitioned"
                            or (hint != "broadcast"
@@ -848,8 +1107,9 @@ class DistributedExecutor:
                 return self._compile_broadcast_multi_join(
                     node, up, build_page, build_dicts, build_key_types,
                     build_null_stats)
-            table = self.local._build_join_table(build_page, node.right_keys,
-                                                 build_key_types)
+            table = self._keep(
+                node, ("table",), lambda: self.local._build_join_table(
+                    build_page, node.right_keys, build_key_types))
             if table is None:
                 return self._decline(node, "duplicate build keys with a "
                                            "residual filter shape the multi-"
@@ -918,10 +1178,9 @@ class DistributedExecutor:
             # skew overflow: more rows hashed to this worker than cap_r holds
             return dataclasses.replace(jt, overflow=jt.overflow | (n_recv > cap_r))
 
-        table_g = self._build_cache.get(("ptable", id(node)))
-        if table_g is None:
-            table_g = self._sharded_build_exchange(node, build_page, make_table)
-            self._build_cache[("ptable", id(node))] = table_g
+        table_g = self._keep(node, ("ptable",), lambda:
+                             self._sharded_build_exchange(node, build_page,
+                                                          make_table))
 
         probe_bucket_of = self._probe_bucket
 
@@ -1065,13 +1324,9 @@ class DistributedExecutor:
         semi = node.kind in ("semi", "anti")
         # (no empty-build branch: _has_duplicate_keys needs >= 2 equal-key rows,
         # and the Join branch pads empty builds before the multi check)
-        mt = self._build_cache.get(("bmtable", id(node)))
-        if mt is None:
-            capacity = max(1 << max(build_page.capacity - 1, 1).bit_length(),
-                           16) * 2
-            mt = multi_build(capacity, build_page, node.right_keys,
-                             build_key_types)
-            self._build_cache[("bmtable", id(node))] = mt
+        mt = self._keep(node, ("bmtable",), lambda: multi_build(
+            max(1 << max(build_page.capacity - 1, 1).bit_length(), 16) * 2,
+            build_page, node.right_keys, build_key_types))
         ef = self._expand_factor
 
         def transform(cols, nulls, valid, aux, up=up, node=node, ef=ef,
@@ -1113,10 +1368,9 @@ class DistributedExecutor:
             return MultiJoinTable(table, counts, starts, order, ccols, cnulls,
                                   boflow | (n_recv > cap_r))
 
-        mt_g = self._build_cache.get(("pmtable", id(node)))
-        if mt_g is None:
-            mt_g = self._sharded_build_exchange(node, build_page, make_table)
-            self._build_cache[("pmtable", id(node))] = mt_g
+        mt_g = self._keep(node, ("pmtable",), lambda:
+                          self._sharded_build_exchange(node, build_page,
+                                                       make_table))
 
         probe_bucket = self._probe_bucket
         ef = self._expand_factor
@@ -1156,10 +1410,10 @@ class DistributedExecutor:
         wholly within a shard.  Reference: per-task OrderByOperator + the
         merging exchange (operator/OrderByOperator.java, MergeOperator.java) —
         re-planned as range exchange + shard-parallel sort."""
-        return self._retry_exchange(lambda: self._run_sort_once(node))
+        return self._retry_exchange(node, lambda: self._run_sort_once(node))
 
     def _run_sort_once(self, node: P.Sort):
-        stream = self._compile_stream(node.child)
+        stream = self._fragment(node.child)
         if stream is None or not stream.scan_lo_batches:
             return None
         keys = node.keys
@@ -1186,16 +1440,20 @@ class DistributedExecutor:
         # pulls the full batch and its rows seed the collect buffers via
         # host-side routing (so the device never re-runs batch 0).
         if self.device_exchange:
-            @partial(shard_map, mesh=mesh,
-                     in_specs=(PS(WORKER_AXIS), stream.aux_specs),
-                     out_specs=PS(WORKER_AXIS))
-            def sample_key(lo_g, aux, stream=stream):
-                cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
-                nm = nulls[ch] if nulls[ch] is not None \
-                    else jnp.zeros(valid.shape, bool)
-                return cols[ch][None], nm[None], valid[None], of[None]
+            def make_sample_key(stream=stream):
+                @partial(shard_map, mesh=mesh,
+                         in_specs=(PS(WORKER_AXIS), stream.aux_specs),
+                         out_specs=PS(WORKER_AXIS))
+                def sample_key(lo_g, aux):
+                    cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
+                    nm = nulls[ch] if nulls[ch] is not None \
+                        else jnp.zeros(valid.shape, bool)
+                    return cols[ch][None], nm[None], valid[None], of[None]
 
-            got = _host(list(_jit(sample_key)(
+                return _jit(sample_key, site="dist.sort.sample_key")
+
+            got = _host(list(self._step(node, ("sort.sample_key",),
+                                        make_sample_key)(
                             jax.device_put(stream.scan_lo_batches[0], sharded),  # device-ok: mesh-sharded placement
                             stream.aux))
                         + ([luts[ch]] if ch in luts else []),
@@ -1208,18 +1466,21 @@ class DistributedExecutor:
             lut_np = None if ch not in luts else got[-1]
             seed, skip = None, 0
         else:
-            @partial(shard_map, mesh=mesh,
-                     in_specs=(PS(WORKER_AXIS), stream.aux_specs),
-                     out_specs=PS(WORKER_AXIS))
-            def sample(lo_g, aux, stream=stream):
-                cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
-                nulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
-                              for c, m in zip(cols, nulls))
-                return (tuple(c[None] for c in cols),
-                        tuple(m[None] for m in nulls),
-                        valid[None], of[None])
+            def make_sample(stream=stream):
+                @partial(shard_map, mesh=mesh,
+                         in_specs=(PS(WORKER_AXIS), stream.aux_specs),
+                         out_specs=PS(WORKER_AXIS))
+                def sample(lo_g, aux):
+                    cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
+                    nulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
+                                  for c, m in zip(cols, nulls))
+                    return (tuple(c[None] for c in cols),
+                            tuple(m[None] for m in nulls),
+                            valid[None], of[None])
 
-            c0, n0, v0, of0 = _jit(sample)(
+                return _jit(sample, site="dist.sort.sample")
+
+            c0, n0, v0, of0 = self._step(node, ("sort.sample",), make_sample)(
                 jax.device_put(stream.scan_lo_batches[0], sharded), stream.aux)  # device-ok: mesh-sharded placement
             got = _host(list(c0) + list(n0) + [v0, of0]
                         + ([luts[ch]] if ch in luts else []),
@@ -1287,24 +1548,28 @@ class DistributedExecutor:
                         tuple(None for _ in fields), None)
             return (page, stream.dicts), False
 
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), PS(WORKER_AXIS), PS()),
-                 out_specs=PS(WORKER_AXIS))
-        def sort_shard(cols_g, nulls_g, valid_g, luts_t):
-            cols = tuple(c[0] for c in cols_g)
-            nulls_ = tuple(m[0] for m in nulls_g)
-            valid = valid_g[0]
-            idx = _lex_indices(keys, luts_t, cols, nulls_, valid)
-            return (tuple(c[idx][None] for c in cols),
-                    tuple(m[idx][None] for m in nulls_), valid[idx][None])
+        def make_sort_shard():
+            @partial(shard_map, mesh=mesh,
+                     in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), PS(WORKER_AXIS), PS()),
+                     out_specs=PS(WORKER_AXIS))
+            def sort_shard(cols_g, nulls_g, valid_g, luts_t):
+                cols = tuple(c[0] for c in cols_g)
+                nulls_ = tuple(m[0] for m in nulls_g)
+                valid = valid_g[0]
+                idx = _lex_indices(keys, luts_t, cols, nulls_, valid)
+                return (tuple(c[idx][None] for c in cols),
+                        tuple(m[idx][None] for m in nulls_), valid[idx][None])
 
-        scols, snulls, _ = _jit(sort_shard)(
+            return _jit(sort_shard, site="dist.sort.shard")
+
+        scols, snulls, _ = self._keep(node, ("sort.shard",), make_sort_shard)(
             tuple(jax.device_put(c, sharded) for c in cols_g),  # device-ok: mesh-sharded placement
             tuple(jax.device_put(m, sharded) for m in nulls_g),  # device-ok: mesh-sharded placement
             jax.device_put(valid_g, sharded), luts_t)  # device-ok: mesh-sharded placement
         # sorted shards: valid rows lead (``~valid`` is the last lex key), so
         # worker w contributes exactly its counts[w] head rows, in rank order
-        page = _page_from_shards(stream.schema, scols, snulls, counts)
+        page = _page_from_shards(stream.schema, scols, snulls, counts,
+                                 keep=partial(self._keep, node))
         return (page, stream.dicts), False
 
     # ---------------------------------------------------------------- window
@@ -1319,12 +1584,12 @@ class DistributedExecutor:
         part = specs[0].partition
         if not part or any(s.partition != part for s in specs):
             return None  # no common non-empty PARTITION BY -> not routable
-        return self._retry_exchange(lambda: self._run_window_once(node))
+        return self._retry_exchange(node, lambda: self._run_window_once(node))
 
     def _run_window_once(self, node: P.Window):
         from .local_executor import _window_kernel
 
-        stream = self._compile_stream(node.child)
+        stream = self._fragment(node.child)
         if stream is None or not stream.scan_lo_batches:
             return None
         specs = node.specs
@@ -1356,24 +1621,29 @@ class DistributedExecutor:
                         tuple(None for _ in node.schema.fields), None)
             return (page, stream.dicts + spec_dicts), False
 
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), PS(WORKER_AXIS)),
-                 out_specs=PS(WORKER_AXIS))
-        def wstep(cols_g, nulls_g, valid_g, specs=specs):
-            cols = tuple(c[0] for c in cols_g)
-            nulls_ = tuple(m[0] for m in nulls_g)
-            valid = valid_g[0]
-            ocols, onulls = _window_kernel(specs, cols, nulls_, valid)
-            onulls = tuple(jnp.zeros(valid.shape, bool) if m is None else m
-                           for m in onulls)
-            return (tuple(c[None] for c in ocols), tuple(m[None] for m in onulls))
+        def make_wstep():
+            @partial(shard_map, mesh=mesh,
+                     in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), PS(WORKER_AXIS)),
+                     out_specs=PS(WORKER_AXIS))
+            def wstep(cols_g, nulls_g, valid_g):
+                cols = tuple(c[0] for c in cols_g)
+                nulls_ = tuple(m[0] for m in nulls_g)
+                valid = valid_g[0]
+                ocols, onulls = _window_kernel(specs, cols, nulls_, valid)
+                onulls = tuple(jnp.zeros(valid.shape, bool) if m is None else m
+                               for m in onulls)
+                return (tuple(c[None] for c in ocols),
+                        tuple(m[None] for m in onulls))
 
-        ocols, onulls = _jit(wstep)(
+            return _jit(wstep, site="dist.window.step")
+
+        ocols, onulls = self._keep(node, ("window.step",), make_wstep)(
             tuple(jax.device_put(c, sharded) for c in cols_g),  # device-ok: mesh-sharded placement
             tuple(jax.device_put(m, sharded) for m in nulls_g),  # device-ok: mesh-sharded placement
             jax.device_put(valid_g, sharded))  # device-ok: mesh-sharded placement
         page = _page_from_shards(node.schema, tuple(cols_g) + tuple(ocols),
-                                 tuple(nulls_g) + tuple(onulls), counts)
+                                 tuple(nulls_g) + tuple(onulls), counts,
+                                 keep=partial(self._keep, node))
         return (page, stream.dicts + spec_dicts), False
 
     def _exchange_collect(self, stream: _DStream, pid_fn, route_aux,
@@ -1409,23 +1679,26 @@ class DistributedExecutor:
             return self._exchange_collect_device(stream, pid_fn, route_aux,
                                                  bucket_of, node=node)
 
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(PS(WORKER_AXIS), stream.aux_specs, PS()),
-                 out_specs=PS(WORKER_AXIS))
-        def step(lo_g, aux, route_aux, stream=stream):
-            cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
-            pid = pid_fn(cols, nulls, valid, route_aux)
-            n = valid.shape[0]
-            rcols, rnulls, rvalid, r_of = _route_rows(
-                tuple(cols), tuple(nulls), valid, pid, W,
-                bucket_of(n), WORKER_AXIS)
-            rnulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
-                           for c, m in zip(rcols, rnulls))
-            return (tuple(c[None] for c in rcols),
-                    tuple(m[None] for m in rnulls),
-                    rvalid[None], (of | r_of)[None])
+        def make_step(stream=stream):
+            @partial(shard_map, mesh=mesh,
+                     in_specs=(PS(WORKER_AXIS), stream.aux_specs, PS()),
+                     out_specs=PS(WORKER_AXIS))
+            def step(lo_g, aux, route_aux):
+                cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
+                pid = pid_fn(cols, nulls, valid, route_aux)
+                n = valid.shape[0]
+                rcols, rnulls, rvalid, r_of = _route_rows(
+                    tuple(cols), tuple(nulls), valid, pid, W,
+                    bucket_of(n), WORKER_AXIS)
+                rnulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
+                               for c, m in zip(rcols, rnulls))
+                return (tuple(c[None] for c in rcols),
+                        tuple(m[None] for m in rnulls),
+                        rvalid[None], (of | r_of)[None])
 
-        step = _jit(step)
+            return _jit(step, site="dist.exchange.spool")
+
+        step = self._step(node, ("exchange.spool",), make_step)
         if seed is not None:
             per_cols, per_nulls = seed
         else:
@@ -1464,12 +1737,10 @@ class DistributedExecutor:
     # ------------------------------------------------- device-resident exchange
     def _batch_rows(self, stream: _DStream) -> int:
         """Per-worker row capacity of one scan batch (static shape fact)."""
-        b0 = stream.scan_lo_batches[0]
-        if isinstance(b0, np.ndarray):  # traced scan: [W] offset vector
-            out = jax.eval_shape(stream.scan_fn,
-                                 jax.ShapeDtypeStruct((), b0.dtype))
-            return int(out[2].shape[0])
-        return int(b0[2].shape[1])  # host-fed: stacked [W, cap] pytree
+        if isinstance(stream.scan_lo_batches, _ShardedScan):
+            return stream.scan_lo_batches.rows
+        # host-fed: stacked [W, cap] pytree
+        return int(stream.scan_lo_batches[0][2].shape[1])
 
     def _recv_capacity(self, stream: _DStream) -> int:
         """Initial receive-buffer capacity: 2x the scan's total per-worker rows
@@ -1495,24 +1766,28 @@ class DistributedExecutor:
                 put(np.zeros((W,), bool)),
                 put(np.zeros((W,), bool)))
 
-    def _slim_shards(self, state, counts, site: str):
+    def _slim_shards(self, node, state, counts, site: str):
         """Trim carried [W, cap + 1] receive buffers to the smallest pow2 cover
         of the largest shard and derive per-row validity from the cursors —
         ONE dispatch, outputs stay device-sharded for the consumer."""
         nmax = max(max(counts), 1)
         nmax_p2 = 1 << (nmax - 1).bit_length()
 
-        @partial(shard_map, mesh=self.mesh, in_specs=(PS(WORKER_AXIS),) * 3,
-                 out_specs=PS(WORKER_AXIS))
-        def slim(bufs_g, nbufs_g, cursor_g):
-            cur = cursor_g[0]
-            cols = tuple(b[0][:nmax_p2] for b in bufs_g)
-            nulls = tuple(b[0][:nmax_p2] for b in nbufs_g)
-            valid = jnp.arange(nmax_p2, dtype=cur.dtype) < cur
-            return (tuple(c[None] for c in cols),
-                    tuple(m[None] for m in nulls), valid[None])
+        def make_slim():
+            @partial(shard_map, mesh=self.mesh, in_specs=(PS(WORKER_AXIS),) * 3,
+                     out_specs=PS(WORKER_AXIS))
+            def slim(bufs_g, nbufs_g, cursor_g):
+                cur = cursor_g[0]
+                cols = tuple(b[0][:nmax_p2] for b in bufs_g)
+                nulls = tuple(b[0][:nmax_p2] for b in nbufs_g)
+                valid = jnp.arange(nmax_p2, dtype=cur.dtype) < cur
+                return (tuple(c[None] for c in cols),
+                        tuple(m[None] for m in nulls), valid[None])
 
-        return _jit(slim, site=site)(state[0], state[1], state[2])
+            return _jit(slim, site=site)
+
+        return self._keep(node, (site, nmax_p2), make_slim)(
+            state[0], state[1], state[2])
 
     def _exchange_collect_device(self, stream: _DStream, pid_fn, route_aux,
                                  bucket_of, node=None):
@@ -1533,34 +1808,39 @@ class DistributedExecutor:
             t0 = time.perf_counter()
             state = self._recv_state_init(cap, dtypes)
 
-            @partial(shard_map, mesh=mesh,
-                     in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS),
-                               stream.aux_specs, PS()),
-                     out_specs=PS(WORKER_AXIS))
-            def step(state_g, lo_g, aux, route_aux, stream=stream):
-                bufs = tuple(b[0] for b in state_g[0])
-                nbufs = tuple(b[0] for b in state_g[1])
-                cursor = state_g[2][0]
-                lad_of, recv_of = state_g[3][0], state_g[4][0]
-                cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
-                pid = pid_fn(cols, nulls, valid, route_aux)
-                rcols, rnulls, rvalid, r_of = _route_rows(
-                    tuple(cols), tuple(nulls), valid, pid, W,
-                    bucket_of(valid.shape[0]), WORKER_AXIS)
-                # cast to the schema dtypes the buffers were allocated at
-                # (same cast _stack_shards applies on the host path)
-                rcols = tuple(c.astype(dt) for c, dt in zip(rcols, dtypes))
-                rnulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
-                               for c, m in zip(rcols, rnulls))
-                new, ncur, b_of = append_rows(bufs + nbufs, cursor,
-                                              rcols + rnulls, rvalid)
-                k = len(bufs)
-                return (tuple(b[None] for b in new[:k]),
-                        tuple(b[None] for b in new[k:]),
-                        ncur[None], (lad_of | of | r_of)[None],
-                        (recv_of | b_of)[None])
+            def make_step(stream=stream):
+                @partial(shard_map, mesh=mesh,
+                         in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS),
+                                   stream.aux_specs, PS()),
+                         out_specs=PS(WORKER_AXIS))
+                def step(state_g, lo_g, aux, route_aux):
+                    bufs = tuple(b[0] for b in state_g[0])
+                    nbufs = tuple(b[0] for b in state_g[1])
+                    cursor = state_g[2][0]
+                    lad_of, recv_of = state_g[3][0], state_g[4][0]
+                    cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
+                    pid = pid_fn(cols, nulls, valid, route_aux)
+                    rcols, rnulls, rvalid, r_of = _route_rows(
+                        tuple(cols), tuple(nulls), valid, pid, W,
+                        bucket_of(valid.shape[0]), WORKER_AXIS)
+                    # cast to the schema dtypes the buffers were allocated at
+                    # (same cast _stack_shards applies on the host path)
+                    rcols = tuple(c.astype(dt) for c, dt in zip(rcols, dtypes))
+                    rnulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
+                                   for c, m in zip(rcols, rnulls))
+                    new, ncur, b_of = append_rows(bufs + nbufs, cursor,
+                                                  rcols + rnulls, rvalid)
+                    k = len(bufs)
+                    return (tuple(b[None] for b in new[:k]),
+                            tuple(b[None] for b in new[k:]),
+                            ncur[None], (lad_of | of | r_of)[None],
+                            (recv_of | b_of)[None])
 
-            step = _jit(step, site="dist.exchange.route")
+                return _jit(step, site="dist.exchange.route")
+
+            # one wrapper serves every receive capacity: a grown cap is a
+            # new argument shape of it, not a new program object
+            step = self._step(node, ("exchange.route",), make_step)
             for lo in stream.scan_lo_batches:
                 _exchange_fault("exchange_write", "dist.exchange.route")
                 with maybe_span("exchange.route"):
@@ -1582,7 +1862,7 @@ class DistributedExecutor:
                         time.perf_counter() - t0,
                         fields=stream.schema.fields)
         _exchange_fault("exchange_read", "dist.exchange.read")
-        cols_g, nulls_g, valid_g = self._slim_shards(state, counts,
+        cols_g, nulls_g, valid_g = self._slim_shards(node, state, counts,
                                                      "dist.exchange.slim")
         return cols_g, nulls_g, valid_g, counts
 
@@ -1614,30 +1894,32 @@ class DistributedExecutor:
                  jax.device_put(jnp.zeros((W,), bool), sharded))  # oflow acc  # device-ok: mesh-sharded placement
         luts_t = dict(luts)
 
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), stream.aux_specs, PS()),
-                 out_specs=PS(WORKER_AXIS))
-        def step(state_g, lo_g, aux, luts_t, stream=stream):
-            scols = tuple(c[0] for c in state_g[0])
-            snulls = tuple(m[0] for m in state_g[1])
-            svalid = state_g[2][0]
-            s_of = state_g[3][0]
-            cols, nulls, valid = stream.scan_fn(_slice_batch(lo_g))
-            cols, nulls, valid, of = stream.transform(cols, nulls, valid, aux)
-            cat_cols = tuple(jnp.concatenate([sc, c.astype(sc.dtype)])
-                             for sc, c in zip(scols, cols))
-            cat_nulls = tuple(
-                jnp.concatenate([sn, jnp.zeros(v.shape, bool) if nm is None else nm])
-                for sn, nm, v in zip(snulls, nulls, cols))
-            cat_valid = jnp.concatenate([svalid, valid])
-            idx = _lex_indices(sort_keys, luts_t, cat_cols, cat_nulls,
-                               cat_valid)[:k]
-            return (tuple(c[idx][None] for c in cat_cols),
-                    tuple(m[idx][None] for m in cat_nulls),
-                    cat_valid[idx][None],
-                    (s_of | of)[None])
+        def make_step(stream=stream):
+            @partial(shard_map, mesh=mesh,
+                     in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), stream.aux_specs, PS()),
+                     out_specs=PS(WORKER_AXIS))
+            def step(state_g, lo_g, aux, luts_t):
+                scols = tuple(c[0] for c in state_g[0])
+                snulls = tuple(m[0] for m in state_g[1])
+                svalid = state_g[2][0]
+                s_of = state_g[3][0]
+                cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
+                cat_cols = tuple(jnp.concatenate([sc, c.astype(sc.dtype)])
+                                 for sc, c in zip(scols, cols))
+                cat_nulls = tuple(
+                    jnp.concatenate([sn, jnp.zeros(v.shape, bool) if nm is None else nm])
+                    for sn, nm, v in zip(snulls, nulls, cols))
+                cat_valid = jnp.concatenate([svalid, valid])
+                idx = _lex_indices(sort_keys, luts_t, cat_cols, cat_nulls,
+                                   cat_valid)[:k]
+                return (tuple(c[idx][None] for c in cat_cols),
+                        tuple(m[idx][None] for m in cat_nulls),
+                        cat_valid[idx][None],
+                        (s_of | of)[None])
 
-        step = _jit(step)
+            return _jit(step, site="dist.topn.step")
+
+        step = self._step(node, ("topn.step",), make_step)
         t0 = time.perf_counter()
         for lo in stream.scan_lo_batches:
             state = step(state, jax.device_put(lo, sharded), stream.aux, luts_t)  # device-ok: mesh-sharded placement
@@ -1665,7 +1947,7 @@ class DistributedExecutor:
 
     # ---------------------------------------------------------------- aggregation
     def _run_aggregate(self, node: P.Aggregate):
-        out = self._retry_exchange(lambda: self._run_aggregate_once(node))
+        out = self._retry_exchange(node, lambda: self._run_aggregate_once(node))
         if out is None:
             self._trace(node, "local", self._take_decline())
             return self.local._run_aggregate(node)
@@ -1678,7 +1960,7 @@ class DistributedExecutor:
         if any(s.kind in P.SORTED_AGG_KINDS for s in node.aggs):
             return self._decline(node, "sort-based aggregates run the "
                                        "local selection runner")
-        stream = self._compile_stream(node.child)
+        stream = self._fragment(node.child)
         if stream is None:
             return None
         child_schema = stream.schema
@@ -1698,48 +1980,67 @@ class DistributedExecutor:
         mesh = self.mesh
         W = self.n_workers
         sharded = NamedSharding(mesh, PS(WORKER_AXIS))
-        capacity = node.capacity or DEFAULT_GROUP_CAPACITY
+        # what last held: a replay does not grow (or fall back) its way up
+        # again.  [capacity, direct]: keys that are all dictionary codes or
+        # booleans over at most ONEHOT_CAP_MAX slots aggregate direct-indexed
+        # (slot = packed key, masked reductions, no probe and no scatter: the
+        # local executor's aggregate.direct, per worker); else hash mode
+        held = self._keep(node, ("capacity",), lambda: [
+            node.capacity or DEFAULT_GROUP_CAPACITY,
+            self._direct_config(node, stream)])
 
-        while True:
-            t0 = time.perf_counter()
-            state = self._global_state_init(capacity, key_types, acc_specs)
-            of_acc = jax.device_put(jnp.zeros((W,), bool), sharded)  # device-ok: mesh-sharded placement
-
+        def make_step(cfg, stream=stream):
             @partial(shard_map, mesh=mesh,
                      in_specs=(PS(WORKER_AXIS),) * 2 + (PS(WORKER_AXIS), stream.aux_specs),
                      out_specs=PS(WORKER_AXIS))
-            def step(state_g, of_g, lo_g, aux, stream=stream, node=node,
-                     key_types=key_types, acc_exprs=acc_exprs, acc_kinds=acc_kinds):
+            def step(state_g, of_g, lo_g, aux):
                 state = jax.tree.map(lambda x: x[0], state_g,
                                      is_leaf=lambda x: x is None)
-                cols, nulls, valid = stream.scan_fn(_slice_batch(lo_g))
-                cols, nulls, valid, of = stream.transform(cols, nulls, valid, aux)
+                cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
                 key_vals = tuple(cols[i] for i in node.keys)
                 inputs = [(None, None) if e is None else evaluate(e, cols, nulls)
                           for e in acc_exprs]
-                new = hashagg.groupby_insert(state, key_vals, key_types, valid, inputs,
-                                             acc_kinds)
+                if cfg is not None:
+                    new = hashagg.direct_groupby_insert(
+                        state, cfg, key_vals, valid, inputs, acc_kinds)
+                else:
+                    new = _groupby_insert_live(state, key_vals, key_types,
+                                               valid, inputs, acc_kinds)
                 return (jax.tree.map(lambda x: x[None], new,
                                      is_leaf=lambda x: x is None),
                         (of_g[0] | of)[None])
 
-            step = _jit(step)
+            return _jit(step, site="dist.agg.direct_step" if cfg is not None
+                        else "dist.agg.step")
+
+        while True:
+            t0 = time.perf_counter()
+            capacity, cfg = held
+            # one wrapper serves every capacity: the state is an argument
+            step = self._step(node, ("agg.step", cfg is not None),
+                              partial(make_step, cfg))
+            state = self._global_state_init(capacity, key_types, acc_specs, cfg)
+            of_acc = jax.device_put(jnp.zeros((W,), bool), sharded)  # device-ok: mesh-sharded placement
             for lo in stream.scan_lo_batches:
                 state, of_acc = step(state, of_acc, jax.device_put(lo, sharded),  # device-ok: mesh-sharded placement
                                      stream.aux)
 
-            if bool(np.any(_host([of_acc],
-                                 site="dist.agg.overflow")[0])):
-                return None, True  # exchange bucket overflow: ladder retry
-            merged, nocc_g = self._merge_states(state, key_types, acc_specs,
-                                                merge_kinds, capacity)
-            of2 = _host([merged.overflow, state.overflow, nocc_g],
+            # the merge is dispatched before the ladder's flag is read, so
+            # that ONE pull serves both (a short bucket wastes one merge)
+            merged, nocc_g = self._merge_states(node, state, key_types,
+                                                acc_specs, merge_kinds)
+            of2 = _host([merged.overflow, state.overflow, nocc_g, of_acc],
                         site="dist.agg.overflow")
+            if bool(np.any(of2[3])):
+                return None, True  # exchange bucket overflow: ladder retry
             overflow = bool(np.any(of2[0])) or bool(np.any(of2[1]))
+            if overflow and cfg is not None:
+                held[1] = None  # a key outside its static range: hash mode
+                continue
             if not overflow or capacity >= MAX_GROUP_CAPACITY:
                 agg_wall = time.perf_counter() - t0
                 break
-            capacity *= 4
+            held[0] = capacity * 4
 
         nk = len(merged.key_cols)
         _exchange_fault("exchange_read", "dist.agg.groups")
@@ -1758,20 +2059,23 @@ class DistributedExecutor:
                             kind="occupancy")
             out_cap = 1 << (max(int(nocc.max()), 1) - 1).bit_length()
 
-            @partial(shard_map, mesh=mesh, in_specs=PS(WORKER_AXIS),
-                     out_specs=PS(WORKER_AXIS))
-            def compact_groups(state_g):
-                st = jax.tree.map(lambda x: x[0], state_g,
-                                  is_leaf=lambda x: x is None)
-                C = st.capacity
-                occ = st.table[:C] != EMPTY_KEY
-                packed, _ = compact_rows(
-                    tuple(k[:C] for k in st.key_cols)
-                    + tuple(a[:C] for a in st.accs), occ, out_cap)
-                return tuple(p[None] for p in packed)
+            def make_compact():
+                @partial(shard_map, mesh=mesh, in_specs=PS(WORKER_AXIS),
+                         out_specs=PS(WORKER_AXIS))
+                def compact_groups(state_g):
+                    st = jax.tree.map(lambda x: x[0], state_g,
+                                      is_leaf=lambda x: x is None)
+                    C = st.capacity
+                    occ = st.table[:C] != EMPTY_KEY
+                    packed, _ = compact_rows(
+                        tuple(k[:C] for k in st.key_cols)
+                        + tuple(a[:C] for a in st.accs), occ, out_cap)
+                    return tuple(p[None] for p in packed)
 
-            got = _host(list(_jit(compact_groups,
-                                  site="dist.agg.compact")(merged)),
+                return _jit(compact_groups, site="dist.agg.compact")
+
+            got = _host(list(self._keep(node, ("agg.compact", out_cap),
+                                        make_compact)(merged)),
                         site="dist.agg.groups")
             key_cols = [np.concatenate([k[w][:nocc[w]] for w in range(W)])
                         for k in got[:nk]]
@@ -1784,6 +2088,7 @@ class DistributedExecutor:
                         + list(merged.accs),
                         site="dist.agg.groups")  # one batched table pull
             table_np = got[0]  # [W, C+1]
+            capacity = table_np.shape[1] - 1  # the merged table's
             occ = table_np[:, :capacity] != EMPTY_KEY
             self._note_skew("dist.agg.groups", node,
                             occ.sum(axis=1).tolist(), agg_wall,
@@ -1805,7 +2110,26 @@ class DistributedExecutor:
         dicts = tuple(stream.dicts[i] for i in node.keys) + tuple(None for _ in node.aggs)
         return (page, dicts), False
 
-    def _global_state_init(self, capacity, key_types, acc_specs) -> hashagg.GroupByState:
+    def _direct_config(self, node, stream):
+        """The direct-indexed layout of ``node``'s keys, or None: every key a
+        dictionary code or a boolean (static ranges, no connector statistic to
+        go stale) and the whole table small enough for masked reductions."""
+        ranges = []
+        for i in node.keys:
+            d = stream.dicts[i]
+            if d is not None and getattr(d, "values", None) is not None:
+                ranges.append((0, max(len(d.values) - 1, 0)))
+            elif stream.schema.fields[i].type.name == "boolean":
+                ranges.append((0, 1))
+            else:
+                return None
+        cfg = hashagg.direct_config(tuple(ranges), (False,) * len(ranges))
+        if cfg is None or cfg.capacity > hashagg.ONEHOT_CAP_MAX:
+            return None
+        return cfg
+
+    def _global_state_init(self, capacity, key_types, acc_specs,
+                           cfg=None) -> hashagg.GroupByState:
         """[n_workers, ...] sharded state with identical empty contents per worker."""
         W = self.n_workers
         sharded = NamedSharding(self.mesh, PS(WORKER_AXIS))
@@ -1813,10 +2137,13 @@ class DistributedExecutor:
         def tile(x):
             return jax.device_put(jnp.broadcast_to(x[None], (W,) + x.shape), sharded)  # device-ok: mesh-sharded placement
 
-        local = hashagg.groupby_init(capacity, tuple(t.dtype for t in key_types), acc_specs)
+        key_dtypes = tuple(t.dtype for t in key_types)
+        local = hashagg.groupby_init(capacity, key_dtypes, acc_specs) \
+            if cfg is None else hashagg.direct_groupby_init(cfg, key_dtypes,
+                                                            acc_specs)
         return jax.tree.map(tile, local, is_leaf=lambda x: x is None)
 
-    def _merge_states(self, state, key_types, acc_specs, merge_kinds, capacity):
+    def _merge_states(self, node, state, key_types, acc_specs, merge_kinds):
         """Hash-exchange group entries across workers and re-insert (final
         aggregation).  Returns (merged state, [W] live-group counts) — the
         counts ride the overflow flag pull the driver already pays, sizing
@@ -1828,33 +2155,38 @@ class DistributedExecutor:
         # silently drop groups under skew
         bucket = state.table.shape[-1] - 1
 
-        @partial(shard_map, mesh=self.mesh, in_specs=PS(WORKER_AXIS),
-                 out_specs=PS(WORKER_AXIS))
-        def merge(state_g):
-            state = jax.tree.map(lambda x: x[0], state_g, is_leaf=lambda x: x is None)
-            C = state.capacity
-            occupied = state.table[:C] != EMPTY_KEY
-            keys = tuple(k[:C] for k in state.key_cols)
-            accs = tuple(a[:C] for a in state.accs)
-            pid = partition_ids(keys, W)
-            packed_cols, packed_valid, _ = bucketize(
-                keys + accs, occupied, pid, W, bucket)
-            recv_cols, recv_valid = exchange_all_to_all(packed_cols, packed_valid,
-                                                        WORKER_AXIS, W)
-            rkeys = recv_cols[:len(keys)]
-            raccs = recv_cols[len(keys):]
-            fresh = hashagg.groupby_init(C, tuple(t.dtype for t in key_types), acc_specs)
-            merged = hashagg.groupby_insert(
-                fresh, rkeys, key_types, recv_valid,
-                [(a, None) for a in raccs], merge_kinds)
-            merged = dataclasses.replace(merged, overflow=merged.overflow | state.overflow)
-            nocc = jnp.sum(merged.table[:C] != EMPTY_KEY, dtype=jnp.int64)
-            return (jax.tree.map(lambda x: x[None], merged,
-                                 is_leaf=lambda x: x is None), nocc[None])
+        def make_merge():
+            @partial(shard_map, mesh=self.mesh, in_specs=PS(WORKER_AXIS),
+                     out_specs=PS(WORKER_AXIS))
+            def merge(state_g):
+                state = jax.tree.map(lambda x: x[0], state_g, is_leaf=lambda x: x is None)
+                C = state.capacity
+                occupied = state.table[:C] != EMPTY_KEY
+                keys = tuple(k[:C] for k in state.key_cols)
+                accs = tuple(a[:C] for a in state.accs)
+                pid = partition_ids(keys, W)
+                packed_cols, packed_valid, _ = bucketize(
+                    keys + accs, occupied, pid, W, bucket)
+                recv_cols, recv_valid = exchange_all_to_all(packed_cols, packed_valid,
+                                                            WORKER_AXIS, W)
+                rkeys = recv_cols[:len(keys)]
+                raccs = recv_cols[len(keys):]
+                fresh = hashagg.groupby_init(max(C, _MERGE_MIN_SLOTS),
+                                             tuple(t.dtype for t in key_types), acc_specs)
+                merged = hashagg.groupby_insert(
+                    fresh, rkeys, key_types, recv_valid,
+                    [(a, None) for a in raccs], merge_kinds)
+                merged = dataclasses.replace(merged, overflow=merged.overflow | state.overflow)
+                nocc = jnp.sum(merged.table[:merged.capacity] != EMPTY_KEY,
+                               dtype=jnp.int64)
+                return (jax.tree.map(lambda x: x[None], merged,
+                                     is_leaf=lambda x: x is None), nocc[None])
+
+            return _jit(merge, site="dist.agg.merge")
 
         _exchange_fault("exchange_write", "dist.agg.merge")
         with maybe_span("exchange.merge"):
-            return _jit(merge)(state)
+            return self._keep(node, ("agg.merge", bucket), make_merge)(state)
 
     def _run_global_aggregate(self, node, stream: _DStream):
         """Ungrouped aggregation: per-worker jnp reductions + psum/pmin/pmax across the
@@ -1886,8 +2218,7 @@ class DistributedExecutor:
                  acc_kinds=acc_kinds):
             st = tuple(s[0] for s in state_g[:-1])
             s_of = state_g[-1][0]
-            cols, nulls, valid = stream.scan_fn(_slice_batch(lo_g))
-            cols, nulls, valid, of = stream.transform(cols, nulls, valid, aux)
+            cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
             out = []
             for s, e, kind in zip(st, acc_exprs, acc_kinds):
                 if kind == "count_star":
@@ -1914,7 +2245,8 @@ class DistributedExecutor:
                     raise NotImplementedError(f"global agg kind {kind}")
             return tuple(o[None] for o in out) + ((s_of | of)[None],)
 
-        step = _jit(step)
+        step = self._step(node, ("global.step",),
+                          lambda: _jit(step, site="dist.global.step"))
         for lo in stream.scan_lo_batches:
             state = step(state, jax.device_put(lo, sharded), stream.aux)  # device-ok: mesh-sharded placement
 
@@ -1954,14 +2286,14 @@ class DistributedExecutor:
         @partial(shard_map, mesh=mesh, in_specs=(PS(WORKER_AXIS), stream.aux_specs),
                  out_specs=PS(WORKER_AXIS))
         def run(lo_g, aux, stream=stream):
-            cols, nulls, valid = stream.scan_fn(_slice_batch(lo_g))
-            cols, nulls, valid, of = stream.transform(cols, nulls, valid, aux)
+            cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
             nulls = tuple(jnp.zeros(c.shape, bool) if n is None else n
                           for c, n in zip(cols, nulls))
             return (tuple(c[None] for c in cols), tuple(n[None] for n in nulls),
                     valid[None], of[None])
 
-        run = _jit(run)
+        run = self._step(node, ("stream.run",),
+                         lambda: _jit(run, site="dist.stream.run"))
         parts_cols, parts_nulls, parts_valid = [], [], []
         oflow = False
         rows_w = np.zeros((self.n_workers,), np.int64)
@@ -2026,7 +2358,8 @@ class DistributedExecutor:
                         ncur[None], (lad_of | of)[None],
                         (recv_of | b_of)[None])
 
-            run = _jit(run, site="dist.stream.route")
+            run = self._step(node, ("stream.route",), lambda run=run:
+                             _jit(run, site="dist.stream.route"))
             for lo in stream.scan_lo_batches:
                 state = run(state, jax.device_put(lo, sharded), stream.aux)  # device-ok: mesh-sharded placement
             cursor, lad_of, recv_of = _host(
@@ -2047,7 +2380,8 @@ class DistributedExecutor:
                         tuple(jnp.zeros((0,), dt) for dt in dtypes),
                         tuple(None for _ in dtypes), None)
             return (page, stream.dicts), False
-        cols_g, nulls_g, valid_g = self._slim_shards(state, counts,
+        cols_g, nulls_g, valid_g = self._slim_shards(node, state, counts,
                                                      "dist.stream.slim")
-        page = _page_from_shards(stream.schema, cols_g, nulls_g, counts)
+        page = _page_from_shards(stream.schema, cols_g, nulls_g, counts,
+                                 keep=partial(self._keep, node))
         return (page, stream.dicts), False

@@ -324,8 +324,10 @@ class DeviceBufferPool:
         with self._lock:
             return key in self._entries
 
-    def put_page(self, key, page) -> bool:
+    def put_page(self, key, page, nbytes: Optional[int] = None) -> bool:
         """Store a COMPLETED scan already staged as one device-resident page
+        (or, with ``nbytes``, the mesh executor's row-sharded batches of one:
+        exec.distributed._ShardedScan, priced at the bytes ONE chip holds)
         (exec.local_executor._stage_scan_entry does the staging: host arrays
         through the sanctioned _page_to_device chokepoint, concatenation as
         one COUNTED _jit dispatch — device work here would be invisible to
@@ -343,7 +345,8 @@ class DeviceBufferPool:
         # assertions pass vacuously
         if faults.maybe_inject("cache_store", f"page.{key[2]}") == "deny":
             return False
-        nbytes = _page_nbytes(page)
+        if nbytes is None:
+            nbytes = _page_nbytes(page)
         if nbytes > self.page_entry_cap():
             return False
         return self._store(key, _Entry("page", key[1], key[2], page, nbytes),

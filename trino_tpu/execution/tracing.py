@@ -268,6 +268,16 @@ class QueryCounters:
     # width for a batch that stayed dense)
     join_match_lanes: int = 0
     join_gather_lanes: int = 0
+    # PR 32: the mesh path.  Rows the statement's all-to-all exchanges
+    # delivered and the fullest worker's share of them, summed over its
+    # exchanges from the receive cursors and occupancy counts the exchange
+    # already pulls (record_shard_stats: no pull, no dispatch of their own);
+    # kept fragments (exec/distributed.py: a plan node's compiled stream,
+    # its jitted shard_map steps) served, and fragments compiled
+    exchange_rows: int = 0
+    exchange_rows_max_shard: int = 0
+    mesh_fragment_hits: int = 0
+    mesh_fragment_compiles: int = 0
     # PR 25: the statement's wait states, seconds (each also a span of the
     # same name family: server.queued, batcher.wait, executor.checkout,
     # server.encode, server.deliver), recorded where the wait happens, and
@@ -283,6 +293,7 @@ class QueryCounters:
     wall_h2d_s: float = 0.0
     wall_dispatch_s: float = 0.0
     wall_host_pull_s: float = 0.0
+    wall_exchange_wait_s: float = 0.0
     wall_unattributed_s: float = 0.0
     # round 19: adaptive execution.  A replan means the statement ran a
     # CORRECTED plan (the advisor's history-backed cardinality/capacity
@@ -328,12 +339,14 @@ class QueryCounters:
                    "compactions", "compact_lanes_in", "compact_lanes_out",
                    "groupby_slots", "groupby_state_bytes", "groupby_regrows",
                    "groupby_partitioned_passes", "join_build_rows",
-                   "rows_generated", "join_match_lanes", "join_gather_lanes")
+                   "rows_generated", "join_match_lanes", "join_gather_lanes",
+                   "exchange_rows", "exchange_rows_max_shard",
+                   "mesh_fragment_hits", "mesh_fragment_compiles")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
                      "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
                      "wall_dispatch_s", "wall_host_pull_s",
-                     "wall_unattributed_s")
+                     "wall_exchange_wait_s", "wall_unattributed_s")
 
     def reset(self) -> None:
         for f in self._INT_FIELDS:
@@ -600,6 +613,17 @@ def record_rows_generated(rows: int) -> None:
         c.rows_generated += rows
 
 
+def record_mesh_fragment(hit: bool) -> None:
+    """One lookup of a kept mesh fragment (exec/distributed.py): served, or
+    compiled now."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        if hit:
+            c.mesh_fragment_hits += 1
+        else:
+            c.mesh_fragment_compiles += 1
+
+
 def _attribute_extra(site: Optional[str], **extras) -> None:
     """Charge non-boundary extras (cache hits/misses/bytes saved) to the
     active op scope's site record and boundary sink — same "<op>/<site>" key
@@ -750,6 +774,11 @@ def record_shard_stats(site: str, per_worker, wall_s: float = 0.0,
     if c is not None:
         c.shard_stats.append(dict(rec))
         del c.shard_stats[:-SHARD_STATS_MAX]
+        if kind in ("exchange", "occupancy"):
+            # what an all-to-all delivered: receive cursors, or the groups
+            # each worker owns after the merge exchange
+            c.exchange_rows += int(sum(rec["rows"]))
+            c.exchange_rows_max_shard += int(mx)
     return rec
 
 

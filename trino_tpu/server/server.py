@@ -766,7 +766,7 @@ class CoordinatorServer:
                       "of finished statements by wall_breakdown bucket.",
                       "# TYPE trino_tpu_wall_seconds_total counter"]
             for bucket in ("plan", "split_generation", "h2d", "dispatch",
-                           "host_pull", "unattributed"):
+                           "host_pull", "exchange_wait", "unattributed"):
                 lines.append(
                     f'trino_tpu_wall_seconds_total{{bucket="{bucket}"}} '
                     f"{getattr(ct, f'wall_{bucket}_s', 0.0):.6f}")
@@ -796,7 +796,16 @@ class CoordinatorServer:
                     ("join_match_lanes", "Lanes that entered the match step "
                      "of a split join."),
                     ("join_gather_lanes", "Lanes at which split joins then "
-                     "gathered their build columns.")):
+                     "gathered their build columns."),
+                    ("exchange_rows", "Rows the mesh executor's all-to-all "
+                     "exchanges delivered (receive cursors and merged "
+                     "group counts)."),
+                    ("exchange_rows_max_shard", "The fullest worker's share "
+                     "of exchange_rows, summed over exchanges."),
+                    ("mesh_fragment_hits", "Kept mesh fragments served to a "
+                     "replayed plan."),
+                    ("mesh_fragment_compiles", "Mesh fragments compiled "
+                     "(first sight of a plan node at a ladder rung).")):
                 lines += [f"# HELP trino_tpu_{field}_total {what}",
                           f"# TYPE trino_tpu_{field}_total counter",
                           f"trino_tpu_{field}_total {getattr(ct, field, 0)}"]

@@ -110,6 +110,16 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
         jb = getattr(counters, "join_build_rows", 0)
         if rg or jb:
             lines.append(f"Scan: {rg} rows generated, {jb} join build rows")
+        xr = getattr(counters, "exchange_rows", 0)
+        fh = getattr(counters, "mesh_fragment_hits", 0)
+        fc = getattr(counters, "mesh_fragment_compiles", 0)
+        if xr or fh or fc:
+            # the mesh path (PR 32): what its all-to-all exchanges delivered,
+            # and whether the plan's fragments were kept ones
+            lines.append(
+                f"Exchange: {xr} rows routed, fullest shard "
+                f"{getattr(counters, 'exchange_rows_max_shard', 0)}; "
+                f"mesh fragments: {fh} kept, {fc} compiled")
         sp = getattr(counters, "spilled_bytes", 0)
         aq = getattr(counters, "admission_queued", 0)
         if sp or aq:
