@@ -162,28 +162,33 @@ def test_q1_page_step_compiles_for_v5e(one_chip, as_on_tpu):
 
 
 def test_exchange_fragment_compiles_over_four_chips(topo, as_on_tpu):
-    """bucketize -> all_to_all inside shard_map with check_vma ON over a Mesh
-    of the four described chips: the Pallas compaction sits inside the
-    partitioned pack, and the exchange is a real all-to-all."""
-    from trino_tpu.ops.exchange import bucketize, exchange_all_to_all
+    """The mesh executor's probe exchange (bucketize -> all_to_all, and the side
+    channel that carries its counts to the consumer's flags pull) inside shard_map
+    with check_vma ON over a Mesh of the four described chips: the Pallas compaction
+    sits inside the partitioned pack, and the exchange is a real all-to-all."""
+    from trino_tpu.exec.distributed import (_route_rows, _side, _side_merge,
+                                            _side_probe)
     from trino_tpu.parallel.mesh import WORKER_AXIS
 
     W = 4
     mesh = Mesh(np.array(topo.devices).reshape(W), (WORKER_AXIS,))
     per, bucket = 1 << 16, 1 << 15
 
-    def frag(keys, vals):
+    def frag(keys, vals, carried):
         k, v = keys[0], vals[0]
+        valid = jnp.ones_like(k, bool)
         pid = (k % W).astype(jnp.int32)
-        packed, pvalid, _ = bucketize((k, v), jnp.ones_like(k, bool), pid, W, bucket)
-        recv, rvalid = exchange_all_to_all(packed, pvalid, WORKER_AXIS, W)
-        return recv[0][None], recv[1][None], rvalid[None]
+        recv, _, rvalid, counts = _route_rows((k, v), (None, None), valid, pid, W,
+                                              bucket, WORKER_AXIS)
+        of = _side_merge(carried[0], _side_probe(_side(valid), counts, bucket))
+        return recv[0][None], recv[1][None], rvalid[None], of[None]
 
-    f = jax.shard_map(frag, mesh=mesh, in_specs=(PS(WORKER_AXIS),) * 2,
-                      out_specs=(PS(WORKER_AXIS),) * 3)
+    f = jax.shard_map(frag, mesh=mesh, in_specs=(PS(WORKER_AXIS),) * 3,
+                      out_specs=(PS(WORKER_AXIS),) * 4)
     sharded = NamedSharding(mesh, PS(WORKER_AXIS))
     text = _compile(f, jax.ShapeDtypeStruct((W, per), jnp.int64, sharding=sharded),
-                    jax.ShapeDtypeStruct((W, per), jnp.int32, sharding=sharded))
+                    jax.ShapeDtypeStruct((W, per), jnp.int32, sharding=sharded),
+                    jax.ShapeDtypeStruct((W, 5), jnp.int64, sharding=sharded))
     assert "all-to-all" in text
     assert "tpu_custom_call" in text
 

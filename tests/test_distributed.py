@@ -247,6 +247,29 @@ def test_probe_bucket_overflow_retries(engine, mesh8):
     _frames_equal(dist, local)
 
 
+def test_forget_plan_forgets_a_learned_probe_bucket(engine, mesh8):
+    """A selective filter under a partitioned join's probe: one run learns the bucket its
+    exchange needs (kept for the Join node, PR 33); ``forget_plan`` drops it with the rest,
+    so a new plan node that lands on the old one's id starts from the ladder's own bucket."""
+    from trino_tpu.exec.distributed import DistributedExecutor
+    from trino_tpu.sql.frontend import compile_sql
+
+    sql = ("select count(*) c, sum(l_quantity) q from lineitem, orders "
+           "where l_orderkey = o_orderkey and l_shipdate > date '1995-03-15'")
+    s = engine.create_session("tpch")
+    local = engine.execute_sql(sql, s).to_pandas()
+    ex = DistributedExecutor(engine.catalogs, mesh=mesh8, partition_threshold=8)
+    plan = compile_sql(sql, engine, s)
+    _frames_equal(ex.execute(plan).to_pandas(), local)
+    learned = [k for k in ex._kept if k[1:] == ("probe_need",)]
+    assert len(learned) == 1 and ex.counters.probe_exchange_lanes > 0
+    _frames_equal(ex.execute(plan).to_pandas(), local)  # the narrowed fragment
+    assert ex.counters.mesh_fragment_compiles > 0
+    ex.forget_plan(plan)
+    assert not [k for k in ex._kept if k[1:] == ("probe_need",)]
+    assert not [k for k in ex._kept if k[0] == learned[0][0]]
+
+
 def test_partitioned_join_matches_local(engine):
     """Hash-partitioned (all-to-all) join distribution vs broadcast/local results."""
     import numpy as np

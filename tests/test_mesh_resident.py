@@ -203,6 +203,130 @@ def test_a_short_bucket_climbs_the_ladder_from_the_kept_rung(deployment):
     assert len(_EXCHANGE_LADDER) > max(rungs)
 
 
+# PR 33: a partitioned join's probe exchange sends what is live.  SF1's orders pass the
+# default partition threshold by themselves; at SF0.01 the threshold is lowered on the kept
+# executor, so that q3 has the deployment's shape: orders PARTITIONED, customer broadcast
+PARTITION_THRESHOLD = 1024
+W = 4
+
+
+def probe_lanes(deployment, bucket):
+    """W x bucket a chip a batch, over the chips and the batches of lineitem's scan."""
+    splits = deployment["conn"].splits("lineitem", n_hint=W)
+    return W * bucket * W * (len(splits) // W)
+
+
+def rung0_bucket(deployment):
+    """2n / W, n the lanes of a split of lineitem: seven an order of its range."""
+    split = deployment["conn"].splits("lineitem", n_hint=W)[0]
+    return -(-(split.hi - split.lo) * 7 * 2 // W)
+
+
+@pytest.fixture(scope="module")
+def narrowing(deployment):
+    """q3 three times on a kept engine of its own: each run's rows and counters, and the
+    engine after them."""
+    engine = mesh_engine(deployment["conn"], deployment["mesh"])
+    with engine._mesh_executor(None) as ex:
+        ex.partition_threshold = PARTITION_THRESHOLD
+    return [run(engine, "q3") for _ in range(3)] + [engine]
+
+
+@pytest.mark.parametrize("nth, compiles, narrow", [(0, True, False), (1, True, True),
+                                                   (2, False, True)])
+def test_q3s_probe_bucket_follows_the_rows_it_carried(deployment, narrowing, nth, compiles,
+                                                      narrow):
+    """(a) the first run learns, the second compiles the fragment at the learned bucket, the
+    third compiles nothing; (d) what the counters say of each; the same answer from all."""
+    rows, c = narrowing[nth]
+    assert rows == run(deployment["plain"], "q3")[0]
+    assert_reference(deployment, "q3", ["l_orderkey", "revenue", "o_orderdate",
+                                        "o_shippriority"], rows)
+    assert (c.compiles > 0, c.mesh_fragment_compiles > 0) == (compiles, compiles), c.as_dict()
+    assert rung0_bucket(deployment) >= 2 * 1024  # narrowing pays: half or less
+    bucket = 1024 if narrow else rung0_bucket(deployment)
+    assert c.probe_exchange_lanes == probe_lanes(deployment, bucket)
+    lineitem = deployment["tables"].columns("lineitem")
+    cutoff = (pd.Timestamp(q3.VALIDATION["date"]) - pd.Timestamp("1970-01-01")).days
+    assert c.probe_exchange_rows == int((lineitem["l_shipdate"] > cutoff).sum())
+    # the group-by's merge is the exchange it was: the probe's rows are not folded into it
+    _, served = run(deployment["engine"], "q3")
+    assert c.exchange_rows == served.exchange_rows > 0
+    assert c.exchange_rows_max_shard == served.exchange_rows_max_shard
+    assert served.probe_exchange_lanes == 0  # under the default threshold both joins broadcast
+
+
+def test_explain_analyze_and_metrics_report_the_probe_exchange(narrowing):
+    import re
+    import urllib.request
+
+    engine = narrowing[-1]
+    text = "\n".join(r[0] for r in engine.execute_sql(
+        "explain analyze " + sql_of("q3"), engine.create_session("tpch")).rows())
+    c = engine.last_query_counters
+    m = re.search(r"Exchange: .*; probe exchanges: (\d+) rows in (\d+) receive lanes", text)
+    assert m, text
+    assert tuple(map(int, m.groups())) == (c.probe_exchange_rows, c.probe_exchange_lanes)
+    server = CoordinatorServer(engine, port=0)
+    server.start()
+    try:
+        body = urllib.request.urlopen(server.url + "/v1/metrics", timeout=10).read().decode()
+    finally:
+        server.stop()
+    total = engine.counters_total
+    assert f"trino_tpu_probe_exchange_rows_total {total.probe_exchange_rows}\n" in body
+    assert f"trino_tpu_probe_exchange_lanes_total {total.probe_exchange_lanes}\n" in body
+    assert total.probe_exchange_lanes > c.probe_exchange_lanes > 0
+
+
+def dense_probe(deployment):
+    """orders onto customer, PARTITIONED, no filter under the probe: every lane of orders'
+    first split holds a row.  (plan, the answer of ``Engine()``, a fresh executor)"""
+    from trino_tpu.exec.distributed import DistributedExecutor
+    from trino_tpu.sql.frontend import compile_sql
+
+    plain = deployment["plain"]
+    sql = ("select count(*) c, sum(o_totalprice) s from orders join customer "
+           "on o_custkey = c_custkey")
+    s = plain.create_session("tpch")
+    s.properties["join_distribution_type"] = "PARTITIONED"  # the optimizer would broadcast
+    ex = DistributedExecutor(plain.catalogs, mesh=deployment["mesh"])
+    return compile_sql(sql, plain, s), plain.execute_sql(sql, s).rows(), ex
+
+
+def test_a_dense_probe_keeps_its_program(deployment):
+    """(c) the learned bucket is over half of what ran: nothing is kept, nothing compiles."""
+    plan, want, ex = dense_probe(deployment)
+    assert ex.execute(plan).rows() == want
+    first = ex.counters.snapshot()
+    assert first.probe_exchange_lanes > 0 and first.mesh_fragment_compiles > 0
+    assert not [k for k in ex._kept if k[1:] == ("probe_need",)]
+    assert ex.execute(plan).rows() == want
+    second = ex.counters.snapshot()
+    assert (second.compiles, second.mesh_fragment_compiles) == (0, 0), second.as_dict()
+    assert (second.probe_exchange_rows, second.probe_exchange_lanes) == \
+        (first.probe_exchange_rows, first.probe_exchange_lanes)
+
+
+def test_a_learned_bucket_that_falls_short_is_forgotten_and_the_ladder_answers(deployment):
+    """(b) a kept ``need`` pushed below the truth (the data changed under a kept plan): the
+    narrow run drops rows, says so, forgets ``need`` and climbs the ladder."""
+    plan, want, ex = dense_probe(deployment)
+    assert ex.execute(plan).rows() == want
+    (join,) = [v[0] for k, v in ex._kept.items() if k[1:] == ("ptable",)]
+    ex._kept[(id(join), "probe_need")] = (join, 10)
+    assert ex.execute(plan).rows() == want
+    c = ex.counters.snapshot()
+    assert c.mesh_fragment_compiles >= 2  # the narrow fragment, then the next rung's
+    assert max(v[1][0] for k, v in ex._kept.items() if k[1:] == ("rung",)) > 0
+    # the rung that held runs at the always-safe bucket n, and what IT counted is kept: the
+    # truth, which asks for more than the 1,024 slots that fell short
+    assert ex._kept[(id(join), "probe_need")][1] > 1024
+    for compiled in (True, False):
+        assert ex.execute(plan).rows() == want
+        assert (ex.counters.snapshot().mesh_fragment_compiles > 0) == compiled
+
+
 def test_two_concurrent_mesh_statements_both_answer_right(deployment):
     """(e) the executor's caches are single-statement state: the second statement waits."""
     engine, url = deployment["engine"], deployment["urls"][0]

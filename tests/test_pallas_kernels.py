@@ -311,12 +311,14 @@ def test_bucketize_byte_parity(forced):
     pid = jnp.asarray(rng.integers(0, P, n).astype(np.int32))
 
     def run():
-        packed, pvalid, oflow = bucketize(cols, valid, pid, P, bucket)
+        packed, pvalid, counts = bucketize(cols, valid, pid, P, bucket)
         return ([np.asarray(c) for c in packed], np.asarray(pvalid),
-                bool(oflow))
+                np.asarray(counts))
 
     (rc, rv, ro), (gc, gv, go) = forced(run)
-    assert ro == go
+    # the rows bound for each partition, before the bucket cuts them
+    assert np.array_equal(ro, go)
+    assert np.array_equal(ro, np.bincount(np.asarray(pid)[np.asarray(valid)], minlength=P))
     assert np.array_equal(rv, gv)
     for r, g in zip(rc, gc):
         assert np.array_equal(r, g)

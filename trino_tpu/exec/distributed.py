@@ -39,7 +39,8 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from ..execution import faults
 from ..execution.tracing import (maybe_span, record_join_build,
                                  record_mesh_fragment, record_page_cache,
-                                 record_rows_generated, record_shard_stats)
+                                 record_probe_exchange, record_rows_generated,
+                                 record_shard_stats)
 from ..ops import hashagg
 from ..ops.arrays import append_rows, compact_rows
 from ..ops.exchange import bucketize, exchange_all_to_all, partition_ids
@@ -64,23 +65,36 @@ def _route_rows(cols, nulls, valid, pid, n_parts: int, bucket: int, axis_name):
     the receive side.  The one routing protocol both the partitioned-join build
     and its per-batch probe exchange speak.
 
-    Returns (cols, nulls, valid, overflow): ``overflow`` is this worker's
-    SEND-side drop flag (a partition got more rows than ``bucket``); the stream
-    contract carries it to the driver, which retries at a bigger bucket —
-    exchange backpressure, re-planned as a host-level retry."""
+    Returns (cols, nulls, valid, counts): ``counts`` [n_parts] is the rows this
+    worker had BOUND for each destination (``bucketize``).  More than
+    ``bucket`` of them is the SEND-side drop: the stream contract carries it
+    to the driver, which retries at a bigger bucket — exchange backpressure,
+    re-planned as a host-level retry."""
     payload = list(cols)
     null_slots = []
     for ci, nm in enumerate(nulls):
         if nm is not None:
             null_slots.append(ci)
             payload.append(nm)
-    packed, pvalid, oflow = bucketize(tuple(payload), valid, pid, n_parts, bucket)
+    packed, pvalid, counts = bucketize(tuple(payload), valid, pid, n_parts, bucket)
     recv, recv_valid = exchange_all_to_all(packed, pvalid, axis_name, n_parts)
     rcols = list(recv[:len(cols)])
     rnulls = [None] * len(cols)
     for j, ci in enumerate(null_slots):
         rnulls[ci] = recv[len(cols) + j]
-    return rcols, rnulls, recv_valid, oflow
+    return rcols, rnulls, recv_valid, counts
+
+
+# The side channel of a stream, the ``of`` of ``transform -> (cols, nulls,
+# valid, of)``: ONE int64 vector a worker.  Slot 0 is the overflow flag (an
+# exchange or expansion bucket of the fragment dropped rows: the consumer
+# retries the whole run one rung up, _retry_exchange); then, for each probe
+# exchange of the fragment in plan order (_DStream.probes), _SIDE_FIELDS
+# slots: the largest count it had bound for one destination (``need``), the
+# bucket it ran at, the rows it routed and the lanes its receive tensor held.
+# A consumer carries it across its batches (_side_merge) to the flags pull it
+# makes anyway, where _settle reads it: no pull and no dispatch of its own.
+_SIDE_FIELDS = 4
 
 
 def _false(valid):
@@ -88,6 +102,41 @@ def _false(valid):
     unvarying and cannot join varying carries/outputs; deriving from the data
     inherits the axis."""
     return jnp.any(valid) & False
+
+
+def _side(valid):
+    """The channel of a fragment with no exchange yet."""
+    return _false(valid).astype(jnp.int64)[None]
+
+
+def _side_flag(of, flag):
+    """``of`` with the overflow flag raised where ``flag`` is."""
+    return of.at[0].max(flag.astype(of.dtype))
+
+
+def _side_probe(of, counts, bucket: int):
+    """``of`` with one probe exchange more: ``counts`` as _route_rows gave
+    them, ``bucket`` the static bucket the exchange ran at."""
+    need = jnp.max(counts).astype(of.dtype)
+    here = need * 0  # the statics below take the worker axis from the data
+    rec = jnp.stack([need, here + bucket, jnp.sum(counts).astype(of.dtype),
+                     here + counts.shape[0] * bucket])
+    return jnp.concatenate([_side_flag(of, need > bucket), rec])
+
+
+def _side_merge(acc, of, xp=jnp):
+    """Two batches' channels as one: flag, need and bucket by high-water, rows
+    and lanes by sum (``xp=np`` for the host-spool paths)."""
+    probes = (acc.shape[-1] - 1) // _SIDE_FIELDS
+    summed = np.asarray([False] + [False, False, True, True] * probes)  # host-ok: static mask
+    return xp.where(summed, acc + of, xp.maximum(acc, of))
+
+
+def _learned_bucket(need: int) -> int:
+    """The probe bucket a kept ``need`` asks for: the smallest power of two at
+    or above need * 5 / 4, never under 1,024 (_probe_bucket holds it to the
+    input's lanes)."""
+    return max(1 << (max(need * 5 // 4, 1) - 1).bit_length(), 1024)
 
 
 def _exchange_fault(point: str, site: str):
@@ -645,16 +694,20 @@ class _DStream:
     dicts: tuple
     scan_lo_batches: list  # list of np.ndarray [n_workers] of per-worker row offsets
     scan_fn: Callable  # (lo_scalar) -> (cols, nulls, valid); traced per worker
-    transform: Callable  # (cols, nulls, valid, aux) -> (cols, nulls, valid, oflow)
-    # oflow: per-worker bool scalar — True when an exchange/expansion bucket in
-    # the fragment dropped rows this batch; the consumer retries the whole run
-    # at a bigger bucket (_retry_exchange)
+    transform: Callable  # (cols, nulls, valid, aux) -> (cols, nulls, valid, of)
+    # of: the side channel (_side): per worker, the flag that an exchange or
+    # expansion bucket in the fragment dropped rows this batch (the consumer
+    # retries the whole run at a bigger bucket, _retry_exchange) and what
+    # each probe exchange carried
     aux: tuple = ()  # device state (join tables) threaded as a jit ARGUMENT —
     # closed over, a table is baked into the executable as a constant: every
     # new table is a recompile and its bytes live in the program
     aux_specs: object = PS()  # shard_map in_specs pytree (prefix) for aux:
     # PS() = replicated (broadcast tables); exchange-routed partitioned-join
     # tables are sharded [W, ...] on the worker axis and carry PS(WORKER_AXIS)
+    probes: tuple = ()  # the Join nodes whose probe exchanges report on ``of``
+    key: tuple = ()  # (ladder rung, learned probe buckets) it was compiled at:
+    # part of the kept key of every step over it (_fragment, _step)
 
 
 class DistributedExecutor:
@@ -666,8 +719,10 @@ class DistributedExecutor:
     as LocalExecutor._stream_cache keeps its streams: the _DStream with its
     join tables in ``aux``, the jitted shard_map steps over it, the build
     side's page and facts, the rung of _EXCHANGE_LADDER and the group-by
-    capacity that last held.  A replay of one plan compiles nothing, builds
-    nothing and pulls only the flags its exchanges need.  ``forget_plan``
+    capacity that last held, the rows a partitioned join's probe exchange
+    last had bound for one destination (its bucket follows them, _settle).
+    A replay of one plan compiles nothing, builds nothing and pulls only the
+    flags its exchanges need.  ``forget_plan``
     (the engine's version-stale path) and the engine's ``_invalidate`` (which
     drops the executor) are what forgets.  All of it is single-statement
     state: the engine runs one statement at a time under ``statement_lock``."""
@@ -765,23 +820,33 @@ class DistributedExecutor:
         return hit[1]
 
     def _fragment(self, node) -> Optional[_DStream]:
-        """The compiled stream of ``node`` at the current rung, kept; None
-        (with its reason) when it has no distributable scan spine.  THE
-        lookup the fragment counters and the ``mesh.fragment`` span read:
-        one a consumer's attempt."""
-        what = ("stream", self._rung)
+        """The compiled stream of ``node`` at the current rung and the probe
+        buckets learned along its spine, kept; None (with its reason) when it
+        has no distributable scan spine.  A fragment whose probe exchange
+        learned a narrower bucket is a kept fragment of its own, compiled by
+        the execution right after the one that learned.  THE lookup the
+        fragment counters and the ``mesh.fragment`` span read: one a
+        consumer's attempt."""
+        what = ("stream", self._rung, self._narrowed(node))
+
+        def compile_fragment():
+            stream = self._compile_stream(node)
+            if stream is not None:
+                stream = dataclasses.replace(stream, key=what[1:])
+            return stream, self._decline_reason
+
         hit = self._held(node, what) is not None
         with maybe_span("mesh.fragment", hit=hit, node=type(node).__name__):
             record_mesh_fragment(hit)
-            stream, reason = self._keep(node, what, lambda: (
-                self._compile_stream(node), self._decline_reason))
+            stream, reason = self._keep(node, what, compile_fragment)
         if stream is None and self._decline_reason is None:
             self._decline_reason = reason
         return stream
 
-    def _step(self, node, what: tuple, make):
-        """A jitted step over ``node``'s fragment at the current rung."""
-        return self._keep(node, what + (self._rung,), make)
+    def _step(self, node, stream: _DStream, what: tuple, make):
+        """A jitted step of ``node`` over ``stream``, kept under what the
+        stream was compiled at."""
+        return self._keep(node, what + stream.key, make)
 
     def forget_plan(self, plan: P.PlanNode) -> None:
         """Drop what is kept for a plan the engine is replacing or will not
@@ -1010,7 +1075,7 @@ class DistributedExecutor:
                     return tuple(cols), tuple(nulls), valid
 
                 return _DStream(node.schema, dicts, batches, host_scan_fn,
-                                lambda c, n, v, aux: (c, n, v, _false(v)))
+                                lambda c, n, v, aux: (c, n, v, _side(v)))
             # resident sharded scan: the steps read [W, lanes] column batches
             # as arguments; generation is a program of its own (_ShardedScan)
             scan = _ShardedScan(self, conn, node.catalog, node.table,
@@ -1022,7 +1087,7 @@ class DistributedExecutor:
                 return tuple(cols), tuple(None for _ in cols), valid
 
             return _DStream(node.schema, dicts, scan, scan_fn,
-                            lambda c, n, v, aux: (c, n, v, _false(v)))
+                            lambda c, n, v, aux: (c, n, v, _side(v)))
 
         if isinstance(node, P.Filter):
             up = self._compile_stream(node.child)
@@ -1046,8 +1111,8 @@ class DistributedExecutor:
                 vs, ns = _eval_project(exprs, cols, nulls, valid.shape)
                 return vs, ns, valid, of
 
-            return _DStream(node.schema, dicts, up.scan_lo_batches, up.scan_fn, transform,
-                            aux=up.aux, aux_specs=up.aux_specs)
+            return dataclasses.replace(up, schema=node.schema, dicts=dicts,
+                                       transform=transform)
 
         if isinstance(node, P.Join):
             if node.kind == "mark":
@@ -1147,8 +1212,9 @@ class DistributedExecutor:
                 return out_cols, out_nulls, valid, of
 
             dicts = up.dicts if semi else up.dicts + build_dicts
-            return _DStream(node.schema, dicts, up.scan_lo_batches, up.scan_fn, transform,
-                            aux=(up.aux, table), aux_specs=(up.aux_specs, PS()))
+            return dataclasses.replace(
+                up, schema=node.schema, dicts=dicts, transform=transform,
+                aux=(up.aux, table), aux_specs=(up.aux_specs, PS()))
 
         return self._decline(node, "operator is not part of a streamable "
                                    "fragment (blocking or unsupported shape)")
@@ -1182,7 +1248,7 @@ class DistributedExecutor:
                              self._sharded_build_exchange(node, build_page,
                                                           make_table))
 
-        probe_bucket_of = self._probe_bucket
+        learned = self._learned(node)
 
         def transform(cols, nulls, valid, aux, up=up, node=node):
             up_aux, table_g = aux
@@ -1193,12 +1259,13 @@ class DistributedExecutor:
             # NULL probe keys never match but must SURVIVE for left/anti: route them
             # (to their hash bucket) like any other row; matching excludes them below.
             # The bucket starts at ~2n/W (a W/2-times smaller receive tensor than
-            # the always-safe n); skewed batches report overflow through the
-            # stream contract and the driver retries bigger (_EXCHANGE_LADDER).
-            rcols, rnulls, recv_valid, r_of = _route_rows(
-                tuple(cols), tuple(nulls), valid, rpid, W,
-                probe_bucket_of(n), WORKER_AXIS)
-            of = of | r_of
+            # the always-safe n) and follows the rows once a run has counted
+            # them (_settle); skewed batches report overflow through the stream
+            # contract and the driver retries bigger (_EXCHANGE_LADDER).
+            bucket = self._probe_bucket(n, learned)
+            rcols, rnulls, recv_valid, counts = _route_rows(
+                tuple(cols), tuple(nulls), valid, rpid, W, bucket, WORKER_AXIS)
+            of = _side_probe(of, counts, bucket)
             # this worker's table shard arrives as [1, ...] under aux_specs
             jt = jax.tree.map(lambda x: None if x is None else x[0], table_g,
                               is_leaf=lambda x: x is None)
@@ -1230,17 +1297,74 @@ class DistributedExecutor:
             return (out_cols, out_nulls, out_valid, of)
 
         dicts = up.dicts if semi else up.dicts + build_dicts
-        return _DStream(node.schema, dicts, up.scan_lo_batches, up.scan_fn, transform,
-                        aux=(up.aux, table_g),
-                        aux_specs=(up.aux_specs, PS(WORKER_AXIS)))
+        return dataclasses.replace(
+            up, schema=node.schema, dicts=dicts, transform=transform,
+            aux=(up.aux, table_g), aux_specs=(up.aux_specs, PS(WORKER_AXIS)),
+            probes=up.probes + (node,))
 
-    def _probe_bucket(self, n: int) -> int:
-        """Per-partition probe-exchange bucket for an n-row batch: ~(factor/W)·n
-        on the ladder's adaptive rungs, exact n on the safe rung."""
+    def _probe_bucket(self, n: int, learned: Optional[int] = None) -> int:
+        """Per-partition probe-exchange bucket for an n-row batch: what the
+        rows that join's exchange has carried ask for (_learned) once a run
+        has counted them; until then ~(factor/W)·n on the ladder's adaptive
+        rungs, exact n on the safe rung."""
+        if learned is not None:
+            return min(n, learned)
         pf = self._probe_factor
         if pf is None:
             return n
         return max(min(n, -(-n * pf // self.n_workers)), 1)
+
+    def _learned(self, join) -> Optional[int]:
+        """The probe bucket learned for ``join``, or None: from the ``need``
+        _settle kept for the node."""
+        hit = self._held(join, ("probe_need",))
+        return None if hit is None else _learned_bucket(hit[1])
+
+    def _narrowed(self, node) -> tuple:
+        """The learned probe buckets along ``node``'s stream spine, from the
+        top: with the rung, what a fragment is compiled at."""
+        out = []
+        while True:
+            if isinstance(node, P.Join):
+                out.append(self._learned(node))
+                node = node.left
+            elif isinstance(node, (P.Filter, P.Project)):
+                node = node.child
+            else:
+                return tuple(out)
+
+    def _overflowed(self, stream: _DStream, side) -> bool:
+        """Whether a pulled side channel ([W, slots], host) raised its flag.
+        A run that dropped rows forgets what its probe exchanges had learned:
+        the retry runs the ladder's own buckets, as it always has."""
+        if not np.any(side[:, 0]):
+            return False
+        for node in stream.probes:
+            self._kept.pop((id(node), "probe_need"), None)
+        return True
+
+    def _settle(self, stream: _DStream, side) -> bool:
+        """The side channel's host half, where a consumer's flags pull lands:
+        True when the run dropped rows (ladder retry).  A sound run counts
+        what its probe exchanges carried, and keeps ``need`` for a join whose
+        exchange would run at half its bucket or less: the execution right
+        after this one compiles that fragment narrow (_fragment's key), a
+        dense probe keeps its program."""
+        if self._overflowed(stream, side):
+            return True
+        for i, node in enumerate(stream.probes):
+            at = 1 + _SIDE_FIELDS * i
+            need, bucket = (int(side[:, at + j].max()) for j in (0, 1))
+            record_probe_exchange(*(int(side[:, at + j].sum()) for j in (2, 3)))
+            if 2 * _learned_bucket(need) <= bucket:
+                self._kept[(id(node), "probe_need")] = (node, need)
+        return False
+
+    def _side_init(self, stream: _DStream):
+        """The zeroed [W, slots] carry of ``stream``'s side channel."""
+        return jax.device_put(  # device-ok: mesh-sharded placement
+            np.zeros((self.n_workers, 1 + _SIDE_FIELDS * len(stream.probes)),
+                     np.int64), self._sharded)
 
     def _sharded_build_exchange(self, node: P.Join, build_page, make_table):
         """The partitioned-join build scaffold both table layouts share: shard
@@ -1338,12 +1462,12 @@ class DistributedExecutor:
             ocols, onulls, ovalid, m_of = _multi_probe_expand(
                 node, mt, build_key_types, tuple(cols), tuple(nulls), valid,
                 E, build_null_stats, semi)
-            return ocols, onulls, ovalid, of | m_of
+            return ocols, onulls, ovalid, _side_flag(of, m_of)
 
         dicts = up.dicts if semi else up.dicts + build_dicts
-        return _DStream(node.schema, dicts, up.scan_lo_batches, up.scan_fn,
-                        transform, aux=(up.aux, mt),
-                        aux_specs=(up.aux_specs, PS()))
+        return dataclasses.replace(
+            up, schema=node.schema, dicts=dicts, transform=transform,
+            aux=(up.aux, mt), aux_specs=(up.aux_specs, PS()))
 
     def _compile_partitioned_multi_join(self, node: P.Join, up: _DStream,
                                         build_page, build_dicts,
@@ -1372,7 +1496,7 @@ class DistributedExecutor:
                           self._sharded_build_exchange(node, build_page,
                                                        make_table))
 
-        probe_bucket = self._probe_bucket
+        learned = self._learned(node)
         ef = self._expand_factor
 
         def transform(cols, nulls, valid, aux, up=up, node=node, ef=ef,
@@ -1383,21 +1507,23 @@ class DistributedExecutor:
             n = valid.shape[0]
             pkeys = tuple(cols[i] for i in node.left_keys)
             rpid = partition_ids(pkeys, W)
-            rcols, rnulls, recv_valid, r_of = _route_rows(
-                tuple(cols), tuple(nulls), valid, rpid, W, probe_bucket(n),
-                WORKER_AXIS)
+            bucket = self._probe_bucket(n, learned)
+            rcols, rnulls, recv_valid, counts = _route_rows(
+                tuple(cols), tuple(nulls), valid, rpid, W, bucket, WORKER_AXIS)
             mt = jax.tree.map(lambda x: None if x is None else x[0], mt_g,
                               is_leaf=lambda x: x is None)
             E = max(ef * n, 1024)
             ocols, onulls, ovalid, m_of = _multi_probe_expand(
                 node, mt, build_key_types, tuple(rcols), tuple(rnulls),
                 recv_valid, E, build_null_stats, semi)
-            return ocols, onulls, ovalid, of | r_of | m_of
+            return ocols, onulls, ovalid, _side_flag(
+                _side_probe(of, counts, bucket), m_of)
 
         dicts = up.dicts if semi else up.dicts + build_dicts
-        return _DStream(node.schema, dicts, up.scan_lo_batches, up.scan_fn,
-                        transform, aux=(up.aux, mt_g),
-                        aux_specs=(up.aux_specs, PS(WORKER_AXIS)))
+        return dataclasses.replace(
+            up, schema=node.schema, dicts=dicts, transform=transform,
+            aux=(up.aux, mt_g), aux_specs=(up.aux_specs, PS(WORKER_AXIS)),
+            probes=up.probes + (node,))
 
     # ---------------------------------------------------------------- sort
     def _run_sort(self, node: P.Sort):
@@ -1452,13 +1578,13 @@ class DistributedExecutor:
 
                 return _jit(sample_key, site="dist.sort.sample_key")
 
-            got = _host(list(self._step(node, ("sort.sample_key",),
+            got = _host(list(self._step(node, stream, ("sort.sample_key",),
                                         make_sample_key)(
                             jax.device_put(stream.scan_lo_batches[0], sharded),  # device-ok: mesh-sharded placement
                             stream.aux))
                         + ([luts[ch]] if ch in luts else []),
                         site="dist.sort.sample")
-            if bool(np.any(got[3])):
+            if self._overflowed(stream, got[3]):
                 return None, True
             key0 = got[0].reshape(-1)
             keynull0 = got[1].reshape(-1)
@@ -1480,12 +1606,12 @@ class DistributedExecutor:
 
                 return _jit(sample, site="dist.sort.sample")
 
-            c0, n0, v0, of0 = self._step(node, ("sort.sample",), make_sample)(
+            c0, n0, v0, of0 = self._step(node, stream, ("sort.sample",), make_sample)(
                 jax.device_put(stream.scan_lo_batches[0], sharded), stream.aux)  # device-ok: mesh-sharded placement
             got = _host(list(c0) + list(n0) + [v0, of0]
                         + ([luts[ch]] if ch in luts else []),
                         site="dist.sort.sample")
-            if bool(np.any(got[len(c0) + len(n0) + 1])):
+            if self._overflowed(stream, got[len(c0) + len(n0) + 1]):
                 return None, True
             cols0 = [c.reshape(-1) for c in got[:len(c0)]]
             nulls0 = [m.reshape(-1) for m in got[len(c0):len(c0) + len(n0)]]
@@ -1686,19 +1812,20 @@ class DistributedExecutor:
             def step(lo_g, aux, route_aux):
                 cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
                 pid = pid_fn(cols, nulls, valid, route_aux)
-                n = valid.shape[0]
-                rcols, rnulls, rvalid, r_of = _route_rows(
-                    tuple(cols), tuple(nulls), valid, pid, W,
-                    bucket_of(n), WORKER_AXIS)
+                bucket = bucket_of(valid.shape[0])
+                rcols, rnulls, rvalid, counts = _route_rows(
+                    tuple(cols), tuple(nulls), valid, pid, W, bucket,
+                    WORKER_AXIS)
                 rnulls = tuple(jnp.zeros(c.shape, bool) if m is None else m
                                for c, m in zip(rcols, rnulls))
                 return (tuple(c[None] for c in rcols),
-                        tuple(m[None] for m in rnulls),
-                        rvalid[None], (of | r_of)[None])
+                        tuple(m[None] for m in rnulls), rvalid[None],
+                        _side_flag(of, jnp.any(counts > bucket))[None])
 
             return _jit(step, site="dist.exchange.spool")
 
-        step = self._step(node, ("exchange.spool",), make_step)
+        step = self._step(node, stream, ("exchange.spool",), make_step)
+        side = None
         if seed is not None:
             per_cols, per_nulls = seed
         else:
@@ -1712,8 +1839,9 @@ class DistributedExecutor:
                     jax.device_put(lo, sharded), stream.aux, route_aux)  # device-ok: mesh-sharded placement
                 got = _host(list(rcols) + list(rnulls) + [rvalid, of],
                             site="dist.exchange.collect")
-            if bool(np.any(got[-1])):
+            if self._overflowed(stream, got[-1]):
                 return None
+            side = got[-1] if side is None else _side_merge(side, got[-1], np)
             v = got[-2]
             cols_np = got[:len(rcols)]
             nulls_np = got[len(rcols):len(rcols) + len(rnulls)]
@@ -1727,6 +1855,8 @@ class DistributedExecutor:
         out_nulls = [[np.concatenate(per_nulls[w][i]) for i in range(ncols)]
                      for w in range(W)]
         counts = [len(out_cols[w][0]) if ncols else 0 for w in range(W)]
+        if side is not None:
+            self._settle(stream, side)
         self._note_skew("dist.exchange.collect", node, counts,
                         time.perf_counter() - t0, fields=fields)
         _exchange_fault("exchange_read", "dist.exchange.read")
@@ -1749,11 +1879,11 @@ class DistributedExecutor:
         est = self._batch_rows(stream) * max(len(stream.scan_lo_batches), 1)
         return max(1 << (max(2 * est, 1024) - 1).bit_length(), 1024)
 
-    def _recv_state_init(self, cap: int, dtypes):
+    def _recv_state_init(self, stream: _DStream, cap: int, dtypes):
         """Zeroed receive-buffer carry, mesh-sharded: per-column [W, cap + 1]
         value + null-mask buffers (the +1 slot is append_rows' drop sink),
-        [W] write cursors, [W] ladder-overflow and [W] capacity-overflow
-        flags."""
+        [W] write cursors, the stream's side channel (ladder overflow, what
+        its probe exchanges carried) and [W] capacity-overflow flags."""
         W = self.n_workers
         sharded = NamedSharding(self.mesh, PS(WORKER_AXIS))
 
@@ -1763,7 +1893,7 @@ class DistributedExecutor:
         return (tuple(put(np.zeros((W, cap + 1), dt)) for dt in dtypes),
                 tuple(put(np.zeros((W, cap + 1), bool)) for _ in dtypes),
                 put(np.zeros((W,), np.int64)),
-                put(np.zeros((W,), bool)),
+                self._side_init(stream),
                 put(np.zeros((W,), bool)))
 
     def _slim_shards(self, node, state, counts, site: str):
@@ -1806,7 +1936,7 @@ class DistributedExecutor:
         cap = self._recv_capacity(stream)
         while True:
             t0 = time.perf_counter()
-            state = self._recv_state_init(cap, dtypes)
+            state = self._recv_state_init(stream, cap, dtypes)
 
             def make_step(stream=stream):
                 @partial(shard_map, mesh=mesh,
@@ -1820,9 +1950,11 @@ class DistributedExecutor:
                     lad_of, recv_of = state_g[3][0], state_g[4][0]
                     cols, nulls, valid, of = _stream_batch(stream, lo_g, aux)
                     pid = pid_fn(cols, nulls, valid, route_aux)
-                    rcols, rnulls, rvalid, r_of = _route_rows(
-                        tuple(cols), tuple(nulls), valid, pid, W,
-                        bucket_of(valid.shape[0]), WORKER_AXIS)
+                    bucket = bucket_of(valid.shape[0])
+                    rcols, rnulls, rvalid, counts = _route_rows(
+                        tuple(cols), tuple(nulls), valid, pid, W, bucket,
+                        WORKER_AXIS)
+                    of = _side_flag(of, jnp.any(counts > bucket))
                     # cast to the schema dtypes the buffers were allocated at
                     # (same cast _stack_shards applies on the host path)
                     rcols = tuple(c.astype(dt) for c, dt in zip(rcols, dtypes))
@@ -1833,22 +1965,22 @@ class DistributedExecutor:
                     k = len(bufs)
                     return (tuple(b[None] for b in new[:k]),
                             tuple(b[None] for b in new[k:]),
-                            ncur[None], (lad_of | of | r_of)[None],
+                            ncur[None], _side_merge(lad_of, of)[None],
                             (recv_of | b_of)[None])
 
                 return _jit(step, site="dist.exchange.route")
 
             # one wrapper serves every receive capacity: a grown cap is a
             # new argument shape of it, not a new program object
-            step = self._step(node, ("exchange.route",), make_step)
+            step = self._step(node, stream, ("exchange.route",), make_step)
             for lo in stream.scan_lo_batches:
                 _exchange_fault("exchange_write", "dist.exchange.route")
                 with maybe_span("exchange.route"):
                     state = step(state, jax.device_put(lo, sharded),  # device-ok: mesh-sharded placement
                                  stream.aux, route_aux)
-            cursor, lad_of, recv_of = _host(
+            cursor, side, recv_of = _host(
                 [state[2], state[3], state[4]], site="dist.exchange.flags")
-            if bool(np.any(lad_of)):
+            if self._settle(stream, side):
                 return None  # exchange/expansion bucket overflow: ladder retry
             if not bool(np.any(recv_of)):
                 break
@@ -1891,7 +2023,7 @@ class DistributedExecutor:
         state = (jax.device_put(state_cols, sharded),  # device-ok: mesh-sharded placement
                  jax.device_put(state_nulls, sharded),  # device-ok: mesh-sharded placement
                  jax.device_put(state_valid, sharded),  # device-ok: mesh-sharded placement
-                 jax.device_put(jnp.zeros((W,), bool), sharded))  # oflow acc  # device-ok: mesh-sharded placement
+                 self._side_init(stream))
         luts_t = dict(luts)
 
         def make_step(stream=stream):
@@ -1915,18 +2047,18 @@ class DistributedExecutor:
                 return (tuple(c[idx][None] for c in cat_cols),
                         tuple(m[idx][None] for m in cat_nulls),
                         cat_valid[idx][None],
-                        (s_of | of)[None])
+                        _side_merge(s_of, of)[None])
 
             return _jit(step, site="dist.topn.step")
 
-        step = self._step(node, ("topn.step",), make_step)
+        step = self._step(node, stream, ("topn.step",), make_step)
         t0 = time.perf_counter()
         for lo in stream.scan_lo_batches:
             state = step(state, jax.device_put(lo, sharded), stream.aux, luts_t)  # device-ok: mesh-sharded placement
 
         got = _host(list(state[0]) + list(state[1])
                     + [state[2], state[3]], site="dist.topn.states")
-        oflow = bool(np.any(got[-1]))
+        oflow = self._settle(stream, got[-1])
         if not oflow:
             # per-worker surviving-candidate counts from the states pull the
             # merge already pays — the topN analog of receive-cursor skew
@@ -2008,7 +2140,7 @@ class DistributedExecutor:
                                                valid, inputs, acc_kinds)
                 return (jax.tree.map(lambda x: x[None], new,
                                      is_leaf=lambda x: x is None),
-                        (of_g[0] | of)[None])
+                        _side_merge(of_g[0], of)[None])
 
             return _jit(step, site="dist.agg.direct_step" if cfg is not None
                         else "dist.agg.step")
@@ -2017,21 +2149,21 @@ class DistributedExecutor:
             t0 = time.perf_counter()
             capacity, cfg = held
             # one wrapper serves every capacity: the state is an argument
-            step = self._step(node, ("agg.step", cfg is not None),
+            step = self._step(node, stream, ("agg.step", cfg is not None),
                               partial(make_step, cfg))
             state = self._global_state_init(capacity, key_types, acc_specs, cfg)
-            of_acc = jax.device_put(jnp.zeros((W,), bool), sharded)  # device-ok: mesh-sharded placement
+            of_acc = self._side_init(stream)
             for lo in stream.scan_lo_batches:
                 state, of_acc = step(state, of_acc, jax.device_put(lo, sharded),  # device-ok: mesh-sharded placement
                                      stream.aux)
 
-            # the merge is dispatched before the ladder's flag is read, so
+            # the merge is dispatched before the side channel is read, so
             # that ONE pull serves both (a short bucket wastes one merge)
             merged, nocc_g = self._merge_states(node, state, key_types,
                                                 acc_specs, merge_kinds)
             of2 = _host([merged.overflow, state.overflow, nocc_g, of_acc],
                         site="dist.agg.overflow")
-            if bool(np.any(of2[3])):
+            if self._settle(stream, of2[3]):
                 return None, True  # exchange bucket overflow: ladder retry
             overflow = bool(np.any(of2[0])) or bool(np.any(of2[1]))
             if overflow and cfg is not None:
@@ -2209,7 +2341,7 @@ class DistributedExecutor:
                                 if k in ("min", "max") else (init or 0), dt)[None], (W,)),
                 sharded)
             for (dt, init), k in zip(acc_specs, acc_kinds)
-        ) + (jax.device_put(jnp.zeros((W,), bool), sharded),)  # oflow acc  # device-ok: mesh-sharded placement
+        ) + (self._side_init(stream),)
 
         @partial(shard_map, mesh=mesh,
                  in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS), stream.aux_specs),
@@ -2243,16 +2375,16 @@ class DistributedExecutor:
                     out.append(jnp.maximum(s, jnp.max(jnp.where(mask, v, hashagg._extreme(s.dtype, -1)))))
                 else:
                     raise NotImplementedError(f"global agg kind {kind}")
-            return tuple(o[None] for o in out) + ((s_of | of)[None],)
+            return tuple(o[None] for o in out) + (_side_merge(s_of, of)[None],)
 
-        step = self._step(node, ("global.step",),
+        step = self._step(node, stream, ("global.step",),
                           lambda: _jit(step, site="dist.global.step"))
         for lo in stream.scan_lo_batches:
             state = step(state, jax.device_put(lo, sharded), stream.aux)  # device-ok: mesh-sharded placement
 
         got = _host(list(state),
                     site="dist.agg.states")  # one batched pull
-        if bool(np.any(got[-1])):
+        if self._settle(stream, got[-1]):
             return None, True  # exchange bucket overflow: ladder retry
         # cross-worker combine on host (W scalars)
         finals = []
@@ -2292,25 +2424,27 @@ class DistributedExecutor:
             return (tuple(c[None] for c in cols), tuple(n[None] for n in nulls),
                     valid[None], of[None])
 
-        run = self._step(node, ("stream.run",),
+        run = self._step(node, stream, ("stream.run",),
                          lambda: _jit(run, site="dist.stream.run"))
         parts_cols, parts_nulls, parts_valid = [], [], []
-        oflow = False
+        side = None
         rows_w = np.zeros((self.n_workers,), np.int64)
         t0 = time.perf_counter()
         for lo in stream.scan_lo_batches:
             cols, nulls, valid, of = run(jax.device_put(lo, sharded), stream.aux)  # device-ok: mesh-sharded placement
             got = _host(list(cols) + list(nulls) + [valid, of],
                         site="dist.stream.collect")
-            oflow = oflow or bool(np.any(got[-1]))
-            if oflow:
+            if self._overflowed(stream, got[-1]):
                 return None, True  # exchange bucket overflow: ladder retry
+            side = got[-1] if side is None else _side_merge(side, got[-1], np)
             rows_w += got[-2].sum(axis=1)  # [W, cap] valid, pre-flatten
             v = got[-2].reshape(-1)
             parts_valid.append(v)
             parts_cols.append([c.reshape(-1)[v] for c in got[:len(cols)]])
             parts_nulls.append([n.reshape(-1)[v]
                                 for n in got[len(cols):len(cols) + len(nulls)]])
+        if side is not None:
+            self._settle(stream, side)
         self._note_skew("dist.stream.collect", node, rows_w.tolist(),
                         time.perf_counter() - t0, kind="stream",
                         fields=fields)
@@ -2335,7 +2469,7 @@ class DistributedExecutor:
         cap = self._recv_capacity(stream)
         while True:
             t0 = time.perf_counter()
-            state = self._recv_state_init(cap, dtypes)
+            state = self._recv_state_init(stream, cap, dtypes)
 
             @partial(shard_map, mesh=mesh,
                      in_specs=(PS(WORKER_AXIS), PS(WORKER_AXIS),
@@ -2355,16 +2489,16 @@ class DistributedExecutor:
                 k = len(bufs)
                 return (tuple(b[None] for b in new[:k]),
                         tuple(b[None] for b in new[k:]),
-                        ncur[None], (lad_of | of)[None],
+                        ncur[None], _side_merge(lad_of, of)[None],
                         (recv_of | b_of)[None])
 
-            run = self._step(node, ("stream.route",), lambda run=run:
+            run = self._step(node, stream, ("stream.route",), lambda run=run:
                              _jit(run, site="dist.stream.route"))
             for lo in stream.scan_lo_batches:
                 state = run(state, jax.device_put(lo, sharded), stream.aux)  # device-ok: mesh-sharded placement
-            cursor, lad_of, recv_of = _host(
+            cursor, side, recv_of = _host(
                 [state[2], state[3], state[4]], site="dist.stream.flags")
-            if bool(np.any(lad_of)):
+            if self._settle(stream, side):
                 return None, True  # exchange bucket overflow: ladder retry
             if not bool(np.any(recv_of)):
                 break

@@ -278,6 +278,14 @@ class QueryCounters:
     exchange_rows_max_shard: int = 0
     mesh_fragment_hits: int = 0
     mesh_fragment_compiles: int = 0
+    # PR 33: what the probe exchanges INSIDE the mesh steps carried (a
+    # partitioned join's all-to-all of its probe side): rows routed, and the
+    # lanes their receive tensors held (W x bucket a chip a batch).  They
+    # ride the stream's side channel to the flags pull its consumer makes
+    # anyway (exec/distributed.py _settle); exchange_rows above does not
+    # hold them
+    probe_exchange_rows: int = 0
+    probe_exchange_lanes: int = 0
     # PR 25: the statement's wait states, seconds (each also a span of the
     # same name family: server.queued, batcher.wait, executor.checkout,
     # server.encode, server.deliver), recorded where the wait happens, and
@@ -341,7 +349,8 @@ class QueryCounters:
                    "groupby_partitioned_passes", "join_build_rows",
                    "rows_generated", "join_match_lanes", "join_gather_lanes",
                    "exchange_rows", "exchange_rows_max_shard",
-                   "mesh_fragment_hits", "mesh_fragment_compiles")
+                   "mesh_fragment_hits", "mesh_fragment_compiles",
+                   "probe_exchange_rows", "probe_exchange_lanes")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
                      "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
@@ -622,6 +631,15 @@ def record_mesh_fragment(hit: bool) -> None:
             c.mesh_fragment_hits += 1
         else:
             c.mesh_fragment_compiles += 1
+
+
+def record_probe_exchange(rows: int, lanes: int) -> None:
+    """One run of a mesh fragment's probe exchange (exec/distributed.py): the
+    rows it routed and the lanes its receive tensors held."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.probe_exchange_rows += rows
+        c.probe_exchange_lanes += lanes
 
 
 def _attribute_extra(site: Optional[str], **extras) -> None:

@@ -13,8 +13,9 @@ TPU re-design (runs *inside* shard_map, SURVEY.md §2.8 mapping):
   covers all partitions);
 - ``jax.lax.all_to_all`` over the worker axis swaps buckets so worker w receives every
   row whose key hashes to w — the ICI replacement for the HTTP long-poll;
-- fixed bucket capacity keeps shapes static; overflowing rows are dropped AND reported in
-  an overflow flag so the driver can re-run the batch with a bigger bucket (the moral
+- fixed bucket capacity keeps shapes static; overflowing rows are dropped AND reported (the
+  rows bound for each partition come back beside the send layout: more than ``bucket`` of
+  them is an overflow) so the driver can re-run the batch with a bigger bucket (the moral
   equivalent of exchange backpressure, OutputBuffer#isFull).
 """
 
@@ -37,8 +38,11 @@ def partition_ids(key_cols, n_partitions: int) -> jnp.ndarray:
 def bucketize(cols, valid, pid, n_partitions: int, bucket: int):
     """Pack rows into a [n_partitions * bucket] send layout.
 
-    Returns (packed_cols, packed_valid, overflow): row r of partition p lands at
+    Returns (packed_cols, packed_valid, counts): row r of partition p lands at
     p * bucket + rank_of_r_within_p; slots beyond a partition's row count are invalid.
+    ``counts`` [n_partitions] is the rows BOUND for each partition, before the bucket
+    cuts them: a count over ``bucket`` is an overflow (rows dropped), their largest is
+    the bucket the batch needed and their sum the rows it routed.
 
     Round-13 backend split: with `use_pallas()` the partitioned pack runs as
     ``n_partitions`` sequential masked compactions (ops/arrays.compact_rows —
@@ -64,13 +68,12 @@ def bucketize(cols, valid, pid, n_partitions: int, bucket: int):
         counts = jnp.stack(counts)
         out_valid = (jnp.arange(bucket)[None, :]
                      < jnp.minimum(counts, bucket)[:, None]).reshape(-1)
-        return packed, out_valid, jnp.any(counts > bucket)
+        return packed, out_valid, counts
     sort_key = jnp.where(valid, pid, n_partitions)  # invalid rows sort to the end
     order = jnp.argsort(sort_key, stable=True)
     sorted_pid = sort_key[order]
     starts = jnp.searchsorted(sorted_pid, jnp.arange(n_partitions + 1))
     counts = starts[1:] - starts[:-1]
-    overflow = jnp.any(counts > bucket)
     # slot p * bucket + r takes the r-th row of partition p in sorted order.
     # Written as a GATHER per column over the send layout, not a scatter of
     # the rows into it: on the TPU a scattered lane costs 74-290 ns and a
@@ -82,7 +85,7 @@ def bucketize(cols, valid, pid, n_partitions: int, bucket: int):
     src = order[jnp.clip(starts[p] + r, 0, n - 1)]
     packed = tuple(jnp.where(out_valid, c[src], jnp.zeros((), c.dtype))
                    for c in cols)
-    return packed, out_valid, overflow
+    return packed, out_valid, counts
 
 
 def exchange_all_to_all(packed_cols, packed_valid, axis_name: str, n_partitions: int):

@@ -805,7 +805,12 @@ class CoordinatorServer:
                     ("mesh_fragment_hits", "Kept mesh fragments served to a "
                      "replayed plan."),
                     ("mesh_fragment_compiles", "Mesh fragments compiled "
-                     "(first sight of a plan node at a ladder rung).")):
+                     "(first sight of a plan node at a ladder rung or a "
+                     "learned probe bucket)."),
+                    ("probe_exchange_rows", "Rows routed by the probe "
+                     "exchanges inside mesh fragments."),
+                    ("probe_exchange_lanes", "Lanes the receive tensors of "
+                     "those probe exchanges held.")):
                 lines += [f"# HELP trino_tpu_{field}_total {what}",
                           f"# TYPE trino_tpu_{field}_total counter",
                           f"trino_tpu_{field}_total {getattr(ct, field, 0)}"]

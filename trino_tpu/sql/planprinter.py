@@ -115,11 +115,16 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
         fc = getattr(counters, "mesh_fragment_compiles", 0)
         if xr or fh or fc:
             # the mesh path (PR 32): what its all-to-all exchanges delivered,
-            # and whether the plan's fragments were kept ones
+            # and whether the plan's fragments were kept ones; PR 33: how full
+            # the probe exchanges inside its steps ran
+            pl = getattr(counters, "probe_exchange_lanes", 0)
+            probes = ("; probe exchanges: "
+                      f"{getattr(counters, 'probe_exchange_rows', 0)} rows in "
+                      f"{pl} receive lanes") if pl else ""
             lines.append(
                 f"Exchange: {xr} rows routed, fullest shard "
                 f"{getattr(counters, 'exchange_rows_max_shard', 0)}; "
-                f"mesh fragments: {fh} kept, {fc} compiled")
+                f"mesh fragments: {fh} kept, {fc} compiled{probes}")
         sp = getattr(counters, "spilled_bytes", 0)
         aq = getattr(counters, "admission_queued", 0)
         if sp or aq:
