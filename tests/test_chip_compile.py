@@ -239,3 +239,21 @@ def test_staged_direct_probe_compiles_for_v5e(slots, build, lanes, one_chip,
         rows=jnp.zeros(9, jnp.int32), occ=jnp.zeros(9, bool), build_columns=(),
         build_null_masks=(), dup_count=jnp.int32(0), lo=0)
     assert hj.stage_direct_table(small) is small  # under the gate: left as it is
+
+
+def test_tpcds_store_sales_generator_compiles_small_for_v5e(one_chip, no_persistent_cache):
+    """The generator of a TPC-DS scan, at SF10's split and with the split's first row
+    TRACED, as `TpcdsConnector.generate` runs it: one program a (table, length, column
+    set).  `ss_sold_date_sk` needs a civil month of every candidate day, and in emulated
+    64-bit arithmetic that alone was 18,372 lines of HLO and 204 s of the TPU compiler
+    (PR 36: q65's first run on the chip waited 228 s for it); in int32 it is a quarter of
+    the lines and seconds.  The guard is on the program's size, which is what the compiler
+    was slow over."""
+    from trino_tpu.connectors import tpcds
+
+    def generate(lo):
+        return tpcds._generate_cols("store_sales", 10.0, lo, PAGE_ROWS,
+                                    ("ss_sold_date_sk",), 28_800_000)
+
+    text = _compile(generate, _s(one_chip, (), jnp.int64))
+    assert text.count("\n") < 9_000, text.count("\n")

@@ -290,13 +290,37 @@ def test_push_filter_through_join_splits_sides():
 
 
 def test_push_filter_through_outer_join_keeps_build_conjunct():
-    pred = ir.Call("and", (_pred(1, "gt", 5), _pred(3, "lt", 9)), BOOLEAN)
+    # (a conjunct that a NULL-extended row can pass: IS NULL rejects no NULL)
+    keeps_nulls = ir.Call("is_null", (ir.FieldRef(3, BIGINT),), BOOLEAN)
+    pred = ir.Call("and", (_pred(1, "gt", 5), keeps_nulls), BOOLEAN)
     out = _opt(P.Filter(_join("left"), pred))
     join = _find(out, P.Join)[0]
+    assert join.kind == "left"
     assert _find(join.left, P.Filter), "probe conjunct pushes"
     assert not _find(join.right, P.Filter), \
         "NULL-extended build conjunct must NOT push below a left join"
     assert isinstance(out, P.Filter), "build conjunct stays above"
+
+
+def test_outer_join_under_a_null_rejecting_build_conjunct_becomes_inner():
+    """PR 36 (TPC-DS q93): a comparison on a build-side column is not true of a
+    NULL-extended row, so the LEFT join is an INNER join, and then the build conjunct
+    pushes below it like any other."""
+    pred = ir.Call("and", (_pred(1, "gt", 5), _pred(3, "lt", 9)), BOOLEAN)
+    out = _opt(P.Filter(_join("left"), pred))
+    join = _find(out, P.Join)[0]
+    assert join.kind == "inner" and not isinstance(out, P.Filter)
+    assert _find(join.left, P.Filter) and _find(join.right, P.Filter)
+
+
+def test_outer_join_under_an_inner_join_keyed_on_its_build_side_becomes_inner():
+    lj = _join("left")
+    r2 = P.TableScan("cat", "v", ("e",), Schema((Field("e", BIGINT),)))
+    schema = Schema(lj.schema.fields + (Field("e", BIGINT),))
+    on_build = _opt(P.Join("inner", lj, r2, (2,), (0,), schema))  # keyed on r0
+    assert [j.kind for j in _find(on_build, P.Join)] == ["inner", "inner"]
+    on_probe = _opt(P.Join("inner", lj, r2, (1,), (0,), schema))  # keyed on l1
+    assert sorted(j.kind for j in _find(on_probe, P.Join)) == ["inner", "left"]
 
 
 def test_push_filter_through_aggregate_keys_only():

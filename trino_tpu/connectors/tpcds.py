@@ -220,10 +220,22 @@ DICTS = {
 
 
 def _ymd(days):
-    """Civil (year, month, day, dow, doy) from days-since-epoch (device)."""
-    from ..sql.ir import _extract_ymd
-
-    return _extract_ymd(days)
+    """Civil (year, month, day) from days-since-epoch (device), in int32
+    arithmetic: Howard Hinnant's algorithm as sql/ir._extract_ymd has it, for
+    days after 0000-03-01, where no intermediate passes 2^31.  The int64 form
+    costs the TPU compiler 204 s for ``ss_sold_date_sk`` alone (nine emulated
+    64-bit floor divisions over a split whose first row is traced: 18,372 HLO
+    lines); this one compiles in seconds and gives the same days (PERF.md PR 36)."""
+    z = days.astype(jnp.int32) + 719468
+    era = z // 146097
+    doe = z - era * 146097
+    yoe = (doe - doe // 1460 + doe // 36524 - doe // 146096) // 365
+    doy = doe - (365 * yoe + yoe // 4 - yoe // 100)
+    mp = (5 * doy + 2) // 153
+    d = doy - (153 * mp + 2) // 5 + 1
+    m = jnp.where(mp < 10, mp + 3, mp - 9)
+    y = jnp.where(m <= 2, yoe + era * 400 + 1, yoe + era * 400)
+    return y.astype(jnp.int64), m.astype(jnp.int64), d.astype(jnp.int64)
 
 
 def _seasonal_date(seed: int, i):
@@ -447,9 +459,46 @@ def gen_promotion(sf, lo, length, n=0):
     }
 
 
+def _ticket(i, n_rows: int):
+    """``i // 12``, the ticket of sale row ``i`` (from 0).  64-bit integers
+    are emulated on the TPU and a division of them costs its compiler seconds
+    (6 s here, 15 on the chip's host, for this one): where the table's row
+    indexes fit 32 bits the division is made there, the same quotient."""
+    if n_rows < 1 << 30:  # (a split's masked tail runs past the last row)
+        return (i.astype(jnp.int32) // 12).astype(jnp.int64)
+    return i // 12
+
+
+def _ticket_item(i, n_items: int, n_rows: int):
+    """``ss_item_sk`` of sale row ``i`` (of ``n_rows``): the twelve lines of a ticket
+    (``i // 12``) take twelve DIFFERENT items, a random first one and a random
+    stride of at most a twelfth of the item table, so (``ss_item_sk``,
+    ``ss_ticket_number``) is the primary key the specification says it is
+    (dsdgen permutes the items of a ticket).  Below twelve items they
+    repeat."""
+    t = _ticket(i, n_rows)
+    item = _uniform(608, t, 0, n_items - 1) + (i - 12 * t) * _uniform(
+        615, t, 1, max(n_items // 12, 1))  # (under 2 x n_items)
+    return jnp.where(item >= n_items, item - n_items, item) + 1
+
+
+def _returned_sale(sf, j):
+    """The ``store_sales`` row that ``store_returns`` row ``j`` returns: one
+    of every ten consecutive sales, so no sale is returned twice and about a
+    tenth of the sales have a return (dsdgen returns a tenth of its sale
+    lines, each return copying its sale's item, ticket, customer and store).
+    The returned QUANTITY stays independent of the sold one (1..20 of 1..100,
+    where dsdgen draws 1..ss_quantity): with dsdgen's rule a twentieth of the
+    returns bring everything back, and TPC-DS q93's first hundred rows at
+    scale 10 are all 0.00, an answer no precision can get wrong."""
+    return jnp.minimum(10 * j + _uniform(2812, j, 0, 9),
+                       _scaled_rows("store_sales", sf) - 1)
+
+
 def gen_store_sales(sf, lo, length, n=0):
     i = jnp.arange(length, dtype=jnp.int64) + lo
     fk = _fk_counts(sf)
+    n_sales = _scaled_rows("store_sales", sf)
     # _sale_measures(601) reproduces the historical seed layout bit-for-bit
     # (601 qty .. 605 coupon); its ship measure (seed 606) is unused here and
     # dead-code-eliminated by jit, so the seed overlap with ss_sold_date_sk
@@ -458,14 +507,14 @@ def gen_store_sales(sf, lo, length, n=0):
     return {
         "ss_sold_date_sk": JULIAN_BASE + _seasonal_date(606, i),
         "ss_sold_time_sk": _uniform(607, i, 28800, 75600),
-        "ss_item_sk": _uniform(608, i, 1, fk["item"]),
+        "ss_item_sk": _ticket_item(i, fk["item"], n_sales),
         "ss_customer_sk": _uniform(609, i, 1, fk["customer"]),
         "ss_cdemo_sk": _uniform(610, i, 1, CD_ROWS),
         "ss_hdemo_sk": _uniform(611, i, 1, fk["hd"]),
         "ss_addr_sk": _uniform(612, i, 1, fk["addr"]),
         "ss_store_sk": _uniform(613, i, 1, fk["store"]),
         "ss_promo_sk": _uniform(614, i, 1, fk["promo"]),
-        "ss_ticket_number": i // 12 + 1,
+        "ss_ticket_number": _ticket(i, n_sales) + 1,
         "ss_quantity": m["quantity"],
         "ss_wholesale_cost": m["wholesale_cost"],
         "ss_list_price": m["list_price"],
@@ -1085,20 +1134,20 @@ def gen_web_sales(sf, lo, length, n=0):
 def gen_store_returns(sf, lo, length, n=0):
     i = jnp.arange(length, dtype=jnp.int64) + lo
     fk = _fk_counts(sf)
+    s = _returned_sale(sf, i)  # the sale this row returns
+    n_sales = _scaled_rows("store_sales", sf)
     r = _return_measures(2800, i)
     return {
         "sr_returned_date_sk": JULIAN_BASE + _uniform(2810, i, 0, N_DATES - 1),
         "sr_return_time_sk": _uniform(2811, i, 28800, 75600),
-        "sr_item_sk": _uniform(2812, i, 1, fk["item"]),
-        "sr_customer_sk": _uniform(2813, i, 1, fk["customer"]),
+        "sr_item_sk": _ticket_item(s, fk["item"], n_sales),
+        "sr_customer_sk": _uniform(609, s, 1, fk["customer"]),
         "sr_cdemo_sk": _uniform(2814, i, 1, CD_ROWS),
         "sr_hdemo_sk": _uniform(2815, i, 1, fk["hd"]),
         "sr_addr_sk": _uniform(2816, i, 1, fk["addr"]),
-        "sr_store_sk": _uniform(2817, i, 1, fk["store"]),
+        "sr_store_sk": _uniform(613, s, 1, fk["store"]),
         "sr_reason_sk": _uniform(2818, i, 1, fk["reason"]),
-        "sr_ticket_number": _uniform(2819, i, 1,
-                                     max(int(BASE_ROWS["store_sales"] * sf)
-                                         // 12, 1)),
+        "sr_ticket_number": _ticket(s, n_sales) + 1,
         "sr_return_quantity": r["quantity"],
         "sr_return_amt": r["amt"],
         "sr_return_tax": r["tax"],
@@ -1304,48 +1353,46 @@ class TpcdsConnector:
     def generate(self, split: TpcdsSplit, columns=None) -> Page:
         schema = SCHEMAS[split.table]
         names = tuple(columns) if columns is not None else schema.names
-        length = split.hi - split.lo
-        n = self.row_count(split.table)
-        cols, valid = _jit_generate(split.table, self.sf, split.lo, length,
-                                    names, n if split.hi > n else 0)
+        cols, valid = _jit_generate(split.table, self.sf, split.lo,
+                                    split.hi - split.lo, names,
+                                    self.table_bound(split.table))
         out_schema = Schema(tuple(schema.field(c) for c in names))
         return Page(out_schema, cols, tuple(None for _ in cols), valid)
+
+    def table_bound(self, table: str) -> int:
+        """Mask bound of a table's split ranges: its row count (the name the
+        executor's ``rows_generated`` accounting asks a connector for)."""
+        return self.row_count(table)
 
     def generate_traced(self, table: str, lo, length: int, columns):
         """Trace-time generation with traced ``lo`` and static ``length`` (the
         in-shard_map sharded scan contract shared with
         TpchConnector.generate_traced): returns (cols tuple, valid)."""
-        all_cols = GENERATORS[table](self.sf, lo, length)
-        schema = SCHEMAS[table]
-        cols = tuple(all_cols[c].astype(schema.field(c).type.dtype)
-                     for c in columns)
-        valid = (jnp.arange(length, dtype=jnp.int64) + lo) < self.row_count(table)
-        return cols, valid
+        return _generate_cols(table, self.sf, lo, length, tuple(columns),
+                              self.table_bound(table))
 
 
-def _generate_cols(table: str, sf: float, lo: int, length: int, names: tuple,
-                   n: int = 0):
+def _generate_cols(table: str, sf: float, lo, length: int, names: tuple,
+                   n: int):
     all_cols = GENERATORS[table](sf, lo, length)
     schema = SCHEMAS[table]
-    out = []
-    for c in names:
-        v = all_cols[c]
-        out.append(v.astype(schema.field(c).type.dtype))
-    valid = None if n == 0 else (jnp.arange(length, dtype=jnp.int64) + lo) < n
-    return tuple(out), valid
+    out = tuple(all_cols[c].astype(schema.field(c).type.dtype) for c in names)
+    return out, (jnp.arange(length, dtype=jnp.int64) + lo) < n
 
 
 _GENERATE_PROGRAMS: dict = {}  # table -> its jitted generator
 
 
 def _jit_generate(table: str, sf: float, lo: int, length: int, names: tuple,
-                  n: int = 0):
-    """One program per table, named ``generate.<table>`` (see tpch)."""
+                  n: int):
+    """One program per table, named ``generate.<table>`` (see tpch).  ``lo``
+    is TRACED, as in tpch: every split of a scan runs the one program of its
+    (table, length, column set), and the bound ``n`` masks the tail."""
     run = _GENERATE_PROGRAMS.get(table)
     if run is None:
         from ..execution.tracing import site_program
 
         run = _GENERATE_PROGRAMS[table] = jax.jit(  # compile-ok: host-side table generation; dispatched from connector code outside the executor's _jit paths, one compile per (table, split shape)
             site_program(partial(_generate_cols, table), f"generate.{table}"),
-            static_argnums=(0, 1, 2, 3, 4))
+            static_argnums=(0, 2, 3, 4))
     return run(sf, lo, length, names, n)

@@ -629,6 +629,7 @@ def _partial_once(node, stream, key_types, acc_specs, step, splits,
         for split in splits:
             page = si.conn.generate(split, list(si.scan_columns))
             state = step(state, page, stream.aux)
+            tracing.record_groupby_insert(page.capacity)
             if tick is not None:
                 tick()  # split-boundary preemption point (fair scheduler)
         if not bool(state.overflow):

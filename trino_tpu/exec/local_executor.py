@@ -1173,9 +1173,10 @@ class LocalExecutor:
         return page, stream.dicts
 
     # -- page compaction at pipeline boundaries ------------------------------
-    def _compacted_stream(self, up: _Stream) -> Optional[_Stream]:
+    def _compacted_stream(self, up: _Stream, hashed: bool) -> Optional[_Stream]:
         """The compaction boundary INSIDE a split join (_join_with_build): a
-        selective probe leaves most lanes invalid, and the fixed-shape fusion
+        selective probe (``hashed``: through the open-addressing loop of a
+        ``JoinTable``) leaves most lanes invalid, and the fixed-shape fusion
         model would drag every dead lane through the join's build-column
         gathers and every later probe and insert.  ``up`` is the join's match
         step (the upstream chain, then only what decides ``matched``).  Per
@@ -1239,6 +1240,7 @@ class LocalExecutor:
                         bucket = max(n >> sh, 1)
                         break
                 tracing.record_join_probe(n, bucket)
+                tracing.record_probe_lanes(n, hashed)
                 if bucket >= n:
                     yield Page(up.schema, cols, nulls, valid)
                     continue
@@ -1252,8 +1254,10 @@ class LocalExecutor:
                 yield Page(up.schema, ccols, cnulls, cvalid)
 
         # (no boundary sits under this one: only a chain that is not yet
-        # ``compacted`` gets one, so ``up.scan_info.over`` is None)
-        si = dataclasses.replace(si, over=lambda raw: partial(pages, raw))
+        # ``compacted`` gets one; what does sit under it is a fused join's
+        # count of its pages, _probed_pages)
+        si = dataclasses.replace(
+            si, over=lambda raw, below=si.pages_over: partial(pages, below(raw)))
         return _Stream(up.schema, up.dicts, pages,
                        lambda c, n, v, aux: (c, n, v), si,
                        clustered_by=up.clustered_by, compacted=True)
@@ -2062,6 +2066,11 @@ class LocalExecutor:
         # memory gate: group-by state is device-resident; if it cannot fit the
         # pool, go to partitioned passes (the HBM spill analog).  Reservation is
         # re-checked on every capacity growth.
+        # a replay of a cached plan starts at the capacity its last run ended
+        # with: a regrow is paid once a plan, not once a run
+        proven = self._agg_cache.get(("capacity", id(node)))
+        if proven is not None:
+            capacity = max(capacity, proven[1])
         capacity = ceil_pow2(capacity)  # groupby_init allocates the rounded
         # size; reserving the raw request would under-account by up to 2x
         if cfg is not None and not self.memory_pool.try_reserve(
@@ -2106,12 +2115,16 @@ class LocalExecutor:
                 )
                 state = self._run_hash_inserts(node, stream, key_types, acc_exprs,
                                                acc_kinds, state, pages_once,
-                                               state_bytes, resv)
+                                               state_bytes, resv,
+                                               proven is not None)
                 # growth happens INSIDE the insert loop (snapshot + rehash + chunk
                 # replay); a still-set overflow means the capacity/memory ceiling:
                 # fall back to partitioned passes (the HBM analog of the
                 # reference's SpillableHashAggregationBuilder)
                 if not bool(state.overflow):
+                    if self._agg_cacheable(node):
+                        self._agg_cache[("capacity", id(node))] = \
+                            (node, state.capacity)
                     return self._finalize_groups(node, stream, state)
             tracing.record_groupby(regrows=1)
             return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
@@ -2120,14 +2133,20 @@ class LocalExecutor:
             self.memory_pool.free(resv["bytes"], "group-by")
 
     def _run_hash_inserts(self, node, stream, key_types, acc_exprs, acc_kinds,
-                          state, pages_iter, state_bytes, resv):
+                          state, pages_iter, state_bytes, resv, proven=False):
         """Insert a page stream into hash-mode group-by state, compacting live
         rows first when pages are sparse.  TPU scatters cost by page WIDTH (sink
         writes included), so a 5%-selective filter over a 4M-row page pays 20x
         the scatter it needs — compact with a cheap gather, then scatter at the
         live-row bucket (reference analog: SelectedPositions feeding the
         aggregator, operator/project/SelectedPositions.java).  Live-row counts
-        sync to the host in CHUNKS: every sync blocks the host on the device."""
+        sync to the host in CHUNKS: every sync blocks the host on the device.
+        Until the plan has ``proven`` a capacity (a run that ended without the
+        ceiling), the overflow flag is read after every staged page and not
+        only after the chunk: pages inserted into a table that has already
+        overflowed run the probe loop to MAX_PROBES on every lane (TPC-DS q65
+        at SF10: 78 of a group-by's 102 s, PERF.md PR 36) for a state the
+        regrow throws away."""
         cacheable = self._agg_cacheable(node)
         arts = self._agg_cache.get(("hashpage", id(node))) if cacheable else None
         if arts is None:
@@ -2170,14 +2189,18 @@ class LocalExecutor:
         staged: list = []
 
         def insert_chunk(state, counts):
-            for (keys, knulls, inputs, valid, _), n in zip(staged, counts):
+            for k, ((keys, knulls, inputs, valid, _), n) in enumerate(
+                    zip(staged, counts)):
                 if n == 0:
                     continue
+                if k and not proven and bool(state.overflow):
+                    break  # the regrow replays the chunk: spare it the rest
                 width = valid.shape[0]
                 bucket = max(1 << max(n - 1, 1).bit_length(), 1024)
                 if bucket * 2 >= width:
                     # dense page: compaction would not shrink it meaningfully
                     state = insert_masked(state, keys, knulls, inputs, valid)
+                    tracing.record_groupby_insert(width)
                     continue
                 cols_list = list(keys) + [v for v, _ in inputs if v is not None]
                 nulls_list = list(knulls) + [nu for v, nu in inputs if v is not None]
@@ -2194,6 +2217,7 @@ class LocalExecutor:
                         cinputs.append((rest_v.pop(0), rest_n.pop(0)))
                 state = insert_compact(state, ccols[:nk], cnulls[:nk],
                                        tuple(cinputs), jnp.int32(n))
+                tracing.record_groupby_insert(bucket)
             return state
 
         def drain(state):
@@ -2219,6 +2243,8 @@ class LocalExecutor:
                     return state, True  # ceiling: caller falls back to partitioned
                 resv["bytes"] += delta
                 state = hashagg.rehash(start_state, grown, tuple(acc_kinds))
+                # (the rehash re-inserts every slot of the table it leaves)
+                tracing.record_groupby_insert(start_state.capacity)
 
         for group, live in _coalesced_batches(pages_iter,
                                                self._batch(stream)):
@@ -2358,6 +2384,7 @@ class LocalExecutor:
                             pstep(group[0], stream.aux) if live is None \
                             else bpstep(tuple(group), live, stream.aux)
                         state = mstep(state, kcols, knulls, accs, new)
+                        tracing.record_groupby_insert(new.shape[0])
                     if not bool(state.overflow):
                         return self._finalize_groups(node, stream, state)
                 # merge-state overflow: grow and re-stream (rare — capacity is
@@ -2525,6 +2552,7 @@ class LocalExecutor:
                     src = _prefetched_pages(src, to_device=True, owner=self)
                 for page in src():
                     state = insert(state, page)
+                    tracing.record_groupby_insert(page.capacity)
                 if not bool(state.overflow):
                     break
                 if capacity >= MAX_GROUP_CAPACITY:
@@ -2897,16 +2925,26 @@ class LocalExecutor:
             span = cached["span"]
             table = cached["table"]
         else:
-            build_has_null, build_rows = _build_key_stats(build_page,
-                                                          node.right_keys)
-            build_nonempty = build_rows > 0
-            tracing.record_join_build(build_rows)
-            span = self._direct_join_span(build_page, node.right_keys,
-                                          build_key_types)
-            table = None
-            if node.filter is None and build_page.capacity > 0:
-                table = self._build_join_table(build_page, node.right_keys,
-                                               build_key_types, span)
+            with tracing.maybe_span("join.build") as sp:
+                build_has_null, build_rows = _build_key_stats(
+                    build_page, node.right_keys)
+                build_nonempty = build_rows > 0
+                span = self._direct_join_span(build_page, node.right_keys,
+                                              build_key_types)
+                table = None
+                if node.filter is None and build_page.capacity > 0:
+                    table = self._build_join_table(
+                        build_page, node.right_keys, build_key_types, span)
+                # a unique-key table, or the attempt at one that met
+                # duplicate keys and was dropped (``dups``: the table is then
+                # _compile_multi_join's, under a span of its own)
+                hashed = isinstance(table, JoinTable)
+                slots = table.capacity if hashed else span[1] if span else 0
+                tracing.record_join_build(build_rows, slots if hashed else 0)
+                sp.attributes.update(kind="direct" if span else "hash",
+                                     rows=build_rows, slots=slots)
+                if table is None:
+                    sp.attributes["dups"] = True
             if cache_key is not None:
                 # store-on-failure hardening: a failed admission (injected
                 # fault, pool error) must not fail a join whose build already
@@ -2986,7 +3024,8 @@ class LocalExecutor:
             mdicts = probe_stream.dicts if semi else probe_stream.dicts + (None,)
             packed = self._compacted_stream(_Stream(
                 mschema, mdicts, probe_stream.pages, match_step,
-                probe_stream.scan_info, aux=(probe_stream.aux, table)))
+                probe_stream.scan_info, aux=(probe_stream.aux, table)),
+                isinstance(table, JoinTable))
             if packed is not None:
                 si = dataclasses.replace(si, over=packed.scan_info.over)
                 if semi:
@@ -3025,7 +3064,9 @@ class LocalExecutor:
             out_nulls = tuple(nulls) + bnulls
             return out_cols, out_nulls, valid
 
-        return _Stream(node.schema, dicts, probe_stream.pages, transform, si,
+        pages, si = _probed_pages(probe_stream.pages, si,
+                                  isinstance(table, JoinTable))
+        return _Stream(node.schema, dicts, pages, transform, si,
                        aux=(probe_stream.aux, table), compacted=decided)
 
     def _compile_multi_join(self, node: P.Join, build_page, build_dicts, probe_stream,
@@ -3043,13 +3084,17 @@ class LocalExecutor:
             cols = tuple(jnp.zeros((1,), f.type.dtype) for f in node.right.schema.fields)
             build_page = Page(node.right.schema, cols, tuple(None for _ in cols),
                               jnp.zeros((1,), bool))
-        mt = None
-        if span is not None:
-            mt = _jit(direct_multi_build, static_argnums=(0, 1, 3))(
-                span[0], span[1], build_page, node.right_keys[0])
-        if mt is None:
-            capacity = max(1 << max(build_page.capacity - 1, 1).bit_length(), 16) * 4
-            mt = multi_build(capacity, build_page, node.right_keys, build_key_types)
+        with tracing.maybe_span("join.build", kind="multi",
+                                rows=build_page.capacity) as sp:
+            if span is not None:
+                mt = _jit(direct_multi_build, static_argnums=(0, 1, 3))(
+                    span[0], span[1], build_page, node.right_keys[0])
+                sp.attributes["slots"] = span[1]
+            else:
+                capacity = max(1 << max(build_page.capacity - 1, 1).bit_length(), 16) * 4
+                mt = multi_build(capacity, build_page, node.right_keys, build_key_types)
+                sp.attributes["slots"] = mt.table.shape[0] - 1
+                tracing.record_join_build(0, mt.table.shape[0] - 1)
 
         @_jit
         def count_step(page, mt, up_aux, up=probe_stream, node=node):
@@ -3111,6 +3156,8 @@ class LocalExecutor:
             for page in probe_stream.pages():
                 cols, nulls, valid, slot, matched, cnt, out_cnt, incl = \
                     count_step(page, mt, probe_stream.aux)
+                tracing.record_probe_lanes(
+                    page.capacity, not isinstance(mt, DirectMultiJoinTable))
                 if semi and node.filter is None:
                     if node.kind == "mark":
                         yield Page(node.schema,
@@ -4372,6 +4419,30 @@ def _group_state_bytes(key_types, acc_specs):
     key_w = sum(np.dtype(t.dtype).itemsize + 1 for t in key_types)
     acc_w = sum(np.dtype(dt).itemsize for dt, _ in acc_specs)
     return lambda cap: (cap + 1) * (8 + key_w + acc_w)
+
+
+def _probed_pages(pages, si, hashed: bool):
+    """The page source of a join that runs FUSED into whatever consumes its
+    stream (no dispatch of its own to count at): each page it hands on is
+    recorded, at its static width, under the loop the fused probe puts it
+    through.  Returns the source and the scan provenance whose rebuilt sources
+    (split pruning above the join) count the same way."""
+    def counted(source):
+        def probed():
+            it = source()
+            try:
+                for page in it:
+                    tracing.record_probe_lanes(page.capacity, hashed)
+                    yield page
+            finally:
+                if hasattr(it, "close"):
+                    it.close()
+        return probed
+
+    if si is not None:
+        si = dataclasses.replace(
+            si, over=lambda raw, below=si.pages_over: counted(below(raw)))
+    return counted(pages), si
 
 
 def _split_base_rows(conn, table: str, splits) -> list:

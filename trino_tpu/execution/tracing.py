@@ -268,6 +268,19 @@ class QueryCounters:
     # width for a batch that stayed dense)
     join_match_lanes: int = 0
     join_gather_lanes: int = 0
+    # PR 36: which loops the lanes went through.  Static lanes of the pages
+    # that entered a join's match or probe step over a hashed table
+    # (ops/hashjoin.probe / probe_slots: the open-addressing loop) and over a
+    # direct-indexed one (one gather), split joins and fused ones alike, so
+    # over the split joins alone their sum is join_match_lanes; static lanes
+    # of the dispatches that ran ops/hashagg.groupby_insert (every mode: hash,
+    # sorted merge, Grace partitions, and a regrow's rehash); slots of each
+    # hashed join table when it was built.  Host ints taken where the
+    # executor dispatches (record_probe_lanes, record_groupby_insert)
+    join_hash_probe_lanes: int = 0
+    join_direct_probe_lanes: int = 0
+    join_hash_table_slots: int = 0
+    groupby_insert_lanes: int = 0
     # PR 32: the mesh path.  Rows the statement's all-to-all exchanges
     # delivered and the fullest worker's share of them, summed over its
     # exchanges from the receive cursors and occupancy counts the exchange
@@ -348,6 +361,8 @@ class QueryCounters:
                    "groupby_slots", "groupby_state_bytes", "groupby_regrows",
                    "groupby_partitioned_passes", "join_build_rows",
                    "rows_generated", "join_match_lanes", "join_gather_lanes",
+                   "join_hash_probe_lanes", "join_direct_probe_lanes",
+                   "join_hash_table_slots", "groupby_insert_lanes",
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
                    "probe_exchange_rows", "probe_exchange_lanes")
@@ -610,10 +625,30 @@ def record_groupby(slots: int = 0, state_bytes: int = 0, regrows: int = 0,
         c.groupby_partitioned_passes += partitioned_passes
 
 
-def record_join_build(rows: int) -> None:
+def record_join_build(rows: int, hash_slots: int = 0) -> None:
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.join_build_rows += rows
+        c.join_hash_table_slots += hash_slots
+
+
+def record_probe_lanes(lanes: int, hashed: bool) -> None:
+    """Static lanes of one dispatch of a join's match or probe step, under the
+    loop they went through: a hashed table's open addressing or a direct
+    table's one gather (a host int the dispatch site already holds)."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        if hashed:
+            c.join_hash_probe_lanes += lanes
+        else:
+            c.join_direct_probe_lanes += lanes
+
+
+def record_groupby_insert(lanes: int) -> None:
+    """Static lanes of one dispatch that runs ``hashagg.groupby_insert``."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.groupby_insert_lanes += lanes
 
 
 def record_rows_generated(rows: int) -> None:

@@ -797,6 +797,15 @@ class CoordinatorServer:
                      "of a split join."),
                     ("join_gather_lanes", "Lanes at which split joins then "
                      "gathered their build columns."),
+                    ("join_hash_probe_lanes", "Lanes that joins probed "
+                     "through the open-addressing loop of a hashed table."),
+                    ("join_direct_probe_lanes", "Lanes that joins probed "
+                     "through the one gather of a direct-indexed table."),
+                    ("join_hash_table_slots", "Slots of the hashed join "
+                     "tables that were built."),
+                    ("groupby_insert_lanes", "Lanes that entered the "
+                     "group-by's hash insert loop (a regrow's rehash "
+                     "included)."),
                     ("exchange_rows", "Rows the mesh executor's all-to-all "
                      "exchanges delivered (receive cursors and merged "
                      "group counts)."),

@@ -89,12 +89,17 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"{getattr(counters, 'compact_lanes_in', 0)} lanes in, "
                 f"{getattr(counters, 'compact_lanes_out', 0)} lanes out")
         jm = getattr(counters, "join_match_lanes", 0)
-        if jm:
+        jh = getattr(counters, "join_hash_probe_lanes", 0)
+        jd = getattr(counters, "join_direct_probe_lanes", 0)
+        if jm or jh or jd:
             # split joins (PR 28): lanes that entered a match step, and lanes
-            # at which the build columns were then gathered
+            # at which the build columns were then gathered; every join
+            # (PR 36): lanes probed through the open-addressing loop of a
+            # hashed table, and through the one gather of a direct one
             lines.append(
                 f"Join probe: {jm} lanes matched, "
-                f"{getattr(counters, 'join_gather_lanes', 0)} lanes gathered")
+                f"{getattr(counters, 'join_gather_lanes', 0)} lanes gathered; "
+                f"{jh} lanes hashed, {jd} lanes direct")
         gs = getattr(counters, "groupby_slots", 0)
         if gs:
             # how the statement's group-bys were sized (PR 27): slots of the
@@ -105,7 +110,8 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"{getattr(counters, 'groupby_state_bytes', 0)} state bytes, "
                 f"{getattr(counters, 'groupby_regrows', 0)} regrows, "
                 f"{getattr(counters, 'groupby_partitioned_passes', 0)} "
-                "partitioned passes")
+                "partitioned passes, "
+                f"{getattr(counters, 'groupby_insert_lanes', 0)} lanes inserted")
         rg = getattr(counters, "rows_generated", 0)
         jb = getattr(counters, "join_build_rows", 0)
         if rg or jb:
