@@ -15,6 +15,8 @@ this process: one process at a time may load libtpu, and under xdist every
 worker imports every test file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -193,10 +195,39 @@ def test_exchange_fragment_compiles_over_four_chips(topo, as_on_tpu):
     assert "tpu_custom_call" in text
 
 
+def test_the_narrowed_hash_lookup_compiles_over_four_chips(topo, no_persistent_cache):
+    """`ops/hashjoin._find_slots` as the mesh's q3 runs it (PR 37): a per-worker table of
+    2^21 slots (above `PALLAS_TABLE_MAX`: the XLA lookup), 2^20 received lanes a chip,
+    inside shard_map with check_vma ON, once with a CONSTANT key (unvarying, against the
+    per-worker table).  The packed levels are there: a sort a level, a loop a width."""
+    from trino_tpu.ops import hashjoin as hj
+    from trino_tpu.parallel.mesh import WORKER_AXIS
+    from trino_tpu.types import BIGINT
+
+    W, slots, lanes = 4, 1 << 21, 1 << 20
+    assert len(hj.probe_widths(lanes)) == 1 + len(hj.NARROW_SHIFTS)
+    mesh = Mesh(np.array(topo.devices).reshape(W), (WORKER_AXIS,))
+
+    def frag(tables, keys, valid):
+        slot, matched = hj.probe_slots(tables[0], (keys[0],), (BIGINT,), valid[0])
+        cslot, cmatched = hj.probe_slots(
+            tables[0], (jnp.ones((lanes,), jnp.int64),), (BIGINT,), valid[0])
+        return slot[None], matched[None], cslot[None], cmatched[None]
+
+    f = jax.shard_map(frag, mesh=mesh, in_specs=(PS(WORKER_AXIS),) * 3,
+                      out_specs=(PS(WORKER_AXIS),) * 4)
+    sharded = NamedSharding(mesh, PS(WORKER_AXIS))
+    text = _compile(f, jax.ShapeDtypeStruct((W, slots + 1), jnp.int64, sharding=sharded),
+                    jax.ShapeDtypeStruct((W, lanes), jnp.int64, sharding=sharded),
+                    jax.ShapeDtypeStruct((W, lanes), jnp.bool_, sharding=sharded))
+    levels = 1 + len(hj.NARROW_SHIFTS)
+    assert len(re.findall(r" while\(", text)) >= 2 * levels
+    assert len(re.findall(r" sort\(", text)) >= 2 * (levels - 1)
+
+
 def _gathers_from_arguments(text):
     """Names of the entry parameters that a gather of the compiled program reads
     directly (not through a copy or a fusion of the program's own)."""
-    import re
 
     entry = text[text.index("\nENTRY"):]
     params = set(re.findall(r"%([\w.\-]+) = \S+ parameter\(", entry))

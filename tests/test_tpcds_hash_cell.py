@@ -371,6 +371,83 @@ def test_explain_analyze_and_metrics_carry_the_new_series(tpch_sf001):
         assert after.as_dict()[field] == getattr(after, field)
 
 
+# -- the rounds of the hashed lookup (PR 37) ------------------------------------------------
+def _replayed(engine, sql, catalog):
+    """The counters of the first execution that compiles nothing."""
+    for _ in range(4):  # cold, the advisor's re-plan if it makes one, the replay
+        engine.execute_sql(sql, engine.create_session(catalog))
+        if not engine.last_query_counters.compiles:
+            return engine.last_query_counters
+    raise AssertionError("no run without compiles in 4")
+
+
+def _q93_replayed(conn):
+    e = Engine()  # (its own: a kept plan keeps its compiled match step)
+    e.register_catalog("tpcds", conn)
+    return _replayed(e, DS_Q93.render(DS_Q93.VALIDATION)[0], "tpcds")
+
+
+@pytest.mark.parametrize("case", ["q93-floor-as-shipped", "q93-floor-patched-down",
+                                  "direct-tables"])
+def test_a_split_join_records_the_lanes_its_probe_rounds_gathered_for(
+        case, ds, tpch_sf001, monkeypatch):
+    from trino_tpu.ops import hashjoin
+
+    if case == "direct-tables":
+        e = Engine()
+        e.register_catalog("tpch", tpch_sf001)
+        w = _replayed(e, Q3.render(Q3.VALIDATION)[0], "tpch")
+        assert w.join_direct_probe_lanes > 0 and w.join_hash_probe_lanes == 0
+        assert w.join_hash_probe_round_lanes == 0
+        return
+    _, conn, _ = ds
+    lanes = sum(s.hi - s.lo for s in conn.splits("store_sales"))
+    assert hashjoin.probe_widths(lanes) == (lanes,)  # a tier-1 page is under the floor
+    w = _q93_replayed(conn)
+    assert w.join_hash_probe_lanes == lanes
+    # one level: whole rounds of every lane, at least one, at most MAX_PROBES
+    assert w.join_hash_probe_round_lanes % lanes == 0
+    assert lanes <= w.join_hash_probe_round_lanes <= hashjoin.MAX_PROBES * lanes
+    if case == "q93-floor-patched-down":
+        monkeypatch.setattr(hashjoin, "NARROW_MIN_LANES", 1024)
+        assert len(hashjoin.probe_widths(lanes)) == 1 + len(hashjoin.NARROW_SHIFTS)
+        narrowed = _q93_replayed(conn)
+        # the same lanes and the same rounds, the later ones at a quarter and less
+        assert narrowed.join_hash_probe_lanes == lanes
+        assert lanes <= narrowed.join_hash_probe_round_lanes \
+            < w.join_hash_probe_round_lanes
+
+
+def test_the_probe_round_lanes_reach_explain_analyze_and_the_metrics(tpch_sf001):
+    from test_profiling import _parse_prometheus
+    from trino_tpu.server.server import CoordinatorServer
+
+    e = Engine()
+    e.register_catalog("tpch", tpch_sf001)
+    r = e.execute_sql("explain analyze " + HASHED, e.create_session("tpch"))
+    text = "\n".join(str(row[0]) for row in r.rows())
+    c = e.last_query_counters
+    m = re.search(r"(\d+) lanes hashed, \d+ lanes direct; (\d+) lanes in probe rounds", text)
+    assert m, text
+    assert tuple(map(int, m.groups())) == (
+        c.join_hash_probe_lanes, c.join_hash_probe_round_lanes)
+    assert c.join_hash_probe_round_lanes >= c.join_hash_probe_lanes > 0
+    total = e.counters_total
+    srv = CoordinatorServer(e, port=0)
+    srv.start()
+    try:
+        parsed = _parse_prometheus(urllib.request.urlopen(
+            srv.url + "/v1/metrics", timeout=10).read().decode())
+    finally:
+        srv.stop()
+    name = "trino_tpu_join_hash_probe_round_lanes_total"
+    assert parsed["types"][name] == "counter"
+    assert parsed["samples"][name][0][1] == total.join_hash_probe_round_lanes \
+        >= c.join_hash_probe_round_lanes
+    assert total.as_dict()["join_hash_probe_round_lanes"] \
+        == total.join_hash_probe_round_lanes
+
+
 # -- ties under an ORDER BY that is not total ----------------------------------------------
 def _tie_case():
     full = pd.DataFrame({"name": ["able"] * 3 + ["anti"] * 4 + ["bar"] * 2,

@@ -36,7 +36,8 @@ from ..ops.hashjoin import (DIRECT_JOIN_RANGE_MAX, DirectJoinTable,
                             build_insert, build_table_init, direct_build,
                             direct_match, direct_multi_build, direct_probe,
                             direct_probe_slots,
-                            expand_counts, multi_build, probe, probe_slots,
+                            expand_counts, multi_build, probe, probe_counted,
+                            probe_slots, probe_widths,
                             stage_direct_table, GATHER_FIELDS, MATCH_FIELDS)
 from ..page import Field, Page, Schema
 from ..types import BIGINT, DOUBLE, BOOLEAN, INTEGER, DecimalType, Type
@@ -1181,7 +1182,8 @@ class LocalExecutor:
         gathers and every later probe and insert.  ``up`` is the join's match
         step (the upstream chain, then only what decides ``matched``).  Per
         batch: run it as its own program, read the surviving-row count (one
-        4-byte pull), and pack the live rows into the smallest quantized
+        small pull; a hashed table's lookup sends the rounds it ran along,
+        `tracing.record_probe_lanes`), and pack the live rows into the smallest quantized
         bucket (n/4, n/16, n/64) that holds them.  Buckets are pow2-quantized
         so the downstream pipeline compiles at most a handful of shape
         classes, and a batch that stays dense flows through untouched: the
@@ -1199,8 +1201,19 @@ class LocalExecutor:
 
         compact_jits: dict = {}
 
-        def counted(cols, nulls, valid):
-            return cols, nulls, valid, jnp.sum(valid, dtype=jnp.int32)
+        def counted(cols, nulls, valid, rounds=None):
+            # what the boundary pulls: the survivors' count, and behind it
+            # (one small array, one pull) the rounds a hashed table's lookup
+            # ran at each of its widths, the fourth value of the match step's
+            # transform; a direct table has none (None) and pulls its scalar
+            count = jnp.sum(valid, dtype=jnp.int32)
+            return cols, nulls, valid, (
+                count if rounds is None
+                else jnp.concatenate([count[None], rounds]))
+
+        def pulled(stat, site):
+            """[count, *rounds] of ``counted``'s fourth value, on the host."""
+            return np.atleast_1d(_host([stat], site=site)[0]).tolist()
 
         @partial(_jit, site="join.match")
         def match(page, aux, params, up=up):
@@ -1218,8 +1231,7 @@ class LocalExecutor:
             page = si.conn.generate(si.splits[0], list(si.scan_columns))
             if _page_batch_sig(page) is not None:
                 count = match(page, up.aux, _current_params())[3]
-                if int(_host([count], site="join.match.sample")[0]) \
-                        > page.capacity >> 2:
+                if pulled(count, "join.match.sample")[0] > page.capacity >> 2:
                     return None
 
         def pages(source=up.pages, up=up, self=self):
@@ -1227,20 +1239,21 @@ class LocalExecutor:
                 if live is None and _page_batch_sig(group[0]) is None:
                     # an exact wide-decimal (object) column runs eagerly, an
                     # empty page has nothing to pack
-                    yield Page(up.schema, *up.jitted()(group[0]))
+                    yield Page(up.schema, *up.jitted()(group[0])[:3])
                     continue
                 cols, nulls, valid, count = \
                     match(group[0], up.aux, _current_params()) if live is None \
                     else bmatch(tuple(group), live, up.aux, _current_params())
                 n = int(valid.shape[0])
-                count = int(_host([count], site="join.match.count")[0])
+                count, *rounds = pulled(count, "join.match.count")
                 bucket = n
                 for sh in (6, 4, 2):  # smallest sufficient bucket wins
                     if count <= (n >> sh):
                         bucket = max(n >> sh, 1)
                         break
                 tracing.record_join_probe(n, bucket)
-                tracing.record_probe_lanes(n, hashed)
+                tracing.record_probe_lanes(n, hashed, sum(
+                    r * w for r, w in zip(rounds, probe_widths(n))))
                 if bucket >= n:
                     yield Page(up.schema, cols, nulls, valid)
                     continue
@@ -3004,12 +3017,15 @@ class LocalExecutor:
                 if isinstance(table, DirectJoinTable):
                     carry, matched = direct_match(
                         stage_direct_table(table, MATCH_FIELDS), keys[0], valid)
+                    rounds = None
                 else:
-                    carry, matched = probe(table, keys, build_key_types, valid)
+                    carry, matched, rounds = probe_counted(
+                        table, keys, build_key_types, valid)
                 valid = valid & non_null_keys(matched, nulls)
                 if node.kind == "semi":
-                    return cols, nulls, valid
-                return tuple(cols) + (carry,), tuple(nulls) + (None,), valid
+                    return cols, nulls, valid, rounds
+                return (tuple(cols) + (carry,), tuple(nulls) + (None,), valid,
+                        rounds)
 
             def gather_step(cols, nulls, valid, table, node=node):
                 carry = cols[-1]

@@ -281,6 +281,16 @@ class QueryCounters:
     join_direct_probe_lanes: int = 0
     join_hash_table_slots: int = 0
     groupby_insert_lanes: int = 0
+    # PR 37: the lanes the hashed lookup GATHERED for: rounds x width, summed
+    # over the widths a batch's rounds ran at (ops/hashjoin.probe_widths: the
+    # whole batch, then what was still unfinished, packed).  The rounds are
+    # device scalars that ride a split join's survivor count
+    # (local_executor._compacted_stream: the one pull that is there), so it is
+    # recorded THERE only: a fused join's probe, the multi-match count step
+    # and the mesh fragments count their join_hash_probe_lanes and leave this
+    # 0, and so does the Pallas kernel (tables of 2^16 slots or fewer on the
+    # chip), which has no rounds
+    join_hash_probe_round_lanes: int = 0
     # PR 32: the mesh path.  Rows the statement's all-to-all exchanges
     # delivered and the fullest worker's share of them, summed over its
     # exchanges from the receive cursors and occupancy counts the exchange
@@ -363,6 +373,7 @@ class QueryCounters:
                    "rows_generated", "join_match_lanes", "join_gather_lanes",
                    "join_hash_probe_lanes", "join_direct_probe_lanes",
                    "join_hash_table_slots", "groupby_insert_lanes",
+                   "join_hash_probe_round_lanes",
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
                    "probe_exchange_rows", "probe_exchange_lanes")
@@ -632,14 +643,17 @@ def record_join_build(rows: int, hash_slots: int = 0) -> None:
         c.join_hash_table_slots += hash_slots
 
 
-def record_probe_lanes(lanes: int, hashed: bool) -> None:
+def record_probe_lanes(lanes: int, hashed: bool, round_lanes: int = 0) -> None:
     """Static lanes of one dispatch of a join's match or probe step, under the
     loop they went through: a hashed table's open addressing or a direct
-    table's one gather (a host int the dispatch site already holds)."""
+    table's one gather (a host int the dispatch site already holds).
+    ``round_lanes``: the lanes the hashed lookup's rounds gathered for, where
+    the site pulls its rounds (a split join's boundary)."""
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         if hashed:
             c.join_hash_probe_lanes += lanes
+            c.join_hash_probe_round_lanes += round_lanes
         else:
             c.join_direct_probe_lanes += lanes
 

@@ -25,9 +25,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from .arrays import gather_rows, live_indices
 from .hashing import EMPTY_KEY, ceil_pow2, pack_keys, probe_step, splitmix64
 
-__all__ = ["JoinTable", "build_table_init", "build_insert", "probe", "MAX_PROBES",
+__all__ = ["JoinTable", "build_table_init", "build_insert", "probe", "probe_counted",
+           "probe_widths", "MAX_PROBES",
            "MultiJoinTable", "multi_build", "probe_slots", "expand_counts",
            "DirectJoinTable", "direct_build", "direct_match", "direct_probe",
            "DirectMultiJoinTable",
@@ -104,23 +106,48 @@ def build_insert(jt: JoinTable, key_cols, key_types, valid) -> JoinTable:
     )
 
 
-def probe(jt: JoinTable, key_cols, key_types, valid):
-    """Gather-only probe: returns (build_row_ids[int32], matched[bool]) per probe row.
+# The widths the open-addressing lookup runs at (PR 37).  Its rounds gather the
+# table for every lane of the batch, finished or not, and a batch used to end
+# with its longest chain: over q93's 2^24-slot table at load 0.17 a batch of
+# 8.4 M lanes ran ten rounds (two 14.4-ns gathers a lane a round: 289 ns a
+# lane) where 1.2 gathers a lane were useful.  Now a level's loop runs only
+# while more lanes are unfinished than the next, narrower level holds; then
+# the unfinished lanes' keys are packed to that width (`arrays.live_indices`:
+# one int32 sort), go on from the same round there, and their answers return
+# by one scatter.  The probe sequence and the first hit of every lane are what
+# they were.  Constants, chosen on a v5e (PERF.md section 6, PR 37: q93's shape
+# 208 -> 53 ns a lane; one level at n >> 4 read 67, (3, 6) 69, (2, 4, 6) 59 with
+# the slower hand-back), not knobs.
+NARROW_SHIFTS = (2, 6)  # the narrower levels, as shifts of the batch's lanes
+# Below this many lanes the lookup is the one loop it always was.  On the chip
+# the levels win down to 1,024 lanes (0.9 for 1.2 ms; 5.0 for 15.1 ms at
+# 65,536), but each level is a sort for the TPU compiler (1.7 for 0.6 s at
+# 1,024 lanes, 9 for 0.7 s at 65,536) and under 2^16 lanes a call saves less
+# than 10 ms; on the CPU backend, where every tier-1 table lives, a sort costs
+# more than the gathers it saves
+NARROW_MIN_LANES = 1 << 16
 
-    Backend selection (round 13): small/medium tables route to the Pallas
-    tensor-program probe (`pallas_kernels.hash_probe` — same hash family,
-    same probe order, bit-identical outputs); the XLA while_loop below is the
-    fallback and the only path above `PALLAS_TABLE_MAX`.  The choice is
-    trace-time static (capacity is a shape), so compiled streams bake it in."""
-    from . import pallas_kernels as pk
 
-    packed, _ = pack_keys(key_cols, key_types)
-    C = jt.capacity
+def probe_widths(n: int) -> tuple:
+    """The lane widths at which a batch of ``n`` lanes runs its probe rounds,
+    widest first: what `probe_counted`'s ``rounds`` multiply."""
+    if n < NARROW_MIN_LANES:
+        return (n,)
+    return (n,) + tuple(n >> s for s in NARROW_SHIFTS if n >> s)
+
+
+def _find_slots(table, packed, valid, p=0, widths=None):
+    """(slot, matched, rounds): the gather-only open-addressing lookup of
+    ``packed`` in ``table`` ([C+1] packed keys, C a power of two), the ONE loop
+    of `probe` and `probe_slots`.  ``slot`` is 0 where nothing matched;
+    ``rounds`` int32[len(widths)] the rounds run at each of ``widths``
+    (`probe_widths` of the lanes), from round ``p`` on."""
+    C = table.shape[0] - 1
+    n = packed.shape[0]
+    if widths is None:
+        widths = probe_widths(n)
     h0 = splitmix64(packed)
     stp = probe_step(h0)
-    if pk.table_kernels_enabled(C) and packed.shape[0]:
-        return pk.hash_probe(jt.table[:C], jt.rows[:C], packed, h0, stp, valid,
-                             max_probes=MAX_PROBES)
     # derive the loop carries from BOTH operands' varying axes: under
     # shard_map, fresh constants are "unvarying" and the while_loop rejects a
     # carry the body mixes with per-worker data.  Keys alone are not enough —
@@ -128,31 +155,86 @@ def probe(jt: JoinTable, key_cols, key_types, valid):
     # unvarying array while the TABLE is still per-worker, so the zero must
     # also touch the table (caught by the r05 AddExchanges distribution flip).
     vzero = (h0 * 0).astype(jnp.int32) \
-        + (jt.table[jnp.zeros((), jnp.int32)] * 0).astype(jnp.int32) \
+        + (table[jnp.zeros((), jnp.int32)] * 0).astype(jnp.int32) \
         + (valid.astype(jnp.int32) * 0)
-    row_ids = vzero
+    slot = vzero
     matched = (valid & False) | (vzero != 0)
     done = ~valid | (vzero != 0)
+    leave = widths[1] if len(widths) > 1 else 0
 
+    # the loop counts its OWN rounds from a constant 0 and adds the rounds run
+    # before it (``p``, a device scalar it does not carry): a carried round
+    # that starts at a traced value cost the v5e compiler 84 s more at 8.4 M
+    # lanes than one that starts at 0 (PERF.md section 6, PR 37)
     def cond(carry):
-        p, row_ids, matched, done = carry
-        return (p < MAX_PROBES) & ~jnp.all(done)
+        q, slot, matched, done = carry
+        return (p + q < MAX_PROBES) & (jnp.sum(~done, dtype=jnp.int32) > leave)
 
     def body(carry):
-        p, slots, matched, done = carry
-        idx = ((h0 + p * stp) & (C - 1)).astype(jnp.int32)
-        cur = jt.table[idx]
+        q, slot, matched, done = carry
+        idx = ((h0 + (p + q) * stp) & (C - 1)).astype(jnp.int32)
+        cur = table[idx]
         hit = (cur == packed) & ~done
-        slots = jnp.where(hit, idx, slots)
+        slot = jnp.where(hit, idx, slot)
         matched = matched | hit
         done = done | hit | (cur == EMPTY_KEY)
-        return p + 1, slots, matched, done
+        return q + 1, slot, matched, done
 
-    # the loop carries the SLOT of a hit and gathers ``rows`` once after it:
-    # one gather over every lane a probe round, not two (14.5 ns a lane each)
-    _, slots, matched, done = jax.lax.while_loop(
-        cond, body, (jnp.zeros((), jnp.int32), row_ids, matched, done))
-    return jnp.where(matched, jt.rows[slots], row_ids), matched
+    q, slot, matched, done = jax.lax.while_loop(
+        cond, body, (jnp.zeros((), jnp.int32), slot, matched, done))
+    rounds = q[None]
+    if len(widths) == 1:
+        return slot, matched, rounds
+    # pack what is unfinished (at most ``leave`` lanes, unless the loop left at
+    # MAX_PROBES: then the narrower levels run no round and every lane stays
+    # unmatched, as it always did) and finish it there.  Only the keys move:
+    # an unfinished lane has hit nothing yet, and its hash is two multiplies
+    idx, count = live_indices(~done, leave)
+    packed_lane = jax.lax.iota(jnp.int32, leave)
+    nslot, nmatched, nrounds = _find_slots(
+        table, gather_rows(packed, idx), packed_lane < count, p + q, widths[1:])
+    # hand back: ONE scatter of ``leave`` answers to the lanes they came from
+    # (ascending and distinct; the filler lanes of ``idx`` are sent out of
+    # bounds, each to a place of its own, and dropped).  On a v5e it beat a
+    # gather of all n lanes through the prefix count of the unfinished at
+    # both widths tried (PERF.md section 6, PR 37)
+    found = jnp.full((n,), -1, jnp.int32).at[
+        jnp.where(packed_lane < count, idx, n + packed_lane)].set(
+        jnp.where(nmatched, nslot, -1), mode="drop",
+        indices_are_sorted=True, unique_indices=True)
+    hit = found >= 0
+    return (jnp.where(hit, found, slot), matched | hit,
+            jnp.concatenate([rounds, nrounds]))
+
+
+def probe_counted(jt: JoinTable, key_cols, key_types, valid):
+    """`probe`, and the rounds its lookup ran: (build_row_ids[int32],
+    matched[bool], rounds int32[len(probe_widths(lanes))]).  The rounds of the
+    Pallas kernel (it streams the table once, it has none) read 0."""
+    from . import pallas_kernels as pk
+
+    packed, _ = pack_keys(key_cols, key_types)
+    C = jt.capacity
+    if pk.table_kernels_enabled(C) and packed.shape[0]:
+        h0 = splitmix64(packed)
+        return pk.hash_probe(jt.table[:C], jt.rows[:C], packed, h0,
+                             probe_step(h0), valid, max_probes=MAX_PROBES) \
+            + (jnp.zeros((len(probe_widths(packed.shape[0])),), jnp.int32),)
+    # the lookup carries the SLOT of a hit and ``rows`` is gathered once after
+    # it: one gather over every lane a probe round, not two
+    slot, matched, rounds = _find_slots(jt.table, packed, valid)
+    return jnp.where(matched, jt.rows[slot], 0), matched, rounds
+
+
+def probe(jt: JoinTable, key_cols, key_types, valid):
+    """Gather-only probe: returns (build_row_ids[int32], matched[bool]) per probe row.
+
+    Backend selection (round 13): small/medium tables route to the Pallas
+    tensor-program probe (`pallas_kernels.hash_probe` — same hash family,
+    same probe order, bit-identical outputs); the XLA lookup (`_find_slots`)
+    is the fallback and the only path above `PALLAS_TABLE_MAX`.  The choice is
+    trace-time static (capacity is a shape), so compiled streams bake it in."""
+    return probe_counted(jt, key_cols, key_types, valid)[:2]
 
 
 # ---------------------------------------------------------------------------- direct index
@@ -409,38 +491,11 @@ def probe_slots(table, key_cols, key_types, valid):
 
     packed, _ = pack_keys(key_cols, key_types)
     C = table.shape[0] - 1
-    h0 = splitmix64(packed)
-    stp = probe_step(h0)
     if pk.table_kernels_enabled(C) and packed.shape[0]:
-        return pk.hash_probe(table[:C], jnp.arange(C, dtype=jnp.int32),
-                             packed, h0, stp, valid, max_probes=MAX_PROBES)
-    # carries derive from BOTH operands so they inherit every varying axis a
-    # body output can carry (see probe() above: constant keys + per-worker
-    # table would otherwise mismatch the while_loop carry types)
-    vzero = (h0 * 0).astype(jnp.int32) \
-        + (table[jnp.zeros((), jnp.int32)] * 0).astype(jnp.int32) \
-        + (valid.astype(jnp.int32) * 0)
-    slot = vzero
-    matched = (valid & False) | (vzero != 0)
-    done = ~valid | (vzero != 0)
-
-    def cond(carry):
-        p, slot, matched, done = carry
-        return (p < MAX_PROBES) & ~jnp.all(done)
-
-    def body(carry):
-        p, slot, matched, done = carry
-        idx = ((h0 + p * stp) & (C - 1)).astype(jnp.int32)
-        cur = table[idx]
-        hit = (cur == packed) & ~done
-        slot = jnp.where(hit, idx, slot)
-        matched = matched | hit
-        done = done | hit | (cur == EMPTY_KEY)
-        return p + 1, slot, matched, done
-
-    _, slot, matched, done = jax.lax.while_loop(
-        cond, body, (jnp.zeros((), jnp.int32), slot, matched, done))
-    return slot, matched
+        h0 = splitmix64(packed)
+        return pk.hash_probe(table[:C], jnp.arange(C, dtype=jnp.int32), packed,
+                             h0, probe_step(h0), valid, max_probes=MAX_PROBES)
+    return _find_slots(table, packed, valid)[:2]
 
 
 def expand_counts(incl, out_counts, size: int):

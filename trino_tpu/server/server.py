@@ -799,6 +799,9 @@ class CoordinatorServer:
                      "gathered their build columns."),
                     ("join_hash_probe_lanes", "Lanes that joins probed "
                      "through the open-addressing loop of a hashed table."),
+                    ("join_hash_probe_round_lanes", "Lanes the hashed "
+                     "probes of split joins gathered for, rounds times "
+                     "width."),
                     ("join_direct_probe_lanes", "Lanes that joins probed "
                      "through the one gather of a direct-indexed table."),
                     ("join_hash_table_slots", "Slots of the hashed join "

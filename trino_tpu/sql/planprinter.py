@@ -96,10 +96,13 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
             # at which the build columns were then gathered; every join
             # (PR 36): lanes probed through the open-addressing loop of a
             # hashed table, and through the one gather of a direct one
+            # (PR 37): the lanes the hashed lookups' rounds gathered for
+            jr = getattr(counters, "join_hash_probe_round_lanes", 0)
             lines.append(
                 f"Join probe: {jm} lanes matched, "
                 f"{getattr(counters, 'join_gather_lanes', 0)} lanes gathered; "
-                f"{jh} lanes hashed, {jd} lanes direct")
+                f"{jh} lanes hashed, {jd} lanes direct"
+                + (f"; {jr} lanes in probe rounds" if jr else ""))
         gs = getattr(counters, "groupby_slots", 0)
         if gs:
             # how the statement's group-bys were sized (PR 27): slots of the
