@@ -45,8 +45,8 @@ def _load_reader():
 read_flight_dir, summarize_compiles, summarize_skew = _load_reader()
 
 WALL_BUCKETS = ("plan", "compile", "admission_queue", "split_generation",
-                "h2d", "device_dispatch", "host_pull", "exchange_wait",
-                "retry_backoff", "unattributed")
+                "h2d", "device_dispatch", "host_pull", "scan_wait",
+                "exchange_wait", "retry_backoff", "unattributed")
 
 
 def _top_bucket(bd):

@@ -637,13 +637,15 @@ class TpchConnector:
                                     self.table_bound(split.table), tuple(names))
         return Page(out_schema, cols, tuple(None for _ in cols), valid)
 
-    def warm_scan(self, table: str, columns) -> None:
+    def warm_scan(self, table: str, columns, generate=None) -> None:
         """Start compiling the page generator of (table, columns) on a
         background thread, once a connector: the executor calls this for every
         scan of a plan before it runs the first, so that the compile of a probe
         side's generator (45 s for four lineitem columns at SF10 on a v5e,
         PERF.md PR 27) runs beside the build sides and not after them.  The
-        thread generates the first split's page and drops it.  (An
+        thread generates the first split's page and drops it, through
+        ``generate(split, columns)`` where the caller brings one (the
+        executor's, which records the launch and its compile).  (An
         ahead-of-time ``lower().compile()`` was tried in its place: the first
         ``sf10_scan`` run with it lost a quarter of its window, PERF.md PR 27.)"""
         key = (table, tuple(columns))
@@ -654,9 +656,10 @@ class TpchConnector:
         if not splits:
             return
 
-        def warm(split=splits[0], columns=list(columns)):
+        def warm(split=splits[0], columns=list(columns),
+                 generate=generate or self.generate):
             try:
-                self.generate(split, columns)
+                generate(split, columns)
             except Exception:
                 pass  # the scan itself will raise what is wrong
 

@@ -24,6 +24,7 @@ _WALL_COUNTERS = (("plan", "wall_plan_s"),
                   ("h2d", "wall_h2d_s"),
                   ("device_dispatch", "wall_dispatch_s"),
                   ("host_pull", "wall_host_pull_s"),
+                  ("scan_wait", "wall_scan_wait_s"),
                   ("exchange_wait", "wall_exchange_wait_s"),
                   ("unattributed", "wall_unattributed_s"))
 
@@ -956,9 +957,9 @@ class Engine:
         ``plan_history`` and publish the statement's payload (thread-local
         for the completion event + ``last_plan_actuals``).  Reads only what
         the executor already computed — the collection is a host-side dict
-        walk plus one batched value read of the row counters, so it adds
-        zero _jit dispatches and zero _host pulls (the budget suite pins
-        this by running with the store enabled).  ``extra_records`` lets the
+        walk plus one batched pull of the row counters through ``_host``
+        (site ``history.actuals``; a few scalars), so it adds zero _jit
+        dispatches (the budget suite runs with the store enabled).  ``extra_records`` lets the
         cluster coordinator fold harvested worker records in under the same
         fingerprint.  History is observability: any failure here leaves the
         query successful and the store unchanged."""
@@ -971,13 +972,18 @@ class Engine:
         if ph is None or not ph.enabled:
             return
         try:
+            from .execution import tracing
             from .execution.history import collect_plan_actuals, fold_records
 
-            recs = collect_plan_actuals(
-                plan, ex.stats, boundary=ex.boundary, catalogs=self.catalogs,
-                paths=getattr(ex, "_node_paths", None),
-                ests=getattr(ex, "_node_ests", None),
-                facts=getattr(ex, "_plan_facts", None))
+            # the row counters' pull is the statement's: on its counters,
+            # which execute_plan accounts after this returns
+            with tracing.track_counters(ex.counters):
+                recs = collect_plan_actuals(
+                    plan, ex.stats, boundary=ex.boundary,
+                    catalogs=self.catalogs,
+                    paths=getattr(ex, "_node_paths", None),
+                    ests=getattr(ex, "_node_ests", None),
+                    facts=getattr(ex, "_plan_facts", None))
             for path, rec in (extra_records or {}).items():
                 fold_records(recs, path, rec)
             if not recs:
@@ -1207,15 +1213,22 @@ class Engine:
                     tracing.activate_tracer(self.tracer), \
                     tracing.statement_waits() as waits, \
                     self.tracer.span("query", trace_id=qid, sql=sql) as root:
-                # accepted -> the root span opens: the server's pool queue
-                # and statement lock, session set-up, admission
-                tracing.record_wait(
-                    "server.queued", "queued_s",
-                    max(time.perf_counter() - accepted[0], 0.0),
-                    end_s=root.start_s, **accepted[1])
-                result = self._execute_admitted(sql, sess, qsm, distributed, mesh,
-                                                fault_tolerant,
-                                                parameters=parameters)
+                # CPU seconds of this thread under the root span: beside
+                # its wall they tell computing from waiting
+                cpu0 = time.thread_time()
+                try:
+                    # accepted -> the root span opens: the server's pool queue
+                    # and statement lock, session set-up, admission
+                    tracing.record_wait(
+                        "server.queued", "queued_s",
+                        max(time.perf_counter() - accepted[0], 0.0),
+                        end_s=root.start_s, **accepted[1])
+                    result = self._execute_admitted(
+                        sql, sess, qsm, distributed, mesh, fault_tolerant,
+                        parameters=parameters)
+                finally:
+                    waits["host_cpu_s"] = time.thread_time() - cpu0
+                    root.attributes["cpu_s"] = round(waits["host_cpu_s"], 6)
             if result is not None and hasattr(result, "__len__"):
                 qsm.rows = len(result)
             qsm.transition(QueryState.FINISHING)
@@ -2319,12 +2332,13 @@ class Engine:
             res = ex.execute(plan)
             dt = time.perf_counter() - t0
             t1_wall = time.time()
-            self._account_counters(ex.counters)
             # an ANALYZE run is a clean completion like any other: it feeds
-            # the plan-history store, and the plan print carries the per-node
-            # est-vs-actual annotations + "Misestimates:" summary from the
-            # same begin_plan maps the executor stamped
+            # the plan-history store (before the accounting: its pull of the
+            # row counters is the statement's), and the plan print carries
+            # the per-node est-vs-actual annotations + "Misestimates:" summary
+            # from the same begin_plan maps the executor stamped
             self._record_plan_history(plan, ex)
+            self._account_counters(ex.counters)
             lines = format_plan(plan, ex.stats, counters=ex.counters,
                                 boundary=ex.boundary, ests=ex._node_ests,
                                 paths=ex._node_paths,

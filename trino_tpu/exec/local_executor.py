@@ -668,7 +668,8 @@ class LocalExecutor:
             return _dispatch_batch_default()
         return int(b)
 
-    def _rewrap_pruned_pages(self, pages_fn, conn, n_splits: int):
+    def _rewrap_pruned_pages(self, pages_fn, conn, n_splits: int,
+                             table: str = ""):
         """Re-apply the scan's prefetch policy to a pruner-replaced page
         source: split pruning builds a bare generator, losing whichever wrap
         the TableScan compiled with.  HOST_DECODE connectors prefetch
@@ -676,10 +677,12 @@ class LocalExecutor:
         device generators get the coalescing double buffer when multi-split
         and coalescing is on."""
         if conn is not None and getattr(conn, "HOST_DECODE", False):
-            return _prefetched_pages(pages_fn, to_device=True, owner=self)
+            return _prefetched_pages(pages_fn, to_device=True, owner=self,
+                                     table=table)
         if n_splits > 1 and self._batch() > 1:
             return _prefetched_pages(pages_fn, depth=self._batch(),
-                                     to_device=True, warmup=2, owner=self)
+                                     to_device=True, warmup=2, owner=self,
+                                     table=table)
         return pages_fn
 
     def _page_cache_on(self) -> bool:
@@ -723,9 +726,9 @@ class LocalExecutor:
                 # on the PREFETCH PRODUCER thread when the scan is wrapped,
                 # which is exactly the path whose cleanup the chaos suite pins
                 faults.maybe_inject("generate", f"scan.{table}")
-                yield conn.generate(s, list(scan_cols))
+                yield _generate(conn, table, s, scan_cols)
 
-        wrapped = self._rewrap_pruned_pages(raw, conn, len(splits))
+        wrapped = self._rewrap_pruned_pages(raw, conn, len(splits), table)
         bp = self.buffer_pool
         split_rows = _split_base_rows(conn, table, splits)
 
@@ -931,10 +934,31 @@ class LocalExecutor:
         while stack:
             n = stack.pop()
             if isinstance(n, P.TableScan):
-                warm = getattr(self.catalogs.get(n.catalog), "warm_scan", None)
+                conn = self.catalogs.get(n.catalog)
+                warm = getattr(conn, "warm_scan", None)
                 if warm is not None:
-                    warm(n.table, tuple(n.columns))
+                    warm(n.table, tuple(n.columns),
+                         generate=self._warm_generate(conn, n.table))
             stack.extend(n.children)
+
+    def _warm_generate(self, conn, table: str):
+        """``generate(split, columns)`` for a connector's warm thread: the
+        launch goes through ``_generate`` under THIS statement's counters,
+        query id and trace, so that the generator's first compile (the
+        longest of a cold SF10 set-up) is a compile event of the statement
+        that started it.  Not a ``generator_dispatches``: no scan source asked
+        for the page."""
+        qid = tracing.current_query_id()
+        tracer = tracing.current_tracer()
+        parent = tracer.current() if tracer is not None else None
+
+        def generate(split, columns):
+            with _statement_scopes(self.counters, qid, tracer), \
+                    contextlib.nullcontext() if tracer is None else \
+                    tracer.span("generate.warm", parent=parent, table=table):
+                return _generate(conn, table, split, columns, count=False)
+
+        return generate
 
     def execute_batched(self, node: P.PlanNode, runtimes) -> list:
         """Round 21 — continuous template batching: ONE fused execution of a
@@ -1228,7 +1252,7 @@ class LocalExecutor:
 
         si = up.scan_info
         if si.splits:  # (generated here, not pulled through the prefetcher)
-            page = si.conn.generate(si.splits[0], list(si.scan_columns))
+            page = _generate(si.conn, si.table, si.splits[0], si.scan_columns)
             if _page_batch_sig(page) is not None:
                 count = match(page, up.aux, _current_params())[3]
                 if pulled(count, "join.match.sample")[0] > page.capacity >> 2:
@@ -2109,7 +2133,10 @@ class LocalExecutor:
                         state = dstep(state, group[0], stream.aux) \
                             if live is None \
                             else bdstep(state, tuple(group), live, stream.aux)
-                    if not bool(state.overflow):
+                    # the host waits HERE for every step it queued (a scan
+                    # statement's one long wait): a pull like any other
+                    if not _host([state.overflow],
+                                 site="agg.direct.overflow")[0]:
                         return self._finalize_groups(node, stream, state)
                 # stale stats put keys out of range: hash mode, over the
                 # whole input again
@@ -2134,7 +2161,7 @@ class LocalExecutor:
                 # replay); a still-set overflow means the capacity/memory ceiling:
                 # fall back to partitioned passes (the HBM analog of the
                 # reference's SpillableHashAggregationBuilder)
-                if not bool(state.overflow):
+                if not _host([state.overflow], site="agg.hash.overflow")[0]:
                     if self._agg_cacheable(node):
                         self._agg_cache[("capacity", id(node))] = \
                             (node, state.capacity)
@@ -2206,7 +2233,8 @@ class LocalExecutor:
                     zip(staged, counts)):
                 if n == 0:
                     continue
-                if k and not proven and bool(state.overflow):
+                if k and not proven and _host([state.overflow],
+                                              site="agg.hash.overflow")[0]:
                     break  # the regrow replays the chunk: spare it the rest
                 width = valid.shape[0]
                 bucket = max(1 << max(n - 1, 1).bit_length(), 1024)
@@ -2245,7 +2273,7 @@ class LocalExecutor:
                 # chunk — never the whole input stream
                 start_state = state
                 state = insert_chunk(state, counts)
-                if not bool(state.overflow):
+                if not _host([state.overflow], site="agg.hash.overflow")[0]:
                     staged.clear()
                     return state, False
                 grown = start_state.capacity * 4
@@ -2398,7 +2426,8 @@ class LocalExecutor:
                             else bpstep(tuple(group), live, stream.aux)
                         state = mstep(state, kcols, knulls, accs, new)
                         tracing.record_groupby_insert(new.shape[0])
-                    if not bool(state.overflow):
+                    if not _host([state.overflow],
+                                 site="agg.sorted.overflow")[0]:
                         return self._finalize_groups(node, stream, state)
                 # merge-state overflow: grow and re-stream (rare — capacity is
                 # stats-sized upstream like the hash path)
@@ -2436,7 +2465,8 @@ class LocalExecutor:
         # compact occupied groups ON DEVICE before any host transfer: the table is
         # capacity-sized but group counts are usually tiny, and the device->host
         # transfer is priced by the byte
-        n_groups = int(hashagg.group_count(state))
+        n_groups = int(_host([hashagg.group_count(state)],
+                             site="agg.group_count")[0])
         bucket = max(1 << max(n_groups - 1, 1).bit_length(), 64)
         keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
         tracing.record_compaction(state.capacity, bucket)
@@ -2453,7 +2483,7 @@ class LocalExecutor:
         fin = self._device_finalize(node)
         if fin is not None:
             fin_cols, fin_nulls, bad = fin(tuple(accs))
-            if not bool(bad):
+            if not _host([bad], site="agg.finalize.envelope")[0]:
                 out_cols = tuple(k[:n_groups] for k in keys) \
                     + tuple(c[:n_groups] for c in fin_cols)
                 out_nulls = tuple(kn[:n_groups] for kn in key_nulls) + tuple(
@@ -2566,7 +2596,8 @@ class LocalExecutor:
                 for page in src():
                     state = insert(state, page)
                     tracing.record_groupby_insert(page.capacity)
-                if not bool(state.overflow):
+                if not _host([state.overflow],
+                             site="agg.partitioned.overflow")[0]:
                     break
                 if capacity >= MAX_GROUP_CAPACITY:
                     if parts >= 1 << 16:
@@ -2786,9 +2817,9 @@ class LocalExecutor:
         new_splits = conn.splits(handle)
         scan_cols = si.scan_columns
 
-        def pages(conn=conn, splits=new_splits, cols=scan_cols):
+        def pages(conn=conn, splits=new_splits, cols=scan_cols, table=table):
             for s in splits:
-                yield conn.generate(s, list(cols))
+                yield _generate(conn, table, s, cols)
 
         st = self._node_stats(node)
         st["index_join_keys"] = len(keys)
@@ -3996,9 +4027,9 @@ def _static_pruned_stream(up: _Stream, pred):
         return None
     conn, scan_cols = si.conn, si.scan_columns
 
-    def pages(conn=conn, kept=kept, scan_cols=scan_cols):
+    def pages(conn=conn, kept=kept, scan_cols=scan_cols, table=si.table):
         for s in kept:
-            yield conn.generate(s, list(scan_cols))
+            yield _generate(conn, table, s, scan_cols)
 
     return pages, dataclasses.replace(si, splits=kept)
 
@@ -4079,7 +4110,7 @@ def _dynamic_pruned_pages(probe_stream: _Stream, node, build_page: Page):
 
     def pages():
         for s in kept:
-            yield conn.generate(s, list(scan_cols))
+            yield _generate(conn, si.table, s, scan_cols)
 
     return pages, kept
 
@@ -4544,8 +4575,25 @@ def _plan_fingerprint(node: P.PlanNode, catalogs: dict) -> str:
     return fp(node)
 
 
+@contextlib.contextmanager
+def _statement_scopes(counters, qid, tracer):
+    """Another thread's work recorded as the statement's (the prefetch
+    producer, a connector's warm thread): its counters, its query id and its
+    tracer on this thread, each where the statement has one.  track_counters
+    enters BEFORE query_scope: live-counter registration keys on the qid
+    active at entry, and the query thread already registered this set."""
+    with contextlib.ExitStack() as scopes:
+        if counters is not None:
+            scopes.enter_context(tracing.track_counters(counters))
+        if qid is not None:
+            scopes.enter_context(tracing.query_scope(qid))
+        if tracer is not None:
+            scopes.enter_context(tracing.activate_tracer(tracer))
+        yield
+
+
 def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
-                      warmup: int = 0, owner=None):
+                      warmup: int = 0, owner=None, table: str = ""):
     """Wrap a page generator with background-thread prefetch: up to ``depth``
     pages decode ahead of the consumer.  ``to_device`` additionally moves each
     page's host (numpy) arrays onto the device FROM THE PRODUCER THREAD
@@ -4565,8 +4613,15 @@ def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
     consumer generator is never closed — a mid-query error's traceback pins
     the consumer frames (and so the generators) alive, which used to leave
     the producer pumping against a full queue until the traceback was
-    released."""
+    released.
+
+    Both sides of the queue are timed where they block (PR 38): each of the
+    consumer's ``q.get()`` is a finished ``scan.wait`` span (bucket scan_wait,
+    ``table`` its attribute) and a ``trino_tpu:scan.wait`` annotation; the
+    producer sums the seconds its ``put`` found the queue full into
+    ``put_wait_s`` of its ``prefetch`` span, beside the thread's ``cpu_s``."""
     import queue as _queue
+    import time as _time
 
     def pages():
         it = pages_fn()
@@ -4590,25 +4645,30 @@ def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
         # and h2d fault injections fire ON this thread, and without the
         # query's counters installed here record_fault would no-op — a chaos
         # run over the default prefetch path would read 0 faults_injected.
-        # The producer still records nothing else and never touches executor
-        # state (the round-6 rule).  track_counters must enter BEFORE
-        # query_scope: live-counter registration keys on the qid active at
-        # entry, and the query thread already registered this counter set.
+        # Beside faults the producer records its generator launches
+        # (_generate) and never touches executor state (the round-6 rule).
         counters = tracing.current_counters()
         qid = tracing.current_query_id()
 
         def producer():
+            put_wait = [0.0]
+
             def put(item) -> bool:
-                while not closed.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        return True
-                    except _queue.Full:
-                        continue
-                return False
+                t0 = _time.perf_counter()
+                try:
+                    while not closed.is_set():
+                        try:
+                            q.put(item, timeout=0.1)
+                            return True
+                        except _queue.Full:
+                            continue
+                    return False
+                finally:
+                    put_wait[0] += _time.perf_counter() - t0
 
             def pump(span):
                 n = 0
+                cpu0 = _time.thread_time()
                 try:
                     for p in it:
                         if to_device:
@@ -4622,6 +4682,9 @@ def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
                 finally:
                     if span is not None:
                         span.attributes["pages"] = n
+                        span.attributes["put_wait_s"] = round(put_wait[0], 6)
+                        span.attributes["cpu_s"] = round(
+                            _time.thread_time() - cpu0, 6)
                     # the producer owns the source iterator once the thread
                     # starts: close it HERE so connector state (file handles,
                     # decode buffers) releases with the thread, not at GC
@@ -4632,14 +4695,13 @@ def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
                         except Exception:
                             pass
 
-            with contextlib.ExitStack() as scopes:
-                if counters is not None:
-                    scopes.enter_context(tracing.track_counters(counters))
-                if qid is not None:
-                    scopes.enter_context(tracing.query_scope(qid))
+            with _statement_scopes(counters, qid, tracer):
                 if tracer is None:
                     pump(None)
                 else:
+                    # (the tracer is active on this thread too, so that the
+                    # generator launches it runs, _generate, are spans of
+                    # the statement, under this one)
                     with tracer.span("prefetch", parent=parent,
                                      to_device=to_device) as span:
                         pump(span)
@@ -4653,7 +4715,13 @@ def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
         t.start()
         try:
             while True:
-                item = q.get()
+                t0 = _time.perf_counter()
+                with tracing.annotate("scan.wait"):
+                    item = q.get()
+                if tracer is not None:
+                    tracer.add_completed("scan.wait",
+                                         _time.perf_counter() - t0,
+                                         table=table)
                 if item is done:
                     return
                 if isinstance(item, BaseException):
@@ -4684,6 +4752,52 @@ def _page_to_device(page: Page) -> Page:
     return Page(page.schema, tuple(up(c) for c in page.columns),
                 tuple(None if m is None else up(m) for m in page.null_masks),
                 None if page.valid is None else up(page.valid))
+
+
+_GENERATED: set = set()  # generator shapes that have run once (_generate)
+
+
+def _generate(conn, table: str, split, cols, count: bool = True):
+    """One page of ``table`` from its connector: the chokepoint of the
+    generator launches, as ``_jit`` is of the executor's own programs.  A
+    device generator (connectors/tpch.py ``_jit_generate``, tpcds.py alike) is
+    a bare ``jax.jit`` the connector owns, so it is counted HERE, on whichever
+    thread runs the scan source (the prefetch producer's mostly): one
+    ``generator_dispatches``, a finished ``generate`` span with
+    ``site=generate.<table>``, a ``trino_tpu:generate`` annotation, an
+    in-flight entry while it runs, and what XLA compiled inside it as a
+    ``record_compile`` event of that site.  ``count=False``: a launch that no
+    scan source asked for (the connector's warm thread) is all of that but
+    the count."""
+    import time as _time
+
+    site = "generate." + table
+    # what a device generator compiles once for: the stall watchdog judges a
+    # first launch as a compile (TRINO_TPU_STALL_COMPILE_S), as _jit's
+    # first-seen signatures are
+    shape = (type(conn), getattr(conn, "sf", None), table, tuple(cols),
+             getattr(split, "hi", 0) - getattr(split, "lo", 0))
+    reg = tracing.current_inflight()
+    tok = reg.enter("generate", site, compiling=shape not in _GENERATED)
+    cap = tracing.begin_compile_capture()
+    t0 = _time.perf_counter()
+    try:
+        with tracing.annotate("generate"):
+            page = conn.generate(split, list(cols))
+        _GENERATED.add(shape)
+        return page
+    finally:
+        reg.exit(tok)
+        dt = _time.perf_counter() - t0
+        xla_s = tracing.end_compile_capture(cap)
+        if xla_s is not None:  # compile events fired: the generator's first
+            # launch at this (length, column set), or one served by the
+            # persistent cache
+            tracing.record_compile(
+                xla_s, site=site,
+                signature=f"{table}[{shape[-1]}]({', '.join(shape[3])})",
+                cache_misses=tracing.compile_capture_misses(cap))
+        tracing.record_generate(table, dt, count=count)
 
 
 def _host(arrays, site=None):

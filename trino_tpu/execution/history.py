@@ -228,7 +228,7 @@ def collect_plan_actuals(plan, stats: dict, boundary: Optional[dict] = None,
     are the maps the executor stamped at ``begin_plan`` time (recomputed here
     only when a driver skipped begin_plan).  Row counts may still live on
     device (the executor defers the sync); they are fetched in ONE batched
-    value read — no new dispatches, no ``_host``-counted pulls.
+    pull through ``_host`` (site ``history.actuals``) — no new dispatches.
 
     Each record carries an ``unestimated`` marker — True when the CBO had NO
     estimate for the node — so a consumer (the adaptive advisor) can tell
@@ -297,11 +297,17 @@ def collect_plan_actuals(plan, stats: dict, boundary: Optional[dict] = None,
             pending.append((path, rec, fact["build_rows"]))
     if not pending:
         return {}
-    import jax
-
     # one batched read of the already-computed row counters (mixed python
-    # ints and 0-d device arrays); the values exist — nothing new dispatches
-    vals = jax.device_get([r[2] for r in pending])
+    # ints and 0-d device arrays); the values exist — nothing new dispatches.
+    # The device ones go through the executor's pull chokepoint (PR 38: the
+    # read waits for whatever the device still runs, so it is a ``host_pull``
+    # span, a counted transfer, an in-flight entry and a fault point like any
+    # other); host ints alone cost no round trip and record none
+    vals = [r[2] for r in pending]
+    if any(hasattr(v, "copy_to_host_async") for v in vals):
+        from ..exec.local_executor import _host
+
+        vals = _host(vals, site="history.actuals")
     out: dict = {}
     for (path, rec, _), v in zip(pending, vals):
         rec["actual_rows"] = int(v)

@@ -44,7 +44,7 @@ __all__ = ["Span", "Tracer", "QueryCounters", "track_counters",
            "begin_compile_capture", "end_compile_capture",
            "compile_capture_misses", "site_program", "wait_span",
            "statement_waits", "accepted_scope", "take_accepted",
-           "record_wait", "annotate"]
+           "record_wait", "annotate", "record_generate"]
 
 _log = logging.getLogger("trino_tpu.stall")
 
@@ -309,6 +309,11 @@ class QueryCounters:
     # hold them
     probe_exchange_rows: int = 0
     probe_exchange_lanes: int = 0
+    # PR 38: launches of a connector's page generator from the executor's scan
+    # sources (record_generate: one a split, on whichever thread runs it, the
+    # prefetch producer's mostly).  NOT part of device_dispatches, whose
+    # ceilings count the executor's own programs
+    generator_dispatches: int = 0
     # PR 25: the statement's wait states, seconds (each also a span of the
     # same name family: server.queued, batcher.wait, executor.checkout,
     # server.encode, server.deliver), recorded where the wait happens, and
@@ -326,6 +331,13 @@ class QueryCounters:
     wall_host_pull_s: float = 0.0
     wall_exchange_wait_s: float = 0.0
     wall_unattributed_s: float = 0.0
+    # PR 38: the scan_wait bucket (the consumer's waits on the prefetch queue,
+    # spans "scan.wait"), and the CPU seconds of the statement's own thread
+    # under its root span (time.thread_time at both ends: beside the wall it
+    # tells a thread that computes from one that waits for a device, a queue
+    # or the interpreter lock)
+    wall_scan_wait_s: float = 0.0
+    host_cpu_s: float = 0.0
     # round 19: adaptive execution.  A replan means the statement ran a
     # CORRECTED plan (the advisor's history-backed cardinality/capacity
     # facts re-planned it); a hold means a material misestimate existed but
@@ -376,12 +388,14 @@ class QueryCounters:
                    "join_hash_probe_round_lanes",
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
-                   "probe_exchange_rows", "probe_exchange_lanes")
+                   "probe_exchange_rows", "probe_exchange_lanes",
+                   "generator_dispatches")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
                      "wall_plan_s", "wall_split_generation_s", "wall_h2d_s",
                      "wall_dispatch_s", "wall_host_pull_s",
-                     "wall_exchange_wait_s", "wall_unattributed_s")
+                     "wall_exchange_wait_s", "wall_unattributed_s",
+                     "wall_scan_wait_s", "host_cpu_s")
 
     def reset(self) -> None:
         for f in self._INT_FIELDS:
@@ -669,6 +683,20 @@ def record_rows_generated(rows: int) -> None:
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.rows_generated += rows
+
+
+def record_generate(table: str, seconds: float, count: bool = True) -> None:
+    """One launch of a connector's page generator, measured where the
+    executor calls it (local_executor._generate): the count (a scan source's
+    launches; not a warm thread's), and a finished ``generate`` span (bucket
+    split_generation) under the thread's current span, the prefetch
+    producer's ``prefetch`` span mostly."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None and count:
+        c.generator_dispatches += 1
+    tr = current_tracer()
+    if tr is not None:
+        tr.add_completed("generate", seconds, site="generate." + table)
 
 
 def record_mesh_fragment(hit: bool) -> None:
@@ -1215,7 +1243,7 @@ DISPATCH_TEST_HOOK = None
 @dataclasses.dataclass
 class InflightEntry:
     token: int
-    kind: str  # dispatch | host_pull | split-generation | exchange-segment
+    kind: str  # dispatch | host_pull | generate | split-generation | exchange-segment
     site: str
     op: Optional[str]
     label: str  # "<Op>#<k>/<site>" — same key shape as QueryCounters.sites
@@ -1878,8 +1906,8 @@ def spans_to_otlp(spans, service: str = "trino_tpu") -> dict:
 # criterion pins within 5%.
 
 WALL_BUCKETS = ("plan", "compile", "admission_queue", "split_generation",
-                "h2d", "device_dispatch", "host_pull", "exchange_wait",
-                "retry_backoff", "unattributed")
+                "h2d", "device_dispatch", "host_pull", "scan_wait",
+                "exchange_wait", "retry_backoff", "unattributed")
 
 # span name -> bucket.  Container spans (query/execution/task) and
 # unrecognized names stay out of the sweep: their time is the sum of their
@@ -1890,8 +1918,13 @@ _SPAN_BUCKETS = {
     "dispatch": "device_dispatch",
     "host_pull": "host_pull",
     "split-generation": "split_generation",
+    # PR 38: one a launch of a connector's page generator (record_generate),
+    # inside the producer's "prefetch" span, whose other seconds (its blocked
+    # puts: ``put_wait_s``; host decode and staging) stay h2d
+    "generate": "split_generation",
     "prefetch": "h2d",
-    "h2d": "h2d",
+    # PR 38: the consumer's wait on the prefetch queue (_prefetched_pages)
+    "scan.wait": "scan_wait",
     "exchange.read": "exchange_wait",
     "exchange.stream": "exchange_wait",
     # round 18: the mesh exchange (exec/distributed.py) opens these around its
@@ -1908,8 +1941,22 @@ _SPAN_BUCKETS = {
 # compile span always nests inside the first-seen dispatch span, and a cold
 # statement's wall is compilation, not execution — before this, cold walls
 # silently inflated the dispatch bucket.
-_BUCKET_PRIORITY = ("compile", "device_dispatch", "host_pull",
+_BUCKET_PRIORITY = ("compile", "device_dispatch", "host_pull", "scan_wait",
                     "exchange_wait", "split_generation", "plan", "h2d")
+
+# PR 38: the spans that CONTAIN work and are no bucket themselves, all opened
+# on the statement's own thread.  The remainder of the sweep is split by the
+# innermost one open at each uncovered slice (``unattributed_by``), so the
+# tree that is there says where the unnamed host time sits.
+_CONTAINER_SPANS = ("query", "execution", "join.build", "mesh.fragment", "task",
+                    # the two waits under the root span (counters of their
+                    # own: batch_wait_s, executor_wait_s) are no bucket, so
+                    # their seconds are the remainder's: named here
+                    "batcher.wait", "executor.checkout")
+
+
+def _is_container(name) -> bool:
+    return name in _CONTAINER_SPANS or str(name).startswith("aggregate.")
 
 
 def wall_breakdown(spans, window=None, queued_s: float = 0.0,
@@ -1925,7 +1972,12 @@ def wall_breakdown(spans, window=None, queued_s: float = 0.0,
     so it is carved out of the unattributed remainder — never added on top,
     which would double-count the same seconds.  Returns None when no
     closed window can be established.  Host-only arithmetic — zero device
-    work (the flight-recorder feed discipline)."""
+    work (the flight-recorder feed discipline).
+
+    ``unattributed_by`` (PR 38) splits the remainder by the innermost
+    container span (_CONTAINER_SPANS, ``aggregate.*``) open at each slice
+    that no leaf span covers: {span name: seconds}, summing to
+    ``unattributed``; "outside" where none is open (an explicit window)."""
     dicts = [s if isinstance(s, dict) else span_dict(s) for s in spans]
     if window is None:
         root = next((s for s in dicts
@@ -1936,27 +1988,35 @@ def wall_breakdown(spans, window=None, queued_s: float = 0.0,
         window = (root["start_s"], root["end_s"])
     lo, hi = window
     wall = max(float(hi) - float(lo), 0.0)
-    intervals = []
-    for s in dicts:
-        bucket = _SPAN_BUCKETS.get(s.get("name"))
-        if bucket is None or s.get("end_s") is None \
-                or s.get("start_s") is None:
-            continue
-        a = max(float(s["start_s"]), lo)
-        z = min(float(s["end_s"]), hi)
-        if z > a:
-            intervals.append((a, z, bucket))
-    buckets = {b: 0.0 for b in WALL_BUCKETS}
     rank = {b: i for i, b in enumerate(_BUCKET_PRIORITY)}
     # single event sweep with per-bucket active counts — O(n log n), not
     # O(slices x intervals): a SF100 capture query's trace holds thousands
-    # of dispatch/generation/pull spans and this runs at every completion
-    events: list = []
-    for a, z, b in intervals:
-        events.append((a, 1, b))
-        events.append((z, -1, b))
+    # of dispatch/generation/pull spans and this runs at every completion.
+    # An event is (time, +1 | -1, bucket rank) for a leaf span and
+    # (time, +1 | -1, (start, span id, name)) for a container span
+    events: list = [(lo, 0, None), (hi, 0, None)]
+    for s in dicts:
+        name, end = s.get("name"), s.get("end_s")
+        bucket = _SPAN_BUCKETS.get(name)
+        if bucket is None:
+            if not _is_container(name):
+                continue
+            if end is None:  # a container still open (EXPLAIN ANALYZE reads
+                end = hi     # its window from inside the statement) holds it
+        if end is None or s.get("start_s") is None:
+            continue
+        a = max(float(s["start_s"]), lo)
+        z = min(float(end), hi)
+        if z > a:
+            what = rank[bucket] if bucket is not None else \
+                (float(s["start_s"]), s.get("span_id") or 0, name)
+            events.append((a, 1, what))
+            events.append((z, -1, what))
     events.sort(key=lambda ev: ev[0])
+    buckets = {b: 0.0 for b in WALL_BUCKETS}
     active = [0] * len(_BUCKET_PRIORITY)
+    open_containers: set = set()
+    by_container: dict = {}
     prev = None
     i, n = 0, len(events)
     while i < n:
@@ -1966,8 +2026,19 @@ def wall_breakdown(spans, window=None, queued_s: float = 0.0,
                 if active[j]:
                     buckets[b] += t - prev
                     break
+            else:
+                # no leaf span covers the slice: it is the remainder's, under
+                # the innermost (latest opened) container that is open
+                inner = max(open_containers)[2] if open_containers else "outside"
+                by_container[inner] = by_container.get(inner, 0.0) + t - prev
         while i < n and events[i][0] == t:
-            active[rank[events[i][2]]] += events[i][1]
+            _, step, what = events[i]
+            if isinstance(what, int):
+                active[what] += step
+            elif step > 0:
+                open_containers.add(what)
+            elif step < 0:
+                open_containers.discard(what)
             i += 1
         prev = t
     attributed = sum(buckets.values())
@@ -1978,8 +2049,17 @@ def wall_breakdown(spans, window=None, queued_s: float = 0.0,
     buckets["retry_backoff"] = min(max(float(retry_backoff_s or 0.0), 0.0),
                                    remainder)
     buckets["unattributed"] = remainder - buckets["retry_backoff"]
+    carve = buckets["retry_backoff"]  # out of the containers too, largest first
+    for name in sorted(by_container, key=by_container.get, reverse=True):
+        if carve <= 0.0:
+            break
+        took = min(by_container[name], carve)
+        by_container[name] -= took
+        carve -= took
     out = {b: round(v, 6) for b, v in buckets.items()}
     out["wall_s"] = round(wall + buckets["admission_queue"], 6)
+    out["unattributed_by"] = {k: round(v, 6) for k, v in sorted(
+        by_container.items(), key=lambda kv: -kv[1]) if v > 0.0}
     return out
 
 
@@ -1990,5 +2070,11 @@ def format_wall_breakdown(bd: dict) -> str:
              for b in WALL_BUCKETS if bd.get(b, 0.0) > 0.0005]
     if not parts:
         parts = ["unattributed 0.0ms"]
+    where = bd.get("unattributed_by")
+    if where and parts[-1].startswith("unattributed") \
+            and bd.get("unattributed", 0.0) > 0.05 * bd.get("wall_s", 0.0):
+        # where the remainder sits, once it passes 5 % of the wall
+        parts[-1] += " [" + ", ".join(
+            f"{k} {v * 1000:.1f}ms" for k, v in where.items()) + "]"
     return ("Wall breakdown: " + ", ".join(parts)
             + f" (total {bd.get('wall_s', 0.0) * 1000:.1f}ms)")

@@ -39,6 +39,8 @@ _SECONDS_SERIES = (
      "published (rows to JSON, spooling)."),
     ("deliver_wait_s", "deliver_wait", "Seconds from FINISHED published to "
      "the response carrying the last page."),
+    ("host_cpu_s", "host_cpu", "CPU seconds of the statements' own threads "
+     "under their root spans (time.thread_time)."),
 )
 
 _qids = itertools.count(1)
@@ -766,7 +768,8 @@ class CoordinatorServer:
                       "of finished statements by wall_breakdown bucket.",
                       "# TYPE trino_tpu_wall_seconds_total counter"]
             for bucket in ("plan", "split_generation", "h2d", "dispatch",
-                           "host_pull", "exchange_wait", "unattributed"):
+                           "host_pull", "scan_wait", "exchange_wait",
+                           "unattributed"):
                 lines.append(
                     f'trino_tpu_wall_seconds_total{{bucket="{bucket}"}} '
                     f"{getattr(ct, f'wall_{bucket}_s', 0.0):.6f}")
@@ -822,7 +825,10 @@ class CoordinatorServer:
                     ("probe_exchange_rows", "Rows routed by the probe "
                      "exchanges inside mesh fragments."),
                     ("probe_exchange_lanes", "Lanes the receive tensors of "
-                     "those probe exchanges held.")):
+                     "those probe exchanges held."),
+                    ("generator_dispatches", "Launches of the connectors' "
+                     "page generators from the executor's scan sources "
+                     "(not in device_dispatches).")):
                 lines += [f"# HELP trino_tpu_{field}_total {what}",
                           f"# TYPE trino_tpu_{field}_total counter",
                           f"trino_tpu_{field}_total {getattr(ct, field, 0)}"]
@@ -1577,4 +1583,7 @@ class CoordinatorServer:
             "query": q.sql,
             "error": q.error,
             "elapsedMs": round(((q.finished_at or time.time()) - q.created_at) * 1000),
+            # the root span's seconds by bucket, and under which container
+            # span the unattributed part sits (``unattributed_by``)
+            "wallBreakdown": (q.trace or {}).get("wall_breakdown"),
         }

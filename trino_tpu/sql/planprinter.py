@@ -33,8 +33,9 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
     them by structural node path.  ``breakdown``: optional wall-clock
     decomposition (execution/tracing.wall_breakdown over the analyze run's
     window) rendered as one "Wall breakdown:" line — where the time went
-    (plan / split generation / h2d / device dispatch / host pull / exchange
-    wait / unattributed), not just how much there was.  ``adaptive``:
+    (plan / split generation / h2d / device dispatch / host pull / scan wait /
+    exchange wait / unattributed, and under which container span the
+    unattributed part sits), not just how much there was.  ``adaptive``:
     optional adaptive-advisor decision dict (round 19) rendered as one
     "Adaptive:" line with the win-vs-price arithmetic and the corrections —
     why this statement's plan changed, or why the advisor held (no decision
@@ -117,8 +118,11 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"{getattr(counters, 'groupby_insert_lanes', 0)} lanes inserted")
         rg = getattr(counters, "rows_generated", 0)
         jb = getattr(counters, "join_build_rows", 0)
-        if rg or jb:
-            lines.append(f"Scan: {rg} rows generated, {jb} join build rows")
+        gd = getattr(counters, "generator_dispatches", 0)
+        if rg or jb or gd:
+            # (PR 38) the launches of the connectors' generators behind them
+            lines.append(f"Scan: {rg} rows generated, {jb} join build rows"
+                         + (f", {gd} generator launches" if gd else ""))
         xr = getattr(counters, "exchange_rows", 0)
         fh = getattr(counters, "mesh_fragment_hits", 0)
         fc = getattr(counters, "mesh_fragment_compiles", 0)
