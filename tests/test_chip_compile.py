@@ -151,6 +151,46 @@ def test_topn_selection_compiles_for_v5e(n, count, one_chip, no_persistent_cache
     assert time.perf_counter() - t0 < 60
 
 
+# PR 39: a group-by statement's epilogue as one program.  The dashboard's q1 (a full
+# Sort of a 64-lane packed page by two dictionary keys, ten columns, six with a mask),
+# SF10 q3's TopN (10 of 113,513 groups in a 2^17-lane page: the selection, no sort).
+# (A full Sort of 2^14 lanes by two keys with masks costs the v5e compiler 67 s here,
+# 65 of them the five-key `jnp.lexsort` alone, as it did eager: PERF.md PR 27, PR 39.)
+@pytest.mark.parametrize("name,n,count,select", [
+    ("dashboard_q1", 64, 4, False), ("sf10_q3_topn", 1 << 17, 10, True)])
+def test_the_sort_program_compiles_for_v5e(name, n, count, select, one_chip,
+                                           no_persistent_cache):
+    import time
+
+    from trino_tpu.exec import local_executor as LE
+
+    strings = 2 if name == "dashboard_q1" else 0
+    cols = tuple(_s(one_chip, (n,), jnp.int32) for _ in range(strings)) \
+        + tuple(_s(one_chip, (n,), jnp.int64) for _ in range(4 if strings else 3)) \
+        + ((_s(one_chip, (n,), jnp.float64),) * 4 if strings else ())
+    nulls = tuple(_s(one_chip, (n,), jnp.bool_) if i < 6 else None
+                  for i in range(len(cols)))
+    luts = tuple(_s(one_chip, (3,), jnp.int64) for _ in range(strings))
+    keys = ((0, True, False, 0), (1, True, False, 1)) if strings \
+        else ((1, False, False, -1), (2, True, False, -1))
+    narrow = tuple(np.dtype(np.int8) if i < strings else None for i in range(len(cols)))
+    t0 = time.perf_counter()
+    text = LE._sorted_rows.lower(cols, nulls, _s(one_chip, (n,), jnp.bool_), luts,
+                                 keys, count, select, narrow, False).compile().as_text()
+    assert (" sort(" in text) != select  # the selection sorts nothing
+    assert time.perf_counter() - t0 < 90
+
+
+def test_a_wide_group_bys_initial_state_compiles_for_v5e(one_chip, no_persistent_cache):
+    """`agg.hash.init` at SF10 q18's 2^24 slots: fills, nothing folded into the program
+    as a constant of the state's size."""
+    from trino_tpu.exec import local_executor as LE
+
+    compiled = LE._hash_init.lower(
+        1 << 24, (jnp.int64,), ((jnp.int64, 0), (jnp.float64, 0))).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 1 << 20
+
+
 def test_q1_page_step_compiles_for_v5e(one_chip, as_on_tpu):
     """The jitted per-page step of Q1 (scan transform -> group-by insert into
     the 64-slot table) — the first aggregation of the first query."""

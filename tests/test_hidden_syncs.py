@@ -115,15 +115,17 @@ def test_a_warm_replay_reads_no_device_value_outside_host(engines, where, catalo
 
 
 def test_a_planted_bool_of_a_device_scalar_is_caught(engines, monkeypatch):
-    """The census is not blind: the sync this PR took out, planted again."""
-    real = local_executor.hashagg.group_count
+    """The census is not blind: the sync this PR took out, planted again (PR 39: where
+    the group-by's finalize reads its one pull, the count now rides with the flag)."""
+    real = local_executor.tracing.record_compaction
 
-    def planted(state):
-        count = real(state)
-        bool(count > 0)  # what `if not bool(state.overflow)` was
-        return count
+    def planted(lanes_in, lanes_out):
+        import jax.numpy as jnp
 
-    monkeypatch.setattr(local_executor.hashagg, "group_count", planted)
+        bool(jnp.zeros(()) > 0)  # what `if not bool(state.overflow)` was
+        return real(lanes_in, lanes_out)
+
+    monkeypatch.setattr(local_executor.tracing, "record_compaction", planted)
     engine = engines["plain"]
     session = engine.create_session("tpch")
     with Census() as census:
@@ -138,9 +140,9 @@ def test_the_group_bys_syncs_are_host_pull_spans_with_their_sites(engines):
     engine.execute_sql(sql_of("q1"), session)
     pulls = [s["attributes"].get("site") for s in engine.last_query_trace["spans"]
              if s["name"] == "host_pull"]
-    for site in ("agg.direct.overflow", "agg.group_count", "agg.finalize.envelope",
-                 "history.actuals"):
-        assert pulls.count(site) == 1, (site, pulls)
+    # PR 39: a replay reads the flag, the group count and the envelope flag in ONE
+    # pull behind the finalize's program, and its history record holds host ints only
+    assert pulls == ["agg.direct.overflow", "sort.pull", "page"], pulls
     sites = engine.last_query_counters.sites
     assert sum(v["transfers"] for v in sites.values()) \
         == engine.last_query_counters.host_transfers == len(pulls)
@@ -201,8 +203,8 @@ def test_explain_analyze_prints_the_generator_launches_and_where_the_remainder_s
     assert f", {splits} generator launches" in text, text
     line = next(ln for ln in text.split("\n") if ln.startswith("Wall breakdown:"))
     assert "host_pull" in line, line
-    if "unattributed" in line:  # over 5 % of the wall: its containers are named
-        assert "[" in line and "aggregate.direct" in line or "execution" in line, line
+    if "[" in line:  # the remainder is over 5 % of the wall: its containers are named
+        assert "aggregate.direct" in line or "execution" in line, line
 
 
 def test_a_generators_first_launch_is_a_compile_event_of_its_site():

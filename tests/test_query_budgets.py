@@ -119,11 +119,19 @@ QUERIES = {
 # bytes are counted.  Measured warm: q1 +30 B (1 + 4 + 1 + three 8-byte row
 # counters), q3 +47, q9 +75, q18 +55; host_transfers 4 -> 8, 5 -> 10, 6 -> 11,
 # 5 -> 10.  Every ceiling still holds them, so none is raised.
+# PR 39: a group-by statement's prologue and epilogue are counted programs now
+# (they were about 120-285 eager launches that no counter saw): +agg.direct.init
+# or agg.hash.init, +sort.rows (the Sort/TopN's whole device part), and the
+# packed page of the finalize needs no _compact_part_sized before the sort: +1
+# dispatch a statement.  The group count, the envelope flag and the sort's
+# count ride pulls that were there (warm q1: host_transfers 8 -> 3), and the
+# bytes fall with them.  Measured warm: q3 9 dispatches, q18 9; q1 and q9
+# stay inside their ceilings: the two that sat AT theirs move by the one.
 BUDGETS = {
     "q1": (6, 400),
-    "q3": (8, 400),
+    "q3": (9, 400),
     "q9": (9, 3400),    # pre-round-6 trace: 4228 bytes — must stay below it
-    "q18": (8, 3200),
+    "q18": (9, 3200),
 }
 
 

@@ -329,8 +329,12 @@ def test_a_hash_group_by_replays_at_the_capacity_it_grew_to(tpch_sf001, tpch_pan
     assert n == groups and first_slots == [1024]
     assert first.groupby_slots >= groups > 1024  # it grew, inside the run
     n, again_slots, again = run()
-    assert n == groups and again.compiles == 0
+    # the one program a table size that is new to the replay: its initial state (PR 39:
+    # `agg.hash.init`; the regrow made its tables inside the rehash)
+    assert n == groups and [site.split("/")[-1] for site, rec in again.sites.items()
+                            if rec.get("compiles")] == ["agg.hash.init"]
     assert again_slots == [first.groupby_slots] and again.groupby_slots == first.groupby_slots
+    assert run()[2].compiles == 0
     # no rehash and no chunk inserted twice: fewer lanes than the run that grew
     assert 0 < again.groupby_insert_lanes < first.groupby_insert_lanes
 

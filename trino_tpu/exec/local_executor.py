@@ -464,6 +464,9 @@ class _Stream:
     # match and gather steps (_compacted_stream), or nowhere because its first
     # page stayed dense.  Later joins of the chain run fused at the width
     # that left
+    passes_valid: bool = False  # the transform hands its input's validity
+    # mask on untouched (a source, projections over one): a packed page that
+    # knows its live count (Page.live) is still packed behind it
     _jitted: Callable = None  # cached jit of transform applied to a Page
     _batch_jitted: Callable = None  # cached jit of transform over a STACKED
     # group of uniform pages (dispatch coalescing; retraces per group arity)
@@ -1113,7 +1116,13 @@ class LocalExecutor:
         # keep the row count ON DEVICE (async dispatch): forcing it here would pay a
         # device->host RTT per operator on the normal query path; EXPLAIN ANALYZE
         # materializes lazily when formatting
-        s["rows"] = jnp.sum(page.valid_mask(), dtype=jnp.int64) if page.capacity else 0
+        # a page that knows its live count, or has no mask, costs no launch
+        if page.live is not None:
+            s["rows"] = page.live
+        elif page.valid is None:
+            s["rows"] = page.capacity
+        else:
+            s["rows"] = jnp.sum(page.valid, dtype=jnp.int64)
         s["wall_s"] += _time.perf_counter() - t0
 
     # ---------------------------------------------------------------- internal
@@ -1140,12 +1149,14 @@ class LocalExecutor:
         t0 = _time.perf_counter()
         if isinstance(node, P.Output):
             child, dicts = self._execute_to_page(node.child)
-            return Page(node.schema, child.columns, child.null_masks, child.valid), dicts
+            return Page(node.schema, child.columns, child.null_masks, child.valid,
+                        child.live), dicts
         if isinstance(node, P.Sort):
             child, dicts = self._execute_to_page(node.child)
             # device-resident input: sort on device and pull only live rows
             # (the host path pulls the whole capacity-padded page first)
             page = _sort_page_device(child, node.keys, dicts)
+            tracing.record_tail(compiled=page is not None)
             if page is None:
                 page = _sort_page(child, node.keys, dicts)
             self._record(node, page, t0)
@@ -1159,6 +1170,7 @@ class LocalExecutor:
                 child, dicts = self._execute_to_page(node.child.child)
                 page = _topn_page_device(child, node.child.keys, node.count,
                                          dicts)
+                tracing.record_tail(compiled=page is not None)
                 if page is None:
                     page = _topn_page(child, node.child.keys, node.count,
                                       dicts)
@@ -1422,7 +1434,8 @@ class LocalExecutor:
                     up.scan_info.columns[e.index] if isinstance(e, FieldRef) else None
                     for e in node.exprs))
             return _Stream(node.schema, dicts, up.pages, transform, si, aux=up.aux,
-                           clustered_by=up.clustered_by, compacted=up.compacted)
+                           clustered_by=up.clustered_by, compacted=up.compacted,
+                           passes_valid=up.passes_valid)
 
         if isinstance(node, P.Join):
             return self._compile_join(node)
@@ -1461,7 +1474,8 @@ class LocalExecutor:
                     pg, _ = self._execute_to_page(node)
                     yield pg
 
-            return _Stream(node.schema, dicts, pages, lambda c, n, v, aux: (c, n, v))
+            return _Stream(node.schema, dicts, pages, lambda c, n, v, aux: (c, n, v),
+                           passes_valid=True)
 
         raise NotImplementedError(f"node {type(node).__name__}")
 
@@ -1541,6 +1555,23 @@ class LocalExecutor:
                         rng = (int(r[0]), int(r[1]))
                 out.append(rng)
         return tuple(out)
+
+    def _key_nullable(self, node, stream, first):
+        """Per group key, whether the stream's transform hands it a null
+        mask: a fact of the plan, which ``jax.eval_shape`` learns by tracing
+        the whole transform over the first page's shapes.  Kept with the
+        node's other artefacts, so a replay traces nothing."""
+        cacheable = self._agg_cacheable(node)
+        hit = self._agg_cache.get(("nullable", id(node))) if cacheable else None
+        if hit is not None:
+            return hit[1]
+        _, onulls, _ = jax.eval_shape(
+            lambda c, n, v, aux: stream.transform(c, n, v, aux),
+            first.columns, first.null_masks, first.valid_mask(), stream.aux)
+        key_nullable = tuple(onulls[i] is not None for i in node.keys)
+        if cacheable:
+            self._agg_cache[("nullable", id(node))] = (node, key_nullable)
+        return key_nullable
 
     def _direct_step(self, node, cfg, stream, key_types, acc_exprs, acc_kinds):
         """Jitted direct-indexed insert steps (cached per (node, cfg)):
@@ -2066,12 +2097,8 @@ class LocalExecutor:
         if first is not None:
             key_ranges = self._key_ranges(stream, node)
             if all(r is not None for r in key_ranges):
-                _, onulls, _ = jax.eval_shape(
-                    lambda c, n, v, aux: stream.transform(c, n, v, aux),
-                    first.columns, first.null_masks, first.valid_mask(),
-                    stream.aux)
-                key_nullable = tuple(onulls[i] is not None for i in node.keys)
-                cfg = hashagg.direct_config(key_ranges, key_nullable)
+                cfg = hashagg.direct_config(
+                    key_ranges, self._key_nullable(node, stream, first))
             if cfg is None and not node.capacity:
                 # hash mode: size the initial table from the key-range product
                 # and/or the input row bound so huge group counts don't crawl
@@ -2123,8 +2150,8 @@ class LocalExecutor:
         try:
             if cfg is not None:
                 with tracing.maybe_span("aggregate.direct", slots=cfg.capacity):
-                    state = hashagg.direct_groupby_init(
-                        cfg, tuple(t.dtype for t in key_types), acc_specs)
+                    state = _direct_init(cfg, tuple(t.dtype for t in key_types),
+                                         tuple(acc_specs))
                     dstep, bdstep = self._direct_step(node, cfg, stream,
                                                       key_types, acc_exprs,
                                                       acc_kinds)
@@ -2135,9 +2162,10 @@ class LocalExecutor:
                             else bdstep(state, tuple(group), live, stream.aux)
                     # the host waits HERE for every step it queued (a scan
                     # statement's one long wait): a pull like any other
-                    if not _host([state.overflow],
-                                 site="agg.direct.overflow")[0]:
-                        return self._finalize_groups(node, stream, state)
+                    out = self._finalize_groups(node, stream, state,
+                                                "agg.direct.overflow")
+                    if out is not None:
+                        return out
                 # stale stats put keys out of range: hash mode, over the
                 # whole input again
                 tracing.record_groupby(regrows=1)
@@ -2150,9 +2178,8 @@ class LocalExecutor:
                 resv["bytes"] = state_bytes(capacity)
                 pages_once = stream.pages()
             with tracing.maybe_span("aggregate.hash", slots=capacity):
-                state = hashagg.groupby_init(
-                    capacity, tuple(t.dtype for t in key_types), acc_specs
-                )
+                state = _hash_init(capacity, tuple(t.dtype for t in key_types),
+                                   tuple(acc_specs))
                 state = self._run_hash_inserts(node, stream, key_types, acc_exprs,
                                                acc_kinds, state, pages_once,
                                                state_bytes, resv,
@@ -2161,11 +2188,13 @@ class LocalExecutor:
                 # replay); a still-set overflow means the capacity/memory ceiling:
                 # fall back to partitioned passes (the HBM analog of the
                 # reference's SpillableHashAggregationBuilder)
-                if not _host([state.overflow], site="agg.hash.overflow")[0]:
+                out = self._finalize_groups(node, stream, state,
+                                            "agg.hash.overflow")
+                if out is not None:
                     if self._agg_cacheable(node):
                         self._agg_cache[("capacity", id(node))] = \
                             (node, state.capacity)
-                    return self._finalize_groups(node, stream, state)
+                    return out
             tracing.record_groupby(regrows=1)
             return self._run_aggregate_partitioned(node, parts=node.grace_parts or 4)
         finally:
@@ -2257,7 +2286,7 @@ class LocalExecutor:
                     else:
                         cinputs.append((rest_v.pop(0), rest_n.pop(0)))
                 state = insert_compact(state, ccols[:nk], cnulls[:nk],
-                                       tuple(cinputs), jnp.int32(n))
+                                       tuple(cinputs), np.int32(n))
                 tracing.record_groupby_insert(bucket)
             return state
 
@@ -2445,8 +2474,11 @@ class LocalExecutor:
             self.memory_pool.free(resv, "group-by")
 
     def _device_finalize(self, node: P.Aggregate):
-        """Jitted device finalization for one Aggregate's accumulator layout,
-        or None when an agg kind needs the host-exact path.  Cached per node."""
+        """The group-by's epilogue as ONE program a (node, bucket): the
+        occupied groups packed into ``bucket`` lanes, the accumulators
+        finalized on the device, the wide-decimal envelope flag, the group
+        count and the packed page's validity mask.  None when an agg kind
+        needs the host-exact path.  Cached per node."""
         hit = self._agg_cache.get(("devfin", id(node)))
         if hit is not None:
             return hit[1]
@@ -2455,42 +2487,75 @@ class LocalExecutor:
         except NotImplementedError:
             self._agg_cache[("devfin", id(node))] = (node, None)
             return None
-        fin = _jit(lambda accs, aggs=node.aggs:
-                      _finalize_aggs_device(aggs, accs),
-                   site="agg.finalize")
+
+        def finalize(state, bucket, aggs=node.aggs):
+            keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
+            cols, nulls, bad = _finalize_aggs_device(aggs, accs)
+            count = hashagg.group_count(state)
+            return (keys + cols, key_nulls + nulls,
+                    jnp.arange(bucket, dtype=jnp.int32) < count, count, bad)
+
+        fin = _jit(finalize, site="agg.finalize", static_argnums=(1,))
         self._agg_cache[("devfin", id(node))] = (node, fin)
         return fin
 
-    def _finalize_groups(self, node: P.Aggregate, stream, state):
-        # compact occupied groups ON DEVICE before any host transfer: the table is
-        # capacity-sized but group counts are usually tiny, and the device->host
-        # transfer is priced by the byte
-        n_groups = int(_host([hashagg.group_count(state)],
-                             site="agg.group_count")[0])
+    def _finalize_groups(self, node: P.Aggregate, stream, state, site=None):
+        """The page of a finished group-by state, device-resident: packed
+        into the pow2 bucket of its group count, with a validity mask and the
+        count it already knows (``Page.live``), so nothing downstream pulls or
+        reduces for it.  ``site`` names the pull that reads the state's
+        overflow flag here, WITH the group count and the envelope flag; the
+        answer is None when the flag is set.  A caller that has read the flag
+        itself passes no site.
+
+        The bucket is a static of the program and the count a device scalar,
+        so a cached plan runs the program at the bucket its last run found
+        and reads all three scalars in ONE pull after it; a first run (or a
+        count that left its bucket) counts first and finalizes second."""
+        dicts = tuple(stream.dicts[i] for i in node.keys) + tuple(None for _ in node.aggs)
+        fin = self._device_finalize(node)
+        cacheable = self._agg_cacheable(node)
+        learned = self._agg_cache.get(("bucket", id(node))) \
+            if cacheable and fin is not None else None
+        guess = None if learned is None else learned[1]
+        out, bad = None, fin is None  # (bad: the host-exact path answers)
+        if guess is None:
+            scalars = [_group_count(state)]
+        else:
+            out = fin(state, guess)
+            scalars = [out[3], out[4]]
+        got = _host(scalars + ([state.overflow] if site else []),
+                    site=site or ("agg.group_count" if guess is None
+                                  else "agg.finalize.envelope"))
+        if site and got[-1]:
+            return None
+        n_groups = int(got[0])
         bucket = max(1 << max(n_groups - 1, 1).bit_length(), 64)
-        keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
+        if guess == bucket:
+            bad = bool(got[1])
+        elif fin is not None:
+            out = fin(state, bucket)
+            bad = bool(_host([out[4]], site="agg.finalize.envelope")[0])
+            if cacheable:
+                self._agg_cache[("bucket", id(node))] = (node, bucket)
         tracing.record_compaction(state.capacity, bucket)
         tracing.record_groupby(slots=state.capacity)
-        nk = len(keys)
-        dicts = tuple(stream.dicts[i] for i in node.keys) + tuple(None for _ in node.aggs)
 
         # DEVICE-RESIDENT finalize (round 5): the aggregate output
         # stays on device, so a downstream projection/join/topn consumes it
         # without the pull-down + re-upload pair the host page costs
-        # (the full-width _host pull here was the single largest Q3 transfer).  One scalar sync checks the
-        # wide-decimal exact-int64 envelope; outside it, fall through to the
-        # host-exact path below (the _combine_limbs_vec fallback class).
-        fin = self._device_finalize(node)
-        if fin is not None:
-            fin_cols, fin_nulls, bad = fin(tuple(accs))
-            if not _host([bad], site="agg.finalize.envelope")[0]:
-                out_cols = tuple(k[:n_groups] for k in keys) \
-                    + tuple(c[:n_groups] for c in fin_cols)
-                out_nulls = tuple(kn[:n_groups] for kn in key_nulls) + tuple(
-                    None if fn is None else fn[:n_groups] for fn in fin_nulls)
-                page = Page(node.schema, out_cols, out_nulls, None)
-                return page, dicts
+        # (the full-width _host pull here was the single largest Q3 transfer).
+        # The envelope flag says a wide-decimal sum left exact int64: then,
+        # and for agg kinds without a device finalize, the host-exact path
+        # below (the _combine_limbs_vec fallback class).
+        if not bad:
+            tracing.record_tail(compiled=True)
+            cols, nulls, valid = out[:3]
+            return Page(node.schema, cols, nulls, valid, n_groups), dicts
 
+        tracing.record_tail(compiled=False)
+        keys, key_nulls, accs = hashagg.compact_groups(state, bucket)
+        nk = len(keys)
         got = _host(list(keys) + list(key_nulls) + list(accs),
                     site="agg.groups")
         key_cols = [k[:n_groups] for k in got[:nk]]
@@ -2627,10 +2692,12 @@ class LocalExecutor:
         flat = _host(flat, site="agg.stream.pull")
         w = len(node.schema.fields)
         host_pages = []
-        for pi in range(len(pages_out)):
+        for pi, p in enumerate(pages_out):
             base = pi * 2 * w
-            host_pages.append((flat[base:base + w],
-                               flat[base + w:base + 2 * w]))
+            # a packed partition page: its groups are its first ``live`` lanes
+            host_pages.append(tuple(
+                [a if a is None or p.live is None else a[:p.live] for a in part]
+                for part in (flat[base:base + w], flat[base + w:base + 2 * w])))
         cols = tuple(np.concatenate([hp[0][i] for hp in host_pages])
                      for i in range(w))
         nulls = []
@@ -3836,6 +3903,26 @@ def _finalize_aggs_device(aggs, acc_cols):
     return tuple(out), tuple(nulls), bad
 
 
+@partial(_jit, site="agg.direct.init", static_argnums=(0, 1, 2))
+def _direct_init(cfg, key_dtypes, acc_specs):
+    """The direct group-by's initial state as one program a (config, key
+    dtypes, accumulator specs): every statement starts from it, and eager it
+    was a launch a fill.  Nothing is kept on the device between statements:
+    a 2^24-slot state is not pinned."""
+    return hashagg.direct_groupby_init(cfg, key_dtypes, acc_specs)
+
+
+@partial(_jit, site="agg.hash.init", static_argnums=(0, 1, 2))
+def _hash_init(capacity, key_dtypes, acc_specs):
+    """The hash group-by's, one program a (capacity, key dtypes, specs)."""
+    return hashagg.groupby_init(capacity, key_dtypes, acc_specs)
+
+
+@partial(_jit, site="agg.group_count")
+def _group_count(state):
+    return hashagg.group_count(state)
+
+
 def _compact_page(cols, nulls, valid, bucket: int):
     """_compacted_stream's step: the shared masked-lane pack
     (ops/arrays.compact_rows: live-lane index then gathers, or the round-13
@@ -3885,9 +3972,12 @@ def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
     def _drain():
         # one batched host sync per chunk of pages (per-page int() is a
         # blocking device->host sync per page); chunking bounds how many
-        # uncompacted pages sit on device at once
-        for (cols, nulls, valid), n in zip(
-                staged, [int(c) for c in _host(sums, site="compact.counts")]):
+        # uncompacted pages sit on device at once.  A count the source page
+        # already knew on the host (a host int among ``sums``) is not pulled
+        unknown = [c for c in sums if not isinstance(c, int)]
+        pulled = iter(_host(unknown, site="compact.counts") if unknown else ())
+        for (cols, nulls, valid), c in zip(staged, sums):
+            n = c if isinstance(c, int) else int(next(pulled))
             if n == 0:
                 continue
             if any(isinstance(c, np.ndarray) and c.dtype == object
@@ -3905,6 +3995,11 @@ def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
                 continue
             bucket = min(max(1 << max(n - 1, 1).bit_length(), 1024),
                          valid.shape[0])
+            if isinstance(c, int) and bucket == valid.shape[0]:
+                # packed already, at this very bucket: the pack would move
+                # no row
+                parts.append((cols, nulls, valid, n))
+                continue
             ccols, cnulls, pvalid = _compact_part_sized(
                 cols, nulls, valid, bucket)
             tracing.record_compaction(valid.shape[0], bucket)
@@ -3916,7 +4011,10 @@ def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
         cols, nulls, valid = step(group[0]) if live is None \
             else bstep(group, live)
         staged.append((cols, nulls, valid))
-        sums.append(jnp.sum(valid, dtype=jnp.int32))
+        if live is None and group[0].live is not None and stream.passes_valid:
+            sums.append(group[0].live)  # a packed page, still packed
+        else:
+            sums.append(jnp.sum(valid, dtype=jnp.int32))
         if len(staged) >= 8:
             _drain()
     _drain()
@@ -3933,8 +4031,8 @@ def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
         # whole scan as one page): there is nothing to concatenate — the
         # compacted part IS the page, and its validity mask was computed
         # inside the _compact_part_sized dispatch (no extra device op at all)
-        ccols, cnulls, pvalid, _ = parts[0]
-        return Page(stream.schema, ccols, cnulls, pvalid)
+        ccols, cnulls, pvalid, n = parts[0]
+        return Page(stream.schema, ccols, cnulls, pvalid, n)
     if any(isinstance(c, np.ndarray) and c.dtype == object
            for c in parts[0][0]):
         # host concat for exact wide-decimal parts (host-compacted above)
@@ -4841,9 +4939,20 @@ def _host(arrays, site=None):
                              site=site or "")
 
 
+@partial(_jit, site="page.head", static_argnums=(2,))
+def _head_rows(cols, nulls, count: int):
+    return (tuple(c[:count] for c in cols),
+            tuple(None if n is None else n[:count] for n in nulls))
+
+
 def _host_page(page: Page, site="page"):
     """(valid, cols, nulls) as numpy, fetched in ONE batched transfer.  A page with
     no validity mask gets a host-side ones() — no device fetch fabricated for it."""
+    if page.live is not None and page.live < page.capacity \
+            and all(isinstance(c, jax.Array) for c in page.columns):
+        # a packed device page: the wire carries its live rows, not its bucket
+        page = Page(page.schema, *_head_rows(page.columns, page.null_masks,
+                                             page.live))
     nc = len(page.columns)
     has_valid = page.valid is not None
     got = _host(list(page.columns) + list(page.null_masks)
@@ -4968,10 +5077,82 @@ def _sort_page_device(page: Page, keys, dicts=None):
     bit-packed on the wire.  The host path (_sort_page) pulls every lane of
     the page at full width before sorting; for a device-resident aggregate
     output that is pure transfer waste (measured: warm SF1 q9's ORDER BY pull
-    dropped 4200 -> 3041 bytes).  One extra scalar sync buys the live count.
+    dropped 4200 -> 3041 bytes).  A page that does not know its live count
+    (``Page.live``) pays one scalar sync for it.
     Returns None (host fallback) on host pages or unrankable keys, like
     _topn_page_device."""
     return _topn_page_device(page, keys, None, dicts)
+
+
+def _rank_lut_device(d):
+    """``_collation_rank_lut`` as the sort program's argument: a small one is
+    kept on the device beside the host copy (a dashboard sorts by the same
+    few-valued column every statement), a large one is handed over per sort
+    as before and pins nothing."""
+    rank = _collation_rank_lut(d)
+    if rank.nbytes > 1 << 20:
+        return rank
+    dev = getattr(d, "_rank_lut_device", None)
+    if dev is None or dev.shape[0] != len(rank):
+        dev = jax.device_put(rank)  # device-ok: a kept id->rank table of at most 1 MiB, an argument of the sort program, not a scan's page
+        try:
+            object.__setattr__(d, "_rank_lut_device", dev)
+        except Exception:
+            pass
+    return dev
+
+
+@partial(_jit, site="sort.count")
+def _live_count(valid):
+    return jnp.sum(valid, dtype=jnp.int64)
+
+
+@partial(_jit, site="sort.rows", static_argnums=(4, 5, 6, 7, 8))
+def _sorted_rows(cols, nulls, valid, luts, keys, count, select, narrow,
+                 fetch_valid):
+    """The device part of a Sort or TopN as ONE program a (schema, sort keys,
+    count, capacity, which masks exist): collation ranks, the lex keys, a
+    keys-only sort (or ``first_rows``' selection) with the gathers of every
+    column and mask behind it, the narrowing casts and the bit-packing: what
+    the host then pulls, in the order it unpacks.  ``keys`` is ``(channel,
+    ascending, nulls_first, position in luts or -1)`` in ORDER BY order,
+    ``narrow`` the wire dtype of each column or None."""
+    lex = []
+    for channel, ascending, nulls_first, lut in reversed(keys):
+        c = cols[channel]
+        if lut >= 0:
+            rank = luts[lut]
+            c = rank[jnp.clip(c, 0, max(rank.shape[0] - 1, 0))]
+        if c.dtype == bool:
+            c = c.astype(jnp.int8)
+        nm = nulls[channel]
+        if nm is not None:
+            # NULL lanes hold arbitrary fill values: pin them to one constant
+            # so secondary keys keep breaking ties among NULL rows (the host
+            # path's equivalent pin in _sort_page)
+            c = jnp.where(nm, jnp.zeros((), c.dtype), c)
+        if not ascending:
+            c = ~c if jnp.issubdtype(c.dtype, jnp.integer) else -c
+        lex.append(c)
+        if nm is not None:
+            # null placement outranks the value ordering for this key (a key
+            # without a mask has a constant indicator, which moves no row)
+            ind = nm.astype(jnp.int8)
+            lex.append(-ind if nulls_first else ind)
+    if valid is not None:
+        lex.append(~valid)  # invalid lanes last — top-count rows are live ones
+    if select:
+        idx = first_rows(tuple(lex), count)
+    else:
+        idx = jnp.lexsort(tuple(lex))[:count]
+    fetch = [c[idx] if nd is None else c[idx].astype(nd)
+             for c, nd in zip(cols, narrow)]
+    # boolean masks ship BIT-packed (8x): the result pull
+    # is byte-priced, and masks are the compressible half of a narrow result
+    fetch += [jnp.packbits(nm[idx]) for nm in nulls if nm is not None]
+    if fetch_valid:
+        fetch.append(jnp.packbits(valid[idx]))
+    return fetch
 
 
 def _topn_page_device(page: Page, keys, count, dicts=None):
@@ -4980,81 +5161,61 @@ def _topn_page_device(page: Page, keys, count, dicts=None):
     input page (often a 100k+-row aggregate output) before sorting, and
     that transfer is most of what such a query pulls (round-5 Q3 finding).  Returns None when the page is host-resident or a sort key
     cannot rank on device (formatter dictionaries, object-dtype decimals);
-    the caller falls back to the host path."""
+    the caller falls back to the host path.  Everything the device does is
+    the one program ``_sorted_rows``; here is what the host decides from the
+    page and the plan, and the pull."""
     if not page.capacity \
             or not all(isinstance(c, jax.Array) for c in page.columns):
         return None
-    lex = []
-    for k in reversed(keys):
-        c = page.columns[k.channel]
+    luts, spec, floating = [], [], False
+    for k in keys:
         t = page.schema.fields[k.channel].type
         d = dicts[k.channel] if dicts is not None else None
+        lut = -1
         if t.is_string:
             if d is None or getattr(d, "values", None) is None:
                 return None
-            rank = _collation_rank_lut(d)
-            c = jnp.asarray(rank)[jnp.clip(c, 0, max(len(rank) - 1, 0))]
-        if c.dtype == bool:
-            c = c.astype(jnp.int8)
-        nm = page.null_masks[k.channel]
-        if nm is not None:
-            # NULL lanes hold arbitrary fill values: pin them to one constant
-            # so secondary keys keep breaking ties among NULL rows (the host
-            # path's equivalent pin in _sort_page)
-            c = jnp.where(nm, jnp.zeros((), c.dtype), c)
-        if not k.ascending:
-            c = ~c if jnp.issubdtype(c.dtype, jnp.integer) else -c
-        lex.append(c)
-        if nm is not None:
-            # null placement outranks the value ordering for this key (a key
-            # without a mask has a constant indicator, which moves no row)
-            ind = nm.astype(jnp.int8)
-            lex.append(-ind if k.nulls_first else ind)
-    valid = page.valid_mask()
-    if page.valid is not None:
-        lex.append(~valid)  # invalid lanes last — top-count rows are live ones
-    # count=None (full device sort): fetch exactly the live rows.  The live
-    # count syncs through _host (counted, batched-API) and only AFTER every
-    # rankability check above — a fallback to the host path must not pay a
-    # wasted round-trip first.
-    all_live = count is None
-    if all_live:
-        count = int(_host([jnp.sum(valid, dtype=jnp.int64)],
-                          site="sort.count")[0])
+            lut = len(luts)
+            luts.append(_rank_lut_device(d))
+        elif jnp.issubdtype(page.columns[k.channel].dtype, jnp.floating):
+            floating = True
+        spec.append((k.channel, bool(k.ascending), bool(k.nulls_first), lut))
+    # count=None (full device sort): fetch exactly the live rows.  A page
+    # that knows its live count says it; any other syncs it through _host
+    # (counted, batched-API) and only AFTER every rankability check above —
+    # a fallback to the host path must not pay a wasted round-trip first.
     n = page.capacity
-    if not all_live and count <= TOPN_SELECT_MAX \
-            and count * n <= TOPN_SELECT_WORK \
-            and not any(jnp.issubdtype(c.dtype, jnp.floating) for c in lex):
-        idx = first_rows(tuple(lex), min(count, n))
-    else:
-        idx = jnp.lexsort(tuple(lex))[:count]
+    live = n if page.valid is None else page.live
+    if count is None and live is None:
+        live = int(_host([_live_count(page.valid)], site="sort.count")[0])
+    select = count is not None and count <= TOPN_SELECT_MAX \
+        and count * n <= TOPN_SELECT_WORK and not floating
+    # every fetched row is live by construction when the live count bounds
+    # the fetch: no validity fetch and no filter then
+    fetch_valid = live is None
+    count = min(n if count is None else count, n if live is None else live)
     nc = len(page.columns)
+    if not count:
+        return Page(page.schema,
+                    tuple(np.zeros((0,), c.dtype) for c in page.columns),
+                    tuple(None if nm is None else np.zeros((0,), bool)
+                          for nm in page.null_masks), None)
     # transfer-narrow dictionary-id columns (id bound known from the dict, no
     # sync); the schema dtype is restored host-side after the pull, so only
     # the wire format shrinks
-    wide = []
-    fetch = []
+    narrow = []
     for ci, c in enumerate(page.columns):
-        cc = c[idx]
         nd = None
         if page.schema.fields[ci].type.is_string:
             nd = _narrow_pull_dtype(dicts[ci] if dicts is not None else None)
-        if nd is not None and jnp.issubdtype(cc.dtype, jnp.integer) \
-                and np.dtype(nd).itemsize < np.dtype(cc.dtype).itemsize:
-            wide.append(np.dtype(cc.dtype))
-            cc = cc.astype(nd)
+        if nd is not None and jnp.issubdtype(c.dtype, jnp.integer) \
+                and np.dtype(nd).itemsize < np.dtype(c.dtype).itemsize:
+            narrow.append(np.dtype(nd))
         else:
-            wide.append(None)
-        fetch.append(cc)
-    # boolean masks ship BIT-packed (8x): the result pull
-    # is byte-priced, and masks are the compressible half of a narrow result.
-    # ``all_live`` (full device sort: every fetched row is live by
-    # construction) skips the validity fetch and filter entirely.
-    fetch += [jnp.packbits(nm[idx]) for nm in page.null_masks
-              if nm is not None]
-    if not all_live:
-        fetch.append(jnp.packbits(valid[idx]))
-    got = _host(fetch, site="sort.pull")
+            narrow.append(None)
+    got = _host(_sorted_rows(page.columns, page.null_masks, page.valid,
+                             tuple(luts), tuple(spec), count, select,
+                             tuple(narrow), fetch_valid), site="sort.pull")
     m = len(got[0]) if nc else 0
 
     def unpack(b):
@@ -5068,9 +5229,9 @@ def _topn_page_device(page: Page, keys, count, dicts=None):
         else:
             nulls.append(unpack(got[pos]))
             pos += 1
-    cols = tuple(c if w is None else c.astype(w)
-                 for c, w in zip(got[:nc], wide))
-    if not all_live:
+    cols = tuple(c if nd is None else c.astype(pc.dtype)
+                 for c, nd, pc in zip(got[:nc], narrow, page.columns))
+    if fetch_valid:
         v = unpack(got[pos])
         cols = tuple(c[v] for c in cols)
         nulls = [None if nm is None else nm[v] for nm in nulls]

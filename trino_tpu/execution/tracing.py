@@ -291,6 +291,13 @@ class QueryCounters:
     # 0, and so does the Pallas kernel (tables of 2^16 slots or fewer on the
     # chip), which has no rounds
     join_hash_probe_round_lanes: int = 0
+    # PR 39: how a group-by's finalize and a Sort or TopN ran, one count
+    # each: as a compiled program over a device-resident page
+    # (local_executor._device_finalize, _sorted_rows), or on the eager/host
+    # path (a host-resident page, an unrankable sort key, an agg kind or a
+    # wide-decimal sum that needs the host-exact finalize)
+    tail_compiled: int = 0
+    tail_eager: int = 0
     # PR 32: the mesh path.  Rows the statement's all-to-all exchanges
     # delivered and the fullest worker's share of them, summed over its
     # exchanges from the receive cursors and occupancy counts the exchange
@@ -386,6 +393,7 @@ class QueryCounters:
                    "join_hash_probe_lanes", "join_direct_probe_lanes",
                    "join_hash_table_slots", "groupby_insert_lanes",
                    "join_hash_probe_round_lanes",
+                   "tail_compiled", "tail_eager",
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
                    "probe_exchange_rows", "probe_exchange_lanes",
@@ -631,6 +639,15 @@ def record_compaction(lanes_in: int, lanes_out: int) -> None:
         c.compactions += 1
         c.compact_lanes_in += lanes_in
         c.compact_lanes_out += lanes_out
+
+
+def record_tail(compiled: bool) -> None:
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        if compiled:
+            c.tail_compiled += 1
+        else:
+            c.tail_eager += 1
 
 
 def record_join_probe(match_lanes: int, gather_lanes: int) -> None:
@@ -913,7 +930,9 @@ def arg_signature(args, kw=None):
         shape = getattr(x, "shape", None)
         dtype = getattr(x, "dtype", None)
         if shape is not None and dtype is not None:
-            key.append(("a", tuple(shape), str(dtype)))
+            # (the dtype itself, not its name: hashable, and naming it was a
+            # quarter of a warm dispatch's host time)
+            key.append(("a", tuple(shape), dtype))
         elif isinstance(x, (bool, int, float, str, bytes, type(None))):
             key.append(("v", x))
         else:

@@ -76,13 +76,18 @@ class Page:
 
     ``columns[i]`` is a jnp array of shape ``(capacity,)`` (dtype per ``schema``);
     ``null_masks[i]`` is an optional bool array (True = NULL); ``valid`` is an optional
-    bool row mask (None = all ``capacity`` rows are live).
+    bool row mask (None = all ``capacity`` rows are live).  ``live`` is an optional
+    HOST int: the page is packed (its live rows are its first ``live`` lanes) and
+    whoever made it already knows the count, so a consumer neither pulls nor
+    reduces for it.  It is no part of the pytree: a page that crosses a jit
+    boundary forgets it.
     """
 
     schema: Schema
     columns: tuple
     null_masks: tuple
     valid: Optional[jnp.ndarray] = None
+    live: Optional[int] = None
 
     # -- pytree protocol --------------------------------------------------------
     def tree_flatten(self):

@@ -805,6 +805,10 @@ class CoordinatorServer:
                     ("join_hash_probe_round_lanes", "Lanes the hashed "
                      "probes of split joins gathered for, rounds times "
                      "width."),
+                    ("tail_compiled", "Group-by finalizes and Sorts/TopNs "
+                     "that ran as one compiled program."),
+                    ("tail_eager", "Group-by finalizes and Sorts/TopNs that "
+                     "fell back to the eager/host path."),
                     ("join_direct_probe_lanes", "Lanes that joins probed "
                      "through the one gather of a direct-indexed table."),
                     ("join_hash_table_slots", "Slots of the hashed join "

@@ -89,6 +89,12 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"Compaction: {cp} compactions, "
                 f"{getattr(counters, 'compact_lanes_in', 0)} lanes in, "
                 f"{getattr(counters, 'compact_lanes_out', 0)} lanes out")
+        tc = getattr(counters, "tail_compiled", 0)
+        te = getattr(counters, "tail_eager", 0)
+        if tc or te:
+            # group-by finalizes and Sorts/TopNs (PR 39): run as a compiled
+            # program, or on the eager/host path
+            lines.append(f"Tail: {tc} compiled, {te} eager")
         jm = getattr(counters, "join_match_lanes", 0)
         jh = getattr(counters, "join_hash_probe_lanes", 0)
         jd = getattr(counters, "join_direct_probe_lanes", 0)
