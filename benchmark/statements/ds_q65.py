@@ -5,19 +5,18 @@ value 1176: 1176..1187); ``0.1`` is part of the template's text and is a paramet
 only so that a test at a tiny scale, where no pair falls under a tenth, can ask for an
 answer that has rows.
 
-The statement is in no cell yet (its first run is still too long for a new cell's
-parent run: PERF.md section 6, PR 36), so it lives beside the tier-1 test that runs it
-and moves to ``benchmark/statements/`` with its cell.
-
 ORDER BY s_store_name, i_item_desc leaves ties in this deployment (the repo's generator
 repeats a store name every 12 stores and an item description every 18,000 items: 45 of
-the answer's 100 rows at scale 10 share both keys with another row), and the text is not
-changed for it.  SQL leaves the order inside a tie group, and which rows of a group that
-the LIMIT cuts are kept, to the engine.  So the REFERENCE breaks ties, by the remaining
-columns in SELECT order (revenue, i_current_price, i_wholesale_cost, i_brand), and can
-give the answer without its LIMIT; a comparison puts the engine's rows in the same order
-inside each tie group and holds the rows of a cut group to membership in the reference's
-(``tests/test_tpcds_hash_cell.tie_aligned``)."""
+the answer's 100 rows at scale 10 share both keys with another row).  SQL leaves the
+order inside a tie group, and which rows of a group that the LIMIT cuts are kept, to the
+engine, and the harness's comparison is positional.  So the cell's text, ``SQL``, departs
+from the template in ONE place: its ORDER BY goes on with the remaining SELECT columns in
+SELECT order (sc.revenue, i_current_price, i_wholesale_cost, i_brand), which makes the
+order total; joins, group-bys, predicate, LIMIT and every column are the template's.  The
+reference sorts by exactly these six.  The template's own text stays here as
+``TEMPLATE_SQL`` (``render_template``) for the tier-1 test that compares tie groups as
+sets (``tests/test_tpcds_hash_cell.tie_aligned``); when the harness has a tie-aware
+comparison the cell's text goes back to it (ROADMAP S7)."""
 
 from fractions import Fraction
 
@@ -31,7 +30,7 @@ TABLES = {"store_sales": ["ss_sold_date_sk", "ss_item_sk", "ss_store_sk", "ss_sa
                    "i_brand"]}
 VALIDATION = {"dms": 1176, "factor": "0.1"}  # query65.tpl, qualification substitution
 
-SQL = """
+TEMPLATE_SQL = """
 select s_store_name, i_item_desc, sc.revenue, i_current_price, i_wholesale_cost, i_brand
 from store, item,
      (select ss_store_sk, avg(revenue) as ave
@@ -50,6 +49,12 @@ where sb.ss_store_sk = sc.ss_store_sk and
       i_item_sk = sc.ss_item_sk
 order by s_store_name, i_item_desc
 limit 100"""
+ORDER_BY = ["s_store_name", "i_item_desc"]  # the template's: it leaves ties
+# the cell's text: the template's ORDER BY completed to a total order (module docstring)
+SQL = TEMPLATE_SQL.replace(
+    "order by s_store_name, i_item_desc",
+    "order by s_store_name, i_item_desc, sc.revenue, i_current_price, i_wholesale_cost, i_brand")
+assert SQL != TEMPLATE_SQL
 
 
 def params(rng, config):
@@ -60,7 +65,9 @@ def render(p):
     return SQL.format(**p), None
 
 
-ORDER_BY = ["s_store_name", "i_item_desc"]
+def render_template(p):
+    """query65.tpl as it is written."""
+    return TEMPLATE_SQL.format(**p), None
 
 
 def reference(T, p, dtype=np.float64, limit=100):

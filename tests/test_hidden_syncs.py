@@ -137,7 +137,8 @@ def test_a_planted_bool_of_a_device_scalar_is_caught(engines, monkeypatch):
 def test_the_group_bys_syncs_are_host_pull_spans_with_their_sites(engines):
     engine = engines["plain"]
     session = engine.create_session("tpch")
-    engine.execute_sql(sql_of("q1"), session)
+    for _ in range(2):  # (a replay, whichever test of the module met the engine first)
+        engine.execute_sql(sql_of("q1"), session)
     pulls = [s["attributes"].get("site") for s in engine.last_query_trace["spans"]
              if s["name"] == "host_pull"]
     # PR 39: a replay reads the flag, the group count and the envelope flag in ONE
@@ -202,7 +203,12 @@ def test_explain_analyze_prints_the_generator_launches_and_where_the_remainder_s
     splits = len(conn.splits("lineitem"))
     assert f", {splits} generator launches" in text, text
     line = next(ln for ln in text.split("\n") if ln.startswith("Wall breakdown:"))
-    assert "host_pull" in line, line
+    # the wait for the device is asserted by its counter and its span: the line prints
+    # a bucket only when it does not round to nothing, which the host's speed decides
+    assert engine.last_query_counters.host_transfers > 0
+    pulls = [s for s in engine.tracer.spans_for(engine.last_query_trace["query_id"])
+             if s.name == "host_pull"]
+    assert any(s.attributes.get("site") == "agg.direct.overflow" for s in pulls), pulls
     if "[" in line:  # the remainder is over 5 % of the wall: its containers are named
         assert "aggregate.direct" in line or "execution" in line, line
 

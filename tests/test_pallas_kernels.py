@@ -88,7 +88,7 @@ def _build_xla(keys, C, valid=None):
     table0 = jnp.full((C + 1,), EMPTY_KEY, jnp.int64)
     pk.force(False)
     try:
-        table, slot, placed = hashagg._probe_insert(table0, packed, valid)
+        table, slot, placed, _ = hashagg._probe_insert(table0, packed, valid)
     finally:
         pk.force(None)
     rows = jnp.full((C + 1,), 2**31 - 1, jnp.int32).at[
@@ -157,7 +157,7 @@ def test_hash_probe_dictionary_id_key_mix():
                               ranges=((0, 1 << 20), (0, 499)))
     assert exact
     table0 = jnp.full((C + 1,), EMPTY_KEY, jnp.int64)
-    table, slot, placed = hashagg._probe_insert(table0, packed,
+    table, slot, placed, _ = hashagg._probe_insert(table0, packed,
                                                 jnp.ones((n,), bool))
     rows = jnp.arange(C + 1, dtype=jnp.int32)
     valid = jnp.asarray(rng.random(n) < 0.8)
@@ -189,7 +189,7 @@ def test_hash_insert_observable_parity(n, C_req, dup, seed):
     t0 = jnp.full((C + 1,), EMPTY_KEY, jnp.int64)
     pk.force(False)
     try:
-        tx, sx, px = hashagg._probe_insert(t0, packed, valid)
+        tx, sx, px, _ = hashagg._probe_insert(t0, packed, valid)
     finally:
         pk.force(None)
     tp, sp, pp = pk.hash_insert(t0, packed, valid, interpret=INTERPRET)
@@ -360,7 +360,7 @@ def test_shard_map_pallas_parity(forced):
         recv, rvalid = exchange_all_to_all(packed, pvalid, WORKER_AXIS, W)
         bpacked, _ = pack_keys((bkeys,), (BIGINT,))
         t0 = jnp.full((C + 1,), EMPTY_KEY, jnp.int64)
-        table, _, _ = hashagg._probe_insert(t0, bpacked,
+        table, _, _, _ = hashagg._probe_insert(t0, bpacked,
                                             jnp.ones(bkeys.shape, bool))
         slot, matched = hashjoin.probe_slots(table, (recv[0],), (BIGINT,),
                                              rvalid)

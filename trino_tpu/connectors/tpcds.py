@@ -28,7 +28,7 @@ import numpy as np
 
 from ..page import Field, Page, Schema
 from ..types import BIGINT, DATE, INTEGER, DecimalType, VarcharType
-from .tpch import Dictionary, _enum, _rand, _uniform, parse_date_literal
+from .tpch import Dictionary, WarmScans, _enum, _rand, _uniform, parse_date_literal
 
 __all__ = ["TpcdsConnector"]
 
@@ -1285,7 +1285,7 @@ class TpcdsSplit:
     hi: int
 
 
-class TpcdsConnector:
+class TpcdsConnector(WarmScans):
     name = "tpcds"
     supports_count_pushdown = True  # row counts are index-derived (exact)
     CACHEABLE_SCANS = True  # deterministic generator (see TpchConnector)
@@ -1296,6 +1296,7 @@ class TpcdsConnector:
     def __init__(self, sf: float = 1.0, split_rows: int = 1 << 20):
         self.sf = sf
         self.split_rows = split_rows
+        self._warming: dict = {}  # (table, columns) -> its generator's warm thread
 
     def tables(self):
         return sorted(SCHEMAS)
@@ -1353,6 +1354,7 @@ class TpcdsConnector:
     def generate(self, split: TpcdsSplit, columns=None) -> Page:
         schema = SCHEMAS[split.table]
         names = tuple(columns) if columns is not None else schema.names
+        self._await_warm(split.table, names)
         cols, valid = _jit_generate(split.table, self.sf, split.lo,
                                     split.hi - split.lo, names,
                                     self.table_bound(split.table))

@@ -451,7 +451,57 @@ class TpchSplit:
     hi: int
 
 
-class TpchConnector:
+class WarmScans:
+    """``warm_scan`` for a connector whose ``generate(split, columns)`` compiles one
+    program a (table, split length, column set): TpchConnector, and since PR 40
+    TpcdsConnector, whose four generators are 95 s of a cold q65 at scale 10.  The
+    connector's ``__init__`` sets ``_warming`` ((table, columns) -> the thread that
+    was started for it, None once a scan has waited for it) and its ``generate``
+    calls ``_await_warm`` first."""
+
+    def warm_scan(self, table: str, columns, generate=None) -> None:
+        """Start compiling the page generator of (table, columns) on a
+        background thread, once a connector: the executor calls this for every
+        scan of a plan before it runs the first, so that the compile of a probe
+        side's generator (45 s for four lineitem columns at SF10 on a v5e,
+        PERF.md PR 27) runs beside the build sides and not after them.  The
+        thread generates the first split's page and drops it, through
+        ``generate(split, columns)`` where the caller brings one (the
+        executor's, which records the launch and its compile).  (An
+        ahead-of-time ``lower().compile()`` was tried in its place: the first
+        ``sf10_scan`` run with it lost a quarter of its window, PERF.md PR 27.)"""
+        key = (table, tuple(columns))
+        if key in self._warming:
+            return
+        splits = self.splits(table)
+        if not splits:
+            self._warming[key] = None
+            return
+
+        def warm(split=splits[0], columns=list(columns),
+                 generate=generate or self.generate):
+            try:
+                generate(split, columns)
+            except Exception:
+                pass  # the scan itself will raise what is wrong
+
+        thread = threading.Thread(target=warm, daemon=True, name="generate-warm")
+        thread.start()  # (before it is published: only a started thread is joined)
+        self._warming[key] = thread
+
+    def _await_warm(self, table: str, columns) -> None:
+        """A scan that reaches a generator while its warm launch still compiles
+        waits for that compile and then finds the program, where it would
+        otherwise compile the same program a second time beside it (a scan
+        reached 30 s into a 70-s compile ended 30 s later than it had to)."""
+        key = (table, tuple(columns))
+        thread = self._warming.get(key)
+        if thread is not None and thread is not threading.current_thread():
+            thread.join()
+            self._warming[key] = None  # (warmed: later scans look no thread up)
+
+
+class TpchConnector(WarmScans):
     """Connector over generated TPC-H data (see trino_tpu.spi for the SPI contract)."""
 
     supports_count_pushdown = True  # via exact_row_count below
@@ -464,7 +514,7 @@ class TpchConnector:
     def __init__(self, sf: float = 1.0, split_rows: int = 1 << 20):
         self.sf = sf
         self.split_rows = split_rows
-        self._warmed: set = set()  # (table, columns) whose generator was started
+        self._warming: dict = {}  # (table, columns) -> its generator's warm thread
 
     # metadata ---------------------------------------------------------------
     def tables(self):
@@ -632,38 +682,11 @@ class TpchConnector:
         """Jit-compiled page generation for one split (shape class = split size)."""
         schema = TPCH_SCHEMAS[split.table]
         names = columns if columns is not None else schema.names
+        self._await_warm(split.table, names)
         out_schema = Schema(tuple(schema.field(n) for n in names))
         cols, valid = _jit_generate(split.table, self.sf, split.lo, split.hi - split.lo,
                                     self.table_bound(split.table), tuple(names))
         return Page(out_schema, cols, tuple(None for _ in cols), valid)
-
-    def warm_scan(self, table: str, columns, generate=None) -> None:
-        """Start compiling the page generator of (table, columns) on a
-        background thread, once a connector: the executor calls this for every
-        scan of a plan before it runs the first, so that the compile of a probe
-        side's generator (45 s for four lineitem columns at SF10 on a v5e,
-        PERF.md PR 27) runs beside the build sides and not after them.  The
-        thread generates the first split's page and drops it, through
-        ``generate(split, columns)`` where the caller brings one (the
-        executor's, which records the launch and its compile).  (An
-        ahead-of-time ``lower().compile()`` was tried in its place: the first
-        ``sf10_scan`` run with it lost a quarter of its window, PERF.md PR 27.)"""
-        key = (table, tuple(columns))
-        if key in self._warmed:
-            return
-        self._warmed.add(key)
-        splits = self.splits(table)
-        if not splits:
-            return
-
-        def warm(split=splits[0], columns=list(columns),
-                 generate=generate or self.generate):
-            try:
-                generate(split, columns)
-            except Exception:
-                pass  # the scan itself will raise what is wrong
-
-        threading.Thread(target=warm, daemon=True, name="generate-warm").start()
 
     def generate_traced(self, table: str, lo, length: int, columns):
         """Trace-time generation with traced ``lo`` and static ``length`` (for

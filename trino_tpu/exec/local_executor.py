@@ -392,6 +392,11 @@ DEFAULT_GROUP_CAPACITY = 1 << 16
 # the table under ~1.3GB of a 16GB-HBM budget — the memory pool still gates
 # the actual reservation)
 MAX_GROUP_CAPACITY = 1 << 25
+# the most slots an ESTIMATE gives a hash group-by's first run: estimates
+# overshoot (a row bound is no group count).  A table that was cut to it may
+# take one step of four times the slots for ROOM, before a page with more live
+# lanes than it has slots (_run_hash_inserts); else only an overflow grows it
+FIRST_CAPACITY_CAP = 1 << 20
 
 
 @dataclasses.dataclass
@@ -2094,6 +2099,7 @@ class LocalExecutor:
         page_iter = iter(stream.pages())
         first = next(page_iter, None)
         cfg = None
+        capped = False  # the estimate asked for more slots than its cap gives
         if first is not None:
             key_ranges = self._key_ranges(stream, node)
             if all(r is not None for r in key_ranges):
@@ -2113,7 +2119,8 @@ class LocalExecutor:
                     # modest cap: in-loop rehash makes undershoot cheap, while an
                     # oversized table costs a long cold compile
                     target = 1 << max(2 * est - 1, 1).bit_length()
-                    capacity = max(capacity, min(target, 1 << 20))
+                    capped = target > max(capacity, FIRST_CAPACITY_CAP)
+                    capacity = max(capacity, min(target, FIRST_CAPACITY_CAP))
         pages_once = itertools.chain([first], page_iter) if first is not None else ()
 
         # streaming (sorted-input) aggregation: the scan's declared sort order
@@ -2183,7 +2190,7 @@ class LocalExecutor:
                 state = self._run_hash_inserts(node, stream, key_types, acc_exprs,
                                                acc_kinds, state, pages_once,
                                                state_bytes, resv,
-                                               proven is not None)
+                                               proven is not None, capped)
                 # growth happens INSIDE the insert loop (snapshot + rehash + chunk
                 # replay); a still-set overflow means the capacity/memory ceiling:
                 # fall back to partitioned passes (the HBM analog of the
@@ -2202,7 +2209,8 @@ class LocalExecutor:
             self.memory_pool.free(resv["bytes"], "group-by")
 
     def _run_hash_inserts(self, node, stream, key_types, acc_exprs, acc_kinds,
-                          state, pages_iter, state_bytes, resv, proven=False):
+                          state, pages_iter, state_bytes, resv, proven=False,
+                          capped=False):
         """Insert a page stream into hash-mode group-by state, compacting live
         rows first when pages are sparse.  TPU scatters cost by page WIDTH (sink
         writes included), so a 5%-selective filter over a 4M-row page pays 20x
@@ -2215,7 +2223,21 @@ class LocalExecutor:
         only after the chunk: pages inserted into a table that has already
         overflowed run the probe loop to MAX_PROBES on every lane (TPC-DS q65
         at SF10: 78 of a group-by's 102 s, PERF.md PR 36) for a state the
-        regrow throws away."""
+        regrow throws away.  And a table that the estimate's cap cut short
+        (``capped``) takes ONE step of four times the slots for room, before
+        the first page that alone has more live lanes than the table has
+        slots: it would else fill up under the page, run the lanes that find
+        it full to MAX_PROBES, and be regrown from the chunk's start after
+        all (q65 again: both of its (store, item) group-bys, 87 s of a cold
+        statement's device time, PERF.md section 6, PR 40).  Live lanes are
+        not groups: one step, for one page's lanes (not the lanes so far: q3
+        at SF10 takes 3 M lanes for 113,513 groups), and only where the
+        estimate had asked for more (a table sized by default or by hand
+        grows by overflow alone: the avg over q65's 2.1 M pairs has 120
+        groups).  A replay starts where the last run ended and reads nothing
+        between its pages, as before.  Every insert step hands back the
+        rounds its loop ran, and the chunk's pull of the flag takes them
+        along (``ran``): ``groupby_insert_round_lanes``."""
         cacheable = self._agg_cacheable(node)
         arts = self._agg_cache.get(("hashpage", id(node))) if cacheable else None
         if arts is None:
@@ -2243,21 +2265,41 @@ class LocalExecutor:
                                acc_kinds=acc_kinds):
                 valid = jnp.arange(keys[0].shape[0], dtype=jnp.int32) < n
                 return hashagg.groupby_insert(state, keys, key_types, valid, inputs,
-                                              acc_kinds, knulls)
+                                              acc_kinds, knulls, with_rounds=True)
 
             @partial(_jit, site="agg.hash.insert_masked")
             def insert_masked(state, keys, knulls, inputs, valid,
                               key_types=key_types, acc_kinds=acc_kinds):
                 return hashagg.groupby_insert(state, keys, key_types, valid, inputs,
-                                              acc_kinds, knulls)
+                                              acc_kinds, knulls, with_rounds=True)
 
             arts = (node, prepare, bprepare, insert_compact, insert_masked)
             if cacheable:
                 self._agg_cache[("hashpage", id(node))] = arts
         _, prepare, bprepare, insert_compact, insert_masked = arts
         staged: list = []
+        ran: list = []  # (rounds an insert's loop ran: a device scalar, its width)
+        room = capped and not proven  # the one step for room is still to take
+
+        def grow(state, reserved):
+            """``state`` re-inserted into a table of four times the ``reserved``
+            slots (those of the table the reservation covers now); None at the
+            capacity or memory ceiling."""
+            grown = reserved * 4
+            delta = state_bytes(grown) - state_bytes(reserved)
+            if grown > MAX_GROUP_CAPACITY or not self.memory_pool.try_reserve(
+                    delta, "group-by"):
+                return None
+            resv["bytes"] += delta
+            out, rounds = hashagg.rehash(state, grown, tuple(acc_kinds),
+                                         with_rounds=True)
+            # (the rehash re-inserts every slot of the table it leaves)
+            tracing.record_groupby_insert(state.capacity)
+            ran.append((rounds, state.capacity))
+            return out
 
         def insert_chunk(state, counts):
+            nonlocal room
             for k, ((keys, knulls, inputs, valid, _), n) in enumerate(
                     zip(staged, counts)):
                 if n == 0:
@@ -2265,12 +2307,16 @@ class LocalExecutor:
                 if k and not proven and _host([state.overflow],
                                               site="agg.hash.overflow")[0]:
                     break  # the regrow replays the chunk: spare it the rest
+                if room and n > state.capacity:
+                    room = False
+                    state = grow(state, state.capacity) or state
                 width = valid.shape[0]
                 bucket = max(1 << max(n - 1, 1).bit_length(), 1024)
                 if bucket * 2 >= width:
                     # dense page: compaction would not shrink it meaningfully
-                    state = insert_masked(state, keys, knulls, inputs, valid)
+                    state, rounds = insert_masked(state, keys, knulls, inputs, valid)
                     tracing.record_groupby_insert(width)
+                    ran.append((rounds, width))
                     continue
                 cols_list = list(keys) + [v for v, _ in inputs if v is not None]
                 nulls_list = list(knulls) + [nu for v, nu in inputs if v is not None]
@@ -2285,9 +2331,10 @@ class LocalExecutor:
                         cinputs.append((None, None))
                     else:
                         cinputs.append((rest_v.pop(0), rest_n.pop(0)))
-                state = insert_compact(state, ccols[:nk], cnulls[:nk],
-                                       tuple(cinputs), np.int32(n))
+                state, rounds = insert_compact(state, ccols[:nk], cnulls[:nk],
+                                               tuple(cinputs), np.int32(n))
                 tracing.record_groupby_insert(bucket)
+                ran.append((rounds, bucket))
             return state
 
         def drain(state):
@@ -2302,19 +2349,22 @@ class LocalExecutor:
                 # chunk — never the whole input stream
                 start_state = state
                 state = insert_chunk(state, counts)
-                if not _host([state.overflow], site="agg.hash.overflow")[0]:
+                # the rounds the inserts' loops ran ride the flag's pull (what
+                # the failed attempt of a regrow ran counts too)
+                overflow, *rounds = _host(
+                    [state.overflow] + [r for r, _ in ran],
+                    site="agg.hash.overflow")
+                tracing.record_groupby_insert(0, round_lanes=sum(
+                    int(r) * width for r, (_, width) in zip(rounds, ran)))
+                ran.clear()
+                if not overflow:
                     staged.clear()
                     return state, False
-                grown = start_state.capacity * 4
-                delta = state_bytes(grown) - state_bytes(start_state.capacity)
-                if grown > MAX_GROUP_CAPACITY or not self.memory_pool.try_reserve(
-                        delta, "group-by"):
+                grown = grow(start_state, state.capacity)
+                if grown is None:
                     staged.clear()
                     return state, True  # ceiling: caller falls back to partitioned
-                resv["bytes"] += delta
-                state = hashagg.rehash(start_state, grown, tuple(acc_kinds))
-                # (the rehash re-inserts every slot of the table it leaves)
-                tracing.record_groupby_insert(start_state.capacity)
+                state = grown
 
         for group, live in _coalesced_batches(pages_iter,
                                                self._batch(stream)):

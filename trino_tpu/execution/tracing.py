@@ -291,6 +291,19 @@ class QueryCounters:
     # 0, and so does the Pallas kernel (tables of 2^16 slots or fewer on the
     # chip), which has no rounds
     join_hash_probe_round_lanes: int = 0
+    # PR 40: the lanes the group-by's hash insert PROBED for: the rounds of
+    # ops/hashagg._probe_insert's open-addressing loop (two gathers, a
+    # scatter-min and a set each, every one at the full width of its batch)
+    # times that width.  The rounds are device scalars that the insert steps
+    # of a hash-mode group-by (local_executor._run_hash_inserts:
+    # agg.hash.insert_compact, agg.hash.insert_masked, a regrow's rehash)
+    # hand back, and they ride the agg.hash.overflow pull that ends each
+    # chunk: no pull and no dispatch of its own.  Recorded THERE only:
+    # the sorted merge, Grace partitions, FTE's partial step and the mesh
+    # steps count their groupby_insert_lanes and leave this 0, and so does
+    # the Pallas kernel (tables of 2^16 slots or fewer on the chip), which
+    # has no rounds
+    groupby_insert_round_lanes: int = 0
     # PR 39: how a group-by's finalize and a Sort or TopN ran, one count
     # each: as a compiled program over a device-resident page
     # (local_executor._device_finalize, _sorted_rows), or on the eager/host
@@ -393,6 +406,7 @@ class QueryCounters:
                    "join_hash_probe_lanes", "join_direct_probe_lanes",
                    "join_hash_table_slots", "groupby_insert_lanes",
                    "join_hash_probe_round_lanes",
+                   "groupby_insert_round_lanes",
                    "tail_compiled", "tail_eager",
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
@@ -689,11 +703,14 @@ def record_probe_lanes(lanes: int, hashed: bool, round_lanes: int = 0) -> None:
             c.join_direct_probe_lanes += lanes
 
 
-def record_groupby_insert(lanes: int) -> None:
-    """Static lanes of one dispatch that runs ``hashagg.groupby_insert``."""
+def record_groupby_insert(lanes: int, round_lanes: int = 0) -> None:
+    """Static lanes of one dispatch that runs ``hashagg.groupby_insert``;
+    ``round_lanes``: the lanes its open-addressing rounds ran over, where the
+    site pulls them (a hash-mode group-by's insert loop)."""
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.groupby_insert_lanes += lanes
+        c.groupby_insert_round_lanes += round_lanes
 
 
 def record_rows_generated(rows: int) -> None:

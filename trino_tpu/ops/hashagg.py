@@ -238,7 +238,9 @@ def _onehot_agg_update(acc, kind, onehot, vals_nulls):
 
 def _probe_insert(table, packed, valid):
     """Assign each valid row a slot whose table word == its packed key; claim empty slots
-    deterministically. Returns (table, slot[int32], placed[bool]).
+    deterministically. Returns (table, slot[int32], placed[bool], rounds[int32]): the
+    rounds the open-addressing loop ran, every one at the full width of ``packed`` (0
+    from the Pallas kernel, which has no rounds to report).
 
     Round-13 backend split: capacities within `PALLAS_TABLE_MAX` route to the
     in-kernel claim loop (`pallas_kernels.hash_insert`).  Its contention
@@ -252,7 +254,8 @@ def _probe_insert(table, packed, valid):
 
     C = table.shape[0] - 1
     if pk.table_kernels_enabled(C) and packed.shape[0]:
-        return pk.hash_insert(table, packed, valid, max_probes=MAX_PROBES)
+        return pk.hash_insert(table, packed, valid, max_probes=MAX_PROBES) \
+            + (jnp.zeros((), jnp.int32),)
     h0 = splitmix64(packed)
     stp = probe_step(h0)
     # derive every loop carry from the (possibly device-varying) inputs: under
@@ -291,15 +294,16 @@ def _probe_insert(table, packed, valid):
         placed = placed | won
         return p + 1, table, slot, placed
 
-    _, table, slot, placed = jax.lax.while_loop(
+    rounds, table, slot, placed = jax.lax.while_loop(
         cond, body, (jnp.zeros((), jnp.int32), table, slot, placed))
-    return table, slot, placed
+    return table, slot, placed, rounds
 
 
 def groupby_insert(state: GroupByState, key_vals: Sequence, key_types, valid,
                    agg_inputs: Sequence, agg_updates: Sequence[str],
-                   key_nulls: Sequence = None) -> GroupByState:
-    """One page of input → updated state.
+                   key_nulls: Sequence = None, with_rounds: bool = False):
+    """One page of input → updated state; ``with_rounds``: (state, the rounds
+    ``_probe_insert`` ran, an int32 scalar).
 
     agg_inputs[i]: (value_array|None, input_null_mask|None); agg_updates[i]: update kind
     ('sum','count','min','max','count_star'); key_nulls[i]: null mask of key i or None
@@ -338,7 +342,7 @@ def groupby_insert(state: GroupByState, key_vals: Sequence, key_types, valid,
             pack_cols.append(mv)
             pack_types.append(kt)
         packed, exact = pack_keys(tuple(pack_cols), tuple(pack_types))
-    table, slot, placed = _probe_insert(state.table, packed, valid)
+    table, slot, placed, rounds = _probe_insert(state.table, packed, valid)
     overflow = state.overflow | jnp.any(valid & ~placed)
     live = valid & placed
 
@@ -356,7 +360,8 @@ def groupby_insert(state: GroupByState, key_vals: Sequence, key_types, valid,
         agg_update(acc, kind, slot, live, vals_nulls)
         for acc, kind, vals_nulls in zip(state.accs, agg_updates, agg_inputs)
     )
-    return GroupByState(table, key_cols, state_knulls, accs, overflow)
+    out = GroupByState(table, key_cols, state_knulls, accs, overflow)
+    return (out, rounds) if with_rounds else out
 
 
 def agg_update(acc, kind, slot, live, vals_nulls):
@@ -411,11 +416,13 @@ _REHASH_KIND = {"sum": "sum", "count": "sum", "count_star": "sum",
                 "sum_hi32": "sum", "sum_lo32": "sum"}
 
 
-@partial(jax.jit, static_argnums=(1, 2))  # compile-ok: module-level kernel invoked from exec's _jit-wrapped steps and driver loops; per-capacity compiles are bounded by pow2 growth
-def rehash(state: GroupByState, new_capacity: int, acc_kinds: tuple = ()) -> GroupByState:
+@partial(jax.jit, static_argnums=(1, 2, 3))  # compile-ok: module-level kernel invoked from exec's _jit-wrapped steps and driver loops; per-capacity compiles are bounded by pow2 growth
+def rehash(state: GroupByState, new_capacity: int, acc_kinds: tuple = (),
+           with_rounds: bool = False):
     """Re-insert every occupied entry into a larger table (reference:
     FlatHash#rehash).  Accumulators re-insert as partial values (count -> sum).
-    Keeps growth at one table-sized pass instead of re-streaming the input."""
+    Keeps growth at one table-sized pass instead of re-streaming the input.
+    ``with_rounds``: (state, the rounds the re-insert's loop ran)."""
     C = state.capacity
     occupied = state.table[:C] != EMPTY_KEY
     keys = tuple(k[:C] for k in state.key_cols)
@@ -432,7 +439,8 @@ def rehash(state: GroupByState, new_capacity: int, acc_kinds: tuple = ()) -> Gro
     key_types = tuple(_DTYPE_KEY_TYPE(k.dtype) for k in keys)
     merge = [_REHASH_KIND[k] for k in acc_kinds]
     return groupby_insert(fresh, keys, key_types, occupied,
-                          [(a, None) for a in accs], merge, knulls)
+                          [(a, None) for a in accs], merge, knulls,
+                          with_rounds=with_rounds)
 
 
 def _init_for(kind: str, dtype):

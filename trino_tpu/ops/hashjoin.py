@@ -85,7 +85,7 @@ def build_insert(jt: JoinTable, key_cols, key_types, valid) -> JoinTable:
 
     packed, _ = pack_keys(key_cols, key_types)
     packed = jnp.where(valid, packed, EMPTY_KEY - 1)
-    table, slot, placed = _probe_insert(jt.table, packed, valid)
+    table, slot, placed, _ = _probe_insert(jt.table, packed, valid)
     live = valid & placed
     C = jt.capacity
     row_idx = jnp.arange(packed.shape[0], dtype=jnp.int32)
@@ -442,7 +442,7 @@ def _multi_build_step(table0, key_cols, key_types, valid):
 
     packed, _ = pack_keys(key_cols, key_types)
     packed = jnp.where(valid, packed, EMPTY_KEY - 1)
-    table, slot, placed = _probe_insert(table0, packed, valid)
+    table, slot, placed, _ = _probe_insert(table0, packed, valid)
     C = table.shape[0] - 1
     live = valid & placed
     slot_v = jnp.where(live, slot, C).astype(jnp.int32)

@@ -816,6 +816,8 @@ class CoordinatorServer:
                     ("groupby_insert_lanes", "Lanes that entered the "
                      "group-by's hash insert loop (a regrow's rehash "
                      "included)."),
+                    ("groupby_insert_round_lanes", "Lanes the hash-mode "
+                     "group-by's inserts probed for, rounds times width."),
                     ("exchange_rows", "Rows the mesh executor's all-to-all "
                      "exchanges delivered (receive cursors and merged "
                      "group counts)."),
