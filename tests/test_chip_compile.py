@@ -240,12 +240,12 @@ def test_the_narrowed_hash_lookup_compiles_over_four_chips(topo, no_persistent_c
     2^21 slots (above `PALLAS_TABLE_MAX`: the XLA lookup), 2^20 received lanes a chip,
     inside shard_map with check_vma ON, once with a CONSTANT key (unvarying, against the
     per-worker table).  The packed levels are there: a sort a level, a loop a width."""
-    from trino_tpu.ops import hashjoin as hj
+    from trino_tpu.ops import hashing, hashjoin as hj
     from trino_tpu.parallel.mesh import WORKER_AXIS
     from trino_tpu.types import BIGINT
 
     W, slots, lanes = 4, 1 << 21, 1 << 20
-    assert len(hj.probe_widths(lanes)) == 1 + len(hj.NARROW_SHIFTS)
+    assert len(hj.probe_widths(lanes)) == 1 + len(hashing.NARROW_SHIFTS)
     mesh = Mesh(np.array(topo.devices).reshape(W), (WORKER_AXIS,))
 
     def frag(tables, keys, valid):
@@ -260,9 +260,46 @@ def test_the_narrowed_hash_lookup_compiles_over_four_chips(topo, no_persistent_c
     text = _compile(f, jax.ShapeDtypeStruct((W, slots + 1), jnp.int64, sharding=sharded),
                     jax.ShapeDtypeStruct((W, lanes), jnp.int64, sharding=sharded),
                     jax.ShapeDtypeStruct((W, lanes), jnp.bool_, sharding=sharded))
-    levels = 1 + len(hj.NARROW_SHIFTS)
+    levels = 1 + len(hashing.NARROW_SHIFTS)
     assert len(re.findall(r" while\(", text)) >= 2 * levels
     assert len(re.findall(r" sort\(", text)) >= 2 * (levels - 1)
+
+
+def test_the_narrowed_hash_insert_compiles_over_four_chips(topo, no_persistent_cache,
+                                                           monkeypatch):
+    """`ops/hashagg._probe_insert` (PR 41) inside shard_map with check_vma ON: a
+    per-worker table of 2^18 slots (above `PALLAS_TABLE_MAX`: the XLA claim loop) under
+    2^17 lanes a chip, once with a CONSTANT key (unvarying) against the per-worker table
+    and once with per-worker keys against a table made in the program (`groupby_init`:
+    unvarying).  The packed levels are there: a sort and a loop a level, each level
+    behind a conditional on what its wider loop left unplaced."""
+    from trino_tpu.ops import hashagg, hashing
+    from trino_tpu.ops.hashing import EMPTY_KEY
+    from trino_tpu.parallel.mesh import WORKER_AXIS
+
+    W, slots, lanes = 4, 1 << 18, 1 << 17
+    # (the insert's floor is 2^20 lanes: a sort a level at that width is a minute of
+    # this file's time; the levels' program is the same at 2^17)
+    monkeypatch.setattr(hashing, "INSERT_MIN_LANES", 1 << 16)
+    levels = len(hashagg.insert_widths(lanes))
+    assert levels == 1 + len(hashing.INSERT_SHIFTS)
+    mesh = Mesh(np.array(topo.devices).reshape(W), (WORKER_AXIS,))
+
+    def frag(tables, keys, valid):
+        out = hashagg._probe_insert(tables[0], jnp.ones((lanes,), jnp.int64), valid[0])
+        out += hashagg._probe_insert(jnp.full((slots + 1,), EMPTY_KEY, jnp.int64),
+                                     keys[0], valid[0])
+        return tuple(o[None] for o in out)
+
+    f = jax.shard_map(frag, mesh=mesh, in_specs=(PS(WORKER_AXIS),) * 3,
+                      out_specs=(PS(WORKER_AXIS),) * 8)
+    sharded = NamedSharding(mesh, PS(WORKER_AXIS))
+    text = _compile(f, jax.ShapeDtypeStruct((W, slots + 1), jnp.int64, sharding=sharded),
+                    jax.ShapeDtypeStruct((W, lanes), jnp.int64, sharding=sharded),
+                    jax.ShapeDtypeStruct((W, lanes), jnp.bool_, sharding=sharded))
+    assert len(re.findall(r" while\(", text)) >= 2 * levels
+    assert len(re.findall(r" sort\(", text)) >= 2 * (levels - 1)
+    assert len(re.findall(r" conditional\(", text)) >= 2 * (levels - 1)
 
 
 def _gathers_from_arguments(text):

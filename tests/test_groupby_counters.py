@@ -10,7 +10,7 @@ import pytest
 import trino_tpu.exec.local_executor as LE
 from trino_tpu import Engine
 from trino_tpu.connectors.tpch import TpchConnector
-from trino_tpu.ops import hashagg
+from trino_tpu.ops import hashagg, hashing
 
 INNER_Q18 = ("select l_orderkey, sum(l_quantity) q from lineitem "
              "group by l_orderkey order by q desc, l_orderkey limit 5")
@@ -195,3 +195,96 @@ def test_group_by_steps_are_named_by_mode():
     e.execute_sql(INNER_Q18)
     assert any(k.endswith("/agg.direct.batch") or k.endswith("/agg.direct.step")
                for k in e.last_query_counters.sites), e.last_query_counters.sites
+
+
+# -- the lanes the hash insert's rounds ran over (PR 40), a width a level (PR 41) --------
+HASHED = "select l_orderkey * 8 + l_linenumber k, count(*) n from lineitem group by 1"
+
+
+def _insert_rounds(monkeypatch, sql, floor=None, split_rows=4096, capacity=None):
+    """(counters of a replay, [(rounds at each width, lanes)] of its insert steps): the
+    vectors are read where the executor pulls them, behind the chunk's overflow flag."""
+    import numpy as np
+
+    from trino_tpu.execution import tracing
+    from trino_tpu.ops import hashing
+
+    if floor is not None:
+        monkeypatch.setattr(hashing, "INSERT_MIN_LANES", floor)
+    e = _engine(split_rows)
+    for _ in range(4):  # cold, the advisor's re-plan if it makes one, the replay
+        session = e.create_session("tpch")
+        if capacity:
+            session.properties["group_by_capacity"] = capacity
+        e.execute_sql(sql, session)
+        if not e.last_query_counters.compiles:
+            break
+    else:
+        raise AssertionError("no run without compiles in 4")
+    pulled, lanes = [], []
+    host, record = LE._host, tracing.record_groupby_insert
+
+    def spy_host(arrays, site=None):
+        out = host(arrays, site=site)
+        if site == "agg.hash.overflow":
+            # (the loop's end pulls scalars at this site too: the count, the envelope flag)
+            pulled.extend(np.asarray(r).tolist() for r in out[1:] if np.ndim(r) == 1)
+        return out
+
+    def spy_record(n, round_lanes=0):
+        if n:
+            lanes.append(n)
+        return record(n, round_lanes=round_lanes)
+
+    monkeypatch.setattr(LE, "_host", spy_host)
+    monkeypatch.setattr(tracing, "record_groupby_insert", spy_record)
+    e.execute_sql(sql, session)
+    c = e.last_query_counters
+    assert c.compiles == 0 and len(pulled) == len(lanes) > 0
+    assert sum(lanes) == c.groupby_insert_lanes
+    return c, list(zip(pulled, lanes))
+
+
+@pytest.mark.parametrize("floor", [None, 1024], ids=["floor-as-shipped", "floor-patched-down"])
+def test_insert_round_lanes_are_the_sum_over_levels_of_rounds_times_width(
+        floor, monkeypatch):
+    insert_widths = hashagg.insert_widths
+
+    c, steps = _insert_rounds(monkeypatch, HASHED, floor)
+    assert c.groupby_insert_round_lanes == sum(
+        r * w for rounds, lanes in steps for r, w in zip(rounds, insert_widths(lanes)))
+    if floor is None:
+        # under the floor the loop is the one loop it was: rounds x lanes
+        assert all(len(rounds) == 1 for rounds, _ in steps)
+        assert c.groupby_insert_round_lanes == sum(r[0] * n for r, n in steps)
+    else:
+        assert all(len(rounds) == 1 + len(hashing.INSERT_SHIFTS) for rounds, _ in steps)
+        assert any(sum(rounds[1:]) for rounds, _ in steps)
+        assert c.groupby_insert_round_lanes < sum(sum(r) * n for r, n in steps)
+
+
+def test_a_half_full_table_over_a_lowered_floor_runs_a_third_of_the_round_lanes(
+        monkeypatch):
+    """15,000 groups from ONE page (under the shipped floor) into 2^15 slots: the one loop
+    runs every round at the page's width, the levels the later ones at a quarter and a
+    sixty-fourth."""
+    sql = "select o_orderkey * 3 k, count(*) n from orders group by 1"
+    one = dict(split_rows=1 << 14, capacity=1 << 15)
+    c1, steps1 = _insert_rounds(monkeypatch, sql, None, **one)
+    (rounds1, lanes), = steps1
+    assert len(rounds1) == 1 and c1.groupby_insert_round_lanes == rounds1[0] * lanes
+    monkeypatch.undo()
+    c3, steps3 = _insert_rounds(monkeypatch, sql, 1024, **one)
+    (rounds3, lanes3), = steps3
+    assert lanes3 == lanes and sum(rounds3) == rounds1[0] >= 6
+    assert lanes <= c3.groupby_insert_round_lanes < c1.groupby_insert_round_lanes // 3
+    assert c3.groupby_insert_lanes == c1.groupby_insert_lanes == lanes
+
+
+def test_a_page_that_one_round_places_reports_exactly_its_lanes(monkeypatch):
+    """Seven groups in a roomy table: round 0 places every lane of every page, no level
+    runs, and rounds x width is the inserted lanes, over a lowered floor too."""
+    c, steps = _insert_rounds(
+        monkeypatch, "select l_linenumber * 3 k, count(*) n from lineitem group by 1", 1024)
+    assert all(rounds == [1] + [0] * len(hashing.INSERT_SHIFTS) for rounds, _ in steps), steps
+    assert c.groupby_insert_round_lanes == c.groupby_insert_lanes

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from trino_tpu.ops import hashjoin
+from trino_tpu.ops import hashing, hashjoin
 from trino_tpu.ops.hashing import (EMPTY_KEY, pack_keys, probe_step,
                                    splitmix64)
 from trino_tpu.ops.hashjoin import MAX_PROBES
@@ -50,7 +50,7 @@ def old_loop(table, packed, valid):
 
 @pytest.fixture
 def low_floor(monkeypatch):
-    monkeypatch.setattr(hashjoin, "NARROW_MIN_LANES", FLOOR)
+    monkeypatch.setattr(hashing, "NARROW_MIN_LANES", FLOOR)
 
 
 def _build(key_cols, slots=SLOTS):
@@ -205,10 +205,10 @@ def test_above_the_floor_the_later_rounds_run_narrow(low_floor):
 def test_below_the_floor_the_lookup_is_the_one_loop_it_was():
     """The program's own floor, unpatched: a tier-1 page is under it."""
     lanes = 1 << 13
-    assert lanes < hashjoin.NARROW_MIN_LANES
+    assert lanes < hashing.NARROW_MIN_LANES
     assert hashjoin.probe_widths(lanes) == (lanes,)
-    assert len(hashjoin.probe_widths(hashjoin.NARROW_MIN_LANES)) \
-        == 1 + len(hashjoin.NARROW_SHIFTS)
+    assert len(hashjoin.probe_widths(hashing.NARROW_MIN_LANES)) \
+        == 1 + len(hashing.NARROW_SHIFTS)
     jt, types, keys, valid = _half_full(np.random.default_rng(6), lanes)
     rounds, _ = _check(jt, types, (keys,), valid)  # one level: exactly the old count
     assert rounds.shape == (1,) and rounds[0] > 2

@@ -449,7 +449,7 @@ def _q93_replayed(conn):
                                   "direct-tables"])
 def test_a_split_join_records_the_lanes_its_probe_rounds_gathered_for(
         case, ds, tpch_sf001, monkeypatch):
-    from trino_tpu.ops import hashjoin
+    from trino_tpu.ops import hashing, hashjoin
 
     if case == "direct-tables":
         e = Engine()
@@ -467,8 +467,8 @@ def test_a_split_join_records_the_lanes_its_probe_rounds_gathered_for(
     assert w.join_hash_probe_round_lanes % lanes == 0
     assert lanes <= w.join_hash_probe_round_lanes <= hashjoin.MAX_PROBES * lanes
     if case == "q93-floor-patched-down":
-        monkeypatch.setattr(hashjoin, "NARROW_MIN_LANES", 1024)
-        assert len(hashjoin.probe_widths(lanes)) == 1 + len(hashjoin.NARROW_SHIFTS)
+        monkeypatch.setattr(hashing, "NARROW_MIN_LANES", 1024)
+        assert len(hashjoin.probe_widths(lanes)) == 1 + len(hashing.NARROW_SHIFTS)
         narrowed = _q93_replayed(conn)
         # the same lanes and the same rounds, the later ones at a quarter and less
         assert narrowed.join_hash_probe_lanes == lanes
@@ -561,8 +561,9 @@ def _insert_rounds_by_hand(packed, capacity):
 
 def test_the_insert_hands_back_rounds_times_width_on_a_known_collision_chain():
     """Three keys whose first probe is one slot: the smallest word claims it, the other
-    two go on, so the loop runs as many rounds as the longest chain, every one at the
-    width of the page; the count is what ``groupby_insert(with_rounds=True)`` returns."""
+    two go on, so the loop runs as many rounds as the longest chain, every one (under
+    `hashing.INSERT_MIN_LANES`) at the width of the page; the count is what
+    ``groupby_insert(with_rounds=True)`` returns, one entry a width."""
     import jax.numpy as jnp
     from trino_tpu.ops import hashagg, hashing
     from trino_tpu.types import BIGINT
@@ -580,7 +581,8 @@ def test_the_insert_hands_back_rounds_times_width_on_a_known_collision_chain():
         ("count_star",), with_rounds=True)
     packed = np.asarray(hashing.pack_keys((jnp.asarray(chain),), (BIGINT,))[0])
     want = _insert_rounds_by_hand([int(v) for v in packed], C)
-    assert int(rounds) == want >= 2 and not bool(state.overflow)
+    # (one entry a width of the page: 64 lanes are under the floor, one width)
+    assert rounds.tolist() == [want] and want >= 2 and not bool(state.overflow)
     assert int(np.asarray(state.accs[0])[:C].sum()) == 3
     # and the plain call is the state alone, as every other caller takes it
     again = hashagg.groupby_insert(
@@ -602,10 +604,10 @@ def test_the_insert_round_lanes_reach_explain_analyze_and_the_metrics(tpch_sf001
     w = _replayed(e, sql, "tpch")
     lanes = w.groupby_insert_lanes
     assert lanes > 0 and w.groupby_regrows == 0
-    # whole rounds of every inserted lane: at least one, at most MAX_PROBES
+    # rounds of every inserted lane: at least one, at most MAX_PROBES (whole rounds of
+    # the page under `hashing.INSERT_MIN_LANES`; over it the later rounds run narrower)
     from trino_tpu.ops import hashagg
 
-    assert w.groupby_insert_round_lanes % lanes == 0
     assert lanes <= w.groupby_insert_round_lanes <= hashagg.MAX_PROBES * lanes
     # the sum rides the overflow flag's pull, no pull and no dispatch of its own: the one
     # chunk's and the loop's end are the statement's two pulls at that site

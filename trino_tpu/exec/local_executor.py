@@ -2236,8 +2236,9 @@ class LocalExecutor:
         grows by overflow alone: the avg over q65's 2.1 M pairs has 120
         groups).  A replay starts where the last run ended and reads nothing
         between its pages, as before.  Every insert step hands back the
-        rounds its loop ran, and the chunk's pull of the flag takes them
-        along (``ran``): ``groupby_insert_round_lanes``."""
+        rounds its loop ran at each width (`hashagg.insert_widths` of its
+        lanes), and the chunk's pull of the flag takes them along (``ran``):
+        ``groupby_insert_round_lanes``."""
         cacheable = self._agg_cacheable(node)
         arts = self._agg_cache.get(("hashpage", id(node))) if cacheable else None
         if arts is None:
@@ -2278,7 +2279,7 @@ class LocalExecutor:
                 self._agg_cache[("hashpage", id(node))] = arts
         _, prepare, bprepare, insert_compact, insert_masked = arts
         staged: list = []
-        ran: list = []  # (rounds an insert's loop ran: a device scalar, its width)
+        ran: list = []  # (rounds an insert's loop ran at each width: a device vector, its lanes)
         room = capped and not proven  # the one step for room is still to take
 
         def grow(state, reserved):
@@ -2355,7 +2356,8 @@ class LocalExecutor:
                     [state.overflow] + [r for r, _ in ran],
                     site="agg.hash.overflow")
                 tracing.record_groupby_insert(0, round_lanes=sum(
-                    int(r) * width for r, (_, width) in zip(rounds, ran)))
+                    int(r) * w for level, (_, lanes) in zip(rounds, ran)
+                    for r, w in zip(level, hashagg.insert_widths(lanes))))
                 ran.clear()
                 if not overflow:
                     staged.clear()

@@ -293,8 +293,11 @@ class QueryCounters:
     join_hash_probe_round_lanes: int = 0
     # PR 40: the lanes the group-by's hash insert PROBED for: the rounds of
     # ops/hashagg._probe_insert's open-addressing loop (two gathers, a
-    # scatter-min and a set each, every one at the full width of its batch)
-    # times that width.  The rounds are device scalars that the insert steps
+    # scatter-min and a set each) times the width each ran at, summed over
+    # the widths of a page (PR 41, ops/hashagg.insert_widths: the whole page,
+    # then what was still unplaced, packed).  Over groupby_insert_lanes it is
+    # the rounds an inserted lane cost.  The rounds are device vectors, one
+    # entry a width, that the insert steps
     # of a hash-mode group-by (local_executor._run_hash_inserts:
     # agg.hash.insert_compact, agg.hash.insert_masked, a regrow's rehash)
     # hand back, and they ride the agg.hash.overflow pull that ends each

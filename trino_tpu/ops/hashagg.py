@@ -10,8 +10,10 @@ TPU re-design (no per-row control flow, everything jit-compiled):
 - the table is a fixed-capacity int64 array; insertion is a *deterministic parallel claim*:
   per probe round, rows gather their slot, matching rows finish, rows seeing EMPTY contend
   with scatter-min (min over distinct packed keys is a deterministic winner), losers advance
-  to the next slot (linear probing).  MAX_PROBES rounds of gather+scatter replace the
-  reference's per-row CAS loop;
+  along their double-hashed probe sequence.  Up to MAX_PROBES rounds of gather+scatter
+  replace the reference's per-row CAS loop, each round at the width of what is still
+  unplaced: the whole page first, then the unplaced lanes packed to ever narrower
+  vectors (`insert_widths`, `_claim_slots`);
 - aggregation state is a struct-of-arrays indexed by slot; updates are masked segment
   scatter-adds (XLA lowers these to efficient sorted-scatter on TPU);
 - the table never rehashes inside a trace: capacity is a static bucket chosen by the planner
@@ -33,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..types import BOOLEAN as _BOOL_KEY
+from . import hashing
 from .arrays import gather_rows, live_indices
 from .hashing import ceil_pow2, probe_step, EMPTY_KEY, pack_keys, splitmix64
 
@@ -236,11 +239,17 @@ def _onehot_agg_update(acc, kind, onehot, vals_nulls):
     raise NotImplementedError(kind)
 
 
+def insert_widths(n: int) -> tuple:
+    """The lane widths at which a page of ``n`` lanes runs its claim rounds,
+    widest first: what the ``rounds`` of `groupby_insert` and `rehash` multiply."""
+    return hashing.probe_widths(n, hashing.INSERT_SHIFTS, hashing.INSERT_MIN_LANES)
+
+
 def _probe_insert(table, packed, valid):
     """Assign each valid row a slot whose table word == its packed key; claim empty slots
-    deterministically. Returns (table, slot[int32], placed[bool], rounds[int32]): the
-    rounds the open-addressing loop ran, every one at the full width of ``packed`` (0
-    from the Pallas kernel, which has no rounds to report).
+    deterministically. Returns (table, slot[int32], placed[bool], rounds): ``rounds``
+    int32[len(insert_widths(lanes))], the rounds the open-addressing loop ran at each
+    width (`_claim_slots`; zeros from the Pallas kernel, which has no rounds to report).
 
     Round-13 backend split: capacities within `PALLAS_TABLE_MAX` route to the
     in-kernel claim loop (`pallas_kernels.hash_insert`).  Its contention
@@ -252,32 +261,66 @@ def _probe_insert(table, packed, valid):
     pin the observables; never assert raw slot order across backends."""
     from . import pallas_kernels as pk
 
-    C = table.shape[0] - 1
-    if pk.table_kernels_enabled(C) and packed.shape[0]:
+    widths = insert_widths(packed.shape[0])
+    if pk.table_kernels_enabled(table.shape[0] - 1) and packed.shape[0]:
         return pk.hash_insert(table, packed, valid, max_probes=MAX_PROBES) \
-            + (jnp.zeros((), jnp.int32),)
+            + (jnp.zeros((len(widths),), jnp.int32),)
+    return _claim_slots(table, packed, valid, 0, widths)
+
+
+def _claim_slots(table, packed, valid, p, widths):
+    """`_probe_insert`'s claim loop from round ``p`` on, at ``widths[0]`` lanes
+    (those of ``packed``) and then at each narrower width: (table, slot,
+    placed, rounds int32[len(widths)]).
+
+    A round gathers the table twice, scatter-mins the contenders' keys and
+    sets the sink for EVERY lane of its vector, placed or not (a placed lane
+    is routed to the sink slot C and still pays), and a page ends with its
+    longest chain: q65's 7,340,032 lanes ran 20 rounds at 2^22 slots where
+    a fifth of them went past the first (PERF.md section 6, PR 41).  So a
+    level's loop runs only while more lanes are unplaced than the next level
+    holds; then the unplaced lanes' keys are packed to that width, go on from
+    the same round there against the SAME carried table, and their slots
+    return by one scatter (as `hashjoin._find_slots` hands back its answers).
+    A round's contenders are the same lanes with the same keys at the same
+    probe position in a vector of n or of n/8, scatter-min picks the same
+    winner, and a duplicate of the winner's key still finishes in the winner's
+    round: the table, the slots and ``placed`` are what the one loop gave, bit
+    for bit."""
+    C = table.shape[0] - 1
+    n = packed.shape[0]
     h0 = splitmix64(packed)
     stp = probe_step(h0)
-    # derive every loop carry from the (possibly device-varying) inputs: under
+    # derive every loop carry from BOTH operands' varying axes: under
     # shard_map a fresh constant (a groupby_init table built inside the traced
     # program, a zeros slot vector) is "unvarying" and the while_loop rejects
     # the carry once the body mixes it with per-worker data.  Adding a zeroed
-    # varying term is a no-op numerically but inherits the varying axis.
+    # varying term is a no-op numerically but inherits the varying axis; keys
+    # alone are not enough (a constant key against a per-worker table), so the
+    # lanes' zero touches the table and the table's the keys.
     # (a reduction keeps the varying axis and, unlike packed[:1], broadcasts
     # against the table even when the page has zero rows)
-    table = table + (jnp.sum(packed) & 0)
-    slot = (h0 * 0 + C).astype(jnp.int32)  # default: overflow sink
-    placed = ~valid  # invalid rows are trivially "done" (routed to sink)
+    table = table + (jnp.sum(packed) & 0) + (jnp.sum(valid) & 0)
+    vzero = (h0 * 0).astype(jnp.int32) \
+        + (table[jnp.zeros((), jnp.int32)] * 0).astype(jnp.int32) \
+        + (valid.astype(jnp.int32) * 0)
+    slot = vzero + C  # default: overflow sink
+    placed = ~valid | (vzero != 0)  # invalid rows are trivially "done" (routed to sink)
+    leave = widths[1] if len(widths) > 1 else 0
 
+    # the loop counts its OWN rounds from a constant 0 and adds the rounds run
+    # before it (``p``, a device scalar it does not carry): a carried round
+    # that starts at a traced value cost the v5e compiler 84 s more at 8.4 M
+    # lanes than one that starts at 0 (PERF.md section 6, PR 37)
     def cond(carry):
-        p, table, slot, placed = carry
-        # early exit once every row is placed: typical inserts finish in 1-3
-        # rounds, far below the MAX_PROBES worst case
-        return (p < MAX_PROBES) & ~jnp.all(placed)
+        q, table, slot, placed = carry
+        # early exit once what is unplaced fits the next level (none, at the
+        # last): typical inserts finish in 1-3 rounds, far below MAX_PROBES
+        return (p + q < MAX_PROBES) & (jnp.sum(~placed, dtype=jnp.int32) > leave)
 
     def body(carry):
-        p, table, slot, placed = carry
-        idx = ((h0 + p * stp) & (C - 1)).astype(jnp.int32)
+        q, table, slot, placed = carry
+        idx = ((h0 + (p + q) * stp) & (C - 1)).astype(jnp.int32)
         idx = jnp.where(placed, C, idx)
         cur = table[idx]
         hit = (cur == packed) & ~placed
@@ -292,18 +335,54 @@ def _probe_insert(table, packed, valid):
         won = (cur2 == packed) & ~placed
         slot = jnp.where(won, idx, slot)
         placed = placed | won
-        return p + 1, table, slot, placed
+        return q + 1, table, slot, placed
 
-    rounds, table, slot, placed = jax.lax.while_loop(
+    q, table, slot, placed = jax.lax.while_loop(
         cond, body, (jnp.zeros((), jnp.int32), table, slot, placed))
-    return table, slot, placed, rounds
+    rounds = q[None]
+    if len(widths) == 1:
+        return table, slot, placed, rounds
+
+    def narrow(table):
+        # pack what is unplaced (at most ``leave`` lanes) and claim for it
+        # there.  Only the keys move: an unplaced lane has no slot yet, and
+        # its hash is two multiplies.  Filler lanes are not valid: routed to
+        # the sink, never contending
+        idx, count = live_indices(~placed, leave)
+        lane = jax.lax.iota(jnp.int32, leave)
+        table, nslot, nplaced, nrounds = _claim_slots(
+            table, gather_rows(packed, idx), lane < count, p + q, widths[1:])
+        # hand back: ONE scatter of ``leave`` slots to the lanes they came
+        # from (ascending and distinct; the filler lanes of ``idx`` are sent
+        # out of bounds, each to a place of its own, and dropped)
+        found = (vzero - 1).at[jnp.where(lane < count, idx, n + lane)].set(
+            jnp.where(nplaced & (lane < count), nslot, -1), mode="drop",
+            indices_are_sorted=True, unique_indices=True)
+        return table, found, nrounds
+
+    def done(table):
+        return table, vzero - 1, jnp.zeros((len(widths) - 1,), jnp.int32) + (q & 0)
+
+    # a page that the wide loop places whole (most inserts at low load: 1-3
+    # rounds) pays nothing for the levels: no sort, no narrower loop, no
+    # hand-back.  Nor does one whose wide loop ran out of rounds with more than
+    # ``leave`` unplaced: no level would run a round, and every such lane
+    # overflows as it always did.  Only the table goes through the conditional
+    # and comes back; the lanes' slots come back as ``found``
+    unplaced = jnp.sum(~placed, dtype=jnp.int32)
+    table, found, nrounds = jax.lax.cond(
+        (unplaced > 0) & (p + q < MAX_PROBES), narrow, done, table)
+    hit = found >= 0
+    slot, placed = jnp.where(hit, found, slot), placed | hit
+    return table, slot, placed, jnp.concatenate([rounds, nrounds])
 
 
 def groupby_insert(state: GroupByState, key_vals: Sequence, key_types, valid,
                    agg_inputs: Sequence, agg_updates: Sequence[str],
                    key_nulls: Sequence = None, with_rounds: bool = False):
     """One page of input → updated state; ``with_rounds``: (state, the rounds
-    ``_probe_insert`` ran, an int32 scalar).
+    ``_probe_insert`` ran at each of `insert_widths` of the page's lanes,
+    an int32 vector).
 
     agg_inputs[i]: (value_array|None, input_null_mask|None); agg_updates[i]: update kind
     ('sum','count','min','max','count_star'); key_nulls[i]: null mask of key i or None
@@ -422,7 +501,8 @@ def rehash(state: GroupByState, new_capacity: int, acc_kinds: tuple = (),
     """Re-insert every occupied entry into a larger table (reference:
     FlatHash#rehash).  Accumulators re-insert as partial values (count -> sum).
     Keeps growth at one table-sized pass instead of re-streaming the input.
-    ``with_rounds``: (state, the rounds the re-insert's loop ran)."""
+    ``with_rounds``: (state, the rounds the re-insert's loop ran at each of
+    `insert_widths` of the table it leaves)."""
     C = state.capacity
     occupied = state.table[:C] != EMPTY_KEY
     keys = tuple(k[:C] for k in state.key_cols)

@@ -16,7 +16,9 @@ import numpy as np
 
 from ..types import Type
 
-__all__ = ["splitmix64", "hash_columns", "pack_keys", "key_bits", "EMPTY_KEY"]
+__all__ = ["splitmix64", "hash_columns", "pack_keys", "key_bits", "EMPTY_KEY",
+           "probe_widths", "NARROW_SHIFTS", "NARROW_MIN_LANES", "INSERT_SHIFTS",
+           "INSERT_MIN_LANES"]
 
 # Sentinel for an unoccupied hash-table slot: int64 max sorts AFTER every packed key (packed
 # keys are offset to be non-negative and < 2^62), so scatter-min claims work.
@@ -63,6 +65,65 @@ def probe_step(h0):
     (Table constructors round capacities up to powers of two for coprimality.)
     Reference analog: the chained addressing of join/JoinHash.java."""
     return splitmix64(h0) | jnp.int64(1)
+
+
+# The widths the open-addressing loops run at: the join's lookup
+# (`hashjoin._find_slots`, PR 37) and the claim loop of the insert
+# (`hashagg._probe_insert`, PR 41).  A round works for every lane of its
+# vector, finished or not, and a vector used to end with its longest chain:
+# over q93's 2^24-slot table at load 0.17 a batch of 8.4 M lanes ran ten rounds
+# of lookup (two 14.4-ns gathers a lane a round: 289 ns a lane) where 1.2
+# gathers a lane were useful.  Now a level's loop runs only while more lanes
+# are unfinished than the next, narrower level holds; then the unfinished
+# lanes' keys are packed to that width (`arrays.live_indices`: one int32
+# sort), go on from the same round there, and their answers return by one
+# scatter.  The probe sequence and the answer of every lane are what they
+# were.  Constants, chosen on a v5e (PERF.md section 6, PR 37: q93's shape
+# 208 -> 53 ns a lane; one level at n >> 4 read 67, (3, 6) 69, (2, 4, 6) 59 with
+# the slower hand-back), not knobs.
+NARROW_SHIFTS = (2, 6)  # the narrower levels, as shifts of the vector's lanes
+# Below this many lanes a loop is the one loop it always was.  On the chip the
+# levels win down to 1,024 lanes (0.9 for 1.2 ms; 5.0 for 15.1 ms at 65,536),
+# but each level is a sort for the TPU compiler (1.7 for 0.6 s at 1,024 lanes,
+# 9 for 0.7 s at 65,536) and under 2^16 lanes a call saves less than 10 ms; on
+# the CPU backend, where every tier-1 table lives, a sort costs more than the
+# gathers it saves
+NARROW_MIN_LANES = 1 << 16
+
+
+# The insert's levels (PR 41) are not the lookup's, for two reasons read on a
+# v5e at q65's shape (7,340,032 lanes, 2,213,324 of them live, 2,103,739 keys,
+# 2^22 slots; PERF.md section 6, PR 41).  A claim round costs 144 ns a lane
+# (two gathers, a scatter-min, a set) where a lookup round costs 29, and a
+# level costs what it costs the lookup (its sort 1.7 ns a lane of the wider
+# level, its hand-back 5.3 ns a lane of its own): a level pays sooner.  And a
+# masked page arrives a quarter to all live (`_run_hash_inserts` packs a
+# sparser one first), so a first level at n/2 takes a page that is under half
+# live before it runs any round at n: q65's page runs none.  Read there:
+# (2, 6) 2.31 s, (2, 4, 6) 2.21, (3, 6) 1.95, (2, 4) 3.31, (1, 3, 6) 1.39,
+# the one loop 21.2
+INSERT_SHIFTS = (1, 3, 6)
+# The insert's floor is not the lookup's either.  On the microbenchmark the
+# levels win at 114,688 lanes (0.076 -> 0.026 s), but in the cells the whole
+# insert STEP read slower where its page is small: q9 at SF1 (one page of about
+# 180,000 lanes that ONE round places, 2^20 slots) 0.3207 -> 0.3743 s a
+# statement, q93 (114,688 lanes) +0.6 %, with the levels' sorts and conditional
+# in the step's program beside the key columns' and accumulators' scatters
+# (cause not resolved; PERF.md section 6, PR 41).  A page of a million lanes or
+# more is where a round costs 0.15 s and up; q65's has seven million
+INSERT_MIN_LANES = 1 << 20
+
+
+def probe_widths(n: int, shifts: tuple = None, floor: int = None) -> tuple:
+    """The lane widths at which a vector of ``n`` lanes runs its probe rounds,
+    widest first: what the ``rounds`` of `hashjoin.probe_counted` (the
+    lookup's `NARROW_SHIFTS` over `NARROW_MIN_LANES`, the defaults) and of
+    `hashagg.groupby_insert` (`INSERT_SHIFTS` over `INSERT_MIN_LANES`, through
+    `hashagg.insert_widths`) multiply."""
+    if n < (NARROW_MIN_LANES if floor is None else floor):
+        return (n,)
+    shifts = NARROW_SHIFTS if shifts is None else shifts
+    return (n,) + tuple(n >> s for s in shifts if n >> s)
 
 
 def ceil_pow2(n: int) -> int:

@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from .arrays import gather_rows, live_indices
-from .hashing import EMPTY_KEY, ceil_pow2, pack_keys, probe_step, splitmix64
+from .hashing import (EMPTY_KEY, ceil_pow2, pack_keys, probe_step, probe_widths,
+                      splitmix64)
 
 __all__ = ["JoinTable", "build_table_init", "build_insert", "probe", "probe_counted",
            "probe_widths", "MAX_PROBES",
@@ -104,36 +105,6 @@ def build_insert(jt: JoinTable, key_cols, key_types, valid) -> JoinTable:
         dup_count=jt.n_build_rows + n_valid - occupied,
         overflow=jt.overflow | jnp.any(valid & ~placed),
     )
-
-
-# The widths the open-addressing lookup runs at (PR 37).  Its rounds gather the
-# table for every lane of the batch, finished or not, and a batch used to end
-# with its longest chain: over q93's 2^24-slot table at load 0.17 a batch of
-# 8.4 M lanes ran ten rounds (two 14.4-ns gathers a lane a round: 289 ns a
-# lane) where 1.2 gathers a lane were useful.  Now a level's loop runs only
-# while more lanes are unfinished than the next, narrower level holds; then
-# the unfinished lanes' keys are packed to that width (`arrays.live_indices`:
-# one int32 sort), go on from the same round there, and their answers return
-# by one scatter.  The probe sequence and the first hit of every lane are what
-# they were.  Constants, chosen on a v5e (PERF.md section 6, PR 37: q93's shape
-# 208 -> 53 ns a lane; one level at n >> 4 read 67, (3, 6) 69, (2, 4, 6) 59 with
-# the slower hand-back), not knobs.
-NARROW_SHIFTS = (2, 6)  # the narrower levels, as shifts of the batch's lanes
-# Below this many lanes the lookup is the one loop it always was.  On the chip
-# the levels win down to 1,024 lanes (0.9 for 1.2 ms; 5.0 for 15.1 ms at
-# 65,536), but each level is a sort for the TPU compiler (1.7 for 0.6 s at
-# 1,024 lanes, 9 for 0.7 s at 65,536) and under 2^16 lanes a call saves less
-# than 10 ms; on the CPU backend, where every tier-1 table lives, a sort costs
-# more than the gathers it saves
-NARROW_MIN_LANES = 1 << 16
-
-
-def probe_widths(n: int) -> tuple:
-    """The lane widths at which a batch of ``n`` lanes runs its probe rounds,
-    widest first: what `probe_counted`'s ``rounds`` multiply."""
-    if n < NARROW_MIN_LANES:
-        return (n,)
-    return (n,) + tuple(n >> s for s in NARROW_SHIFTS if n >> s)
 
 
 def _find_slots(table, packed, valid, p=0, widths=None):

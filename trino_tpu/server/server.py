@@ -817,7 +817,9 @@ class CoordinatorServer:
                      "group-by's hash insert loop (a regrow's rehash "
                      "included)."),
                     ("groupby_insert_round_lanes", "Lanes the hash-mode "
-                     "group-by's inserts probed for, rounds times width."),
+                     "group-by's inserts probed for: rounds times width, "
+                     "summed over the widths a page's rounds ran at (the "
+                     "page, then what was still unplaced, packed)."),
                     ("exchange_rows", "Rows the mesh executor's all-to-all "
                      "exchanges delivered (receive cursors and merged "
                      "group counts)."),

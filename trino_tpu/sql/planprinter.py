@@ -115,16 +115,19 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
             # how the statement's group-bys were sized (PR 27): slots of the
             # final states, the largest reservations, overflows that cost a
             # re-scan, Grace passes; (PR 40) the lanes the hash inserts'
-            # open-addressing rounds ran over
+            # open-addressing rounds ran over, each round at the width of what
+            # was still unplaced (PR 41), and so the rounds an inserted lane cost
             gr = getattr(counters, "groupby_insert_round_lanes", 0)
+            gl = getattr(counters, "groupby_insert_lanes", 0)
             lines.append(
                 f"Group-by: {gs} slots, "
                 f"{getattr(counters, 'groupby_state_bytes', 0)} state bytes, "
                 f"{getattr(counters, 'groupby_regrows', 0)} regrows, "
                 f"{getattr(counters, 'groupby_partitioned_passes', 0)} "
                 "partitioned passes, "
-                f"{getattr(counters, 'groupby_insert_lanes', 0)} lanes inserted"
-                + (f"; {gr} lanes in insert rounds" if gr else ""))
+                f"{gl} lanes inserted"
+                + (f"; {gr} lanes in insert rounds ({gr / gl:.2f} rounds a lane)"
+                   if gr and gl else ""))
         rg = getattr(counters, "rows_generated", 0)
         jb = getattr(counters, "join_build_rows", 0)
         gd = getattr(counters, "generator_dispatches", 0)
