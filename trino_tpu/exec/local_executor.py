@@ -2825,31 +2825,45 @@ class LocalExecutor:
     def _run_window(self, node: P.Window):
         """Blocking window evaluation: materialize, sort, segmented scans, scatter back
         (ops/window.py; reference: WindowOperator over a sorted PagesIndex)."""
-        page, dicts = self._execute_to_page_streamed(node.child)
-        n = page.capacity
-        spec_dicts = _window_spec_dicts(node.specs, dicts)
-        if n == 0:
-            cols = tuple(page.columns) + tuple(
-                jnp.zeros((0,), s.type.dtype) for s in node.specs)
-            return (Page(node.schema, cols,
-                         tuple(page.null_masks) + tuple(None for _ in node.specs), None),
-                    tuple(dicts) + spec_dicts)
+        with tracing.maybe_span(
+                "window", functions=[s.kind for s in node.specs],
+                partition_keys=sorted({c for s in node.specs for c in s.partition}),
+                order_keys=sorted({k.channel for s in node.specs
+                                   for k in s.order})) as sp:
+            page, dicts = self._execute_to_page_streamed(node.child)
+            n = page.capacity
+            sp.attributes["lanes"] = n
+            # the live count where the page knows it without a pull
+            rows = n if page.valid is None else page.live
+            if rows is not None:
+                sp.attributes["rows"] = rows
+            spec_dicts = _window_spec_dicts(node.specs, dicts)
+            if n == 0:
+                cols = tuple(page.columns) + tuple(
+                    jnp.zeros((0,), s.type.dtype) for s in node.specs)
+                return (Page(node.schema, cols,
+                             tuple(page.null_masks) + tuple(None for _ in node.specs),
+                             None),
+                        tuple(dicts) + spec_dicts)
 
-        hit = self._agg_cache.get(("window", id(node)))
-        if hit is None:
-            # valid matters: a partially-filled page's invalid rows must not
-            # join real partitions (they'd inflate ranks/sums); the kernel
-            # isolates them into a pad partition
-            kernel = _jit(site="window.kernel",
-                      fn=lambda cols, nulls, valid, specs=node.specs:
-                             _window_kernel(specs, cols, nulls, valid))
-            self._agg_cache[("window", id(node))] = (node, kernel)
-        else:
-            kernel = hit[1]
-        out_cols, out_nulls = kernel(page.columns, page.null_masks, page.valid)
-        cols = tuple(page.columns) + out_cols
-        nulls = tuple(page.null_masks) + out_nulls
-        return Page(node.schema, cols, nulls, page.valid), tuple(dicts) + spec_dicts
+            hit = self._agg_cache.get(("window", id(node)))
+            if hit is None:
+                # valid matters: a partially-filled page's invalid rows must not
+                # join real partitions (they'd inflate ranks/sums); the kernel
+                # isolates them into a pad partition
+                kernel = _jit(site="window.kernel",
+                          fn=lambda cols, nulls, valid, specs=node.specs:
+                                 _window_kernel(specs, cols, nulls, valid))
+                self._agg_cache[("window", id(node))] = (node, kernel)
+            else:
+                kernel = hit[1]
+            tracing.record_window(n, _window_sort_passes(
+                node.specs, page.null_masks, page.valid is not None))
+            out_cols, out_nulls = kernel(page.columns, page.null_masks, page.valid)
+            cols = tuple(page.columns) + out_cols
+            nulls = tuple(page.null_masks) + out_nulls
+            return (Page(node.schema, cols, nulls, page.valid),
+                    tuple(dicts) + spec_dicts)
 
     # -- join ---------------------------------------------------------------
     # maximum distinct probe keys shipped into a connector index lookup
@@ -5357,6 +5371,17 @@ def _window_spec_dicts(specs, dicts):
         dicts[s.arg] if s.kind in ("min", "max", "lag", "lead", "first_value",
                                    "last_value") and s.arg is not None else None
         for s in specs)
+
+
+def _window_sort_passes(specs, nulls, padded):
+    """Stable argsorts ``_window_kernel`` runs over its page (``ops/window.
+    window_order``: one a key column): for each distinct (partition, order)
+    clause (specs that share one share its sort) the pad mask of a page with
+    invalid rows, then every partition and order channel, a nullable one with
+    its NULL indicator before it."""
+    return sum(int(padded) + sum(1 + (nulls[c] is not None) for c in
+                                 list(part) + [k.channel for k in order])
+               for part, order in {(s.partition, s.order) for s in specs})
 
 
 def _window_kernel(specs, cols, nulls, valid=None):

@@ -314,6 +314,16 @@ class QueryCounters:
     # wide-decimal sum that needs the host-exact finalize)
     tail_compiled: int = 0
     tail_eager: int = 0
+    # PR 42: what the window operator was handed (local_executor._run_window:
+    # one kernel a Window node over one materialised page), host ints taken
+    # where it dispatches, no sync: kernels, the static lanes of their pages
+    # (live rows or not: every lane is sorted, scanned and scattered back),
+    # and lanes times the stable sort passes of ops/window.window_order (one
+    # a key column of each distinct (partition, order) clause, a NULL
+    # indicator and the pad mask included)
+    window_kernels: int = 0
+    window_lanes: int = 0
+    window_sort_lanes: int = 0
     # PR 32: the mesh path.  Rows the statement's all-to-all exchanges
     # delivered and the fullest worker's share of them, summed over its
     # exchanges from the receive cursors and occupancy counts the exchange
@@ -411,6 +421,7 @@ class QueryCounters:
                    "join_hash_probe_round_lanes",
                    "groupby_insert_round_lanes",
                    "tail_compiled", "tail_eager",
+                   "window_kernels", "window_lanes", "window_sort_lanes",
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
                    "probe_exchange_rows", "probe_exchange_lanes",
@@ -665,6 +676,16 @@ def record_tail(compiled: bool) -> None:
             c.tail_compiled += 1
         else:
             c.tail_eager += 1
+
+
+def record_window(lanes: int, sort_passes: int) -> None:
+    """One window kernel over a page of ``lanes`` static lanes, whose sort
+    permutations took ``sort_passes`` stable argsorts in all."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        c.window_kernels += 1
+        c.window_lanes += lanes
+        c.window_sort_lanes += lanes * sort_passes
 
 
 def record_join_probe(match_lanes: int, gather_lanes: int) -> None:
@@ -1987,7 +2008,7 @@ _BUCKET_PRIORITY = ("compile", "device_dispatch", "host_pull", "scan_wait",
 # on the statement's own thread.  The remainder of the sweep is split by the
 # innermost one open at each uncovered slice (``unattributed_by``), so the
 # tree that is there says where the unnamed host time sits.
-_CONTAINER_SPANS = ("query", "execution", "join.build", "mesh.fragment", "task",
+_CONTAINER_SPANS = ("query", "execution", "join.build", "window", "mesh.fragment", "task",
                     # the two waits under the root span (counters of their
                     # own: batch_wait_s, executor_wait_s) are no bucket, so
                     # their seconds are the remainder's: named here

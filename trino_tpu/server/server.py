@@ -820,6 +820,13 @@ class CoordinatorServer:
                      "group-by's inserts probed for: rounds times width, "
                      "summed over the widths a page's rounds ran at (the "
                      "page, then what was still unplaced, packed)."),
+                    ("window_kernels", "Window kernels dispatched, one a "
+                     "Window node over one materialised page."),
+                    ("window_lanes", "Static lanes of the pages the window "
+                     "kernels were handed, live rows or not."),
+                    ("window_sort_lanes", "Lanes the window kernels sorted: "
+                     "lanes times the stable sort passes of their "
+                     "(partition, order) clauses."),
                     ("exchange_rows", "Rows the mesh executor's all-to-all "
                      "exchanges delivered (receive cursors and merged "
                      "group counts)."),

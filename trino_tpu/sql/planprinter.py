@@ -128,6 +128,14 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 f"{gl} lanes inserted"
                 + (f"; {gr} lanes in insert rounds ({gr / gl:.2f} rounds a lane)"
                    if gr and gl else ""))
+        wk = getattr(counters, "window_kernels", 0)
+        if wk:
+            # the window operator (PR 42): kernels, the static lanes of the
+            # pages they were handed, and lanes times stable sort passes
+            lines.append(
+                f"Window: {wk} kernels, "
+                f"{getattr(counters, 'window_lanes', 0)} lanes, "
+                f"{getattr(counters, 'window_sort_lanes', 0)} lanes sorted")
         rg = getattr(counters, "rows_generated", 0)
         jb = getattr(counters, "join_build_rows", 0)
         gd = getattr(counters, "generator_dispatches", 0)
