@@ -191,6 +191,34 @@ def test_a_wide_group_bys_initial_state_compiles_for_v5e(one_chip, no_persistent
     assert compiled.memory_analysis().generated_code_size_in_bytes < 1 << 20
 
 
+def test_the_observed_direct_insert_keeps_its_one_hot_inside_the_reduces(
+        one_chip, no_persistent_cache):
+    """PR 44: q65's avg by store at scale 10, direct-indexed at the 128 slots its
+    observed bounds name (`hashagg.observed_direct_config`): 4,194,304 lanes, one int64
+    key and one int64 input, both with null masks, `sum_hi32` / `sum_lo32` / `count`.
+    Materialised, the `[lanes, 128]` one-hot would be 4.3 GB an accumulator; inside the
+    reduce fusions the program's temporaries are under a megabyte (708,608 B when this
+    was written).  Were it to leave them, `direct_groupby_insert` has to block its rows."""
+    from trino_tpu.ops import hashagg
+
+    lanes, kinds = 1 << 22, ("sum_hi32", "sum_lo32", "count")
+    cfg = hashagg.observed_direct_config([(1, 120, False)], lanes)
+    assert cfg.capacity == hashagg.ONEHOT_CAP_MAX
+
+    def insert(state, key, key_null, value, value_null, valid):
+        return hashagg.direct_groupby_insert(
+            state, cfg, (key,), valid, tuple((value, value_null) for _ in kinds), kinds,
+            (key_null,))
+
+    state = jax.tree.map(
+        lambda a: _s(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: hashagg.direct_groupby_init(
+            cfg, (jnp.int64,), tuple((jnp.int64, 0) for _ in kinds))))
+    wide, mask = _s(one_chip, (lanes,), jnp.int64), _s(one_chip, (lanes,), jnp.bool_)
+    compiled = jax.jit(insert).lower(state, wide, mask, wide, mask, mask).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def test_q1_page_step_compiles_for_v5e(one_chip, as_on_tpu):
     """The jitted per-page step of Q1 (scan transform -> group-by insert into
     the 64-slot table) — the first aggregation of the first query."""

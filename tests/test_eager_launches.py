@@ -37,11 +37,31 @@ from trino_tpu.exec import local_executor
 
 ROOT = str(pathlib.Path(trino_tpu.__file__).resolve().parent)
 EXECUTOR = str(pathlib.Path(local_executor.__file__).resolve())
-STATEMENTS = {"q1": q1, "agg_lineitem": agg_lineitem, "agg_orders": agg_orders, "q3": q3}
+
+
+class avg_of_sums:
+    """(PR 44) A group-by over a group-by's one page: the outer one reads its key's bounds
+    with ONE counted program (``agg.key_bounds``) and ONE pull before its only step, and
+    is direct-indexed; nothing of it is eager."""
+
+    VALIDATION = {}
+
+    @staticmethod
+    def render(p):
+        return ("select o_orderstatus, k, avg(t) a from (select o_orderstatus, "
+                "o_custkey % 5 k, o_orderpriority, sum(o_totalprice) t from orders "
+                "group by o_orderstatus, o_custkey % 5, o_orderpriority) x "
+                "group by o_orderstatus, k order by o_orderstatus, k"), None
+
+
+STATEMENTS = {"q1": q1, "agg_lineitem": agg_lineitem, "agg_orders": agg_orders, "q3": q3,
+              "avg_of_sums": avg_of_sums}
 # (eager launches at most, pulls after the last group-by step at most).  q3's hash
 # group-by reads its overflow flag at the end of its last chunk and once more with the
 # count (two pulls that were four), and its history record pulls a join's build rows
-CEILINGS = {"q1": (8, 3), "agg_lineitem": (8, 3), "agg_orders": (8, 3), "q3": (12, 5)}
+CEILINGS = {"q1": (8, 3), "agg_lineitem": (8, 3), "agg_orders": (8, 3), "q3": (12, 5),
+            "avg_of_sums": (8, 3)}
+TAILS = {"avg_of_sums": 3}  # two finalizes and the sort; the others one and the sort
 STEPS = ("agg.direct.step", "agg.direct.batch", "agg.hash.insert_masked",
          "agg.hash.insert_compact")
 
@@ -148,7 +168,8 @@ def test_a_warm_replay_launches_nothing_outside_jit(engine, name):
     eager, pulls_after = CEILINGS[name]
     assert len(census.eager) <= eager, census.eager
     counters = engine.last_query_counters
-    assert counters.tail_compiled == 2 and counters.tail_eager == 0  # finalize + sort
+    assert counters.tail_compiled == TAILS.get(name, 2) and counters.tail_eager == 0
+    assert counters.groupby_observed_direct == (name == "avg_of_sums")
     spans = sorted((s for s in engine.last_query_trace["spans"]
                     if s["name"] in ("dispatch", "host_pull")),
                    key=lambda s: s["start_s"])

@@ -37,8 +37,25 @@ from trino_tpu.parallel.mesh import worker_mesh
 
 ROOT = str(pathlib.Path(trino_tpu.__file__).resolve().parent)
 HOST_FILE = str(pathlib.Path(local_executor.__file__).resolve())
+
+
+class avg_of_sums:
+    """(PR 44) A group-by over a group-by's one page: the outer one reads its keys'
+    bounds off that page, through ``_host`` like every other read.  (TPC-DS q65 has the
+    shape, but its multi-match join reads a count outside ``_host``: ROADMAP.)"""
+
+    VALIDATION = {}
+
+    @staticmethod
+    def render(p):
+        return ("select l_returnflag, k, avg(q) a from (select l_returnflag, "
+                "l_suppkey % 5 k, l_linestatus, sum(l_quantity) q from lineitem "
+                "group by l_returnflag, l_suppkey % 5, l_linestatus) x "
+                "group by l_returnflag, k order by l_returnflag, k"), None
+
+
 STATEMENTS = {"q1": q1, "q3": q3, "q18": q18, "q9": q9, "ds_q93": ds_q93,
-              "ds_q51": ds_q51}
+              "ds_q51": ds_q51, "avg_of_sums": avg_of_sums}
 # lineitem in 13 splits at SF0.01, so that a scan is prefetched and coalesced
 SPLIT_ROWS = 1 << 13
 
@@ -99,6 +116,8 @@ CASES = [("plain", "tpch", "q1"), ("plain", "tpch", "q3"), ("plain", "tpch", "q1
          ("plain", "tpch", "q9"), ("plain", "tpcds", "ds_q93"),
          # (PR 42) the window operator adds no read to those its child already makes
          ("plain", "tpcds", "ds_q51"),
+         # (PR 44) a group-by over a group-by reads its keys' bounds through `_host`
+         ("plain", "tpch", "avg_of_sums"),
          ("mesh", "tpch", "q1"), ("mesh", "tpch", "q3")]
 
 
@@ -115,6 +134,11 @@ def test_a_warm_replay_reads_no_device_value_outside_host(engines, where, catalo
     counters = engine.last_query_counters
     assert counters.compiles == 0, counters.compiles  # it WAS a warm replay
     assert counters.host_transfers > 0
+    # only one of them groups a blocking child's one page, and it reads the bounds once
+    bounds = [v for k, v in counters.sites.items() if k.endswith("/agg.key_bounds")]
+    assert [(v["dispatches"], v["transfers"]) for v in bounds] == \
+        ([(1, 1)] if name == "avg_of_sums" else [])
+    assert counters.groupby_observed_direct == len(bounds)
 
 
 def test_a_planted_bool_of_a_device_scalar_is_caught(engines, monkeypatch):

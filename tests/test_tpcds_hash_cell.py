@@ -160,6 +160,10 @@ def test_a_replay_of_q65_compiles_nothing_and_still_groups_the_years_sales(ds):
     assert year <= w.groupby_insert_lanes < first.groupby_insert_lanes
     assert w.groupby_insert_round_lanes >= w.groupby_insert_lanes
     assert w.join_build_rows == 0 and w.groupby_regrows == 0
+    # (PR 44) the avg by store over ``sa``'s finished page reads its key's bounds from
+    # that page and is direct-indexed: it no longer adds to the inserted lanes, and the
+    # (store, item) group-by above still does
+    assert w.groupby_observed_direct == 1
 
 
 def test_q93s_left_join_runs_as_an_inner_join(ds):

@@ -472,6 +472,10 @@ class _Stream:
     passes_valid: bool = False  # the transform hands its input's validity
     # mask on untouched (a source, projections over one): a packed page that
     # knows its live count (Page.live) is still packed behind it
+    materialised: bool = False  # the source is ONE finished page of a blocking
+    # child (Aggregate, Sort, Window, ...), re-made every execution; Filter
+    # and Project keep the mark, joins, unions, overrides and scans have
+    # none.  A group-by over it reads its keys' bounds from that page
     _jitted: Callable = None  # cached jit of transform applied to a Page
     _batch_jitted: Callable = None  # cached jit of transform over a STACKED
     # group of uniform pages (dispatch coalescing; retraces per group arity)
@@ -1410,7 +1414,8 @@ class LocalExecutor:
             if rt is not None:
                 pages = si.pages_over(rt)
             return _Stream(up.schema, up.dicts, pages, transform, si, aux=up.aux,
-                           clustered_by=up.clustered_by, compacted=up.compacted)
+                           clustered_by=up.clustered_by, compacted=up.compacted,
+                           materialised=up.materialised)
 
         if isinstance(node, P.Project):
             up = self._compile_stream(node.child)
@@ -1440,7 +1445,8 @@ class LocalExecutor:
                     for e in node.exprs))
             return _Stream(node.schema, dicts, up.pages, transform, si, aux=up.aux,
                            clustered_by=up.clustered_by, compacted=up.compacted,
-                           passes_valid=up.passes_valid)
+                           passes_valid=up.passes_valid,
+                           materialised=up.materialised)
 
         if isinstance(node, P.Join):
             return self._compile_join(node)
@@ -1480,7 +1486,7 @@ class LocalExecutor:
                     yield pg
 
             return _Stream(node.schema, dicts, pages, lambda c, n, v, aux: (c, n, v),
-                           passes_valid=True)
+                           passes_valid=True, materialised=True)
 
         raise NotImplementedError(f"node {type(node).__name__}")
 
@@ -1577,6 +1583,62 @@ class LocalExecutor:
         if cacheable:
             self._agg_cache[("nullable", id(node))] = (node, key_nullable)
         return key_nullable
+
+    def _observed_direct_config(self, node, stream, first, key_ranges):
+        """The direct config of a group-by over ONE materialised page of a
+        blocking child, from what that page holds, or None (hash mode, as
+        before).  Such a child is FINISHED before its consumer chooses a mode,
+        and no connector states a range for what a group-by hands on (q65's
+        avg by store hashed 4,194,304 lanes into 2^16 slots for 120 groups).
+        For every key that `_key_ranges` left without bounds: min and max over
+        the live non-NULL lanes and whether any is NULL, ONE program
+        (``agg.key_bounds``: the stream's transform, then three reductions a
+        key) and ONE pull an execution.  Nothing of the data outlives the
+        execution: the config is a compile shape (`hashagg.
+        observed_direct_config`), the bounds are read again next time.  The
+        observed NULL flag stands in for `_key_nullable`'s "has a mask" (every
+        key out of a group-by has one, and a flag bit doubles the table);
+        `hashagg._direct_slot` sends a NULL that meets a config without the
+        bit to the overflow flag.  Floating keys stay hashed."""
+        if not stream.materialised or _page_batch_sig(first) is None:
+            return None  # (no mark; or nothing to trace, nothing to read)
+        read = tuple(i for i, r in zip(node.keys, key_ranges) if r is None)
+        if any(not np.issubdtype(np.dtype(stream.schema.fields[i].type.dtype),
+                                 np.integer) for i in read):
+            return None
+        cacheable = self._agg_cacheable(node)
+        hit = self._agg_cache.get(("key_bounds", id(node))) if cacheable else None
+        if hit is None:
+            @partial(_jit, site="agg.key_bounds")
+            def bounds(page, aux, stream=stream, read=read):
+                cols, nulls, valid = stream.transform(
+                    page.columns, page.null_masks, page.valid_mask(), aux)
+                out = []
+                for i in read:
+                    k, kn = cols[i], nulls[i]
+                    live = valid if kn is None else valid & ~kn
+                    lim = jnp.iinfo(k.dtype)
+                    out.append(jnp.stack([
+                        jnp.min(jnp.where(live, k, lim.max)).astype(jnp.int64),
+                        jnp.max(jnp.where(live, k, lim.min)).astype(jnp.int64),
+                        jnp.zeros((), jnp.int64) if kn is None
+                        else jnp.any(valid & kn).astype(jnp.int64)]))
+                return jnp.stack(out)
+
+            hit = (node, bounds)
+            if cacheable:
+                self._agg_cache[("key_bounds", id(node))] = hit
+        seen = iter(_host([hit[1](first, stream.aux)],
+                          site="agg.key_bounds")[0].tolist())
+        key_bounds = []
+        for r, has_mask in zip(key_ranges,
+                               self._key_nullable(node, stream, first)):
+            if r is None:
+                lo, hi, any_null = next(seen)
+                key_bounds.append((lo, hi, bool(any_null)))
+            else:
+                key_bounds.append((r[0], r[1], has_mask))
+        return hashagg.observed_direct_config(key_bounds, first.capacity)
 
     def _direct_step(self, node, cfg, stream, key_types, acc_exprs, acc_kinds):
         """Jitted direct-indexed insert steps (cached per (node, cfg)):
@@ -2099,12 +2161,17 @@ class LocalExecutor:
         page_iter = iter(stream.pages())
         first = next(page_iter, None)
         cfg = None
+        observed = False  # cfg came from bounds read off the child's one page
         capped = False  # the estimate asked for more slots than its cap gives
         if first is not None:
             key_ranges = self._key_ranges(stream, node)
             if all(r is not None for r in key_ranges):
                 cfg = hashagg.direct_config(
                     key_ranges, self._key_nullable(node, stream, first))
+            else:
+                cfg = self._observed_direct_config(node, stream, first,
+                                                   key_ranges)
+                observed = cfg is not None
             if cfg is None and not node.capacity:
                 # hash mode: size the initial table from the key-range product
                 # and/or the input row bound so huge group counts don't crawl
@@ -2156,6 +2223,7 @@ class LocalExecutor:
         peak = 0  # the largest reservation of this group-by (groupby_state_bytes)
         try:
             if cfg is not None:
+                tracing.record_groupby(observed_direct=int(observed))
                 with tracing.maybe_span("aggregate.direct", slots=cfg.capacity):
                     state = _direct_init(cfg, tuple(t.dtype for t in key_types),
                                          tuple(acc_specs))

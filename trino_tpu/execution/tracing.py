@@ -259,6 +259,10 @@ class QueryCounters:
     groupby_state_bytes: int = 0
     groupby_regrows: int = 0
     groupby_partitioned_passes: int = 0
+    # PR 44: group-bys whose direct index came from bounds READ off the one
+    # materialised page of a blocking child (local_executor.
+    # _observed_direct_config), not from a dictionary or the connector
+    groupby_observed_direct: int = 0
     join_build_rows: int = 0
     rows_generated: int = 0
     # PR 28: how often a split join's boundary engages (local_executor.
@@ -414,7 +418,8 @@ class QueryCounters:
                    "batched_requests", "compile_cache_misses",
                    "compactions", "compact_lanes_in", "compact_lanes_out",
                    "groupby_slots", "groupby_state_bytes", "groupby_regrows",
-                   "groupby_partitioned_passes", "join_build_rows",
+                   "groupby_partitioned_passes", "groupby_observed_direct",
+                   "join_build_rows",
                    "rows_generated", "join_match_lanes", "join_gather_lanes",
                    "join_hash_probe_lanes", "join_direct_probe_lanes",
                    "join_hash_table_slots", "groupby_insert_lanes",
@@ -696,13 +701,14 @@ def record_join_probe(match_lanes: int, gather_lanes: int) -> None:
 
 
 def record_groupby(slots: int = 0, state_bytes: int = 0, regrows: int = 0,
-                   partitioned_passes: int = 0) -> None:
+                   partitioned_passes: int = 0, observed_direct: int = 0) -> None:
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.groupby_slots += slots
         c.groupby_state_bytes += state_bytes
         c.groupby_regrows += regrows
         c.groupby_partitioned_passes += partitioned_passes
+        c.groupby_observed_direct += observed_direct
 
 
 def record_join_build(rows: int, hash_slots: int = 0) -> None:

@@ -120,6 +120,27 @@ def direct_config(key_ranges, key_nullable, max_bits: int = DIRECT_BITS_MAX):
     return DirectConfig(tuple(entries), total)
 
 
+def observed_direct_config(key_bounds, lanes: int):
+    """The DirectConfig of a group-by whose keys' bounds were READ from the one
+    page it groups (`LocalExecutor._observed_direct_config`), or None: hash mode.
+
+    key_bounds: per key ``(lo, hi, any_null)``, the inclusive bounds of its live
+    non-NULL values and whether a live lane is NULL; ``lanes``: the page's width.
+    The config is a compile shape, so the bounds are widened to the envelope of
+    the bits they need (``hi = lo + 2^bits - 1``): a maximum that moves inside
+    its bit width names the same config.  Direct only where it is the smaller
+    thing: a table is never wider than the page it groups (keys spread over 2^22
+    values in a 256-lane page stay hashed).  ``hi < lo`` is a key with no live
+    non-NULL value: nothing to index by."""
+    ranges = []
+    for lo, hi, _ in key_bounds:
+        if hi < lo:
+            return None
+        ranges.append((lo, lo + (1 << max((hi - lo).bit_length(), 1)) - 1))
+    cfg = direct_config(ranges, [any_null for _, _, any_null in key_bounds])
+    return cfg if cfg is not None and cfg.capacity <= lanes else None
+
+
 def direct_groupby_init(cfg: DirectConfig, key_dtypes, acc_specs) -> GroupByState:
     """Direct-mode state: key columns are PRE-FILLED by unpacking each slot index
     (packing is injective), so inserts never scatter key captures."""

@@ -792,6 +792,8 @@ class CoordinatorServer:
                      "re-scan of the input."),
                     ("groupby_partitioned_passes", "Grace passes of "
                      "partitioned group-bys."),
+                    ("groupby_observed_direct", "Group-bys direct-indexed "
+                     "by key bounds read off a blocking child's one page."),
                     ("join_build_rows", "Rows inserted into join build "
                      "tables (0 when a replay reuses its streams)."),
                     ("rows_generated", "Base-table rows the connectors "

@@ -119,6 +119,7 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
             # was still unplaced (PR 41), and so the rounds an inserted lane cost
             gr = getattr(counters, "groupby_insert_round_lanes", 0)
             gl = getattr(counters, "groupby_insert_lanes", 0)
+            go = getattr(counters, "groupby_observed_direct", 0)
             lines.append(
                 f"Group-by: {gs} slots, "
                 f"{getattr(counters, 'groupby_state_bytes', 0)} state bytes, "
@@ -127,7 +128,10 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
                 "partitioned passes, "
                 f"{gl} lanes inserted"
                 + (f"; {gr} lanes in insert rounds ({gr / gl:.2f} rounds a lane)"
-                   if gr and gl else ""))
+                   if gr and gl else "")
+                # (PR 44) direct-indexed by bounds read off a blocking
+                # child's one page
+                + (f"; {go} direct by observed bounds" if go else ""))
         wk = getattr(counters, "window_kernels", 0)
         if wk:
             # the window operator (PR 42): kernels, the static lanes of the
