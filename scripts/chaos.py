@@ -13,6 +13,7 @@ Env knobs:
                    cache fault classes have a cache to fault
 """
 
+import functools
 import json
 import os
 import sys
@@ -131,8 +132,9 @@ def main() -> int:
                     skipped += 1
                     continue
                 scratch = tempfile.mkdtemp(prefix="trino_tpu_chaos_spill_")
-                rec = run_pressure_scenario(engine, plan, base, name, cfg,
-                                            spec, kind, scratch)
+                rec = run_pressure_scenario(
+                    functools.partial(LocalExecutor, engine.catalogs), plan,
+                    base, name, cfg, spec, kind, scratch)
                 rec["query"] = qname
                 payload["scenarios"].append(rec)
                 done += 1

@@ -85,13 +85,9 @@ def main():
                     help="trace the WORKER-MESH path instead of the local "
                          "executor: each query runs on the 8-device CPU "
                          "mesh (virtual workers; the flag forces the device "
-                         "count before jax imports) cold+warm in BOTH "
-                         "exchange modes — device-resident receive buffers "
-                         "vs the host spool (TRINO_TPU_DEVICE_EXCHANGE "
-                         "A/B).  The warm device-mode numbers are the "
-                         "tests/test_distributed_budgets.py ceilings; the "
-                         "spool/device exchange-site byte ratio is the "
-                         "round-18 acceptance number")
+                         "count before jax imports) cold+warm.  The warm "
+                         "numbers are the tests/test_distributed_budgets.py "
+                         "ceilings")
     ap.add_argument("--sites", action="store_true",
                     help="print each warm query's per-site attribution table "
                          "(operator/call-site -> dispatches, transfers, "
@@ -280,11 +276,9 @@ def _print_adaptive(engine):
 
 def _trace_distributed(engine, sf, split_rows, names, QUERIES, show_sites,
                        show_skew=False):
-    """Worker-mesh trace: cold+warm counters per query in both exchange
-    modes (device-resident vs host spool).  The warm device rows — total
-    dist.* site bytes and the per-site table — are what
-    tests/test_distributed_budgets.py pins; the spool:device byte ratio is
-    the exchange-elimination factor."""
+    """Worker-mesh trace: cold+warm counters per query.  The warm rows —
+    total dist.* site bytes and the per-site table — are what
+    tests/test_distributed_budgets.py pins."""
     from trino_tpu.exec.distributed import DistributedExecutor
     from trino_tpu.parallel.mesh import worker_mesh
     from trino_tpu.sql.frontend import compile_sql
@@ -295,52 +289,45 @@ def _trace_distributed(engine, sf, split_rows, names, QUERIES, show_sites,
         plan = compile_sql(QUERIES[name], engine, session)
         rec = {"query": name, "sf": sf, "split_rows": split_rows,
                "workers": int(mesh.devices.size)}
-        for mode, dev in (("device", True), ("spool", False)):
-            ex = DistributedExecutor(engine.catalogs, mesh=mesh,
-                                     device_exchange=dev)
-            out = {}
-            for phase in ("cold", "warm"):
-                t0 = time.perf_counter()
-                ex.execute(plan)
-                counters = ex.counters.as_dict()
-                sites = counters.pop("sites", {})
-                counters.pop("dispatch_latency", None)
-                shard = counters.pop("shard_stats", [])
-                dist = {k: v for k, v in sites.items() if "dist." in k}
-                out[phase] = {
-                    "wall_s": round(time.perf_counter() - t0, 3),
-                    "dist_site_bytes": sum(v["bytes"] for v in dist.values()),
-                    **{k: v for k, v in counters.items() if v}}
-                if show_sites and phase == "warm":
-                    print(f"# {name} warm {mode} dist sites "
-                          "(dispatches/transfers/bytes):", flush=True)
-                    for key in sorted(dist, key=lambda k: (
-                            -dist[k]["bytes"], k)):
-                        s = dist[key]
-                        print(f"#   {key:<44} {s['dispatches']:>4} "
-                              f"{s['transfers']:>4} {s['bytes']:>9}",
-                              flush=True)
-                if show_skew and phase == "warm":
-                    print(f"# {name} warm {mode} shard skew "
-                          "(site/kind -> per-worker rows, ratio):",
+        ex = DistributedExecutor(engine.catalogs, mesh=mesh)
+        out = {}
+        for phase in ("cold", "warm"):
+            t0 = time.perf_counter()
+            ex.execute(plan)
+            counters = ex.counters.as_dict()
+            sites = counters.pop("sites", {})
+            counters.pop("dispatch_latency", None)
+            shard = counters.pop("shard_stats", [])
+            dist = {k: v for k, v in sites.items() if "dist." in k}
+            out[phase] = {
+                "wall_s": round(time.perf_counter() - t0, 3),
+                "dist_site_bytes": sum(v["bytes"] for v in dist.values()),
+                **{k: v for k, v in counters.items() if v}}
+            if show_sites and phase == "warm":
+                print(f"# {name} warm dist sites "
+                      "(dispatches/transfers/bytes):", flush=True)
+                for key in sorted(dist, key=lambda k: (
+                        -dist[k]["bytes"], k)):
+                    s = dist[key]
+                    print(f"#   {key:<44} {s['dispatches']:>4} "
+                          f"{s['transfers']:>4} {s['bytes']:>9}",
                           flush=True)
-                    for s in shard:
-                        rows = ",".join(str(int(v))
-                                        for v in (s.get("rows") or [])[:16])
-                        print(f"#   {s.get('site', '?'):<28} "
-                              f"{s.get('kind', '?'):<10} "
-                              f"{s.get('op') or '-':<12} "
-                              f"{s.get('ratio', 1.0):>5.1f}x "
-                              f"worker {s.get('worker', 0):<3} "
-                              f"{s.get('imbalance_s', 0.0) * 1000:>7.1f} ms "
-                              f"[{rows}]", flush=True)
-            rec[mode] = out
+            if show_skew and phase == "warm":
+                print(f"# {name} warm shard skew "
+                      "(site/kind -> per-worker rows, ratio):",
+                      flush=True)
+                for s in shard:
+                    rows = ",".join(str(int(v))
+                                    for v in (s.get("rows") or [])[:16])
+                    print(f"#   {s.get('site', '?'):<28} "
+                          f"{s.get('kind', '?'):<10} "
+                          f"{s.get('op') or '-':<12} "
+                          f"{s.get('ratio', 1.0):>5.1f}x "
+                          f"worker {s.get('worker', 0):<3} "
+                          f"{s.get('imbalance_s', 0.0) * 1000:>7.1f} ms "
+                          f"[{rows}]", flush=True)
+        rec.update(out)
         print(json.dumps(rec), flush=True)
-        db = rec["device"]["warm"]["dist_site_bytes"]
-        sb = rec["spool"]["warm"]["dist_site_bytes"]
-        ratio = (sb / db) if db else float("inf")
-        print(f"# {name}: warm exchange-site bytes spool {sb} -> "
-              f"device {db} ({ratio:.1f}x)", flush=True)
 
 
 def _trace_serve_batch(engine, sf, split_rows):

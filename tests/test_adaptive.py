@@ -177,9 +177,8 @@ def test_aggregate_capacity_and_grace_corrections():
 
 
 def test_dispatch_batch_rides_along():
-    from trino_tpu.exec.local_executor import _dispatch_batch_default
+    from trino_tpu.exec.boundary import DISPATCH_BATCH as cur
 
-    cur = _dispatch_batch_default()
     adv = _advisor(_store({
         "Join#0.0": _rec(),
         "TableScan#0.0.0": _rec(op="TableScan", est=None, actual=0, wall=0.0,
@@ -392,14 +391,16 @@ def test_session_property_is_plan_shaping():
     assert _plan_shape_props(s) == on
 
 
-def test_env_default_off(monkeypatch):
+def test_the_session_sets_adaptive_in_both_directions():
     from trino_tpu.engine import _effective_adaptive, _plan_shape_props
 
     e = _engine()
     s = e.create_session("tpch")
-    monkeypatch.setenv("TRINO_TPU_ADAPTIVE", "0")
+    default = _plan_shape_props(s)
+    e.execute_sql("set session adaptive_execution = false", s)
     assert not _effective_adaptive(s)
     assert _plan_shape_props(s)[-1] is False
-    # the session property overrides the env default in both directions
+    # a session that NAMES the default is keyed as the default is
     e.execute_sql("set session adaptive_execution = true", s)
     assert _effective_adaptive(s)
+    assert _plan_shape_props(s) == default and default[-1] is True

@@ -180,7 +180,7 @@ def test_no_bare_jax_jit(path):
     assert not s.jit_hits, (
         f"{path.name}: bare jax.jit reference at "
         + ", ".join(f"line {ln} (in {fn})" for ln, fn in s.jit_hits)
-        + " — use exec.local_executor._jit so the dispatch is counted "
+        + " — use exec.boundary._jit so the dispatch is counted "
           "against the query budget (partial(jax.jit, ...) counts too)")
 
 
@@ -295,7 +295,7 @@ def test_jit_outside_exec_is_annotated(path):
     assert not hits, (
         f"{path.relative_to(PKG_DIR)}: untracked jax.jit reference at "
         f"line(s) {', '.join(map(str, hits))} — route through "
-        "exec.local_executor._jit so the compile is observed (counted, "
+        "exec.boundary._jit so the compile is observed (counted, "
         "span'd, census'd, compile-aware-stall-judged), or annotate "
         "'# compile-ok: <reason>'")
 
@@ -640,18 +640,9 @@ ENV_OPTIONS = {
                         "(scripts/chaos.py)",
     "TRINO_TPU_COMPILE_MEMSTATS": "operations: executable sizes, at a second "
                                   "compile a signature",
-    # forks with no verdict yet (ROADMAP D2a, D2b, D3a)
+    # a fork with no verdict yet (ROADMAP D2a)
     "TRINO_TPU_PALLAS": "A/B reference of tests/test_pallas_kernels.py and "
                         "tests/test_compaction.py",
-    "TRINO_TPU_DEVICE_EXCHANGE": "A/B reference of "
-                                 "tests/test_distributed_budgets.py",
-    "TRINO_TPU_ADAPTIVE": "A/B reference of tests/test_adaptive.py; default of "
-                          "the adaptive_execution session property",
-    "TRINO_TPU_DISPATCH_BATCH": "A/B reference of tests/test_dispatch_batch.py; "
-                                "default of the dispatch_batch session property",
-    "TRINO_TPU_TEMPLATE_BATCH": "A/B reference of "
-                                "tests/test_template_batching.py",
-    "TRINO_TPU_INDEX_JOIN": "A/B reference of tests/test_dbapi_connector.py",
 }
 
 
@@ -665,4 +656,82 @@ def test_env_options_are_the_listed_ones():
     assert read == set(ENV_OPTIONS), (
         f"not listed: {sorted(read - set(ENV_OPTIONS))}; "
         f"listed but gone: {sorted(set(ENV_OPTIONS) - read)}")
-    assert len(ENV_OPTIONS) == 27
+    assert len(ENV_OPTIONS) == 22
+
+
+# PR 45: arrows point one way under the executor.  What another module needs
+# does not live in an executor (exec/boundary.py, exec/groupby.py,
+# exec/pages.py hold it), and the layers PERF.md section 3 draws below the
+# executors import none of them.
+EXECUTORS = ("local_executor", "distributed", "fte")
+
+
+def _imports(path):
+    """(module path, level, imported names, line) of every import statement of
+    ``path``, at any depth of nesting."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.ImportFrom):
+            yield (node.module or "", node.level,
+                   [a.name for a in node.names], node.lineno)
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                yield a.name, 0, [], node.lineno
+
+
+def _private_executor_imports(path):
+    return [(line, mod, name) for mod, _, names, line in _imports(path)
+            if mod.split(".")[-1] in EXECUTORS and path.stem != mod.split(".")[-1]
+            for name in names if name.startswith("_")]
+
+
+def test_no_private_import_from_an_executor():
+    pkg = EXEC_DIR.parent
+    hits = {str(p.relative_to(pkg)): h for p in sorted(pkg.rglob("*.py"))
+            if (h := _private_executor_imports(p))}
+    assert not hits, (
+        f"{hits}: a name another module needs does not live in an executor: "
+        "move it to exec/boundary.py, exec/groupby.py or exec/pages.py")
+
+
+def _executor_imports(path):
+    hits = []
+    for mod, level, names, line in _imports(path):
+        parts = mod.split(".")
+        if parts[-1] in EXECUTORS and "exec" in parts[:-1]:
+            hits.append((line, mod))
+        elif parts[-1] == "exec" and set(names) & set(EXECUTORS):
+            hits.append((line, mod))
+    return hits
+
+
+def test_execution_layer_does_not_import_exec_executors():
+    pkg = EXEC_DIR.parent
+    hits = {str(p.relative_to(pkg)): h
+            for d in ("execution", "ops") for p in sorted((pkg / d).glob("*.py"))
+            if (h := _executor_imports(p))}
+    assert not hits, (
+        f"{hits}: execution/ and ops/ sit below the executors and import "
+        "none of them (exec/boundary.py is theirs to import)")
+
+
+def test_the_import_lints_flag_what_they_are_for(tmp_path):
+    bad = tmp_path / "history.py"
+    bad.write_text(
+        "from ..exec.boundary import _host\n"                    # fine
+        "def f():\n"
+        "    from ..exec.local_executor import _host, LocalExecutor\n"
+        "    from ..exec import fte\n"
+        "    import trino_tpu.exec.distributed\n")
+    assert _private_executor_imports(bad) == [(3, "exec.local_executor", "_host")]
+    assert [line for line, _ in _executor_imports(bad)] == [3, 4, 5]
+    own = tmp_path / "fte.py"
+    own.write_text("from .fte import _x\nfrom .boundary import _jit\n")
+    assert _private_executor_imports(own) == []
+    assert _executor_imports(tmp_path / "fte.py") == []
+
+
+def test_the_boundary_imports_nothing_of_exec():
+    hits = [(line, mod) for mod, level, names, line
+            in _imports(EXEC_DIR / "boundary.py")
+            if (level == 1) or "exec" in mod.split(".")]
+    assert not hits, f"exec/boundary.py imports {hits}"

@@ -162,7 +162,7 @@ def test_the_sort_program_compiles_for_v5e(name, n, count, select, one_chip,
                                            no_persistent_cache):
     import time
 
-    from trino_tpu.exec import local_executor as LE
+    from trino_tpu.exec import pages
 
     strings = 2 if name == "dashboard_q1" else 0
     cols = tuple(_s(one_chip, (n,), jnp.int32) for _ in range(strings)) \
@@ -175,8 +175,8 @@ def test_the_sort_program_compiles_for_v5e(name, n, count, select, one_chip,
         else ((1, False, False, -1), (2, True, False, -1))
     narrow = tuple(np.dtype(np.int8) if i < strings else None for i in range(len(cols)))
     t0 = time.perf_counter()
-    text = LE._sorted_rows.lower(cols, nulls, _s(one_chip, (n,), jnp.bool_), luts,
-                                 keys, count, select, narrow, False).compile().as_text()
+    text = pages._sorted_rows.lower(cols, nulls, _s(one_chip, (n,), jnp.bool_), luts,
+                                    keys, count, select, narrow, False).compile().as_text()
     assert (" sort(" in text) != select  # the selection sorts nothing
     assert time.perf_counter() - t0 < 90
 
@@ -184,9 +184,9 @@ def test_the_sort_program_compiles_for_v5e(name, n, count, select, one_chip,
 def test_a_wide_group_bys_initial_state_compiles_for_v5e(one_chip, no_persistent_cache):
     """`agg.hash.init` at SF10 q18's 2^24 slots: fills, nothing folded into the program
     as a constant of the state's size."""
-    from trino_tpu.exec import local_executor as LE
+    from trino_tpu.exec import groupby
 
-    compiled = LE._hash_init.lower(
+    compiled = groupby._hash_init.lower(
         1 << 24, (jnp.int64,), ((jnp.int64, 0), (jnp.float64, 0))).compile()
     assert compiled.memory_analysis().generated_code_size_in_bytes < 1 << 20
 

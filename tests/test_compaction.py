@@ -1,5 +1,5 @@
 """Device-side row compaction: `ops/arrays.live_indices` and its four users
-(compact_rows' XLA branch, local_executor._compact_part / _compact_part_sized,
+(compact_rows' XLA branch, exec/pages._compact_part / _compact_part_sized,
 hashagg.compact_groups) against a numpy reference, and the structural guard
 that none of their programs holds a scatter (on a v5e an XLA scatter pays per
 INPUT lane, 175-290 ns each: PERF.md section 6, PR 26)."""
@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from trino_tpu.exec import local_executor as le
+from trino_tpu.exec import pages
 from trino_tpu.ops import hashagg
 from trino_tpu.ops import pallas_kernels as pk
 from trino_tpu.ops.arrays import compact_rows, live_indices
@@ -108,8 +108,8 @@ def test_compaction_parity(n, sel, out_len):
     vals = tuple(c for c in dcols if c is not None)
     nulls = tuple(jnp.asarray(rng.random(n) < 0.3) if i % 2 else None
                   for i in range(len(vals)))
-    ccols, cnulls = le._compact_part(vals, nulls, dvalid, psize)
-    scols, snulls, pvalid = le._compact_part_sized(vals, nulls, dvalid, psize)
+    ccols, cnulls = pages._compact_part(vals, nulls, dvalid, psize)
+    scols, snulls, pvalid = pages._compact_part_sized(vals, nulls, dvalid, psize)
     _same(pvalid, np.arange(psize) < count)
     for got_c, got_n in ((ccols, cnulls), (scols, snulls)):
         for src, got in zip(vals + nulls, got_c + got_n):
@@ -162,7 +162,7 @@ def test_compaction_programs_hold_no_scatter(site, tpch_sf001):
     mask = jax.ShapeDtypeStruct((n,), jnp.bool_)
     cols, nulls = (i64, f64, mask), (mask, None, None)
     if site == "jc_fn":
-        text = jax.jit(le._compact_page, static_argnums=3).lower(
+        text = jax.jit(pages._compact_page, static_argnums=3).lower(
             cols, nulls, mask, size).as_text()
     elif site == "compact_groups":
         slots = jax.ShapeDtypeStruct((n + 1,), jnp.int64)
@@ -171,7 +171,7 @@ def test_compaction_programs_hold_no_scatter(site, tpch_sf001):
             (slots,), jax.ShapeDtypeStruct((), jnp.bool_))
         text = hashagg.compact_groups.lower(state, size).as_text()
     else:
-        text = getattr(le, site).lower(cols, nulls, mask, size).as_text()
+        text = getattr(pages, site).lower(cols, nulls, mask, size).as_text()
     ops = _lowered_ops(text)
     assert "gather" in ops and "sort" in ops, ops
     assert not [op for op in ops if "scatter" in op], ops

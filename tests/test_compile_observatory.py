@@ -74,7 +74,7 @@ def test_jit_wrapper_detects_first_seen_signatures():
     dispatch count keeps counting every invocation."""
     import jax.numpy as jnp
 
-    from trino_tpu.exec.local_executor import _jit
+    from trino_tpu.exec.boundary import _jit
 
     f = _jit(lambda x: x * 2 + 1, site="obs.test")
     c = QueryCounters()
@@ -97,7 +97,7 @@ def test_failed_first_seen_dispatch_does_not_poison_seen():
     (the footgun this round retires)."""
     import jax.numpy as jnp
 
-    from trino_tpu.exec.local_executor import _jit
+    from trino_tpu.exec.boundary import _jit
 
     f = _jit(lambda x: x + 1, site="obs.fail")
     c = QueryCounters()
@@ -396,7 +396,7 @@ def test_jit_site_is_the_device_programs_name(site, module):
     plans lower one site to the same text (one compile-cache key)."""
     import jax.numpy as jnp
 
-    from trino_tpu.exec.local_executor import _jit
+    from trino_tpu.exec.boundary import _jit
 
     def named_step(x, k=2):
         return jnp.cumsum(x) * k
@@ -457,7 +457,7 @@ def test_compiles_are_requests_and_cache_misses_are_compilations(tmp_path):
     import jax.numpy as jnp
     from jax._src import compilation_cache
 
-    from trino_tpu.exec.local_executor import _jit
+    from trino_tpu.exec.boundary import _jit
 
     def make():
         return _jit(lambda x: jnp.cumsum(jnp.sin(x)) * 3.0,

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import trino_tpu.exec.local_executor as LE
+from trino_tpu.exec import pages
 from trino_tpu import Engine
 from trino_tpu.connectors.memory import MemoryConnector
 from trino_tpu.connectors.tpch import TpchConnector
@@ -60,7 +61,7 @@ CASES = {
                 "order by q desc, l_orderkey limit 40", (2, 0)),
     "limit_over_select_max": (
         "tpch", f"select l_orderkey, sum(l_quantity) q from lineitem group by l_orderkey "
-                f"order by q desc, l_orderkey limit {LE.TOPN_SELECT_MAX + 500}", (2, 0)),
+                f"order by q desc, l_orderkey limit {pages.TOPN_SELECT_MAX + 500}", (2, 0)),
     "limit_over_the_groups": ("mem", "select s, count(*) c from t group by s "
                                      "order by c, s nulls last limit 100", (2, 0)),
     "float_key": ("mem", "select g, sum(x) q from t group by g order by q desc limit 3",
@@ -99,7 +100,8 @@ def test_the_compiled_tail_answers_as_the_fallback_does(name, monkeypatch):
     counters = e.last_query_counters
     assert (counters.tail_compiled, counters.tail_eager) == tail
     same_bytes(first, got)
-    monkeypatch.setattr(LE, "_topn_page_device", lambda *args, **kwargs: None)
+    for module in (LE, pages):  # the executor's TopN, and pages' own full sort
+        monkeypatch.setattr(module, "_topn_page_device", lambda *args, **kwargs: None)
     monkeypatch.setattr(LE.LocalExecutor, "_device_finalize", lambda self, node: None)
     e, _ = engine()
     want = e.execute_sql(sql, e.create_session(catalog))
@@ -133,10 +135,10 @@ def test_a_packed_pages_sort_is_the_host_sorts(live, count):
 
     counters = tracing.QueryCounters()
     with tracing.track_counters(counters):
-        got = LE._topn_page_device(page, keys, count)
+        got = pages._topn_page_device(page, keys, count)
     assert not any(site.endswith("sort.count") for site in counters.sites), counters.sites
     plain = Page(page.schema, page.columns, page.null_masks, page.valid)
-    want = LE._sort_page(plain, keys) if count is None else LE._topn_page(plain, keys, count)
+    want = pages._sort_page(plain, keys) if count is None else pages._topn_page(plain, keys, count)
     assert got.capacity == want.capacity == min(live, live if count is None else count)
     for a, b in zip(got.columns, want.columns):
         assert np.asarray(a).dtype == np.asarray(b).dtype
@@ -146,7 +148,7 @@ def test_a_packed_pages_sort_is_the_host_sorts(live, count):
         assert a is None or np.asarray(a).tolist() == np.asarray(b).tolist()
     # a page that does NOT know its count pays the one pull, and answers the same
     with tracing.track_counters(counters):
-        again = LE._topn_page_device(plain, keys, count)
+        again = pages._topn_page_device(plain, keys, count)
     assert any(site.endswith("sort.count") for site in counters.sites) == (count is None)
     for a, b in zip(again.columns, want.columns):
         assert np.asarray(a).tolist() == np.asarray(b).tolist()

@@ -251,19 +251,3 @@ def test_index_join_lookup(fed_engine, probe_catalog):
     spec = list(conn._pushed.values())[-1]
     assert spec["kind"] == "index"
     assert sorted(spec["keys"]) == [5, 9, 700]
-
-
-def test_index_join_disabled_env(fed_engine, probe_catalog, monkeypatch):
-    e, s = fed_engine
-    conn = e.catalogs["db"]
-    monkeypatch.setenv("TRINO_TPU_INDEX_JOIN", "0")
-    before = conn.pushed_queries
-    n_handles = len(conn._pushed)
-    r = e.execute_sql(
-        "select count(*) c from m2.default.probe p, db.default.users u "
-        "where p.uid = u.uid", s).to_pandas()
-    assert r["c"].iloc[0] == 4
-    # the kill switch must actually suppress the pushdown, not just
-    # coincidentally produce the right count
-    assert conn.pushed_queries == before
-    assert len(conn._pushed) == n_handles

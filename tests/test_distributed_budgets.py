@@ -16,12 +16,9 @@ Re-derive after an INTENTIONAL executor change with:
 
 Measured trace the ceilings derive from (2026-08-06, jax 0.7 CPU mesh):
 
-    q3  warm device: dist bytes 20586 (agg.groups 20480), pulled 20610
-        warm spool:  dist bytes 25322984 (1230x)
-    q9  warm device: dist bytes 9349, pulled 9403
-        warm spool:  dist bytes 23522761 (2516x)
-    q18 warm device: dist bytes 563, pulled 598
-        warm spool:  dist bytes 33887208 (60190x)
+    q3  warm: dist bytes 20586 (agg.groups 20480), pulled 20610
+    q9  warm: dist bytes 9349, pulled 9403
+    q18 warm: dist bytes 563, pulled 598
         (PR 30, re-measured through this file's own _warm_run: q18's semi-join
         moved under orders, inside the first join's build fragment
         (PushSemiJoinThroughJoin): dist bytes 554, pulled 592 where the parent
@@ -116,10 +113,9 @@ def dist_env():
     return engine, mesh, plans, baselines
 
 
-def _warm_run(engine, mesh, plan, device_exchange):
+def _warm_run(engine, mesh, plan):
     """Cold + warm run on one executor; returns (warm frame, warm counters)."""
-    ex = DistributedExecutor(engine.catalogs, mesh=mesh,
-                             device_exchange=device_exchange)
+    ex = DistributedExecutor(engine.catalogs, mesh=mesh)
     ex.execute(plan)
     warm = ex.execute(plan).to_pandas()
     return warm, ex.counters
@@ -128,7 +124,7 @@ def _warm_run(engine, mesh, plan, device_exchange):
 @pytest.mark.parametrize("name", list(QUERIES))
 def test_mesh_warm_budget(dist_env, name):
     engine, mesh, plans, baselines = dist_env
-    warm, c = _warm_run(engine, mesh, plans[name], device_exchange=True)
+    warm, c = _warm_run(engine, mesh, plans[name])
     # byte-identity vs the local executor (the acceptance contract)
     _frames_equal(warm, baselines[name])
     sites = c.sites
@@ -145,27 +141,3 @@ def test_mesh_warm_budget(dist_env, name):
     assert c.host_bytes_pulled <= lim["host_bytes_pulled"], \
         f"{name}: total pulled {c.host_bytes_pulled} > " \
         f"{lim['host_bytes_pulled']}: {site_table}"
-
-
-def test_mesh_exchange_ab_ratio(dist_env):
-    """The round-18 acceptance number: the device-resident exchange cuts
-    warm Q3 exchange-site host bytes >= 10x vs the host spool (measured
-    1230x at this scale — 10x is the never-regress floor)."""
-    engine, mesh, plans, baselines = dist_env
-    dev_f, dev_c = _warm_run(engine, mesh, plans["q3"], device_exchange=True)
-    sp_f, sp_c = _warm_run(engine, mesh, plans["q3"], device_exchange=False)
-    _frames_equal(dev_f, baselines["q3"])
-    _frames_equal(sp_f, baselines["q3"])  # both modes byte-identical
-    dev = sum(v["bytes"] for k, v in dev_c.sites.items() if "dist." in k)
-    sp = sum(v["bytes"] for k, v in sp_c.sites.items() if "dist." in k)
-    assert dev > 0  # scalar flag syncs still counted (the path stays honest)
-    assert sp >= 10 * dev, f"spool {sp} vs device {dev}: ratio collapsed"
-
-
-def test_device_exchange_defaults_on(monkeypatch):
-    """TRINO_TPU_DEVICE_EXCHANGE unset = ON everywhere (the mesh path IS the
-    round-18 contract); =0 restores the host spool for A/B captures."""
-    monkeypatch.delenv("TRINO_TPU_DEVICE_EXCHANGE", raising=False)
-    assert DistributedExecutor({}, mesh=worker_mesh(8)).device_exchange
-    monkeypatch.setenv("TRINO_TPU_DEVICE_EXCHANGE", "0")
-    assert not DistributedExecutor({}, mesh=worker_mesh(8)).device_exchange

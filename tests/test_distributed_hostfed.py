@@ -116,6 +116,39 @@ def test_parquet_hostfed_distributed(tmp_path_factory, mesh8):
     _frames_equal(dist, local)
 
 
+@pytest.mark.parametrize("reason", ["no_batch", "decimal38"])
+def test_what_the_host_spool_rule_names(reason, mesh8):
+    """``exec/distributed._host_spooled``: a stream with no batch takes the host
+    spool and is an empty page (the parent raised from ``np.concatenate``); a
+    ``decimal(38, s)`` column is stored int64 like any decimal, holds no object
+    values, and goes through the device buffers of a mesh materialise and a
+    mesh sort like every other column."""
+    from trino_tpu.connectors.memory import MemoryConnector
+
+    e = Engine()
+    e.register_catalog("mem", MemoryConnector())
+    s = e.create_session("mem")
+    e.execute_sql("create table d (a bigint, b decimal(38,2))", s)
+    if reason == "decimal38":
+        e.execute_sql("insert into d values (1, 1234567890123456.25), "
+                      "(2, 1.50), (1, 2.25), (3, null)", s)
+    for sql in ("select a, b from d", "select a, b from d order by b, a"):
+        local = e.execute_sql(sql, s).to_pandas()
+        dist = e.execute_sql(sql, s, distributed=True, mesh=mesh8).to_pandas()
+        if "order by" not in sql:
+            local, dist = (f.sort_values(["a", "b"], ignore_index=True)
+                           for f in (local, dist))
+        assert len(dist) == (4 if reason == "decimal38" else 0)
+        _frames_equal(dist, local)
+        trace = "\n".join(e.execute_sql(
+            "explain analyze " + sql, s, distributed=True,
+            mesh=mesh8).columns[0].tolist())
+        # no batch, no sample to cut ranges from: the sort runs over the
+        # mesh's (empty) materialised scan
+        assert ("[mesh] Sort" if reason == "decimal38" and "order by" in sql
+                else "[mesh] TableScan") in trace, trace
+
+
 def test_exec_trace_reports_modes(mem_engine, mesh8):
     """EXPLAIN ANALYZE on a distributed run prints each fragment's actual
     execution mode with fallback reasons (no silent fallback)."""
@@ -143,7 +176,6 @@ def test_rollup_distributes_per_branch(mesh8):
            "order by l_returnflag, l_linestatus")
     local = e.execute_sql(sql, s).to_pandas()
     ex = DistributedExecutor(e.catalogs, mesh=mesh8)
-    from trino_tpu.exec.local_executor import _sort_page  # noqa: F401 (plan shape doc)
     dist = e.execute_sql(sql, s, distributed=True, mesh=mesh8).to_pandas()
     assert local.shape == dist.shape
     for c in local.columns:

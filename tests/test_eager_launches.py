@@ -33,10 +33,10 @@ import trino_tpu
 from benchmark.statements import agg_lineitem, agg_orders, q1, q3
 from trino_tpu import Engine
 from trino_tpu.connectors.tpch import TpchConnector
-from trino_tpu.exec import local_executor
+from trino_tpu.exec import boundary
 
 ROOT = str(pathlib.Path(trino_tpu.__file__).resolve().parent)
-EXECUTOR = str(pathlib.Path(local_executor.__file__).resolve())
+BOUNDARY = str(pathlib.Path(boundary.__file__).resolve())
 
 
 class avg_of_sums:
@@ -88,7 +88,7 @@ class Launches:
         where, f = None, sys._getframe(2)
         while f is not None:
             code = f.f_code
-            if code.co_filename == EXECUTOR and code.co_name in ("run", "_generate"):
+            if code.co_filename == BOUNDARY and code.co_name in ("run", "_generate"):
                 return  # a counted launch: a program of `_jit`, a generator's
             if where is None and code.co_filename.startswith(ROOT):
                 where = f"{code.co_filename[len(ROOT) + 1:]}:{f.f_lineno} {code.co_name}"
@@ -143,12 +143,12 @@ def test_the_census_sees_an_eager_call_and_a_jitted_wrapper(engine):
     would take the fast path), and a counted program that is none of its business."""
     import jax.numpy as jnp
 
-    def under_the_program():  # a frame under trino_tpu/: the executor's, by file name
+    def under_the_program():  # a frame under trino_tpu/, by file name
         code = compile("a = jnp.arange(8) + 1\nb = jnp.where(a > 2, a, 0)\n"
                        "b = jnp.where(a > 3, a, 0)\nc = program(a)\n",
-                       EXECUTOR, "exec")
+                       BOUNDARY, "exec")
         exec(code, {"jnp": jnp,
-                    "program": local_executor._jit(lambda x: x * 2, site="test.program")})
+                    "program": boundary._jit(lambda x: x * 2, site="test.program")})
 
     with Launches() as census:
         under_the_program()

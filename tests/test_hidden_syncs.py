@@ -5,7 +5,7 @@ A dynamic census, because a static lint cannot tell ``bool(x)`` of a device scal
 ``bool(x)`` of a host one: ``ArrayImpl._value`` is the one place a jax array becomes a
 host value (``bool()``, ``int()``, ``float()``, ``.item()``, ``np.asarray``,
 ``jax.device_get`` all read it), so it is wrapped, and a read whose stack holds a frame
-under ``trino_tpu/`` but not ``local_executor._host`` is a hidden sync: no ``host_pull``
+under ``trino_tpu/`` but not ``exec.boundary._host`` is a hidden sync: no ``host_pull``
 span, no ``host_transfers`` count, no in-flight entry for the stall watchdog, no fault
 point.  (The CPU backend ignores ``jax.transfer_guard_device_to_host``, so the guard
 cannot do this here.)  A warm replay of the benchmark's statements makes none.
@@ -29,14 +29,14 @@ from benchmark.statements import ds_q51, ds_q93, q1, q3, q9, q18
 from trino_tpu import Engine
 from trino_tpu.connectors.tpcds import TpcdsConnector
 from trino_tpu.connectors.tpch import TpchConnector
-from trino_tpu.exec import local_executor
+from trino_tpu.exec import boundary
 from trino_tpu.execution import faults, tracing
 from trino_tpu.execution.bufferpool import DeviceBufferPool
 from trino_tpu.execution.tracing import WALL_BUCKETS
 from trino_tpu.parallel.mesh import worker_mesh
 
 ROOT = str(pathlib.Path(trino_tpu.__file__).resolve().parent)
-HOST_FILE = str(pathlib.Path(local_executor.__file__).resolve())
+HOST_FILE = str(pathlib.Path(boundary.__file__).resolve())
 
 
 class avg_of_sums:
@@ -144,7 +144,7 @@ def test_a_warm_replay_reads_no_device_value_outside_host(engines, where, catalo
 def test_a_planted_bool_of_a_device_scalar_is_caught(engines, monkeypatch):
     """The census is not blind: the sync this PR took out, planted again (PR 39: where
     the group-by's finalize reads its one pull, the count now rides with the flag)."""
-    real = local_executor.tracing.record_compaction
+    real = tracing.record_compaction
 
     def planted(lanes_in, lanes_out):
         import jax.numpy as jnp
@@ -152,7 +152,7 @@ def test_a_planted_bool_of_a_device_scalar_is_caught(engines, monkeypatch):
         bool(jnp.zeros(()) > 0)  # what `if not bool(state.overflow)` was
         return real(lanes_in, lanes_out)
 
-    monkeypatch.setattr(local_executor.tracing, "record_compaction", planted)
+    monkeypatch.setattr(tracing, "record_compaction", planted)
     engine = engines["plain"]
     session = engine.create_session("tpch")
     with Census() as census:

@@ -162,7 +162,7 @@ def test_vectorized_matcher_agrees_with_backtracker():
     randomized data — and must actually ACTIVATE for it."""
     import numpy as np
 
-    import trino_tpu.ops.matcher as M
+    import trino_tpu.exec.local_executor as M  # where `_run_match_recognize` looks `vector_match` up
     from trino_tpu import Engine
     from trino_tpu.connectors.memory import MemoryConnector
 

@@ -12,6 +12,7 @@ pinned contract and the on-device capture artifact cannot drift.
 
 import os
 import threading
+from functools import partial
 
 import pytest
 
@@ -56,8 +57,8 @@ def test_pressure_scenario(env, name, tmp_path):
                            if n == name)
     scratch = tmp_path / "spill"
     scratch.mkdir()
-    rec = run_pressure_scenario(engine, plan, baseline, name, cfg, spec,
-                                kind, str(scratch))
+    rec = run_pressure_scenario(partial(LocalExecutor, engine.catalogs), plan,
+                                baseline, name, cfg, spec, kind, str(scratch))
     assert rec["ok"], rec
 
 

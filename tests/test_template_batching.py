@@ -455,8 +455,9 @@ def test_batcher_singleton_window_runs_serial():
     assert not lane.busy
 
 
-def test_batcher_env_disable(monkeypatch):
-    monkeypatch.setenv("TRINO_TPU_TEMPLATE_BATCH", "0")
-    assert not BA.TemplateBatcher().enabled
-    monkeypatch.setenv("TRINO_TPU_TEMPLATE_BATCH", "1")
-    assert BA.TemplateBatcher().enabled
+def test_batcher_disabled_runs_every_request_serially():
+    off = BA.TemplateBatcher(enabled=False)
+    assert not off.enabled and BA.TemplateBatcher(enabled=True).enabled
+    assert BA.TemplateBatcher().enabled  # on is what runs
+    got = off.execute("k", (1,), lambda rt: ("serial", rt), None)
+    assert got == (("serial", (1,)), 0) and off.info()["batches_total"] == 0

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import trino_tpu.exec.local_executor as LE
+from trino_tpu.exec import pages
 from trino_tpu import Engine
 from trino_tpu.connectors.tpch import TpchConnector
 from trino_tpu.ops.arrays import first_rows
@@ -46,11 +46,11 @@ def test_served_topn_is_the_sorted_answers_head(name, monkeypatch):
         return e
 
     calls = []
-    real = LE.first_rows
-    monkeypatch.setattr(LE, "first_rows", lambda keys, count: calls.append(count) or real(keys, count))
+    real = pages.first_rows
+    monkeypatch.setattr(pages, "first_rows", lambda keys, count: calls.append(count) or real(keys, count))
     got = engine().execute_sql(SQLS[name]).to_pandas()
     assert calls, "the selection did not take this TopN"
-    monkeypatch.setattr(LE, "TOPN_SELECT_MAX", 0)  # the sort takes it
+    monkeypatch.setattr(pages, "TOPN_SELECT_MAX", 0)  # the sort takes it
     del calls[:]
     want = engine().execute_sql(SQLS[name]).to_pandas()
     assert not calls

@@ -99,7 +99,7 @@ class IcebergConnector:
     # the buffer pool keeps decoded columns device-resident across queries
     name = "iceberg"
     HOST_DECODE = True  # pages decode on the host: scans benefit from
-    # background-thread split prefetch (see local_executor._prefetched_pages)
+    # background-thread split prefetch (see exec/boundary._prefetched_pages)
 
     def __init__(self, warehouse: str):
         self.warehouse = warehouse

@@ -115,7 +115,7 @@ class ParquetConnector:
     supports_count_pushdown = True  # exact footer row counts; DDL/DML bumps plan_version
     name = "parquet"
     HOST_DECODE = True  # pages decode on the host: scans benefit from
-    # background-thread split prefetch (see local_executor._prefetched_pages)
+    # background-thread split prefetch (see exec/boundary._prefetched_pages)
 
     def __init__(self, directory: str):
         self.directory = directory

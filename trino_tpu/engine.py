@@ -13,6 +13,11 @@ import itertools
 import re
 from typing import Optional
 
+from .exec.boundary import DISPATCH_BATCH
+from .exec.local_executor import BatchUnsupported, LocalExecutor
+from .exec.pages import MaterializedResult
+from .sql.plan import _plan_fingerprint
+
 __all__ = ["Engine", "Session"]
 
 _query_ids = itertools.count(1)
@@ -112,7 +117,7 @@ class Engine:
         self.event_listeners = EventListenerManager()
         self.session_properties = SessionPropertyManager()
         self.tracer = Tracer()
-        # device-boundary accounting (exec/local_executor counters): lifetime
+        # device-boundary accounting (exec/boundary's counters): lifetime
         # totals for the /v1/metrics Prometheus surface plus the most recent
         # local plan execution's snapshot (what EXPLAIN ANALYZE and the
         # benchmark report per query)
@@ -169,8 +174,8 @@ class Engine:
         # plan decisions.  Consulted at statement admission (after the
         # plan-cache key resolves), fed by _record_plan_history on every
         # clean completion.  Host-only — zero _jit/_host traffic; the
-        # budget suite runs with it enabled.  TRINO_TPU_ADAPTIVE=0 (or the
-        # adaptive_execution session property) disables per statement.
+        # budget suite runs with it enabled.  The adaptive_execution session
+        # property disables per statement.
         from .execution.adaptive import AdaptiveAdvisor
 
         self.adaptive_advisor = AdaptiveAdvisor(
@@ -180,7 +185,7 @@ class Engine:
         # one CONFIRMED template into ONE batched device program
         # (LocalExecutor.execute_batched) with per-request result demux.
         # Zero cost when idle — the first request on an idle lane runs the
-        # unmodified serial path.  TRINO_TPU_TEMPLATE_BATCH=0 disables.
+        # unmodified serial path.
         from .execution.batcher import TemplateBatcher
 
         self.template_batcher = TemplateBatcher()
@@ -599,8 +604,6 @@ class Engine:
         Deliberately NO _record_plan_history: fused actuals are batch-shaped
         (N lanes in one page) and would corrupt the per-statement EWMAs the
         adaptive advisor reads — the serial path remains the history feeder."""
-        from .exec.local_executor import BatchUnsupported
-
         ex = self._checkout_executor()
         ex.dispatch_batch = _effective_dispatch_batch(sess)
         ex.page_cache = _effective_page_cache(sess)
@@ -694,8 +697,6 @@ class Engine:
             conn = self.catalogs.get(name)
             if conn is None or not DeviceBufferPool.cacheable(conn):
                 return None
-        from .exec.local_executor import _plan_fingerprint
-
         fp = _plan_fingerprint(plan, self.catalogs)
         return ("result", fp, tuple(sorted(catalogs)),
                 bool(distributed), bool(fault_tolerant),
@@ -740,8 +741,6 @@ class Engine:
             snap.result_cache_misses += 1
         with self._init_lock:
             self.counters_total.result_cache_misses += 1
-        from .exec.local_executor import MaterializedResult
-
         if not isinstance(result, MaterializedResult):
             return
         try:
@@ -807,8 +806,6 @@ class Engine:
                     if not cache:
                         ex.forget_plan(plan)  # a plan nobody will replay
                     self._account_counters(ex.counters)
-        from .exec.local_executor import LocalExecutor
-
         if not cache:
             # throwaway executors still share the engine's buffer pool: an
             # eager subquery / DML row-eval over a hot table reads the cached
@@ -1009,7 +1006,6 @@ class Engine:
         if not skew or ph is None or not ph.enabled:
             return
         try:
-            from .exec.local_executor import _plan_fingerprint
             from .execution.history import plan_node_paths
 
             paths = plan_node_paths(plan)
@@ -1086,8 +1082,6 @@ class Engine:
         return local
 
     def _checkout_executor(self):
-        from .exec.local_executor import LocalExecutor
-
         with self._init_lock:
             if self._executor_sem is None:
                 import threading
@@ -2153,7 +2147,6 @@ class Engine:
         ShowQueriesRewrite-backed SHOW statements over information_schema)."""
         import numpy as np
 
-        from .exec.local_executor import MaterializedResult
         from .types import VarcharType
 
         def result(names, rows):
@@ -2262,7 +2255,6 @@ class Engine:
 
         import numpy as np
 
-        from .exec.local_executor import MaterializedResult
         from .sql.frontend import Planner
         from .sql.planprinter import format_plan
         from .types import VarcharType
@@ -2305,8 +2297,6 @@ class Engine:
                     lines += [f"  [{mode}] {label}{note}"]
             lines += [f"-- executed in {dt * 1000:.1f} ms, {len(res)} output rows"]
         elif ast.analyze:
-            from .exec.local_executor import LocalExecutor
-
             if decision is not None and decision.get("verdict") == "replan":
                 # ANALYZE executes what the serving path would: the frozen
                 # corrections re-plan here too, and the feedback slots mark
@@ -2390,7 +2380,6 @@ class Engine:
 
         import numpy as np
 
-        from .exec.local_executor import MaterializedResult
         from .sql.planprinter import format_plan
         from .types import VarcharType
 
@@ -2634,18 +2623,14 @@ def _plan_shape_props(sess) -> tuple:
 
 def _effective_adaptive(sess) -> bool:
     """``adaptive_execution`` tri-state session property: the session's
-    setting when present, else the TRINO_TPU_ADAPTIVE env default (ON).
+    setting when present, else ON.
     PLAN-SHAPING (rides _plan_shape_props): the advisor diverts statements
     to corrected plans keyed off the base plan-cache key, so sessions with
     adaptive on and off must never share a key — SET SESSION
     adaptive_execution=false must actually escape the corrected plan."""
-    import os
-
     props = getattr(sess, "properties", None) or {}
     v = props.get("adaptive_execution")
-    if v is not None:
-        return bool(v)
-    return os.environ.get("TRINO_TPU_ADAPTIVE", "1") != "0"
+    return True if v is None else bool(v)
 
 
 def _session_with_corrections(sess, corrections):
@@ -2701,16 +2686,14 @@ def _effective_result_cache(sess):
 
 def _effective_dispatch_batch(sess) -> int:
     """The dispatch-coalescing width a session's queries run at: the
-    ``dispatch_batch`` session property when set (>0), else the
-    TRINO_TPU_DISPATCH_BATCH process default (4)."""
-    from .exec.local_executor import _dispatch_batch_default
-
+    ``dispatch_batch`` session property when set (>0), else
+    ``boundary.DISPATCH_BATCH``."""
     props = getattr(sess, "properties", None) or {}
     try:
         v = int(props.get("dispatch_batch", 0) or 0)
     except (TypeError, ValueError):
         v = 0
-    return v if v > 0 else _dispatch_batch_default()
+    return v if v > 0 else DISPATCH_BATCH
 
 
 def _coerce_host(values, src_t, dst_t):
@@ -2787,7 +2770,6 @@ def _dml_count_result(n: int, label: str = "rows"):
     updateCount / UPDATE n rows output)."""
     import numpy as np
 
-    from .exec.local_executor import MaterializedResult
     from .types import BIGINT
 
     col = np.array([int(n)], np.int64)

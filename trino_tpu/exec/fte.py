@@ -36,18 +36,15 @@ from ..execution import faults, tracing
 from ..ops import hashagg
 from ..page import Page, Schema
 from ..sql import plan as P
-from .local_executor import (LocalExecutor, _accumulators_for, _finalize_aggs,
-                             _host, _materialize)
+from .boundary import _host
+from .groupby import _MERGE_KIND, _accumulators_for, _finalize_aggs
+from .local_executor import LocalExecutor
+from .pages import _host_page, _materialize
+from .spill import concat_host_chunks, padded_page
 
 __all__ = ["FailureInjector", "InjectedFailure", "SpoolingExchange",
            "FaultTolerantExecutor", "serialize_page", "deserialize_page",
            "is_retryable_failure"]
-
-_MERGE_KIND = {"sum": "sum", "count": "sum", "count_star": "sum",
-               "min": "min", "max": "max", "sum_sq": "sum",
-               # two-limb partial sums merge by PLAIN addition (the limbs are
-               # already split; splitting again would corrupt them)
-               "sum_hi32": "sum", "sum_lo32": "sum"}
 
 _MAGIC = b"TTPG"
 
@@ -341,7 +338,7 @@ class FaultTolerantExecutor:
     def execute(self, plan: P.PlanNode, dispatch_batch=None):
         with self._lock:
             # per-query dispatch-coalescing width (the executor is engine-
-            # cached across queries; None = TRINO_TPU_DISPATCH_BATCH default)
+            # cached across queries; None = boundary.DISPATCH_BATCH)
             self.local.dispatch_batch = dispatch_batch
             self.local._overrides = {}
             self._task_seq = 0
@@ -470,8 +467,6 @@ class FaultTolerantExecutor:
 
     def _serialize_result(self, page: Page) -> bytes:
         """Compact (valid rows only) + frame a fragment output page."""
-        from .local_executor import _host_page
-
         valid, pcols, pnulls = _host_page(page)
         cols = [c[valid] for c in pcols]
         nulls = [None if (n is None or not n[valid].any()) else n[valid]
@@ -718,8 +713,6 @@ def read_fragment_outputs(exchange: SpoolingExchange, task_ids, schema):
     power-of-two shape bucket — spooled lengths are data-dependent, and every
     distinct raw shape would cost a fresh XLA compile in the consuming
     pipeline.  An empty task set (zero-split source) yields an empty page."""
-    from .spill import concat_host_chunks, padded_page
-
     ncols = len(schema.fields)
     if not task_ids:
         cols = tuple(jnp.asarray(
@@ -745,8 +738,6 @@ def read_streamed_outputs(fetch_stream, task_ids, schema):
     pipelined data plane) instead of the spool: ``fetch_stream(task_id)``
     yields page envelopes as the producer emits them; chunks concatenate into
     the same padded override page the spool path builds."""
-    from .spill import concat_host_chunks, padded_page
-
     ncols = len(schema.fields)
     parts = []
     for t in task_ids:
@@ -811,8 +802,6 @@ def run_fragment(local: LocalExecutor, node, exchange_dir: str,
     spool or from upstream streaming buffers; returns the serialized output
     envelope.  The caller must hand this task its OWN executor (overrides are
     executor-global)."""
-    from .local_executor import _host_page
-
     saved = local._overrides
     local._overrides = resolve_remote_sources(exchange_dir, node,
                                               stream_sources, fetch_stream)
@@ -872,8 +861,6 @@ def run_stream_splits(local: LocalExecutor, node, exchange_dir: str,
         local._overrides = saved
     if sink is not None:
         return b""
-    from .spill import concat_host_chunks
-
     cols, nulls = concat_host_chunks(stream.schema, parts)
     return serialize_fragment_output(cols, nulls, dicts)
 

@@ -18,7 +18,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import threading
+import itertools
+import time as _time
 from functools import partial
 from typing import Callable, Optional
 
@@ -28,335 +29,55 @@ import numpy as np
 
 from ..connectors.tpch import Dictionary
 from ..execution import faults, tracing
+from ..execution.history import estimate_plan_rows, plan_node_paths
+from ..memory import MemoryPool
 from ..ops import hashagg
-from ..ops.arrays import compact_rows, first_rows, gather_rows, live_indices
+from ..ops.arrays import (ArrayData, MapData, compact_rows, pack_span,
+                          span_len, span_start, unnest_indices)
+from ..ops.exchange import partition_ids
 from ..ops.hashing import ceil_pow2
 from ..ops.hashjoin import (DIRECT_JOIN_RANGE_MAX, DirectJoinTable,
-                            DirectMultiJoinTable, JoinTable, MultiJoinTable,
+                            DirectMultiJoinTable, JoinTable,
                             build_insert, build_table_init, direct_build,
                             direct_match, direct_multi_build, direct_probe,
                             direct_probe_slots,
                             expand_counts, multi_build, probe, probe_counted,
                             probe_slots, probe_widths,
                             stage_direct_table, GATHER_FIELDS, MATCH_FIELDS)
+from ..ops.matcher import vector_match
+from ..ops.window import (_window_kernel, _window_sort_passes,
+                          _window_spec_dicts)
 from ..page import Field, Page, Schema
-from ..types import BIGINT, DOUBLE, BOOLEAN, INTEGER, DecimalType, Type
+from ..spi.predicate import UNION_LIMIT, Domain, Range
+from ..types import BIGINT, INTEGER
+from ..sql import ir as _ir
 from ..sql import plan as P
-from ..sql.ir import Call, Constant, Expr, FieldRef, evaluate, evaluate_predicate
+from ..sql.analyzer import _coerce
+from ..sql.domain_translator import (domain_to_split_pruner,
+                                     extract_domains, split_conjuncts)
+from ..sql.ir import FieldRef, evaluate, evaluate_predicate
+from ..sql.plan import _plan_fingerprint
+from .boundary import (DISPATCH_BATCH, _coalesced_batches,
+                       _current_batch_host_params, _current_host_params,
+                       _current_params, _generate, _host, _jit,
+                       _page_batch_sig, _page_to_device, _params_scope,
+                       _prefetched_pages, _stack_pages, _statement_scopes)
+from .groupby import (DEFAULT_GROUP_CAPACITY, FIRST_CAPACITY_CAP,
+                      MAX_GROUP_CAPACITY, _acc_input_expr, _accumulators_for,
+                      _device_finalize_plan, _direct_init, _finalize_aggs,
+                      _finalize_aggs_device, _global_agg_update,
+                      _global_init_state, _group_count, _group_state_bytes,
+                      _hash_init, _MERGE_KIND)
+from .spill import SpilledPartitions
+from .pages import (MaterializedResult, _build_key_stats, _build_null_stats,
+                    _collation_rank_lut, _compact_page, _compact_part,
+                    _concat_all, _concat_bindings_parts, _concat_stream,
+                    _gather_build, _host_page, _limit_page, _materialize,
+                    _materialize_host, _null_aware_anti, _page_bytes,
+                    _sort_page, _sort_page_device, _split_base_rows,
+                    _topn_page, _topn_page_device, _values_page)
 
-__all__ = ["LocalExecutor", "MaterializedResult"]
-
-
-_WRAPPER_SEQ = [0]  # monotonic _jit-wrapper ids (storm-detection identity)
-_WRAPPER_SEQ_LOCK = threading.Lock()
-
-
-def _compile_memstats_enabled() -> bool:
-    """Opt-in executable-size capture (TRINO_TPU_COMPILE_MEMSTATS=1): the
-    AOT ``lower().compile().memory_analysis()`` path is NOT served by the
-    jit cache, so reading the executable size pays a SECOND trace+compile
-    per first-seen signature — off by default, worth it only on device
-    captures where executable HBM footprint is the question."""
-    import os
-
-    return os.environ.get("TRINO_TPU_COMPILE_MEMSTATS", "") == "1"
-
-
-def _executable_bytes(compiled, args, kw):
-    """Generated-code size of the executable for this call signature via the
-    AOT memory_analysis(), or None when unavailable (CPU reports 0 — treated
-    as unavailable; any failure is swallowed: the census never fails a
-    dispatch)."""
-    try:
-        ma = compiled.lower(*args, **kw).compile().memory_analysis()
-        return int(getattr(ma, "generated_code_size_in_bytes", 0) or 0) or None
-    except Exception:
-        return None
-
-
-def _jit(fn, site=None, **kwargs):
-    """``jax.jit`` + per-query dispatch accounting: every invocation of the
-    compiled function records one device dispatch on the active query's
-    counters (execution/tracing.QueryCounters).  Each dispatch is a host-side
-    launch, so this count IS the budget the warm-query tests pin.  ``site`` labels the call site for per-site
-    attribution (defaults to the wrapped function's name — bare ``@_jit`` on a
-    named step function self-labels; lambdas must pass ``site=``, enforced by
-    tests/test_boundary_lint.py); each invocation's wall time also feeds the
-    per-query + engine-total dispatch-latency histograms.  ``__wrapped__``
-    stays the original python function (callers use it to run the step eagerly
-    for untraceable object columns).  The site is also the device program's
-    name (tracing.site_program: XLA module ``jit_<site>``, body under
-    ``jax.named_scope(site)``), so a device trace can be read by site, and
-    each dispatch is a ``trino_tpu:dispatch`` annotation of the profiler.
-
-    Round 17 — the compile observatory lives HERE, so the boundary lint that
-    forces all executor code through ``_jit`` guarantees compile coverage the
-    same way it guarantees counters/in-flight/faults coverage.  Each wrapper
-    keeps a seen-signature set of the ABSTRACT arg signatures it has
-    dispatched (tracing.arg_signature — a host-side pytree walk, zero
-    dispatches/pulls, so the warm budget ceilings are untouched).  A
-    first-seen signature is a compile: the in-flight entry is flagged
-    ``compiling`` (the stall watchdog judges it against
-    TRINO_TPU_STALL_COMPILE_S and verdicts "compiling", not "stalled"), the
-    jax.monitoring compile events captured on this thread supply the
-    authoritative XLA duration (fallback: the dispatch wall), and the event
-    records to the query counters, a "compile" span, and the process-global
-    CompileLog census."""
-    import time as _time
-
-    label = site or getattr(fn, "__name__", "jit")
-    compiled = jax.jit(tracing.site_program(fn, label), **kwargs)
-    # two signature sets, both under `lock` (an unsynchronized check-then-
-    # act would double-record when concurrent queries race a shared
-    # MODULE-LEVEL wrapper's first dispatch):
-    #   claimed — signatures some in-flight dispatch owns RECORDING for
-    #             (claimed at entry, released on failure so the retry
-    #             re-claims and records THE compile);
-    #   done    — signatures that completed at least once.  The in-flight
-    #             `compiling` flag reads done, not claimed: a second
-    #             concurrent dispatch of a first-seen signature BLOCKS on
-    #             jax's compile just like the claimant, and must also read
-    #             as "compiling" to the watchdog, it just must not record
-    #             a second census event.
-    claimed: set = set()
-    done: set = set()
-    lock = threading.Lock()
-    # storm identity: distinct signatures are counted per WRAPPER (one
-    # compiled stream), not per label — "Aggregate#3" labels from different
-    # queries sharing one label must not pool into a phantom storm
-    with _WRAPPER_SEQ_LOCK:
-        _WRAPPER_SEQ[0] += 1
-        wrapper_id = _WRAPPER_SEQ[0]
-
-    def run(*args, **kw):
-        sig_key = tracing.arg_signature(args, kw)
-        with lock:
-            owns = sig_key not in claimed
-            if owns:
-                claimed.add(sig_key)
-            compiling = sig_key not in done
-        # in-flight registry entry/exit brackets the dispatch: a stuck
-        # device call is VISIBLE (site + operator + thread + elapsed
-        # + compiling flag) to the stall watchdog while it hangs, not just
-        # as a post-hoc latency-histogram blow-up
-        reg = tracing.current_inflight()
-        tok = reg.enter("dispatch", label, compiling=compiling)
-        cap = tracing.begin_compile_capture() if owns else None
-        t0 = _time.perf_counter()
-        ok = False
-        try:
-            if tracing.DISPATCH_TEST_HOOK is not None:
-                tracing.DISPATCH_TEST_HOOK(label)
-            # chaos chokepoint: an armed FaultPlan can raise/delay HERE, so
-            # every dispatch in the engine is injectable (disarmed = one
-            # global None test, nothing on the budget counters)
-            faults.maybe_inject("dispatch", label)
-            with tracing.annotate("dispatch"):
-                out = compiled(*args, **kw)
-            ok = True
-            return out
-        finally:
-            reg.exit(tok)
-            dt = _time.perf_counter() - t0
-            if owns:
-                xla_s = tracing.end_compile_capture(cap)
-                if ok:
-                    with lock:
-                        done.add(sig_key)
-                    exe = _executable_bytes(compiled, args, kw) \
-                        if _compile_memstats_enabled() else None
-                    tracing.record_compile(
-                        xla_s if xla_s is not None else dt, site=label,
-                        signature=tracing.signature_summary(sig_key),
-                        sig_key=f"{hash(sig_key) & 0xffffffffffffffff:016x}",
-                        exe_bytes=exe, wrapper=wrapper_id,
-                        cache_misses=tracing.compile_capture_misses(cap))
-                else:
-                    # a first-seen dispatch that raises (injected fault,
-                    # transient device error) records nothing and releases
-                    # the claim — the RETRY is the run that really
-                    # compiles, and it must still flag `compiling` or a
-                    # tight STALL_S reads the legit compile as a wedge
-                    with lock:
-                        claimed.discard(sig_key)
-            tracing.record_dispatch(site=label, seconds=dt)
-
-    run.__wrapped__ = fn
-    run.lower = compiled.lower  # the program as XLA will name it (tests)
-    return run
-
-
-# one process-wide registration of the jax.monitoring compile-event listener
-# (the /jax/core/compile/* duration family): idempotent, and harmless when
-# the runtime lacks monitoring (captures then fall back to dispatch wall)
-tracing.install_compile_listener()
-
-
-_PARAM_TLS = threading.local()
-
-
-@contextlib.contextmanager
-def _params_scope(values, host_values=(), batch_hosts=()):
-    """Publish the CURRENT query's bound parameter values (tuple of
-    ``(0-d device value, 0-d device isnull)`` pairs, one per plan-template
-    slot) for this thread.  The jitted step wrappers read it at CALL time and
-    pass it into the compiled function as an argument — parameters ride every
-    dispatch exactly like ``_Stream.aux`` (never closed over; round-5
-    invariant), so a warm template re-executes the SAME XLA executable with
-    new inputs.  Empty tuple = no parameters (zero pytree leaves, identical
-    compiled signature).  ``host_values`` keeps the pre-staging numpy pairs:
-    host-side consumers (bind-time split pruning) read them without paying a
-    device->host sync.  ``batch_hosts`` (round 21, continuous template
-    batching) carries the numpy runtime tuples of EVERY request in a fused
-    same-template batch: split pruning takes the UNION of the batch's kept
-    splits so one scan feeds all the stacked predicates.  A fused batch
-    publishes ONLY batch_hosts — ``values`` stays empty so a code path that
-    consumes per-request scalars outside the bindings-vmapped step fails
-    loudly instead of silently computing one member's answer for all."""
-    old = getattr(_PARAM_TLS, "values", ())
-    old_host = getattr(_PARAM_TLS, "host_values", ())
-    old_batch = getattr(_PARAM_TLS, "batch_hosts", ())
-    _PARAM_TLS.values = values
-    _PARAM_TLS.host_values = host_values
-    _PARAM_TLS.batch_hosts = batch_hosts
-    try:
-        yield
-    finally:
-        _PARAM_TLS.values = old
-        _PARAM_TLS.host_values = old_host
-        _PARAM_TLS.batch_hosts = old_batch
-
-
-def _current_params() -> tuple:
-    return getattr(_PARAM_TLS, "values", ())
-
-
-def _current_host_params() -> tuple:
-    return getattr(_PARAM_TLS, "host_values", ())
-
-
-def _current_batch_host_params() -> tuple:
-    """Host runtime tuples of every member of the CURRENT fused template
-    batch, or () outside one (see _params_scope)."""
-    return getattr(_PARAM_TLS, "batch_hosts", ())
-
-
-def _dispatch_batch_default() -> int:
-    """Engine-wide dispatch-coalescing width: how many shape-uniform scan
-    splits fold into ONE device dispatch.  Each dispatch is a host-side
-    launch, so batch K divides the per-split dispatch bill by ~K with
-    zero regeneration cost: pages are still produced once per split (a whole
-    scan fused into one program regenerated them, and lost on the chip).
-    ``TRINO_TPU_DISPATCH_BATCH=1`` restores exact per-split behavior; the
-    ``dispatch_batch`` session property overrides per query (and rides the
-    plan-cache key via engine._plan_shape_props)."""
-    import os
-
-    try:
-        v = int(os.environ.get("TRINO_TPU_DISPATCH_BATCH", "4"))
-    except ValueError:
-        return 4
-    return max(v, 1)
-
-
-def _page_batch_sig(page):
-    """Shape-class signature for dispatch coalescing, or None when the page
-    must never coalesce (exact wide-decimal object columns run eagerly; an
-    empty page has nothing to batch).  Pages group only with identical
-    signatures, so a stacked batch is one XLA shape class."""
-    for c in page.columns:
-        if isinstance(c, np.ndarray) and c.dtype == object:
-            return None
-    if page.capacity == 0:
-        return None
-    return (tuple((str(c.dtype), tuple(c.shape)) for c in page.columns),
-            tuple(m is not None for m in page.null_masks),
-            page.valid is not None)
-
-
-def _coalesced_batches(pages_iter, batch: int):
-    """Group consecutive shape-uniform pages for dispatch coalescing.
-
-    Yields ``(pages, live)``: a singleton ``([page], None)`` runs the ordinary
-    per-page path; a group runs the batched path with ``pages`` padded to
-    EXACTLY ``batch`` entries (short remainders repeat their last page) and
-    ``live`` a [batch] bool mask zeroing the padding's validity inside the
-    trace.  Fixed-K groups mean ONE compiled batch executable per page shape
-    — group-size-shaped executables (a 4-batch AND a 2-batch, etc.) would
-    multiply cold-compile time across every multi-split query.  Padding is
-    masked work the engine's mask-respecting operators already skip
-    semantically; it costs device FLOPs only, never a dispatch.  ``batch<=1``
-    degrades to singleton groups — byte-identical to un-batched iteration.
-    Groups record their REAL split count on the query counters (EXPLAIN
-    ANALYZE's "splits coalesced")."""
-    # closing THIS generator closes its source too (the finally below):
-    # consumer loops that unwind on an exception propagate the close down to
-    # the prefetch wrapper, whose own finally stops the producer thread —
-    # without it, the traceback pins the loop frame and the producer would
-    # sit pumping against a full queue until the traceback is released
-    try:
-        if batch <= 1:
-            for pg in pages_iter:
-                yield [pg], None
-            return
-        buf: list = []
-        sig = None
-
-        def flush():
-            while buf:
-                group, buf[:] = buf[:batch], buf[batch:]
-                if len(group) == 1:
-                    yield group, None
-                    continue
-                tracing.record_coalesced(len(group))
-                live = np.arange(batch) < len(group)
-                while len(group) < batch:  # pad: repeated page, live=False
-                    group.append(group[-1])
-                yield group, live
-
-        for pg in pages_iter:
-            s = _page_batch_sig(pg)
-            if s is None:
-                yield from flush()
-                sig = None
-                yield [pg], None
-                continue
-            if sig is not None and s != sig:
-                yield from flush()
-            sig = s
-            buf.append(pg)
-            if len(buf) >= batch:
-                yield from flush()
-        yield from flush()
-    finally:
-        close = getattr(pages_iter, "close", None)
-        if close is not None:
-            close()
-
-
-def _stack_pages(pages, live=None):
-    """Concatenate K uniform pages into one (cols, nulls, valid) triple INSIDE
-    a trace: the coalescing itself costs no dispatch, and row order is split
-    order, so every row-wise stream transform (filters, projections, LUT
-    gathers, join probes) computes exactly what K per-page runs would — the
-    engine's masks-not-shrinking page model is what makes plain concatenation
-    sound.  ``live`` ([K] bool) invalidates padding pages appended by
-    ``_coalesced_batches`` to hold the group at a fixed K.  Called only under
-    jit (from jitted_batch / the batched agg steps)."""
-    ncol = len(pages[0].columns)
-    n = pages[0].capacity
-    cols = tuple(jnp.concatenate([p.columns[ci] for p in pages])
-                 for ci in range(ncol))
-    nulls = tuple(
-        None if all(p.null_masks[ci] is None for p in pages)
-        else jnp.concatenate([
-            p.null_masks[ci] if p.null_masks[ci] is not None
-            else jnp.zeros((p.columns[ci].shape[0],), bool) for p in pages])
-        for ci in range(ncol))
-    valid = jnp.concatenate([p.valid_mask() for p in pages])
-    if live is not None:
-        valid = valid & jnp.repeat(jnp.asarray(live), n)
-    return cols, nulls, valid
+__all__ = ["LocalExecutor", "BatchUnsupported"]
 
 
 class BatchUnsupported(Exception):
@@ -384,40 +105,6 @@ def _batchable_plan(node) -> bool:
     if not isinstance(node, allowed):
         return False
     return all(_batchable_plan(c) for c in node.children)
-
-
-DEFAULT_GROUP_CAPACITY = 1 << 16
-# ceiling sized for SF10-class group counts on one chip (15M distinct
-# orderkeys need 32M slots to keep the probe load factor sane; ~40B/slot keeps
-# the table under ~1.3GB of a 16GB-HBM budget — the memory pool still gates
-# the actual reservation)
-MAX_GROUP_CAPACITY = 1 << 25
-# the most slots an ESTIMATE gives a hash group-by's first run: estimates
-# overshoot (a row bound is no group count).  A table that was cut to it may
-# take one step of four times the slots for ROOM, before a page with more live
-# lanes than it has slots (_run_hash_inserts); else only an overflow grows it
-FIRST_CAPACITY_CAP = 1 << 20
-
-
-@dataclasses.dataclass
-class MaterializedResult:
-    """Host-side query result (reference: testing MaterializedResult)."""
-
-    names: tuple
-    types: tuple
-    columns: list  # numpy arrays, decoded (strings as objects, decimals as floats)
-    raw_columns: list  # undecoded numpy arrays (dict ids / scaled ints)
-
-    def __len__(self):
-        return 0 if not self.columns else len(self.columns[0])
-
-    def rows(self):
-        return list(zip(*self.columns))
-
-    def to_pandas(self):
-        import pandas as pd
-
-        return pd.DataFrame({n: c for n, c in zip(self.names, self.columns)})
 
 
 @dataclasses.dataclass
@@ -486,8 +173,6 @@ class _Stream:
         """Jit-compiled page->(cols,nulls,valid) function, cached on the stream so
         repeated executions of a cached plan reuse the XLA executable."""
         if self._jitted is None:
-            from ..sql import ir as _ir
-
             def step(page, aux, params):
                 # params bind INSIDE the trace: ir.Parameter leaves read the
                 # traced argument, so bound values are runtime inputs — a
@@ -531,8 +216,6 @@ class _Stream:
         executable per page shape (do not "optimize" the padding away: size-
         shaped groups would retrace per arity and multiply cold compiles)."""
         if self._batch_jitted is None:
-            from ..sql import ir as _ir
-
             def bstep(pages, live, aux, params):
                 with _ir.bind_params(params):  # same contract as jitted()
                     return self.transform(*_stack_pages(pages, live), aux)
@@ -556,8 +239,6 @@ class _Stream:
         demux slices per request.  Callers pad R to a pow2 rung, so this
         compiles one executable per (plan, rung) — never per batch size."""
         if self._bindings_jitted is None:
-            from ..sql import ir as _ir
-
             def bindings_step(page, aux, stacked):
                 def one(params):
                     with _ir.bind_params(params):
@@ -586,14 +267,12 @@ class LocalExecutor:
     cache."""
 
     def __init__(self, catalogs: dict, memory_pool=None, buffer_pool=None):
-        from ..memory import MemoryPool
-
         self.catalogs = catalogs
         # dispatch-coalescing width for this executor's queries: None resolves
-        # to TRINO_TPU_DISPATCH_BATCH (default 4).  The engine sets it per
-        # query from the ``dispatch_batch`` session property, which rides the
-        # plan-cache key — so a cached plan's compiled batch artifacts always
-        # match the batch the plan was keyed under.
+        # to DISPATCH_BATCH.  The engine sets it per query from the
+        # ``dispatch_batch`` session property, which rides the plan-cache key
+        # — so a cached plan's compiled batch artifacts always match the
+        # batch the plan was keyed under.
         self.dispatch_batch = None
         # bound plan-template parameters for the CURRENT query: tuple of
         # (0-d numpy value, isnull) pairs, one per template slot (engine
@@ -677,7 +356,7 @@ class LocalExecutor:
             return 1
         b = self.dispatch_batch
         if b is None or int(b) <= 0:
-            return _dispatch_batch_default()
+            return DISPATCH_BATCH
         return int(b)
 
     def _rewrap_pruned_pages(self, pages_fn, conn, n_splits: int,
@@ -847,8 +526,6 @@ class LocalExecutor:
         guarantees no producer thread survives the query.  Returns how many
         producers were registered (the chaos suite asserts on thread death
         separately)."""
-        import time as _time
-
         procs, self._producers = self._producers, []
         for stop, _t in procs:
             stop.set()
@@ -883,9 +560,6 @@ class LocalExecutor:
         time also has its scans' generators started (_warm_scans)."""
         hit = self._est_cache.get(id(root))
         if hit is None:
-            from ..execution.history import (estimate_plan_rows,
-                                             plan_node_paths)
-
             try:
                 hit = (plan_node_paths(root),
                        estimate_plan_rows(root, self.catalogs))
@@ -1119,8 +793,6 @@ class LocalExecutor:
         operator/OperatorContext.java).  Streaming operators fuse into their sink, so
         stats attach at pipeline-breaker granularity, and wall times are CUMULATIVE
         over the operator's subtree (each breaker includes everything beneath it)."""
-        import time as _time
-
         s = self._node_stats(node)
         # keep the row count ON DEVICE (async dispatch): forcing it here would pay a
         # device->host RTT per operator on the normal query path; EXPLAIN ANALYZE
@@ -1153,8 +825,6 @@ class LocalExecutor:
     def _execute_node(self, node: P.PlanNode):
         # (no overrides check here: _execute_to_page, the only caller, already
         # returned any override hit before opening the operator scope)
-        import time as _time
-
         t0 = _time.perf_counter()
         if isinstance(node, P.Output):
             child, dicts = self._execute_to_page(node.child)
@@ -1242,8 +912,6 @@ class LocalExecutor:
         Reference: operators emit DENSE pages after selective filters
         (FilterAndProjectOperator) — compaction is where the reference gets
         its selectivity win, re-planned for static shapes."""
-        from ..sql import ir as _ir
-
         compact_jits: dict = {}
 
         def counted(cols, nulls, valid, rounds=None):
@@ -1772,8 +1440,6 @@ class LocalExecutor:
             sort, then per-group decode + join on the host (the string result
             lives at the result surface only, like wide-decimal finals).
             Reference: operator/aggregation/listagg."""
-            from ..connectors.tpch import Dictionary
-
             sep, order_ch, asc = spec.param
             vch = spec.arg.index
             d = stream.dicts[vch]
@@ -1822,8 +1488,6 @@ class LocalExecutor:
             (a stream-summary sketch; exact counting over the shared
             key-major sort is within the accuracy contract, the same trade
             approx_percentile makes) and MapHistogramAggregation."""
-            from ..ops.arrays import MapData, pack_span
-
             vch = spec.arg.index
             d = stream.dicts[vch]
             v = page.columns[vch]
@@ -1976,8 +1640,6 @@ class LocalExecutor:
             deviation: NULL elements are dropped and element order is the
             value order — the spec leaves order undefined without WITHIN
             GROUP)."""
-            from ..ops.arrays import ArrayData, pack_span
-
             vch = spec.arg.index
             d = stream.dicts[vch]
             elem_t = stream.schema.fields[vch].type
@@ -2017,8 +1679,6 @@ class LocalExecutor:
             MapData heaps (reference: operator/aggregation/MapAggAggregation;
             deviations: NULL keys are skipped — as the reference does — and
             duplicate keys keep the FIRST value instead of raising)."""
-            from ..ops.arrays import MapData, pack_span
-
             kch = spec.arg.index
             vch2 = int(spec.param)
             kcol = page.columns[kch]
@@ -2156,8 +1816,6 @@ class LocalExecutor:
 
         # direct-indexed fast path: slot = packed key when static ranges are narrow
         # (reference: BigintGroupByHash, operator/GroupByHash.java:90-99)
-        import itertools
-
         page_iter = iter(stream.pages())
         first = next(page_iter, None)
         cfg = None
@@ -2484,8 +2142,6 @@ class LocalExecutor:
         rows (a handful per page) then merge through the ordinary hash insert
         with MERGE kinds, which also stitches groups spanning page
         boundaries."""
-        from .fte import _MERGE_KIND
-
         merge_kinds = [_MERGE_KIND[k] for k in acc_kinds]
         key_dtypes = tuple(t.dtype for t in key_types)
 
@@ -2706,9 +2362,6 @@ class LocalExecutor:
         staging entirely; host/disk readback overlaps device compute through
         the round-6 prefetch double buffer.  Reference:
         SpillableHashAggregationBuilder + FileSingleStreamSpiller."""
-        from ..ops.exchange import partition_ids
-        from .spill import SpilledPartitions
-
         stream, key_types, acc_specs, acc_exprs, acc_kinds, _ = self._agg_compiled(node)
 
         @partial(_jit, site="agg.partitioned.route")
@@ -2958,10 +2611,6 @@ class LocalExecutor:
         WHERE-IN lookup split — dynamic filtering taken to the source (key
         SET pruning instead of min/max split pruning).  Returns a
         replacement probe stream or None."""
-        import os
-
-        if os.environ.get("TRINO_TPU_INDEX_JOIN", "1") == "0":
-            return None
         if len(node.right_keys) != 1:
             return None
         si = probe_stream.scan_info
@@ -3089,8 +2738,6 @@ class LocalExecutor:
             build_page, build_dicts = cached["page"], cached["dicts"]
             build_wall = 0.0
         else:
-            import time as _time
-
             t0 = _time.perf_counter()
             build_page, build_dicts = self._execute_to_page_streamed(node.right)
             build_wall = _time.perf_counter() - t0
@@ -3460,9 +3107,6 @@ class LocalExecutor:
         Reference: the spilling join's partition-at-a-time consumption
         (operator/join/spilling/PartitionedConsumption.java) over
         FileSingleStreamSpiller partitions."""
-        from ..ops.exchange import partition_ids
-        from .spill import SpilledPartitions
-
         bkeys = tuple(build_page.columns[i] for i in node.right_keys)
         bknulls = tuple(build_page.null_masks[i] for i in node.right_keys)
         routed = tuple(kv if kn is None else jnp.where(kn, jnp.zeros((), kv.dtype), kv)
@@ -3555,8 +3199,6 @@ class LocalExecutor:
             si = up.scan_info
         if si is None or not hasattr(si.conn, "split_range"):
             return None
-        from ..sql import ir as _ir
-
         def has_params(e) -> bool:
             if isinstance(e, _ir.Parameter):
                 return True
@@ -3566,9 +3208,6 @@ class LocalExecutor:
 
         if pred is None or not has_params(pred):
             return None
-        from ..sql.analyzer import _coerce
-        from ..sql.domain_translator import (domain_to_split_pruner,
-                                             extract_domains, split_conjuncts)
 
         class _NullParam(Exception):
             pass
@@ -3745,496 +3384,6 @@ class LocalExecutor:
         return table
 
 
-# -- helpers ------------------------------------------------------------------------------
-
-
-def _global_agg_update(state, cols, nulls, valid, acc_exprs, acc_kinds):
-    """One page folded into the ungrouped-aggregation accumulator tuple."""
-    out = []
-    for st, e, kind in zip(state, acc_exprs, acc_kinds):
-        if kind == "count_star":
-            out.append(st + jnp.sum(valid, dtype=st.dtype))
-            continue
-        v, nu = evaluate(e, cols, nulls)
-        mask = valid if nu is None else (valid & ~nu)
-        if kind == "count":
-            out.append(st + jnp.sum(mask, dtype=st.dtype))
-        elif kind == "sum":
-            out.append(st + jnp.sum(jnp.where(mask, v, 0), dtype=st.dtype))
-        elif kind in ("sum_hi32", "sum_lo32"):
-            h = (v >> 32) if kind == "sum_hi32" else (v & 0xFFFFFFFF)
-            out.append(st + jnp.sum(jnp.where(mask, h, 0), dtype=st.dtype))
-        elif kind == "sum_sq":
-            vv = v.astype(st.dtype)
-            out.append(st + jnp.sum(jnp.where(mask, vv * vv, 0),
-                                    dtype=st.dtype))
-        elif kind == "min":
-            out.append(jnp.minimum(st, jnp.min(jnp.where(
-                mask, v, hashagg._extreme(st.dtype, 1))).astype(st.dtype)))
-        elif kind == "max":
-            out.append(jnp.maximum(st, jnp.max(jnp.where(
-                mask, v, hashagg._extreme(st.dtype, -1))).astype(st.dtype)))
-        else:
-            raise NotImplementedError(kind)
-    return tuple(out)
-
-
-def _global_init_state(node):
-    """Initial accumulator tuple for an ungrouped aggregation."""
-    acc_specs = []
-    for spec in node.aggs:
-        acc_specs.extend(_accumulators_for(spec))
-    state = tuple(
-        jnp.asarray(init if init is not None else 0, dtype)
-        for _, dtype, init in acc_specs
-    )
-    # min/max identity
-    return tuple(
-        jnp.asarray(hashagg._extreme(dtype, 1 if kind == "min" else -1), dtype)
-        if kind in ("min", "max") else st
-        for st, (kind, dtype, _) in zip(state, acc_specs)
-    )
-
-
-def _acc_input_expr(spec: P.AggSpec):
-    """The expression accumulators actually consume for one agg call.
-
-    Lives NEXT TO _accumulators_for because every executor building
-    (acc_specs, acc_exprs) must apply the same transform: checksum
-    accumulates the modular sum of per-row HASHES, not raw values — a
-    builder using spec.arg directly would silently disagree with the
-    local path's results."""
-    arg = spec.arg
-    if spec.kind == "checksum" and arg is not None:
-        arg = Call("hash", (arg,), BIGINT)
-    return arg
-
-
-def _accumulators_for(spec: P.AggSpec):
-    """(kind, dtype, init) accumulator list for one agg call."""
-    t = spec.type
-    if spec.kind == "count_star" or spec.kind == "count":
-        return [(spec.kind, jnp.int64, 0)]
-    if spec.kind == "sum":
-        # the trailing count accumulator distinguishes an all-NULL (or empty)
-        # group from a genuine zero sum: SQL sum over no non-null rows is
-        # NULL, not 0 (reference: the null flag of LongSumAggregation state)
-        if isinstance(t, DecimalType):
-            # exact wide sum: two int64 limbs (hi = v>>32, lo = v&0xFFFFFFFF)
-            # accumulate separately and recombine exactly at finalization
-            # (reference: Int128 state, DecimalSumAggregation.java)
-            return [("sum_hi32", jnp.int64, 0), ("sum_lo32", jnp.int64, 0),
-                    ("count", jnp.int64, 0)]
-        dtype = jnp.float64 if t.is_floating else jnp.int64
-        return [("sum", dtype, 0), ("count", jnp.int64, 0)]
-    if spec.kind == "avg":
-        in_t = spec.arg.type
-        if isinstance(in_t, DecimalType):
-            return [("sum_hi32", jnp.int64, 0), ("sum_lo32", jnp.int64, 0),
-                    ("count", jnp.int64, 0)]
-        dtype = jnp.float64 if in_t.is_floating else jnp.int64
-        return [("sum", dtype, 0), ("count", jnp.int64, 0)]
-    if spec.kind in ("min", "max"):
-        dtype = spec.arg.type.dtype
-        return [(spec.kind, dtype, hashagg._extreme(dtype, 1 if spec.kind == "min" else -1))]
-    if spec.kind in ("var_pop", "var_samp", "stddev_pop", "stddev_samp"):
-        # (sum, sum of squares, count) — the reference's VarianceState
-        # (operator/aggregation/state/VarianceState.java keeps mean/m2; sums are
-        # the merge-friendly equivalent for partial aggregation)
-        return [("sum", jnp.float64, 0), ("sum_sq", jnp.float64, 0),
-                ("count", jnp.int64, 0)]
-    if spec.kind == "bool_and":
-        return [("min", jnp.int8, hashagg._extreme(jnp.int8, 1))]
-    if spec.kind == "bool_or":
-        return [("max", jnp.int8, hashagg._extreme(jnp.int8, -1))]
-    if spec.kind == "arbitrary":
-        dtype = spec.arg.type.dtype
-        return [("min", dtype, hashagg._extreme(dtype, 1))]
-    if spec.kind == "checksum":
-        # order-insensitive MODULAR SUM of splitmix64 row hashes (reference:
-        # ChecksumAggregationFunction combines xxhash64 values; wraparound
-        # int64 sum is the same merge-friendly commutative algebra).
-        # Documented deviations: bigint rendering instead of varbinary, and
-        # string arguments hash their per-query dictionary ids
-        return [("sum", jnp.int64, 0), ("count", jnp.int64, 0)]
-    raise NotImplementedError(spec.kind)
-
-
-def _combine_limbs_vec(hi, lo):
-    """Recombine two-limb sums: vectorized int64 when every result fits (the
-    int64 computation is exact mod 2^64, so intermediate wraps don't matter),
-    else (None, exact-Python-int list).  The Python path only runs when a sum
-    actually exceeds ~2^62 — a per-row host loop over a million groups was the
-    dominant cost of decimal aggregation finalize."""
-    hi = np.asarray(hi)
-    lo = np.asarray(lo)
-    approx = hi.astype(np.float64) * 4294967296.0 + lo.astype(np.float64)
-    if np.all(np.abs(approx) < float(1 << 62)):
-        return hi.astype(np.int64) * (1 << 32) + lo.astype(np.int64), None
-    return None, [int(h) * (1 << 32) + int(l)
-                  for h, l in zip(hi.tolist(), lo.tolist())]
-
-
-def _finalize_aggs(aggs, acc_cols, n_groups):
-    """Combine accumulator columns into final output columns (host-side, small).
-
-    Wide decimal sums recombine their two limbs as EXACT Python ints; values
-    still inside int64 emit a normal device-safe column, anything past 2^63
-    emits an object column that lives on the host through the result surface
-    (the reference's Int128 -> long-decimal block).
-
-    Returns (columns, null_masks): SQL aggregates over an all-NULL (or empty)
-    group are NULL — sums/avgs detect it from their count accumulator,
-    min/max/arbitrary/bool_* from a surviving init sentinel (a real value
-    colliding with the sentinel is the accepted int64-extreme collision
-    class)."""
-    out = []
-    nulls = []
-    i = 0
-    for spec in aggs:
-        if spec.kind == "avg" and spec.arg is not None \
-                and isinstance(spec.arg.type, DecimalType):
-            vec, exact = _combine_limbs_vec(acc_cols[i], acc_cols[i + 1])
-            c = np.asarray(acc_cols[i + 2])
-            i += 3
-            if vec is not None:  # HALF_UP rounding, vectorized
-                n = np.maximum(c.astype(np.int64), 1)
-                q, r = np.divmod(np.abs(vec), n)
-                out.append(((q + (2 * r >= n)) *
-                            np.where(vec >= 0, 1, -1)).astype(np.int64))
-            else:
-                vals = []
-                for s, n in zip(exact, c.tolist()):
-                    n = max(int(n), 1)
-                    q, r = divmod(abs(s), n)
-                    vals.append((q + (2 * r >= n)) * (1 if s >= 0 else -1))
-                out.append(np.array(vals, np.int64))  # avg fits the input type
-            nulls.append(np.asarray(c) == 0)
-        elif spec.kind == "avg":
-            s, c = acc_cols[i], acc_cols[i + 1]
-            i += 2
-            c_safe = np.where(c == 0, 1, c)
-            out.append((s / c_safe).astype(np.float64))
-            nulls.append(np.asarray(c) == 0)
-        elif spec.kind == "sum" and isinstance(spec.type, DecimalType):
-            vec, exact = _combine_limbs_vec(acc_cols[i], acc_cols[i + 1])
-            c = np.asarray(acc_cols[i + 2])
-            i += 3
-            if vec is not None:
-                out.append(vec)
-            elif all(-(1 << 63) <= v < (1 << 63) for v in exact):
-                out.append(np.array(exact, np.int64))
-            else:
-                out.append(np.array(exact, dtype=object))
-            nulls.append(c == 0)
-        elif spec.kind in ("sum", "checksum"):
-            s, c = acc_cols[i], acc_cols[i + 1]
-            i += 2
-            out.append(np.asarray(s).astype(np.dtype(spec.type.dtype)))
-            nulls.append(np.asarray(c) == 0)
-        elif spec.kind in ("var_pop", "var_samp", "stddev_pop", "stddev_samp"):
-            s, ssq, c = acc_cols[i], acc_cols[i + 1], acc_cols[i + 2]
-            i += 3
-            c_safe = np.where(c == 0, 1, c).astype(np.float64)
-            m2 = np.maximum(ssq - s * s / c_safe, 0.0)  # clamp fp cancellation
-            if spec.kind.endswith("_pop"):
-                var = m2 / c_safe
-                null = np.asarray(c) == 0
-            else:
-                var = m2 / np.where(c < 2, 1, c - 1)
-                var = np.where(c < 2, 0.0, var)
-                null = np.asarray(c) < 2  # samp undefined below 2 rows
-            out.append(np.sqrt(var) if spec.kind.startswith("stddev") else var)
-            nulls.append(null)
-        else:
-            col = acc_cols[i]
-            i += 1
-            out.append(col.astype(np.dtype(spec.type.dtype)))
-            if spec.kind in ("min", "max", "arbitrary", "bool_and", "bool_or"):
-                k0, dt0, init0 = _accumulators_for(spec)[0][:3]
-                nulls.append(np.asarray(col) == np.asarray(init0))
-            else:  # counts are 0 for empty groups, never NULL
-                nulls.append(None)
-    return out, [None if (m is None or not m.any()) else m for m in nulls]
-
-
-def _device_finalize_plan(aggs):
-    """Raise NotImplementedError when any agg kind lacks a device finalize.
-    Mirrors the branch structure of _finalize_aggs_device."""
-    for spec in aggs:
-        if spec.kind in ("avg", "sum", "checksum", "count", "count_star",
-                         "var_pop", "var_samp", "stddev_pop", "stddev_samp",
-                         "min", "max", "arbitrary", "bool_and", "bool_or"):
-            continue
-        raise NotImplementedError(spec.kind)
-
-
-def _limbs_device(hi, lo):
-    """Two-limb decimal sum recombination on device: exact int64 when the
-    value is inside the +-2^62 envelope (same gate as _combine_limbs_vec);
-    the returned flag marks the out-of-envelope case for host fallback."""
-    approx = hi.astype(jnp.float64) * 4294967296.0 + lo.astype(jnp.float64)
-    bad = jnp.any(jnp.abs(approx) >= float(1 << 62))
-    return hi * (1 << 32) + lo, bad
-
-
-def _finalize_aggs_device(aggs, acc_cols):
-    """Device (jnp) analog of _finalize_aggs: returns (cols, nulls, bad)
-    with ``bad`` a scalar bool — True when a wide-decimal sum leaves the
-    exact-int64 envelope and the caller must redo finalization host-side.
-    Keeping the output on device is the round-5 fix: the aggregate
-    page feeds downstream jitted consumers without a host round-trip."""
-    out, nulls = [], []
-    bad = jnp.zeros((), bool)
-    i = 0
-    for spec in aggs:
-        if spec.kind == "avg" and spec.arg is not None \
-                and isinstance(spec.arg.type, DecimalType):
-            hi, lo, c = acc_cols[i], acc_cols[i + 1], acc_cols[i + 2]
-            i += 3
-            v, b = _limbs_device(hi, lo)
-            bad = bad | b
-            n = jnp.maximum(c.astype(jnp.int64), 1)
-            a = jnp.abs(v)
-            q = a // n
-            r = a - q * n
-            res = (q + (2 * r >= n)) * jnp.where(v >= 0, 1, -1)
-            out.append(res.astype(jnp.int64))
-            nulls.append(c == 0)
-        elif spec.kind == "avg":
-            s, c = acc_cols[i], acc_cols[i + 1]
-            i += 2
-            out.append((s / jnp.where(c == 0, 1, c)).astype(jnp.float64))
-            nulls.append(c == 0)
-        elif spec.kind == "sum" and isinstance(spec.type, DecimalType):
-            hi, lo, c = acc_cols[i], acc_cols[i + 1], acc_cols[i + 2]
-            i += 3
-            v, b = _limbs_device(hi, lo)
-            bad = bad | b
-            out.append(v)
-            nulls.append(c == 0)
-        elif spec.kind in ("sum", "checksum"):
-            s, c = acc_cols[i], acc_cols[i + 1]
-            i += 2
-            out.append(s.astype(spec.type.dtype))
-            nulls.append(c == 0)
-        elif spec.kind in ("var_pop", "var_samp", "stddev_pop", "stddev_samp"):
-            s, ssq, c = acc_cols[i], acc_cols[i + 1], acc_cols[i + 2]
-            i += 3
-            c_safe = jnp.where(c == 0, 1, c).astype(jnp.float64)
-            m2 = jnp.maximum(ssq - s * s / c_safe, 0.0)
-            if spec.kind.endswith("_pop"):
-                var = m2 / c_safe
-                null = c == 0
-            else:
-                var = jnp.where(c < 2, 0.0, m2 / jnp.where(c < 2, 1, c - 1))
-                null = c < 2
-            out.append(jnp.sqrt(var) if spec.kind.startswith("stddev")
-                       else var)
-            nulls.append(null)
-        else:
-            col = acc_cols[i]
-            i += 1
-            out.append(col.astype(spec.type.dtype))
-            if spec.kind in ("min", "max", "arbitrary", "bool_and", "bool_or"):
-                k0, dt0, init0 = _accumulators_for(spec)[0][:3]
-                nulls.append(col == jnp.asarray(init0, col.dtype))
-            else:  # counts are 0 for empty groups, never NULL
-                nulls.append(None)
-    return tuple(out), tuple(nulls), bad
-
-
-@partial(_jit, site="agg.direct.init", static_argnums=(0, 1, 2))
-def _direct_init(cfg, key_dtypes, acc_specs):
-    """The direct group-by's initial state as one program a (config, key
-    dtypes, accumulator specs): every statement starts from it, and eager it
-    was a launch a fill.  Nothing is kept on the device between statements:
-    a 2^24-slot state is not pinned."""
-    return hashagg.direct_groupby_init(cfg, key_dtypes, acc_specs)
-
-
-@partial(_jit, site="agg.hash.init", static_argnums=(0, 1, 2))
-def _hash_init(capacity, key_dtypes, acc_specs):
-    """The hash group-by's, one program a (capacity, key dtypes, specs)."""
-    return hashagg.groupby_init(capacity, key_dtypes, acc_specs)
-
-
-@partial(_jit, site="agg.group_count")
-def _group_count(state):
-    return hashagg.group_count(state)
-
-
-def _compact_page(cols, nulls, valid, bucket: int):
-    """_compacted_stream's step: the shared masked-lane pack
-    (ops/arrays.compact_rows: live-lane index then gathers, or the round-13
-    Pallas kernel) of a page into ``bucket`` lanes, with its validity mask."""
-    packed, total = compact_rows(tuple(cols) + tuple(nulls), valid, bucket)
-    cvalid = jnp.arange(bucket) < total
-    return packed[:len(cols)], packed[len(cols):], cvalid
-
-
-def _gather_part(cols, nulls, idx):
-    return (tuple(gather_rows(c, idx) for c in cols),
-            tuple(None if n is None else gather_rows(n, idx) for n in nulls))
-
-
-@partial(_jit, static_argnums=(3,))
-def _compact_part(cols, nulls, valid, size: int):
-    """Gather valid rows into dense ``size``-bounded arrays (device-side);
-    lanes beyond the live count hold a real row, the caller masks them."""
-    return _gather_part(cols, nulls, live_indices(valid, size)[0])
-
-
-@partial(_jit, static_argnums=(3,))
-def _compact_part_sized(cols, nulls, valid, size: int):
-    """_compact_part plus the compacted part's own validity mask
-    (``arange(size) < live``), computed INSIDE the same dispatch — what lets
-    _concat_stream's single-part fast path skip the _concat_all dispatch
-    without any uncounted eager device work."""
-    idx, live = live_indices(valid, size)
-    return _gather_part(cols, nulls, idx) \
-        + (jnp.arange(size, dtype=jnp.int32) < live,)
-
-
-def _concat_stream(stream: _Stream, batch: int = 1) -> Page:
-    """Materialize a streaming segment into a single device page (compacted).
-
-    Compaction runs ON DEVICE (live-lane index + gathers per page, then a device
-    concat): pages never cross to the host between pipeline-breaking stages —
-    device->host bandwidth is the scarce resource, not FLOPs (reference analog:
-    pages stay in worker memory between operators).  ``batch``>1 coalesces shape-uniform pages: each group
-    of K splits runs its transform in ONE dispatch (and its compaction and
-    live-count sync amortize K-fold with it)."""
-    step = stream.jitted()
-    bstep = stream.jitted_batch() if batch > 1 else None
-    parts = []
-    staged, sums = [], []
-
-    def _drain():
-        # one batched host sync per chunk of pages (per-page int() is a
-        # blocking device->host sync per page); chunking bounds how many
-        # uncompacted pages sit on device at once.  A count the source page
-        # already knew on the host (a host int among ``sums``) is not pulled
-        unknown = [c for c in sums if not isinstance(c, int)]
-        pulled = iter(_host(unknown, site="compact.counts") if unknown else ())
-        for (cols, nulls, valid), c in zip(staged, sums):
-            n = c if isinstance(c, int) else int(next(pulled))
-            if n == 0:
-                continue
-            if any(isinstance(c, np.ndarray) and c.dtype == object
-                   for c in cols):
-                # exact wide-decimal columns: host compaction (cannot trace);
-                # the object columns are host-resident — one batched pull
-                # covers the masks (eager jnp ops may have produced them)
-                got = _host([valid] + [m for m in nulls if m is not None],
-                            site="compact.object")
-                v, rest = got[0], got[1:]
-                ccols = tuple(np.asarray(c)[v] for c in cols)  # host-ok: object cols
-                cnulls = tuple(None if m is None else rest.pop(0)[v]
-                               for m in nulls)
-                parts.append((ccols, cnulls, None, n))
-                continue
-            bucket = min(max(1 << max(n - 1, 1).bit_length(), 1024),
-                         valid.shape[0])
-            if isinstance(c, int) and bucket == valid.shape[0]:
-                # packed already, at this very bucket: the pack would move
-                # no row
-                parts.append((cols, nulls, valid, n))
-                continue
-            ccols, cnulls, pvalid = _compact_part_sized(
-                cols, nulls, valid, bucket)
-            tracing.record_compaction(valid.shape[0], bucket)
-            parts.append((ccols, cnulls, pvalid, n))
-        staged.clear()
-        sums.clear()
-
-    for group, live in _coalesced_batches(stream.pages(), batch):
-        cols, nulls, valid = step(group[0]) if live is None \
-            else bstep(group, live)
-        staged.append((cols, nulls, valid))
-        if live is None and group[0].live is not None and stream.passes_valid:
-            sums.append(group[0].live)  # a packed page, still packed
-        else:
-            sums.append(jnp.sum(valid, dtype=jnp.int32))
-        if len(staged) >= 8:
-            _drain()
-    _drain()
-    if not parts:
-        cols = tuple(jnp.zeros((0,), f.type.dtype) for f in stream.schema.fields)
-        return Page(stream.schema, cols, tuple(None for _ in cols), None)
-    # ONE jitted dispatch for the whole multi-column concat instead of one
-    # top-level concat (a dispatch each) per column
-    ncols = len(parts[0][0])
-    has_null = tuple(any(cnulls[ci] is not None for _, cnulls, _, _ in parts)
-                     for ci in range(ncols))
-    if len(parts) == 1 and parts[0][2] is not None:
-        # single part (single-page stream, or a buffer-pool hit serving the
-        # whole scan as one page): there is nothing to concatenate — the
-        # compacted part IS the page, and its validity mask was computed
-        # inside the _compact_part_sized dispatch (no extra device op at all)
-        ccols, cnulls, pvalid, n = parts[0]
-        return Page(stream.schema, ccols, cnulls, pvalid, n)
-    if any(isinstance(c, np.ndarray) and c.dtype == object
-           for c in parts[0][0]):
-        # host concat for exact wide-decimal parts (host-compacted above)
-        cols_out = tuple(np.concatenate([p[0][ci] for p in parts])
-                         for ci in range(ncols))
-        nulls_out = tuple(
-            np.concatenate([p[1][ci] if p[1][ci] is not None
-                            else np.zeros(p[0][ci].shape[0], bool)
-                            for p in parts]) if has_null[ci] else None
-            for ci in range(ncols))
-        return Page(stream.schema, cols_out, nulls_out, None)
-    ns = jnp.asarray([n for _, _, _, n in parts], jnp.int32)
-    cols_out, nulls_out, valid = _concat_all(
-        tuple((ccols, cnulls) for ccols, cnulls, _, _ in parts), ns, has_null)
-    return Page(stream.schema, cols_out, nulls_out, valid)
-
-
-@partial(_jit, static_argnums=(1,))
-def _concat_bindings_parts(parts, has_null):
-    """ONE dispatch concatenating a fused bindings batch's per-page parts
-    along the ROW axis (axis 1 — axis 0 is the requests lane, round 21).
-    No per-part compaction: the batched path targets the pruned point-lookup
-    shape (one or a few splits after union pruning), where a compaction's
-    count sync would cost more round-trips than it saves lanes."""
-    ncols = len(parts[0][0])
-    cols = tuple(jnp.concatenate([p[0][ci] for p in parts], axis=1)
-                 for ci in range(ncols))
-    nulls = tuple(
-        jnp.concatenate([p[1][ci] if p[1][ci] is not None
-                         else jnp.zeros(p[0][ci].shape, bool)
-                         for p in parts], axis=1)
-        if has_null[ci] else None
-        for ci in range(ncols))
-    valid = jnp.concatenate([p[2] for p in parts], axis=1)
-    return cols, nulls, valid
-
-
-@partial(_jit, static_argnums=(2,))
-def _concat_all(part_arrays, ns, has_null):
-    """ONE dispatch for the whole multi-column concat.  Parts
-    keep their pow2 bucket shapes — live-row counts stay TRACED (a validity mask
-    marks the tail padding), so the executable caches per bucket-shape
-    combination instead of recompiling per exact row count."""
-    cols_out, nulls_out = [], []
-    ncols = len(part_arrays[0][0])
-    for ci in range(ncols):
-        cols_out.append(jnp.concatenate(
-            [ccols[ci] for (ccols, cnulls) in part_arrays]))
-        if has_null[ci]:
-            nulls_out.append(jnp.concatenate(
-                [(cnulls[ci] if cnulls[ci] is not None
-                  else jnp.zeros((ccols[ci].shape[0],), bool))
-                 for (ccols, cnulls) in part_arrays]))
-        else:
-            nulls_out.append(None)
-    valid = jnp.concatenate(
-        [jnp.arange(part[0][0].shape[0], dtype=jnp.int32) < ns[i]
-         for i, part in enumerate(part_arrays)])
-    return tuple(cols_out), tuple(nulls_out), valid
-
-
 def _static_pruned_stream(up: _Stream, pred):
     """Compile-time split pruning from the pushed-down predicate's TupleDomain
     (reference: DomainTranslator.getExtractionResult feeding connector split pruning
@@ -4243,9 +3392,6 @@ def _static_pruned_stream(up: _Stream, pred):
     si = up.scan_info
     if si is None or not hasattr(si.conn, "split_range"):
         return None
-    from ..sql.domain_translator import (domain_to_split_pruner, extract_domains,
-                                         split_conjuncts)
-
     td = extract_domains(split_conjuncts(pred)).tuple_domain
     if td.is_none:
         return (lambda: iter(()), dataclasses.replace(si, splits=[]))
@@ -4289,8 +3435,6 @@ def _dynamic_pruned_pages(probe_stream: _Stream, node, build_page: Page):
         build_page.capacity > 0 and bool(jnp.any(build_page.valid_mask())))
     if not nonempty:
         return (lambda: iter(())), ()  # empty build: no probe row can match
-    from ..spi.predicate import UNION_LIMIT, Domain, Range
-    from ..sql.domain_translator import domain_to_split_pruner
 
     domains = {}
     # large build sides never yield an exact value set (UNION_LIMIT), so don't
@@ -4352,54 +3496,6 @@ def _dynamic_pruned_pages(probe_stream: _Stream, node, build_page: Page):
             yield _generate(conn, si.table, s, scan_cols)
 
     return pages, kept
-
-
-def _build_key_stats(build_page: Page, key_channels):
-    """(build_has_null_key, live build rows) — device reductions, ONE batched
-    scalar sync (pulling capacity-sized masks to host costs megabytes)."""
-    if build_page.capacity == 0:
-        return False, 0
-    valid = build_page.valid_mask()
-    stats = [jnp.sum(valid, dtype=jnp.int64)]
-    for ch in key_channels:
-        nm = build_page.null_masks[ch]
-        if nm is not None:
-            stats.append(jnp.any(nm & valid))
-    got = _host(stats, site="join.build.nulls")
-    has_null = any(bool(x) for x in got[1:])
-    return has_null, int(got[0])
-
-
-def _build_null_stats(build_page: Page, key_channels):
-    """(build_has_null_key, build_nonempty) for null-aware anti joins."""
-    has_null, rows = _build_key_stats(build_page, key_channels)
-    return has_null, rows > 0
-
-
-def _null_aware_anti(node, anti_valid, nulls, build_has_null, build_nonempty):
-    """NOT IN three-valued logic (reference: null-aware anti joins): a NULL among the
-    build keys, or a NULL probe key vs a non-empty build, makes the predicate UNKNOWN
-    (row rejected).  NOT EXISTS anti joins (null_aware=False) skip this."""
-    if not node.null_aware:
-        return anti_valid
-    if build_has_null:
-        return jnp.zeros_like(anti_valid)
-    if build_nonempty:
-        for i in node.left_keys:
-            if nulls[i] is not None:
-                anti_valid = anti_valid & ~nulls[i]
-    return anti_valid
-
-
-def _gather_build(table: JoinTable, row_ids, matched, kind):
-    """Fetch build-side columns for probe matches; unmatched rows -> nulls (left join)."""
-    safe = jnp.where(matched, row_ids, 0)
-    cols, nulls = [], []
-    for c, nmask in zip(table.build_columns, table.build_null_masks):
-        cols.append(c[safe])
-        base = jnp.zeros_like(matched) if nmask is None else nmask[safe]
-        nulls.append((base | ~matched) if kind == "left" else (None if nmask is None else base))
-    return tuple(cols), tuple(nulls)
 
 
 def _run_match_recognize(node: P.MatchRecognize, child: Page, cdicts):
@@ -4533,8 +3629,6 @@ def _run_match_recognize(node: P.MatchRecognize, child: Page, cdicts):
     # computes in one device pass and the host only walks actual matches
     vm = None
     if not getattr(node, "all_rows", False):
-        from ..ops.matcher import vector_match
-
         measure_vars = {var for _, var, _, _ in node.measures
                         if var is not None}
         vm = vector_match(node.pattern, conds, np.asarray(new_part),  # host-ok
@@ -4643,8 +3737,6 @@ def _run_unnest(node: P.Unnest, child: Page, cdicts):
     re-designed as the searchsorted expansion map of ops/arrays.unnest_indices —
     the same fixed-capacity pattern as the multi-match join).  Parallel arrays
     zip by position; shorter ones pad with NULL."""
-    from ..ops.arrays import span_len, span_start, unnest_indices
-
     if child.capacity == 0:
         # zero-row child: expansion map has nothing to gather from; pad to one
         # invalid row so the fixed-shape kernel runs (yielding zero rows out)
@@ -4692,21 +3784,6 @@ def _run_unnest(node: P.Unnest, child: Page, cdicts):
     return page, tuple(dicts)
 
 
-def _values_page(node: P.Values) -> Page:
-    cols = []
-    for ci, f in enumerate(node.schema.fields):
-        cols.append(jnp.asarray(np.array([r[ci] for r in node.rows]), f.type.dtype))
-    return Page(node.schema, tuple(cols), tuple(None for _ in cols), None)
-
-
-def _group_state_bytes(key_types, acc_specs):
-    """cap -> device bytes of a group-by state of ``cap`` slots (and its sink):
-    the table word, each key with its null flag, each accumulator."""
-    key_w = sum(np.dtype(t.dtype).itemsize + 1 for t in key_types)
-    acc_w = sum(np.dtype(dt).itemsize for dt, _ in acc_specs)
-    return lambda cap: (cap + 1) * (8 + key_w + acc_w)
-
-
 def _probed_pages(pages, si, hashed: bool):
     """The page source of a join that runs FUSED into whatever consumes its
     stream (no dispatch of its own to count at): each page it hands on is
@@ -4731,29 +3808,6 @@ def _probed_pages(pages, si, hashed: bool):
     return counted(pages), si
 
 
-def _split_base_rows(conn, table: str, splits) -> list:
-    """Base rows each split stands for, by the connector's own count (host
-    ints: ``row_count`` shared out over the split ranges; lineitem's ranges
-    are orders, at ``row_count``'s lines an order).  Zeros when the connector
-    does not say."""
-    if not (hasattr(conn, "row_count") and hasattr(conn, "table_bound")) \
-            or not all(hasattr(s, "lo") and hasattr(s, "hi") for s in splits):
-        return [0] * len(splits)
-    bound = max(int(conn.table_bound(table)), 1)
-    per = int(conn.row_count(table)) / bound
-    return [int(max(min(int(s.hi), bound) - int(s.lo), 0) * per)
-            for s in splits]
-
-
-def _page_bytes(page: Page) -> int:
-    """Device bytes held by a page's columns + null masks."""
-    total = 0
-    for c in page.columns:
-        total += page.capacity * np.dtype(c.dtype).itemsize
-    total += sum(page.capacity for n in page.null_masks if n is not None)
-    return total
-
-
 def _stage_scan_entry(pages):
     """One device-resident page from a completed scan's page list, for the
     buffer pool's page tier.  Host (HOST_DECODE / memory-connector) arrays
@@ -4772,948 +3826,3 @@ def _stage_scan_entry(pages):
     stack = _jit(lambda ps: _stack_pages(ps), site="cache.store")
     cols, nulls, valid = stack(tuple(pages))
     return Page(pages[0].schema, cols, nulls, valid)
-
-
-def _plan_fingerprint(node: P.PlanNode, catalogs: dict) -> str:
-    """Structural fingerprint of a plan subtree — the build-cache key.
-
-    Two structurally identical build fragments (same operators, expressions,
-    schemas, scanned tables) must collide even when they come from DIFFERENT
-    plan objects (another executor compiling the same cached plan, a second
-    statement sharing the subquery), so the walk is content-based: dataclass
-    leaves print by value, plan children recurse, and TableScans carry their
-    catalog/table/columns plus the connector's plan_version (growable
-    catalogs — the system tables' dictionaries — never serve a stale build).
-    Opaque payloads (dictionary value arrays) print by IDENTITY: they are
-    connector-owned singletons, stable for the life of this process, and
-    printing megabyte arrays by content would be both slow and collision-
-    prone under numpy's truncating repr."""
-    def val(v):
-        if v is None or isinstance(v, (str, int, float, bool, bytes)):
-            return repr(v)
-        if isinstance(v, (tuple, list)):
-            return "(" + ",".join(val(x) for x in v) + ")"
-        if isinstance(v, P.PlanNode):
-            return fp(v)
-        if isinstance(v, np.ndarray):
-            return f"nd#{id(v)}"
-        if dataclasses.is_dataclass(v) and not isinstance(v, type):
-            return f"{type(v).__name__}(" + ",".join(
-                val(getattr(v, f.name)) for f in dataclasses.fields(v)) + ")"
-        return f"{type(v).__name__}#{id(v)}"
-
-    def fp(n):
-        if isinstance(n, P.TableScan):
-            conn = catalogs.get(n.catalog)
-            ver = conn.plan_version() if hasattr(conn, "plan_version") else 0
-            return (f"TableScan({n.catalog},{n.table},"
-                    f"{','.join(n.columns)},v{ver})")
-        return f"{type(n).__name__}(" + ";".join(
-            val(getattr(n, f.name)) for f in dataclasses.fields(n)) + ")"
-
-    return fp(node)
-
-
-@contextlib.contextmanager
-def _statement_scopes(counters, qid, tracer):
-    """Another thread's work recorded as the statement's (the prefetch
-    producer, a connector's warm thread): its counters, its query id and its
-    tracer on this thread, each where the statement has one.  track_counters
-    enters BEFORE query_scope: live-counter registration keys on the qid
-    active at entry, and the query thread already registered this set."""
-    with contextlib.ExitStack() as scopes:
-        if counters is not None:
-            scopes.enter_context(tracing.track_counters(counters))
-        if qid is not None:
-            scopes.enter_context(tracing.query_scope(qid))
-        if tracer is not None:
-            scopes.enter_context(tracing.activate_tracer(tracer))
-        yield
-
-
-def _prefetched_pages(pages_fn, depth: int = 2, to_device: bool = False,
-                      warmup: int = 0, owner=None, table: str = ""):
-    """Wrap a page generator with background-thread prefetch: up to ``depth``
-    pages decode ahead of the consumer.  ``to_device`` additionally moves each
-    page's host (numpy) arrays onto the device FROM THE PRODUCER THREAD
-    (async host->device pipelining: the copy overlaps the consumer's current
-    dispatch instead of serializing in front of the next one; object-dtype
-    wide-decimal columns stay host-side).  ``warmup`` pages are produced
-    SYNCHRONOUSLY before the thread starts: a short-circuiting consumer
-    (LIMIT) that stops within the warmup window generates exactly the pages
-    it consumed — the thread only runs ahead once the consumer proved it
-    wants a long scan.  Exceptions re-raise at the consume site.  An abandoned
-    consumer (LIMIT short-circuit, error unwind) closes the generator; the
-    producer observes the ``closed`` flag on its next bounded put and exits,
-    releasing its decoded pages and file handles instead of blocking on the
-    full queue for the process lifetime.  ``owner`` (the LocalExecutor that
-    compiled the scan) additionally registers the producer's stop flag +
-    thread so ``close_producers()`` can stop it on exception paths where the
-    consumer generator is never closed — a mid-query error's traceback pins
-    the consumer frames (and so the generators) alive, which used to leave
-    the producer pumping against a full queue until the traceback was
-    released.
-
-    Both sides of the queue are timed where they block (PR 38): each of the
-    consumer's ``q.get()`` is a finished ``scan.wait`` span (bucket scan_wait,
-    ``table`` its attribute) and a ``trino_tpu:scan.wait`` annotation; the
-    producer sums the seconds its ``put`` found the queue full into
-    ``put_wait_s`` of its ``prefetch`` span, beside the thread's ``cpu_s``."""
-    import queue as _queue
-    import time as _time
-
-    def pages():
-        it = pages_fn()
-        for _ in range(warmup):
-            try:
-                p = next(it)
-            except StopIteration:
-                return
-            yield _page_to_device(p) if to_device else p
-        q: _queue.Queue = _queue.Queue(maxsize=depth)
-        done = object()
-        closed = threading.Event()
-        # explicit parent handoff: Tracer parenting is thread-local, so the
-        # producer thread's spans would be orphans — capture the consumer
-        # thread's active span HERE (first iteration, on the query thread) and
-        # pass it across.  The producer's span parents correctly into the
-        # query's tree even though it opens on another thread.
-        tracer = tracing.current_tracer()
-        parent = tracer.current() if tracer is not None else None
-        # counters/query-id handoff, same idea as the span parent: generate
-        # and h2d fault injections fire ON this thread, and without the
-        # query's counters installed here record_fault would no-op — a chaos
-        # run over the default prefetch path would read 0 faults_injected.
-        # Beside faults the producer records its generator launches
-        # (_generate) and never touches executor state (the round-6 rule).
-        counters = tracing.current_counters()
-        qid = tracing.current_query_id()
-
-        def producer():
-            put_wait = [0.0]
-
-            def put(item) -> bool:
-                t0 = _time.perf_counter()
-                try:
-                    while not closed.is_set():
-                        try:
-                            q.put(item, timeout=0.1)
-                            return True
-                        except _queue.Full:
-                            continue
-                    return False
-                finally:
-                    put_wait[0] += _time.perf_counter() - t0
-
-            def pump(span):
-                n = 0
-                cpu0 = _time.thread_time()
-                try:
-                    for p in it:
-                        if to_device:
-                            p = _page_to_device(p)
-                        n += 1
-                        if not put(p):
-                            return
-                    put(done)
-                except BaseException as e:  # surfaces in the consumer
-                    put(e)
-                finally:
-                    if span is not None:
-                        span.attributes["pages"] = n
-                        span.attributes["put_wait_s"] = round(put_wait[0], 6)
-                        span.attributes["cpu_s"] = round(
-                            _time.thread_time() - cpu0, 6)
-                    # the producer owns the source iterator once the thread
-                    # starts: close it HERE so connector state (file handles,
-                    # decode buffers) releases with the thread, not at GC
-                    close = getattr(it, "close", None)
-                    if close is not None:
-                        try:
-                            close()
-                        except Exception:
-                            pass
-
-            with _statement_scopes(counters, qid, tracer):
-                if tracer is None:
-                    pump(None)
-                else:
-                    # (the tracer is active on this thread too, so that the
-                    # generator launches it runs, _generate, are spans of
-                    # the statement, under this one)
-                    with tracer.span("prefetch", parent=parent,
-                                     to_device=to_device) as span:
-                        pump(span)
-
-        # named so leak checks (tests/test_chaos.py, scripts/chaos.py) can
-        # assert "no prefetch producer survived the query" by thread name
-        t = threading.Thread(target=producer, daemon=True,
-                             name="prefetch-producer")
-        if owner is not None:
-            owner._producers.append((closed, t))
-        t.start()
-        try:
-            while True:
-                t0 = _time.perf_counter()
-                with tracing.annotate("scan.wait"):
-                    item = q.get()
-                if tracer is not None:
-                    tracer.add_completed("scan.wait",
-                                         _time.perf_counter() - t0,
-                                         table=table)
-                if item is done:
-                    return
-                if isinstance(item, BaseException):
-                    raise item
-                yield item
-        finally:
-            closed.set()
-
-    return pages
-
-
-def _page_to_device(page: Page) -> Page:
-    """Start async host->device copies for a page's numpy arrays (device
-    arrays pass through; object columns cannot live on device).  device_put is
-    an enqueue, not a sync — safe from the prefetch thread, and by the time
-    the consumer dispatches over the page the copy has overlapped."""
-    faults.maybe_inject("h2d", "page_to_device")
-
-    def up(a):
-        if isinstance(a, np.ndarray) and a.dtype != object:
-            return jax.device_put(a)
-        return a
-
-    if not any(isinstance(c, np.ndarray) and c.dtype != object
-               for c in tuple(page.columns) + tuple(
-                   m for m in page.null_masks if m is not None)):
-        return page
-    return Page(page.schema, tuple(up(c) for c in page.columns),
-                tuple(None if m is None else up(m) for m in page.null_masks),
-                None if page.valid is None else up(page.valid))
-
-
-_GENERATED: set = set()  # generator shapes that have run once (_generate)
-
-
-def _generate(conn, table: str, split, cols, count: bool = True):
-    """One page of ``table`` from its connector: the chokepoint of the
-    generator launches, as ``_jit`` is of the executor's own programs.  A
-    device generator (connectors/tpch.py ``_jit_generate``, tpcds.py alike) is
-    a bare ``jax.jit`` the connector owns, so it is counted HERE, on whichever
-    thread runs the scan source (the prefetch producer's mostly): one
-    ``generator_dispatches``, a finished ``generate`` span with
-    ``site=generate.<table>``, a ``trino_tpu:generate`` annotation, an
-    in-flight entry while it runs, and what XLA compiled inside it as a
-    ``record_compile`` event of that site.  ``count=False``: a launch that no
-    scan source asked for (the connector's warm thread) is all of that but
-    the count."""
-    import time as _time
-
-    site = "generate." + table
-    # what a device generator compiles once for: the stall watchdog judges a
-    # first launch as a compile (TRINO_TPU_STALL_COMPILE_S), as _jit's
-    # first-seen signatures are
-    shape = (type(conn), getattr(conn, "sf", None), table, tuple(cols),
-             getattr(split, "hi", 0) - getattr(split, "lo", 0))
-    reg = tracing.current_inflight()
-    tok = reg.enter("generate", site, compiling=shape not in _GENERATED)
-    cap = tracing.begin_compile_capture()
-    t0 = _time.perf_counter()
-    try:
-        with tracing.annotate("generate"):
-            page = conn.generate(split, list(cols))
-        _GENERATED.add(shape)
-        return page
-    finally:
-        reg.exit(tok)
-        dt = _time.perf_counter() - t0
-        xla_s = tracing.end_compile_capture(cap)
-        if xla_s is not None:  # compile events fired: the generator's first
-            # launch at this (length, column set), or one served by the
-            # persistent cache
-            tracing.record_compile(
-                xla_s, site=site,
-                signature=f"{table}[{shape[-1]}]({', '.join(shape[3])})",
-                cache_misses=tracing.compile_capture_misses(cap))
-        tracing.record_generate(table, dt, count=count)
-
-
-def _host(arrays, site=None):
-    """Device->host transfer of many arrays with ONE round-trip of latency: start
-    async copies for every array first, then materialize.  Each serial
-    np.asarray is a blocking sync of its own; batching overlaps the copies.
-
-    This is THE transfer chokepoint (CLAUDE.md: batch ALL transfers through
-    ``_host``): each call records one host transfer and the device bytes it
-    pulls on the active query's counters, which the warm-query budget tests
-    assert against — a stray bulk pull added anywhere upstream fails them.
-    ``site`` labels the pull for per-site attribution (every call site must
-    pass one or carry a ``# site-ok`` marker — tests/test_boundary_lint.py).
-    Each pull also holds an in-flight registry entry while it runs, so a pull
-    stuck on a dead device shows up in the stall watchdog's report."""
-    import time as _time
-
-    reg = tracing.current_inflight()
-    tok = reg.enter("host_pull", site)
-    t0 = _time.perf_counter()
-    try:
-        faults.maybe_inject("host_pull", site)
-        nbytes = 0
-        for a in arrays:
-            if hasattr(a, "copy_to_host_async"):
-                try:
-                    a.copy_to_host_async()
-                    nbytes += a.nbytes
-                except Exception:
-                    pass
-        tracing.record_host_pull(nbytes, site=site)
-        with tracing.annotate("host_pull"):
-            return [None if a is None else np.asarray(a) for a in arrays]
-    finally:
-        reg.exit(tok)
-        # wall-decomposition feed: each batched pull is one "host_pull" span
-        # (same fast path as dispatch spans — no-op without an active tracer)
-        tr = tracing.current_tracer()
-        if tr is not None:
-            tr.add_completed("host_pull", _time.perf_counter() - t0,
-                             site=site or "")
-
-
-@partial(_jit, site="page.head", static_argnums=(2,))
-def _head_rows(cols, nulls, count: int):
-    return (tuple(c[:count] for c in cols),
-            tuple(None if n is None else n[:count] for n in nulls))
-
-
-def _host_page(page: Page, site="page"):
-    """(valid, cols, nulls) as numpy, fetched in ONE batched transfer.  A page with
-    no validity mask gets a host-side ones() — no device fetch fabricated for it."""
-    if page.live is not None and page.live < page.capacity \
-            and all(isinstance(c, jax.Array) for c in page.columns):
-        # a packed device page: the wire carries its live rows, not its bucket
-        page = Page(page.schema, *_head_rows(page.columns, page.null_masks,
-                                             page.live))
-    nc = len(page.columns)
-    has_valid = page.valid is not None
-    got = _host(list(page.columns) + list(page.null_masks)
-                + ([page.valid] if has_valid else []), site=site)
-    valid = got[-1] if has_valid else np.ones((page.capacity,), bool)
-    return valid, got[:nc], got[nc:nc + len(page.null_masks)]
-
-
-def _sort_page(page: Page, keys, dicts=None) -> Page:
-    """Host-side lexicographic sort (result sets; large distributed sort is separate).
-
-    Dictionary-encoded string channels sort by *decoded string order*, not id order
-    (ids are assigned in dictionary, not collation, order)."""
-    valid, pcols, pnulls = _host_page(page)
-    cols = [c[valid] for c in pcols]
-    nulls = [None if n is None else n[valid] for n in pnulls]
-    sort_cols = list(cols)
-    for k in keys:
-        d = dicts[k.channel] if dicts is not None else None
-        if d is not None and page.schema.fields[k.channel].type.is_string:
-            sort_cols[k.channel] = d.decode(cols[k.channel]).astype(str)
-    order = np.arange(len(cols[0]) if cols else 0)
-    for k in reversed(keys):
-        c = sort_cols[k.channel][order]
-        nm_k = nulls[k.channel]
-        if nm_k is not None and len(c):
-            # NULL rows hold arbitrary fill values: pin them all to one value so the
-            # secondary-key order among NULL rows survives this stable pass
-            c = c.copy()
-            c[nm_k[order]] = c[0]
-        if not np.issubdtype(c.dtype, np.number):
-            _, c = np.unique(c, return_inverse=True)  # string -> collation rank
-        if not k.ascending:
-            c = -c.astype(np.int64 if np.issubdtype(c.dtype, np.integer) else np.float64)
-        order = order[np.argsort(c, kind="stable")]
-        nm = nulls[k.channel]
-        if nm is not None:
-            # null placement outranks the value ordering for this key
-            ind = nm[order].astype(np.int8)
-            if k.nulls_first:
-                ind = -ind
-            order = order[np.argsort(ind, kind="stable")]
-    # stay on the host: downstream consumers (limit/materialize) are host-side too,
-    # so pushing back to the device would just buy extra round-trips
-    new_cols = tuple(c[order] for c in cols)
-    new_nulls = tuple(None if n is None else n[order] for n in nulls)
-    return Page(page.schema, new_cols, new_nulls, None)
-
-
-def _topn_page(page: Page, keys, count: int, dicts=None) -> Page:
-    """ORDER BY + LIMIT: argpartition down to ~count candidates on the primary key,
-    then full lexicographic sort of the survivors (host-side; result-set sized)."""
-    valid, pcols, pnulls = _host_page(page)
-    n = int(valid.sum())
-    if n > max(4 * count, 1024) and len(keys) >= 1:
-        k0 = keys[0]
-        c = pcols[k0.channel][valid]
-        nm = pnulls[k0.channel]
-        d = dicts[k0.channel] if dicts is not None else None
-        if nm is None and d is None and np.issubdtype(c.dtype, np.number) and not (
-                np.issubdtype(c.dtype, np.floating) and np.isnan(c).any()):
-            # (NaN keys skip the prefilter: partition would poison the cutoff)
-            v = c if k0.ascending else (
-                -c.astype(np.int64) if np.issubdtype(c.dtype, np.integer)
-                else -c.astype(np.float64))
-            # ties on the primary key require keeping ALL rows equal to the cutoff
-            cutoff = np.partition(v, count - 1)[count - 1]
-            keep_local = v <= cutoff
-            idx = np.nonzero(valid)[0][keep_local]
-            mask = np.zeros_like(valid)
-            mask[idx] = True
-            page = Page(page.schema,
-                        tuple(col[mask] for col in pcols),
-                        tuple(None if m is None else m[mask] for m in pnulls), None)
-    return _limit_page(_sort_page(page, keys, dicts), count)
-
-
-def _collation_rank_lut(d):
-    """id -> collation-rank LUT for a values dictionary, cached on the
-    Dictionary instance (ids are insertion-ordered, ORDER BY compares decoded
-    values).  Shared by listagg ordering, max_by/min_by ranking, and device
-    TopN."""
-    lut = getattr(d, "_rank_lut", None)
-    if lut is None or len(lut) != len(d.values):
-        lut = np.empty(len(d.values), np.int64)
-        order = np.argsort(np.asarray(d.values, dtype=object))  # host-ok: dict values
-        lut[order] = np.arange(len(d.values))
-        try:
-            object.__setattr__(d, "_rank_lut", lut)
-        except Exception:
-            pass
-    return lut
-
-
-def _narrow_pull_dtype(d):
-    """Narrowest integer dtype holding every id of a VALUES dictionary, known
-    statically from the dictionary length (ids are non-negative and
-    < len(values)) — no device sync needed.  Lets result pulls ship a
-    25-value nation column as int8 instead of int64: the result transfer
-    is the warm join query's dominant remaining pull, and
-    dictionary ids are where its bytes are compressible for free."""
-    if d is None or getattr(d, "values", None) is None:
-        return None
-    n = len(d.values)
-    for dt in (np.int8, np.int16, np.int32):
-        if n - 1 <= np.iinfo(dt).max:
-            return dt
-    return None
-
-
-# a TopN of at most this many rows, over at most this many lane-rounds, is
-# selected by ops/arrays.first_rows and not sorted: a round reads every lane
-# twice a key, so 2^31 lane-rounds stay under a tenth of a second on a v5e
-TOPN_SELECT_MAX = 1024
-TOPN_SELECT_WORK = 1 << 31
-
-
-def _sort_page_device(page: Page, keys, dicts=None):
-    """Device-side FULL sort: lexsort on device, then pull exactly the live
-    rows — no dead lanes or pow2 padding, no validity mask (every fetched row
-    is live by construction), dictionary ids narrowed and bool masks
-    bit-packed on the wire.  The host path (_sort_page) pulls every lane of
-    the page at full width before sorting; for a device-resident aggregate
-    output that is pure transfer waste (measured: warm SF1 q9's ORDER BY pull
-    dropped 4200 -> 3041 bytes).  A page that does not know its live count
-    (``Page.live``) pays one scalar sync for it.
-    Returns None (host fallback) on host pages or unrankable keys, like
-    _topn_page_device."""
-    return _topn_page_device(page, keys, None, dicts)
-
-
-def _rank_lut_device(d):
-    """``_collation_rank_lut`` as the sort program's argument: a small one is
-    kept on the device beside the host copy (a dashboard sorts by the same
-    few-valued column every statement), a large one is handed over per sort
-    as before and pins nothing."""
-    rank = _collation_rank_lut(d)
-    if rank.nbytes > 1 << 20:
-        return rank
-    dev = getattr(d, "_rank_lut_device", None)
-    if dev is None or dev.shape[0] != len(rank):
-        dev = jax.device_put(rank)  # device-ok: a kept id->rank table of at most 1 MiB, an argument of the sort program, not a scan's page
-        try:
-            object.__setattr__(d, "_rank_lut_device", dev)
-        except Exception:
-            pass
-    return dev
-
-
-@partial(_jit, site="sort.count")
-def _live_count(valid):
-    return jnp.sum(valid, dtype=jnp.int64)
-
-
-@partial(_jit, site="sort.rows", static_argnums=(4, 5, 6, 7, 8))
-def _sorted_rows(cols, nulls, valid, luts, keys, count, select, narrow,
-                 fetch_valid):
-    """The device part of a Sort or TopN as ONE program a (schema, sort keys,
-    count, capacity, which masks exist): collation ranks, the lex keys, a
-    keys-only sort (or ``first_rows``' selection) with the gathers of every
-    column and mask behind it, the narrowing casts and the bit-packing: what
-    the host then pulls, in the order it unpacks.  ``keys`` is ``(channel,
-    ascending, nulls_first, position in luts or -1)`` in ORDER BY order,
-    ``narrow`` the wire dtype of each column or None."""
-    lex = []
-    for channel, ascending, nulls_first, lut in reversed(keys):
-        c = cols[channel]
-        if lut >= 0:
-            rank = luts[lut]
-            c = rank[jnp.clip(c, 0, max(rank.shape[0] - 1, 0))]
-        if c.dtype == bool:
-            c = c.astype(jnp.int8)
-        nm = nulls[channel]
-        if nm is not None:
-            # NULL lanes hold arbitrary fill values: pin them to one constant
-            # so secondary keys keep breaking ties among NULL rows (the host
-            # path's equivalent pin in _sort_page)
-            c = jnp.where(nm, jnp.zeros((), c.dtype), c)
-        if not ascending:
-            c = ~c if jnp.issubdtype(c.dtype, jnp.integer) else -c
-        lex.append(c)
-        if nm is not None:
-            # null placement outranks the value ordering for this key (a key
-            # without a mask has a constant indicator, which moves no row)
-            ind = nm.astype(jnp.int8)
-            lex.append(-ind if nulls_first else ind)
-    if valid is not None:
-        lex.append(~valid)  # invalid lanes last — top-count rows are live ones
-    if select:
-        idx = first_rows(tuple(lex), count)
-    else:
-        idx = jnp.lexsort(tuple(lex))[:count]
-    fetch = [c[idx] if nd is None else c[idx].astype(nd)
-             for c, nd in zip(cols, narrow)]
-    # boolean masks ship BIT-packed (8x): the result pull
-    # is byte-priced, and masks are the compressible half of a narrow result
-    fetch += [jnp.packbits(nm[idx]) for nm in nulls if nm is not None]
-    if fetch_valid:
-        fetch.append(jnp.packbits(valid[idx]))
-    return fetch
-
-
-def _topn_page_device(page: Page, keys, count, dicts=None):
-    """Device-side TopN: one lexsort over collation-ranked keys, gather the
-    top ``count`` rows, transfer ONLY those.  The host path pulls the whole
-    input page (often a 100k+-row aggregate output) before sorting, and
-    that transfer is most of what such a query pulls (round-5 Q3 finding).  Returns None when the page is host-resident or a sort key
-    cannot rank on device (formatter dictionaries, object-dtype decimals);
-    the caller falls back to the host path.  Everything the device does is
-    the one program ``_sorted_rows``; here is what the host decides from the
-    page and the plan, and the pull."""
-    if not page.capacity \
-            or not all(isinstance(c, jax.Array) for c in page.columns):
-        return None
-    luts, spec, floating = [], [], False
-    for k in keys:
-        t = page.schema.fields[k.channel].type
-        d = dicts[k.channel] if dicts is not None else None
-        lut = -1
-        if t.is_string:
-            if d is None or getattr(d, "values", None) is None:
-                return None
-            lut = len(luts)
-            luts.append(_rank_lut_device(d))
-        elif jnp.issubdtype(page.columns[k.channel].dtype, jnp.floating):
-            floating = True
-        spec.append((k.channel, bool(k.ascending), bool(k.nulls_first), lut))
-    # count=None (full device sort): fetch exactly the live rows.  A page
-    # that knows its live count says it; any other syncs it through _host
-    # (counted, batched-API) and only AFTER every rankability check above —
-    # a fallback to the host path must not pay a wasted round-trip first.
-    n = page.capacity
-    live = n if page.valid is None else page.live
-    if count is None and live is None:
-        live = int(_host([_live_count(page.valid)], site="sort.count")[0])
-    select = count is not None and count <= TOPN_SELECT_MAX \
-        and count * n <= TOPN_SELECT_WORK and not floating
-    # every fetched row is live by construction when the live count bounds
-    # the fetch: no validity fetch and no filter then
-    fetch_valid = live is None
-    count = min(n if count is None else count, n if live is None else live)
-    nc = len(page.columns)
-    if not count:
-        return Page(page.schema,
-                    tuple(np.zeros((0,), c.dtype) for c in page.columns),
-                    tuple(None if nm is None else np.zeros((0,), bool)
-                          for nm in page.null_masks), None)
-    # transfer-narrow dictionary-id columns (id bound known from the dict, no
-    # sync); the schema dtype is restored host-side after the pull, so only
-    # the wire format shrinks
-    narrow = []
-    for ci, c in enumerate(page.columns):
-        nd = None
-        if page.schema.fields[ci].type.is_string:
-            nd = _narrow_pull_dtype(dicts[ci] if dicts is not None else None)
-        if nd is not None and jnp.issubdtype(c.dtype, jnp.integer) \
-                and np.dtype(nd).itemsize < np.dtype(c.dtype).itemsize:
-            narrow.append(np.dtype(nd))
-        else:
-            narrow.append(None)
-    got = _host(_sorted_rows(page.columns, page.null_masks, page.valid,
-                             tuple(luts), tuple(spec), count, select,
-                             tuple(narrow), fetch_valid), site="sort.pull")
-    m = len(got[0]) if nc else 0
-
-    def unpack(b):
-        return np.unpackbits(np.asarray(b, np.uint8))[:m].astype(bool)  # host-ok
-
-    pos = nc
-    nulls = []
-    for nm in page.null_masks:
-        if nm is None:
-            nulls.append(None)
-        else:
-            nulls.append(unpack(got[pos]))
-            pos += 1
-    cols = tuple(c if nd is None else c.astype(pc.dtype)
-                 for c, nd, pc in zip(got[:nc], narrow, page.columns))
-    if fetch_valid:
-        v = unpack(got[pos])
-        cols = tuple(c[v] for c in cols)
-        nulls = [None if nm is None else nm[v] for nm in nulls]
-    return Page(page.schema, cols, tuple(nulls), None)
-
-
-def _limit_page(page: Page, count: int) -> Page:
-    valid, pcols, pnulls = _host_page(page)
-    cols = tuple(c[valid][:count] for c in pcols)
-    nulls = tuple(None if n is None else n[valid][:count] for n in pnulls)
-    return Page(page.schema, cols, nulls, None)
-
-
-def _materialize(page: Page, dicts) -> MaterializedResult:
-    valid, pcols, pnulls = _host_page(page)
-    return _materialize_host(page.schema, valid, pcols, pnulls, dicts)
-
-
-def _materialize_host(schema, valid, pcols, pnulls, dicts) \
-        -> MaterializedResult:
-    """Host-side result decode over already-pulled numpy arrays — shared by
-    the single-statement pull above and the batched demux (round 21), which
-    slices one [R, rows] pull into per-request lanes and decodes each lane
-    through this exact function (byte-identity with serial by construction)."""
-    names, types, columns, raw = [], [], [], []
-    for i, f in enumerate(schema.fields):
-        arr = pcols[i][valid]
-        raw.append(arr)
-        dec = arr
-        if isinstance(f.type, DecimalType):
-            if arr.dtype == object:
-                # exact wide-decimal sums (Python ints past 2^63): decode via
-                # decimal.Decimal so no precision is lost at the surface
-                from decimal import Decimal
-
-                q = Decimal(10) ** f.type.scale
-                dec = np.array([Decimal(int(v)) / q for v in arr.tolist()],
-                               dtype=object)
-            else:
-                dec = arr.astype(np.float64) / (10**f.type.scale)
-        elif f.type.is_string and dicts[i] is not None:
-            dec = dicts[i].decode(arr)
-        else:
-            from ..types import ArrayType, MapType, TimestampType
-
-            if isinstance(f.type, (ArrayType, MapType)) and dicts[i] is not None:
-                dec = dicts[i].decode(arr)  # spans -> python lists / dicts
-            elif f.type.name == "date":
-                # epoch days -> date at the result surface (reference: client
-                # protocol returns DATE values, not their day encoding)
-                dec = arr.astype("datetime64[D]")
-            elif isinstance(f.type, TimestampType):
-                p = f.type.precision
-                dec = (arr * 10 ** (6 - p)).astype("datetime64[us]") \
-                    if p <= 6 else \
-                    (arr * 10 ** (9 - p)).astype("datetime64[ns]")
-        if pnulls[i] is not None:
-            nm = pnulls[i][valid]
-            dec = np.array([None if m else v for v, m in zip(dec.tolist(), nm)], dtype=object) \
-                if nm.any() else dec
-        names.append(f.name)
-        types.append(f.type)
-        columns.append(dec)
-    return MaterializedResult(tuple(names), tuple(types), columns, raw)
-
-
-def _window_spec_dicts(specs, dicts):
-    """Output dictionaries per window spec: value-passing kinds inherit the
-    argument channel's dictionary (shared by the local and distributed paths)."""
-    return tuple(
-        dicts[s.arg] if s.kind in ("min", "max", "lag", "lead", "first_value",
-                                   "last_value") and s.arg is not None else None
-        for s in specs)
-
-
-def _window_sort_passes(specs, nulls, padded):
-    """Stable argsorts ``_window_kernel`` runs over its page (``ops/window.
-    window_order``: one a key column): for each distinct (partition, order)
-    clause (specs that share one share its sort) the pad mask of a page with
-    invalid rows, then every partition and order channel, a nullable one with
-    its NULL indicator before it."""
-    return sum(int(padded) + sum(1 + (nulls[c] is not None) for c in
-                                 list(part) + [k.channel for k in order])
-               for part, order in {(s.partition, s.order) for s in specs})
-
-
-def _window_kernel(specs, cols, nulls, valid=None):
-    """Evaluate all window specs over one materialized page (ops/window primitives).
-
-    Sort permutations are shared across specs with the same (partition, order) clause
-    (reference: WindowOperator groups functions by window specification).
-
-    ``valid`` (optional) marks live rows: invalid (pad) rows are isolated into
-    their own partition — they sort last, never join a real partition's
-    segments, and their outputs are garbage the caller drops.  This is what
-    lets the distributed executor run the kernel per mesh shard over
-    ragged-and-padded row counts."""
-    from ..ops import window as W
-
-    n = cols[0].shape[0]
-    pad = None if valid is None else ~valid
-    cache: dict = {}
-
-    def keyed(ch):
-        """(indicator, value) sort/segment columns for a possibly-nullable channel:
-        NULL rows group together and sort by the indicator, not the fill value."""
-        nm = nulls[ch]
-        if nm is None:
-            return [(None, cols[ch])]
-        return [(nm, jnp.where(nm, jnp.zeros((), cols[ch].dtype), cols[ch]))]
-
-    out_cols, out_nulls = [], []
-    for s in specs:
-        ck = (s.partition, s.order)
-        if ck not in cache:
-            kcols, desc = [], []
-            if pad is not None:
-                kcols.append(pad)  # pads sort after every live row
-                desc.append(False)
-            for c in s.partition:
-                for ind, v in keyed(c):
-                    if ind is not None:
-                        kcols.append(ind)
-                        desc.append(False)
-                    kcols.append(v)
-                    desc.append(False)
-            for k in s.order:
-                for ind, v in keyed(k.channel):
-                    if ind is not None:
-                        # nulls_first -> null indicator sorts first (descending bool)
-                        kcols.append(ind)
-                        desc.append(bool(k.nulls_first))
-                    kcols.append(v)
-                    desc.append(not k.ascending)
-            if kcols:
-                perm = W.window_order(kcols, desc)
-            else:
-                perm = jnp.arange(n, dtype=jnp.int32)
-
-            def seg_cols(channels):
-                out = []
-                for c in channels:
-                    for ind, v in keyed(c):
-                        if ind is not None:
-                            out.append(ind[perm])
-                        out.append(v[perm])
-                return out
-
-            pad_seg = [] if pad is None else [pad[perm]]
-            if s.partition:
-                part_new = W.segments(pad_seg + seg_cols(s.partition))
-            elif pad is not None:
-                part_new = W.segments(pad_seg)
-            else:
-                part_new = jnp.zeros((n,), bool).at[0].set(True)
-            if s.order:
-                peer_new = part_new | W.segments(
-                    seg_cols([k.channel for k in s.order]))
-            else:
-                peer_new = part_new
-            cache[ck] = (perm, part_new, peer_new)
-        perm, part_new, peer_new = cache[ck]
-        framed = bool(s.order)  # ORDER BY -> running frame; else whole partition
-        # explicit ROWS/RANGE BETWEEN frame (reference: FramedWindowFunction):
-        # per-row [lo, hi] bounds; empty frames (hi < lo) are legal and NULL
-        frame = getattr(s, "frame", None)
-        lo_f = hi_f = empty_f = None
-        if frame is not None:
-            order_vals = None
-            if frame[0] == "range" and (frame[1] in ("p", "f")
-                                        or frame[3] in ("p", "f")):
-                # value-offset RANGE bounds: the single ORDER BY key's sorted
-                # values, ascending-normalized, with NULL rows pushed past the
-                # reachable range so they frame only among themselves
-                k0 = s.order[0]
-                ov = cols[k0.channel][perm]
-                if not k0.ascending:
-                    ov = -ov
-                nm0 = nulls[k0.channel]
-                if nm0 is not None:
-                    nmv = nm0[perm]
-                    gap = 2 * (max(frame[2], frame[4]) + 1)
-                    nn_min = jnp.min(jnp.where(nmv, jnp.max(ov), ov))
-                    nn_max = jnp.max(jnp.where(nmv, jnp.min(ov), ov))
-                    sent = nn_min - gap if bool(k0.nulls_first) else nn_max + gap
-                    ov = jnp.where(nmv, sent, ov)
-                order_vals = ov
-            lo_f, hi_f = W.frame_bounds(part_new, peer_new, frame, order_vals)
-            empty_f = hi_f < lo_f
-
-        def wsum(v, dt=None):
-            if frame is not None:
-                return W.framed_sum(v, lo_f, hi_f, dt)
-            return (W.segmented_scan_sum(v, part_new, peer_new, dt) if framed
-                    else W.partition_total(v, part_new, dt))
-
-        def wminmax(v, kind):
-            if frame is not None:
-                return W.framed_minmax(v, lo_f, hi_f, kind)
-            return W.segmented_scan_minmax(
-                v, part_new, peer_new if framed else part_new, kind)
-
-        vals = None
-        vmask = None  # True where the input value counts
-        if s.arg is not None:
-            vals = cols[s.arg][perm]
-            nm = nulls[s.arg]
-            vmask = None if nm is None else ~nm[perm]
-
-        null_out = None
-        if s.kind == "row_number":
-            res = W.row_number(part_new)
-        elif s.kind == "rank":
-            res = W.rank(part_new, peer_new)
-        elif s.kind == "dense_rank":
-            res = W.dense_rank(part_new, peer_new)
-        elif s.kind in ("count", "count_star"):
-            ones = jnp.ones((n,), jnp.int64)
-            if s.kind == "count" and vmask is not None:
-                ones = jnp.where(vmask, 1, 0)
-            res = wsum(ones)  # empty frames count 0 (framed_sum yields 0)
-        elif s.kind in ("sum", "avg"):
-            acc_dt = jnp.float64 if s.type.is_floating else jnp.int64
-            v = vals if vmask is None else jnp.where(vmask, vals, 0)
-            total = wsum(v, acc_dt)
-            nn_cnt = None
-            if vmask is not None:
-                nn_cnt = wsum(jnp.where(vmask, 1, 0))
-                null_out = nn_cnt == 0  # all-NULL (or empty) frame -> NULL
-            elif empty_f is not None:
-                null_out = empty_f
-            if s.kind == "sum":
-                res = total
-            else:
-                cnt = nn_cnt
-                if cnt is None:
-                    cnt = wsum(jnp.ones((n,), jnp.int64))
-                cnt_safe = jnp.maximum(cnt, 1)
-                if s.type.is_floating:
-                    res = total / cnt_safe
-                else:  # decimal avg: HALF_UP like the aggregation path
-                    q, r = jnp.divmod(jnp.abs(total), cnt_safe)
-                    res = ((q + (2 * r >= cnt_safe)) * jnp.sign(total))
-        elif s.kind in ("min", "max"):
-            v = vals
-            if vmask is not None:
-                ident = hashagg._extreme(vals.dtype, 1 if s.kind == "min" else -1)
-                v = jnp.where(vmask, vals, ident)
-                nn_cnt = wsum(jnp.where(vmask, 1, 0))
-                null_out = nn_cnt == 0  # all-NULL frame -> NULL, not the sentinel
-            elif empty_f is not None:
-                null_out = empty_f
-            res = wminmax(v, s.kind)
-        elif s.kind in ("lag", "lead"):
-            off = s.offset if s.kind == "lag" else -s.offset
-            fill = (jnp.zeros((), vals.dtype) if s.default is None
-                    else jnp.asarray(s.default, vals.dtype))
-            if getattr(s, "ignore_nulls", False) and vmask is not None:
-                # navigate over NON-NULL rows only (reference: the ignoreNulls
-                # walk of operator/window/LagFunction.java, here rank
-                # arithmetic over a nonnull-position index)
-                res, miss = W.shift_ignore_nulls(vals, vmask, part_new, off,
-                                                 fill)
-                if s.default is None:
-                    null_out = miss
-                else:
-                    res = jnp.where(miss, fill, res)
-                    null_out = jnp.zeros((n,), bool)
-            else:
-                res, miss = W.shift_in_partition(vals, part_new, off, fill)
-                if s.default is None:
-                    null_out = miss
-                else:
-                    res = jnp.where(miss, fill, res)
-                    null_out = jnp.zeros((n,), bool)
-                if vmask is not None:
-                    shifted_null, _ = W.shift_in_partition(
-                        (~vmask), part_new, off, jnp.zeros((), bool))
-                    null_out = null_out | (shifted_null & ~miss)
-        elif s.kind in ("percent_rank", "cume_dist"):
-            size = W.partition_total(jnp.ones((n,), jnp.int64), part_new)
-            if s.kind == "percent_rank":
-                rk = W.rank(part_new, peer_new)
-                res = jnp.where(size > 1,
-                                (rk - 1) / jnp.maximum(size - 1, 1), 0.0)
-            else:
-                pos = W._ends(peer_new) - W._starts(part_new) + 1
-                res = pos / size
-        elif s.kind == "ntile":
-            # reference: NTileFunction — the first (size % n) buckets take one
-            # extra row
-            nb = s.offset
-            size = W.partition_total(jnp.ones((n,), jnp.int64), part_new)
-            rn = W.row_number(part_new)
-            q, r = size // nb, size % nb
-            boundary = r * (q + 1)
-            res = jnp.where(rn <= boundary,
-                            (rn - 1) // jnp.maximum(q + 1, 1),
-                            r + (rn - 1 - boundary) // jnp.maximum(q, 1)) + 1
-        elif s.kind == "nth_value":
-            # a row whose frame holds fewer than k rows yields NULL (reference:
-            # operator/window/NthValueFunction.java frame bounds check); the
-            # default frame is RANGE UNBOUNDED PRECEDING..CURRENT ROW
-            k = s.offset
-            starts = lo_f if frame is not None else W._starts(part_new)
-            frame_end = hi_f if frame is not None else W._ends(peer_new)
-            if getattr(s, "ignore_nulls", False) and vmask is not None:
-                res, miss = W.framed_nth_nonnull(vals, vmask, starts,
-                                                 frame_end, k)
-                null_out = miss
-            else:
-                frame_size = frame_end - starts + 1
-                idx = jnp.clip(starts + (k - 1), 0, n - 1)
-                res = vals[idx]
-                null_out = frame_size < k  # frame shorter than k -> NULL
-                if vmask is not None:
-                    null_out = null_out | ~vmask[idx]
-        elif s.kind in ("first_value", "last_value"):
-            starts = lo_f if frame is not None else W._starts(part_new)
-            frame_end = (hi_f if frame is not None
-                         else W._ends(peer_new if framed else part_new))
-            if getattr(s, "ignore_nulls", False) and vmask is not None:
-                res, miss = W.framed_nth_nonnull(
-                    vals, vmask, starts, frame_end, 1,
-                    from_end=(s.kind == "last_value"))
-                null_out = miss
-            else:
-                idx = jnp.clip(starts if s.kind == "first_value" else frame_end,
-                               0, n - 1)
-                null_out = empty_f
-                res = vals[idx]
-                if vmask is not None:
-                    miss = ~vmask[idx]
-                    null_out = miss if null_out is None else (null_out | miss)
-        else:
-            raise NotImplementedError(s.kind)
-
-        out = jnp.zeros((n,), res.dtype).at[perm].set(res.astype(res.dtype))
-        out_cols.append(out.astype(s.type.dtype))
-        if null_out is not None:
-            out_nulls.append(jnp.zeros((n,), bool).at[perm].set(null_out))
-        else:
-            out_nulls.append(None)
-    return tuple(out_cols), tuple(out_nulls)

@@ -52,7 +52,7 @@ import numpy as np
 
 from ..execution import faults, tracing
 from ..page import Page
-from .local_executor import _host, _jit
+from .boundary import _host, _jit
 
 __all__ = ["SpilledPartitions", "SpillCapacityError", "concat_host_chunks",
            "padded_page", "padded_host_page", "spill_dir", "live_spill_files",

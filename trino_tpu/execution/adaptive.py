@@ -69,6 +69,8 @@ import threading
 from collections import OrderedDict
 from typing import Optional
 
+from ..exec.boundary import DISPATCH_BATCH
+
 __all__ = ["AdaptiveAdvisor", "ADAPTIVE_THRESHOLD"]
 
 # material-misestimate bar for a correction (2x merely counts as a
@@ -294,9 +296,7 @@ class AdaptiveAdvisor:
         splits = max((int(r.get("splits") or 0)
                       for r in ent.get("nodes", {}).values()), default=0)
         if splits:
-            from ..exec.local_executor import _dispatch_batch_default
-
-            cur = _dispatch_batch_default()
+            cur = DISPATCH_BATCH
             if splits > 2 * cur:
                 k = min(MAX_DISPATCH_BATCH,
                         max(cur, _pow2_at_least(splits / 4.0)))

@@ -29,7 +29,6 @@ lane, and the driver in its gather window, is the statement's
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 
@@ -77,14 +76,11 @@ class TemplateBatcher:
     ``execute`` is the only entry point; ``info()`` snapshots the metrics
     surface (/v1/metrics template-batch counters + size histogram)."""
 
-    def __init__(self, window_ms=None, max_batch=None, enabled=None):
+    def __init__(self, window_ms=None, max_batch=None, enabled=True):
         self.window_s = (DEFAULT_WINDOW_MS if window_ms is None
                          else float(window_ms)) / 1000.0
         self.max_batch = max(DEFAULT_MAX_BATCH if max_batch is None
                              else int(max_batch), 1)
-        if enabled is None:
-            enabled = os.environ.get("TRINO_TPU_TEMPLATE_BATCH", "1") \
-                not in ("0", "false", "no")
         self.enabled = bool(enabled)
         self._lock = threading.Lock()
         self._lanes: dict = {}

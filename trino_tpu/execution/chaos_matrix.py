@@ -314,18 +314,19 @@ PRESSURE_QUERY = """
     group by o_orderkey order by n desc, o_orderkey limit 13"""
 
 
-def run_pressure_scenario(engine, plan, baseline_sig, name, cfg, spec, kind,
-                          scratch_dir) -> dict:
+def run_pressure_scenario(new_executor, plan, baseline_sig, name, cfg, spec,
+                          kind, scratch_dir) -> dict:
     """One pressure scenario against a compiled ``plan``: fresh tiny-budget
-    executor per cfg, fault armed, outcome + extended leak check folded into
-    the returned record ({"ok": bool, ...}) — shared by
-    tests/test_spill_tiers.py and scripts/chaos.py so the pinned contract
-    and the on-device capture cannot drift."""
+    executor per cfg (``new_executor(memory_pool=, buffer_pool=)`` makes it:
+    this layer sits under the executors and imports none), fault armed,
+    outcome + extended leak check folded into the returned record
+    ({"ok": bool, ...}) — shared by tests/test_spill_tiers.py and
+    scripts/chaos.py so the pinned contract and the on-device capture cannot
+    drift."""
     import contextlib
     import os
 
     from ..exec import spill as spill_mod
-    from ..exec.local_executor import LocalExecutor
     from ..exec.spill import SpillCapacityError
     from ..execution.bufferpool import DeviceBufferPool
     from ..memory import MemoryPool
@@ -341,9 +342,8 @@ def run_pressure_scenario(engine, plan, baseline_sig, name, cfg, spec, kind,
     else:
         os.environ["TRINO_TPU_SPILL_HOST_BYTES"] = str(cfg["spill_host"])
     bp = DeviceBufferPool(budget_bytes=cfg.get("page_cache", 0))
-    ex = LocalExecutor(engine.catalogs,
-                       memory_pool=MemoryPool(max_bytes=cfg["pool_bytes"]),
-                       buffer_pool=bp)
+    ex = new_executor(memory_pool=MemoryPool(max_bytes=cfg["pool_bytes"]),
+                      buffer_pool=bp)
     try:
         ctx = faults.injected(spec) if spec else contextlib.nullcontext()
         with ctx as plan_f:

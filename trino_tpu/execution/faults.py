@@ -52,8 +52,8 @@ Arming:
 
 Injection points (the ``point`` vocabulary)::
 
-    dispatch       exec/local_executor._jit     (every compiled-fn invocation)
-    host_pull      exec/local_executor._host    (every batched D2H pull)
+    dispatch       exec/boundary._jit           (every compiled-fn invocation)
+    host_pull      exec/boundary._host          (every batched D2H pull)
     generate       _scan_pages_source           (per-split connector generate)
     h2d            _page_to_device              (H2D staging chokepoint)
     cache_store    DeviceBufferPool.put_page/put_build/put_result

@@ -10,7 +10,7 @@ record lived exactly once, in a released executor's ``stats`` dict, and died
 with it.
 
 ``PlanHistoryStore`` is a bounded, thread-safe map from the STRUCTURAL plan
-fingerprint (exec/local_executor._plan_fingerprint — content-based and
+fingerprint (sql/plan._plan_fingerprint — content-based and
 plan-version-embedding, the same identity the result cache keys on) to
 per-node records keyed by stable structural node paths.  Records merge across
 pooled executors, across warm re-executions of a cached plan, and across the
@@ -43,6 +43,8 @@ import os
 import threading
 from collections import OrderedDict
 from typing import Optional
+
+from ..exec.boundary import _host
 
 __all__ = ["PlanHistoryStore", "plan_node_paths", "estimate_plan_rows",
            "collect_plan_actuals", "fold_records", "translate_path",
@@ -305,8 +307,6 @@ def collect_plan_actuals(plan, stats: dict, boundary: Optional[dict] = None,
     # other); host ints alone cost no round trip and record none
     vals = [r[2] for r in pending]
     if any(hasattr(v, "copy_to_host_async") for v in vals):
-        from ..exec.local_executor import _host
-
         vals = _host(vals, site="history.actuals")
     out: dict = {}
     for (path, rec, _), v in zip(pending, vals):

@@ -81,9 +81,9 @@ SYSTEM_SESSION_PROPERTIES = {p.name: p for p in [
     PropertyMetadata("query_priority", "Scheduling priority", "integer", 1, _positive),
     PropertyMetadata("dispatch_batch",
                      "Coalesce up to K shape-uniform scan splits into one "
-                     "device dispatch (0 = engine default from "
-                     "TRINO_TPU_DISPATCH_BATCH, 1 = exact per-split "
-                     "execution).  Plan-shaping: rides the plan-cache key",
+                     "device dispatch (0 = the engine's default, 1 = exact "
+                     "per-split execution).  Plan-shaping: rides the "
+                     "plan-cache key",
                      "integer", 0, lambda v: None if v >= 0 else "must be >= 0"),
     PropertyMetadata("page_cache",
                      "Serve scans / join builds from the device buffer pool "
@@ -98,10 +98,9 @@ SYSTEM_SESSION_PROPERTIES = {p.name: p for p in [
                      "boolean", True),
     PropertyMetadata("adaptive_execution",
                      "Let the adaptive advisor (execution/adaptive) divert "
-                     "statements to history-corrected plans (env default "
-                     "TRINO_TPU_ADAPTIVE).  Plan-shaping: rides the "
-                     "plan-cache key, so flipping it escapes (or re-enters) "
-                     "the corrected plan", "boolean", True),
+                     "statements to history-corrected plans.  Plan-shaping: "
+                     "rides the plan-cache key, so flipping it escapes (or "
+                     "re-enters) the corrected plan", "boolean", True),
     PropertyMetadata("query_max_memory",
                      "Per-query device memory limit in bytes (0 = node limit "
                      "only; reference: query.max-memory + "

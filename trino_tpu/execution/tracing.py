@@ -178,7 +178,7 @@ class QueryCounters:
     host_transfers: int = 0
     host_bytes_pulled: int = 0
     # splits whose per-page work ran inside a coalesced multi-split dispatch
-    # (exec/local_executor._coalesced_batches): the batching that turns K
+    # (exec/boundary._coalesced_batches): the batching that turns K
     # per-split dispatches into one — visible so EXPLAIN ANALYZE / bench can
     # show HOW a query met its dispatch budget, not just that it did
     coalesced_splits: int = 0
@@ -313,7 +313,7 @@ class QueryCounters:
     groupby_insert_round_lanes: int = 0
     # PR 39: how a group-by's finalize and a Sort or TopN ran, one count
     # each: as a compiled program over a device-resident page
-    # (local_executor._device_finalize, _sorted_rows), or on the eager/host
+    # (local_executor._device_finalize, exec/pages._sorted_rows), or on the eager/host
     # path (a host-resident page, an unrankable sort key, an agg kind or a
     # wide-decimal sum that needs the host-exact finalize)
     tail_compiled: int = 0
@@ -751,7 +751,7 @@ def record_rows_generated(rows: int) -> None:
 
 def record_generate(table: str, seconds: float, count: bool = True) -> None:
     """One launch of a connector's page generator, measured where the
-    executor calls it (local_executor._generate): the count (a scan source's
+    executor calls it (exec/boundary._generate): the count (a scan source's
     launches; not a warm thread's), and a finished ``generate`` span (bucket
     split_generation) under the thread's current span, the prefetch
     producer's ``prefetch`` span mostly."""

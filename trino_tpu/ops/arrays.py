@@ -138,7 +138,7 @@ def live_indices(valid, size: int):
     whole vector stays sorted, so a gather may be promised both.
 
     THE index of every device-side row compaction (compact_rows,
-    local_executor._compact_part*, hashagg.compact_groups).  Scatter-free on
+    exec/pages._compact_part*, hashagg.compact_groups).  Scatter-free on
     purpose: on a v5e an XLA scatter (and ``jnp.nonzero(size=)``, whose
     ``bincount`` is a scatter-add) pays 175-290 ns for EVERY input lane, a
     sort key under 2 ns; the data then moves by gathers of the OUTPUT size.

@@ -20,6 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .hashagg import _extreme
+
 __all__ = ["window_order", "segments", "row_number", "rank", "dense_rank",
            "segmented_scan_sum", "segmented_scan_minmax", "partition_total",
            "shift_in_partition"]
@@ -266,3 +268,281 @@ def shift_in_partition(vals, part_new, offset: int, default):
     seg_id = jnp.cumsum(part_new.astype(jnp.int32))
     ok = (src >= 0) & (src < n) & (seg_id[src_clamped] == seg_id)
     return jnp.where(ok, vals[src_clamped], default), ~ok
+
+
+def _window_spec_dicts(specs, dicts):
+    """Output dictionaries per window spec: value-passing kinds inherit the
+    argument channel's dictionary (shared by the local and distributed paths)."""
+    return tuple(
+        dicts[s.arg] if s.kind in ("min", "max", "lag", "lead", "first_value",
+                                   "last_value") and s.arg is not None else None
+        for s in specs)
+
+
+def _window_sort_passes(specs, nulls, padded):
+    """Stable argsorts ``_window_kernel`` runs over its page (``ops/window.
+    window_order``: one a key column): for each distinct (partition, order)
+    clause (specs that share one share its sort) the pad mask of a page with
+    invalid rows, then every partition and order channel, a nullable one with
+    its NULL indicator before it."""
+    return sum(int(padded) + sum(1 + (nulls[c] is not None) for c in
+                                 list(part) + [k.channel for k in order])
+               for part, order in {(s.partition, s.order) for s in specs})
+
+
+def _window_kernel(specs, cols, nulls, valid=None):
+    """Evaluate all window specs over one materialized page (this module's primitives).
+
+    Sort permutations are shared across specs with the same (partition, order) clause
+    (reference: WindowOperator groups functions by window specification).
+
+    ``valid`` (optional) marks live rows: invalid (pad) rows are isolated into
+    their own partition — they sort last, never join a real partition's
+    segments, and their outputs are garbage the caller drops.  This is what
+    lets the distributed executor run the kernel per mesh shard over
+    ragged-and-padded row counts."""
+    n = cols[0].shape[0]
+    pad = None if valid is None else ~valid
+    cache: dict = {}
+
+    def keyed(ch):
+        """(indicator, value) sort/segment columns for a possibly-nullable channel:
+        NULL rows group together and sort by the indicator, not the fill value."""
+        nm = nulls[ch]
+        if nm is None:
+            return [(None, cols[ch])]
+        return [(nm, jnp.where(nm, jnp.zeros((), cols[ch].dtype), cols[ch]))]
+
+    out_cols, out_nulls = [], []
+    for s in specs:
+        ck = (s.partition, s.order)
+        if ck not in cache:
+            kcols, desc = [], []
+            if pad is not None:
+                kcols.append(pad)  # pads sort after every live row
+                desc.append(False)
+            for c in s.partition:
+                for ind, v in keyed(c):
+                    if ind is not None:
+                        kcols.append(ind)
+                        desc.append(False)
+                    kcols.append(v)
+                    desc.append(False)
+            for k in s.order:
+                for ind, v in keyed(k.channel):
+                    if ind is not None:
+                        # nulls_first -> null indicator sorts first (descending bool)
+                        kcols.append(ind)
+                        desc.append(bool(k.nulls_first))
+                    kcols.append(v)
+                    desc.append(not k.ascending)
+            if kcols:
+                perm = window_order(kcols, desc)
+            else:
+                perm = jnp.arange(n, dtype=jnp.int32)
+
+            def seg_cols(channels):
+                out = []
+                for c in channels:
+                    for ind, v in keyed(c):
+                        if ind is not None:
+                            out.append(ind[perm])
+                        out.append(v[perm])
+                return out
+
+            pad_seg = [] if pad is None else [pad[perm]]
+            if s.partition:
+                part_new = segments(pad_seg + seg_cols(s.partition))
+            elif pad is not None:
+                part_new = segments(pad_seg)
+            else:
+                part_new = jnp.zeros((n,), bool).at[0].set(True)
+            if s.order:
+                peer_new = part_new | segments(
+                    seg_cols([k.channel for k in s.order]))
+            else:
+                peer_new = part_new
+            cache[ck] = (perm, part_new, peer_new)
+        perm, part_new, peer_new = cache[ck]
+        framed = bool(s.order)  # ORDER BY -> running frame; else whole partition
+        # explicit ROWS/RANGE BETWEEN frame (reference: FramedWindowFunction):
+        # per-row [lo, hi] bounds; empty frames (hi < lo) are legal and NULL
+        frame = getattr(s, "frame", None)
+        lo_f = hi_f = empty_f = None
+        if frame is not None:
+            order_vals = None
+            if frame[0] == "range" and (frame[1] in ("p", "f")
+                                        or frame[3] in ("p", "f")):
+                # value-offset RANGE bounds: the single ORDER BY key's sorted
+                # values, ascending-normalized, with NULL rows pushed past the
+                # reachable range so they frame only among themselves
+                k0 = s.order[0]
+                ov = cols[k0.channel][perm]
+                if not k0.ascending:
+                    ov = -ov
+                nm0 = nulls[k0.channel]
+                if nm0 is not None:
+                    nmv = nm0[perm]
+                    gap = 2 * (max(frame[2], frame[4]) + 1)
+                    nn_min = jnp.min(jnp.where(nmv, jnp.max(ov), ov))
+                    nn_max = jnp.max(jnp.where(nmv, jnp.min(ov), ov))
+                    sent = nn_min - gap if bool(k0.nulls_first) else nn_max + gap
+                    ov = jnp.where(nmv, sent, ov)
+                order_vals = ov
+            lo_f, hi_f = frame_bounds(part_new, peer_new, frame, order_vals)
+            empty_f = hi_f < lo_f
+
+        def wsum(v, dt=None):
+            if frame is not None:
+                return framed_sum(v, lo_f, hi_f, dt)
+            return (segmented_scan_sum(v, part_new, peer_new, dt) if framed
+                    else partition_total(v, part_new, dt))
+
+        def wminmax(v, kind):
+            if frame is not None:
+                return framed_minmax(v, lo_f, hi_f, kind)
+            return segmented_scan_minmax(
+                v, part_new, peer_new if framed else part_new, kind)
+
+        vals = None
+        vmask = None  # True where the input value counts
+        if s.arg is not None:
+            vals = cols[s.arg][perm]
+            nm = nulls[s.arg]
+            vmask = None if nm is None else ~nm[perm]
+
+        null_out = None
+        if s.kind == "row_number":
+            res = row_number(part_new)
+        elif s.kind == "rank":
+            res = rank(part_new, peer_new)
+        elif s.kind == "dense_rank":
+            res = dense_rank(part_new, peer_new)
+        elif s.kind in ("count", "count_star"):
+            ones = jnp.ones((n,), jnp.int64)
+            if s.kind == "count" and vmask is not None:
+                ones = jnp.where(vmask, 1, 0)
+            res = wsum(ones)  # empty frames count 0 (framed_sum yields 0)
+        elif s.kind in ("sum", "avg"):
+            acc_dt = jnp.float64 if s.type.is_floating else jnp.int64
+            v = vals if vmask is None else jnp.where(vmask, vals, 0)
+            total = wsum(v, acc_dt)
+            nn_cnt = None
+            if vmask is not None:
+                nn_cnt = wsum(jnp.where(vmask, 1, 0))
+                null_out = nn_cnt == 0  # all-NULL (or empty) frame -> NULL
+            elif empty_f is not None:
+                null_out = empty_f
+            if s.kind == "sum":
+                res = total
+            else:
+                cnt = nn_cnt
+                if cnt is None:
+                    cnt = wsum(jnp.ones((n,), jnp.int64))
+                cnt_safe = jnp.maximum(cnt, 1)
+                if s.type.is_floating:
+                    res = total / cnt_safe
+                else:  # decimal avg: HALF_UP like the aggregation path
+                    q, r = jnp.divmod(jnp.abs(total), cnt_safe)
+                    res = ((q + (2 * r >= cnt_safe)) * jnp.sign(total))
+        elif s.kind in ("min", "max"):
+            v = vals
+            if vmask is not None:
+                ident = _extreme(vals.dtype, 1 if s.kind == "min" else -1)
+                v = jnp.where(vmask, vals, ident)
+                nn_cnt = wsum(jnp.where(vmask, 1, 0))
+                null_out = nn_cnt == 0  # all-NULL frame -> NULL, not the sentinel
+            elif empty_f is not None:
+                null_out = empty_f
+            res = wminmax(v, s.kind)
+        elif s.kind in ("lag", "lead"):
+            off = s.offset if s.kind == "lag" else -s.offset
+            fill = (jnp.zeros((), vals.dtype) if s.default is None
+                    else jnp.asarray(s.default, vals.dtype))
+            if getattr(s, "ignore_nulls", False) and vmask is not None:
+                # navigate over NON-NULL rows only (reference: the ignoreNulls
+                # walk of operator/window/LagFunction.java, here rank
+                # arithmetic over a nonnull-position index)
+                res, miss = shift_ignore_nulls(vals, vmask, part_new, off,
+                                                 fill)
+                if s.default is None:
+                    null_out = miss
+                else:
+                    res = jnp.where(miss, fill, res)
+                    null_out = jnp.zeros((n,), bool)
+            else:
+                res, miss = shift_in_partition(vals, part_new, off, fill)
+                if s.default is None:
+                    null_out = miss
+                else:
+                    res = jnp.where(miss, fill, res)
+                    null_out = jnp.zeros((n,), bool)
+                if vmask is not None:
+                    shifted_null, _ = shift_in_partition(
+                        (~vmask), part_new, off, jnp.zeros((), bool))
+                    null_out = null_out | (shifted_null & ~miss)
+        elif s.kind in ("percent_rank", "cume_dist"):
+            size = partition_total(jnp.ones((n,), jnp.int64), part_new)
+            if s.kind == "percent_rank":
+                rk = rank(part_new, peer_new)
+                res = jnp.where(size > 1,
+                                (rk - 1) / jnp.maximum(size - 1, 1), 0.0)
+            else:
+                pos = _ends(peer_new) - _starts(part_new) + 1
+                res = pos / size
+        elif s.kind == "ntile":
+            # reference: NTileFunction — the first (size % n) buckets take one
+            # extra row
+            nb = s.offset
+            size = partition_total(jnp.ones((n,), jnp.int64), part_new)
+            rn = row_number(part_new)
+            q, r = size // nb, size % nb
+            boundary = r * (q + 1)
+            res = jnp.where(rn <= boundary,
+                            (rn - 1) // jnp.maximum(q + 1, 1),
+                            r + (rn - 1 - boundary) // jnp.maximum(q, 1)) + 1
+        elif s.kind == "nth_value":
+            # a row whose frame holds fewer than k rows yields NULL (reference:
+            # operator/window/NthValueFunction.java frame bounds check); the
+            # default frame is RANGE UNBOUNDED PRECEDING..CURRENT ROW
+            k = s.offset
+            starts = lo_f if frame is not None else _starts(part_new)
+            frame_end = hi_f if frame is not None else _ends(peer_new)
+            if getattr(s, "ignore_nulls", False) and vmask is not None:
+                res, miss = framed_nth_nonnull(vals, vmask, starts,
+                                                 frame_end, k)
+                null_out = miss
+            else:
+                frame_size = frame_end - starts + 1
+                idx = jnp.clip(starts + (k - 1), 0, n - 1)
+                res = vals[idx]
+                null_out = frame_size < k  # frame shorter than k -> NULL
+                if vmask is not None:
+                    null_out = null_out | ~vmask[idx]
+        elif s.kind in ("first_value", "last_value"):
+            starts = lo_f if frame is not None else _starts(part_new)
+            frame_end = (hi_f if frame is not None
+                         else _ends(peer_new if framed else part_new))
+            if getattr(s, "ignore_nulls", False) and vmask is not None:
+                res, miss = framed_nth_nonnull(
+                    vals, vmask, starts, frame_end, 1,
+                    from_end=(s.kind == "last_value"))
+                null_out = miss
+            else:
+                idx = jnp.clip(starts if s.kind == "first_value" else frame_end,
+                               0, n - 1)
+                null_out = empty_f
+                res = vals[idx]
+                if vmask is not None:
+                    miss = ~vmask[idx]
+                    null_out = miss if null_out is None else (null_out | miss)
+        else:
+            raise NotImplementedError(s.kind)
+
+        out = jnp.zeros((n,), res.dtype).at[perm].set(res.astype(res.dtype))
+        out_cols.append(out.astype(s.type.dtype))
+        if null_out is not None:
+            out_nulls.append(jnp.zeros((n,), bool).at[perm].set(null_out))
+        else:
+            out_nulls.append(None)
+    return tuple(out_cols), tuple(out_nulls)

@@ -55,7 +55,8 @@ from ..exec.fte import (FaultTolerantExecutor, SpoolingExchange,
                         read_fragment_outputs, run_fragment,
                         run_partial_aggregate, run_stream_splits,
                         serialize_fragment_output)
-from ..exec.local_executor import LocalExecutor, _materialize
+from ..exec.local_executor import LocalExecutor
+from ..exec.pages import _host_page, _materialize
 from ..execution import faults, tracing
 from ..execution.faults import InjectedFaultError
 from ..execution.tracing import (InflightRegistry, QueryCounters,
@@ -1988,8 +1989,6 @@ class ClusterCoordinator:
                     tid = self._next_tid()
                     if nested:
                         # a remote parent consumes this: spool the merged page
-                        from ..exec.local_executor import _host_page
-
                         valid, pcols, pnulls = _host_page(page)
                         # plan-actuals: the merged fragment output's FINAL
                         # row count, free from the host mask this spool

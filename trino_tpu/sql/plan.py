@@ -11,6 +11,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import numpy as np
+
 from ..page import Schema
 from ..types import Type
 from .ir import Expr
@@ -345,3 +347,43 @@ class Output(PlanNode):
     @property
     def children(self):
         return (self.child,)
+
+
+def _plan_fingerprint(node: PlanNode, catalogs: dict) -> str:
+    """Structural fingerprint of a plan subtree — the build-cache key.
+
+    Two structurally identical build fragments (same operators, expressions,
+    schemas, scanned tables) must collide even when they come from DIFFERENT
+    plan objects (another executor compiling the same cached plan, a second
+    statement sharing the subquery), so the walk is content-based: dataclass
+    leaves print by value, plan children recurse, and TableScans carry their
+    catalog/table/columns plus the connector's plan_version (growable
+    catalogs — the system tables' dictionaries — never serve a stale build).
+    Opaque payloads (dictionary value arrays) print by IDENTITY: they are
+    connector-owned singletons, stable for the life of this process, and
+    printing megabyte arrays by content would be both slow and collision-
+    prone under numpy's truncating repr."""
+    def val(v):
+        if v is None or isinstance(v, (str, int, float, bool, bytes)):
+            return repr(v)
+        if isinstance(v, (tuple, list)):
+            return "(" + ",".join(val(x) for x in v) + ")"
+        if isinstance(v, PlanNode):
+            return fp(v)
+        if isinstance(v, np.ndarray):
+            return f"nd#{id(v)}"
+        if dataclasses.is_dataclass(v) and not isinstance(v, type):
+            return f"{type(v).__name__}(" + ",".join(
+                val(getattr(v, f.name)) for f in dataclasses.fields(v)) + ")"
+        return f"{type(v).__name__}#{id(v)}"
+
+    def fp(n):
+        if isinstance(n, TableScan):
+            conn = catalogs.get(n.catalog)
+            ver = conn.plan_version() if hasattr(conn, "plan_version") else 0
+            return (f"TableScan({n.catalog},{n.table},"
+                    f"{','.join(n.columns)},v{ver})")
+        return f"{type(n).__name__}(" + ";".join(
+            val(getattr(n, f.name)) for f in dataclasses.fields(n)) + ")"
+
+    return fp(node)
