@@ -1,7 +1,9 @@
 """The yardstick still reads the program: a cell's whole control flow
 (``python -m benchmark.run --rehearse``, the CPU at SF0.01) runs to its result
 line, every answer right, for the two traffic shapes: one client replaying
-(``sf1_joins``) and eight over HTTP (``sf1_dashboard``).  Nothing of
+(``sf1_joins``) and eight over HTTP (``sf1_dashboard``), and for the mesh
+(``sf10_mesh4_joins``, PR 46: ``--rehearse`` asks the CPU backend for the
+cell's four host devices).  Nothing of
 ``benchmark/`` is imported: the command and ``BENCHMARK.json`` are the
 contract."""
 
@@ -15,7 +17,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.parametrize("cell", ["sf1_joins", "sf1_dashboard"])
+@pytest.mark.parametrize("cell", ["sf1_joins", "sf1_dashboard", "sf10_mesh4_joins"])
 def test_cell_rehearses_to_a_correct_result_line(cell):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         declared = json.load(f)
@@ -30,6 +32,8 @@ def test_cell_rehearses_to_a_correct_result_line(cell):
     assert last["correct"] is True, last
     assert last["failed"] == 0 and last["attempted"] > 0, last
     assert last["device"]["platform"] == "cpu"  # a rehearsal reports no chip
+    cells = {w["name"]: w for w in declared["workloads"]}
+    assert last["device"]["count"] == cells[cell]["chips"]
     listed = {m["name"] for m in declared["end_to_end"]
               if cell in m.get("workloads", [cell])}
     assert listed <= set(last["metrics"]), (listed, last["metrics"])

@@ -37,8 +37,9 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from ..execution import faults, tracing
 from ..execution.tracing import (QueryCounters, maybe_span, record_join_build,
-                                 record_mesh_fragment, record_page_cache,
-                                 record_probe_exchange, record_rows_generated,
+                                 record_mesh_fragment, record_mesh_scan_batch,
+                                 record_page_cache, record_probe_exchange,
+                                 record_rows_generated,
                                  record_shard_stats)
 from ..ops import hashagg
 from ..ops.arrays import append_rows, compact_rows
@@ -479,7 +480,9 @@ class _ShardedScan:
         if i < 0 or i >= self._n:
             raise IndexError(i)
         self._lookup()
-        if self._held is not None:
+        resident = self._held is not None
+        record_mesh_scan_batch(resident)
+        if resident:
             return self._held[i]
         batch = self._generate(i)
         if self._acc is not None and i == len(self._acc):
@@ -532,19 +535,25 @@ class _ShardedScan:
         stores the entry.  A scan whose chip's share passes the entry cap
         pins nothing, as the local path's does not."""
         bp = self.ex.buffer_pool
+        site = f"dist.scan.{self.table}.cache"
         per_chip = sum(int(a.nbytes) for a in jax.tree.leaves(batch)) \
             // self.ex.n_workers
         if per_chip * self._n > bp.page_entry_cap():
             self._acc = None
+            record_page_cache(over_cap=1, site=site)
             return
         self._acc.append(batch)
         if len(self._acc) < self._n:
             return
         self._held, self._acc = tuple(self._acc), None
         try:
-            bp.put_page(self._key(), self._held, nbytes=per_chip * self._n)
-        except Exception:
-            pass  # uncached, not failed; the next statement regenerates
+            stored = bp.put_page(self._key(), self._held,
+                                 nbytes=per_chip * self._n)
+        except Exception:  # noqa: BLE001 - an injected cache_store fault
+            stored = False
+        if not stored:
+            # uncached, not failed: the next statement regenerates, and says so
+            record_page_cache(store_failed=1, site=site)
 
 
 def _collation_luts(sort_keys, fields, dicts):
@@ -680,6 +689,13 @@ class _DStream:
     probes: tuple = ()  # the Join nodes whose probe exchanges report on ``of``
     key: tuple = ()  # (ladder rung, learned probe buckets) it was compiled at:
     # part of the kept key of every step over it (_fragment, _step)
+
+
+def _row_bytes(fields) -> int:
+    """Bytes of one row of ``fields`` as an exchange routes it: the columns'
+    widths (an object-dtype field never reaches the device)."""
+    return sum(np.dtype(f.type.dtype).itemsize for f in fields
+               if np.dtype(f.type.dtype) != object)
 
 
 def _host_spooled(stream: _DStream) -> bool:
@@ -873,15 +889,14 @@ class DistributedExecutor:
         return r
 
     def _note_skew(self, site: str, node, per_worker, wall_s: float,
-                   kind: str = "exchange", fields=None):
+                   kind: str = "exchange", fields=None, row_bytes=None):
         """Fold an already-pulled per-worker load vector into the query's
         shard_stats and key it by plan node for EXPLAIN ANALYZE (round 20).
         ``per_worker`` must be host ints the caller already synced — this is
         pure host arithmetic, never a new pull or dispatch."""
-        bpr = None
+        bpr = row_bytes
         if fields:
-            bpr = sum(np.dtype(f.type.dtype).itemsize for f in fields
-                      if np.dtype(f.type.dtype) != object) or None
+            bpr = _row_bytes(fields) or None
         rec = record_shard_stats(
             site, per_worker, wall_s=wall_s, kind=kind,
             op=None if node is None else type(node).__name__,
@@ -1319,7 +1334,8 @@ class DistributedExecutor:
         for i, node in enumerate(stream.probes):
             at = 1 + _SIDE_FIELDS * i
             need, bucket = (int(side[:, at + j].max()) for j in (0, 1))
-            record_probe_exchange(*(int(side[:, at + j].sum()) for j in (2, 3)))
+            record_probe_exchange(*(int(side[:, at + j].sum()) for j in (2, 3)),
+                                  row_bytes=_row_bytes(node.left.schema.fields))
             if 2 * _learned_bucket(need) <= bucket:
                 self._kept[(id(node), "probe_need")] = (node, need)
         return False
@@ -2094,7 +2110,9 @@ class DistributedExecutor:
         # which worker owns the heavy key range after the group exchange
         self._note_skew("dist.agg.overflow", node,
                         [int(x) for x in nocc], agg_wall,
-                        kind="occupancy")
+                        kind="occupancy", row_bytes=sum(
+                            a.dtype.itemsize for a in
+                            tuple(merged.key_cols) + tuple(merged.accs)))
         out_cap = 1 << (max(int(nocc.max()), 1) - 1).bit_length()
 
         def make_compact():

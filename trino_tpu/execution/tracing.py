@@ -346,6 +346,17 @@ class QueryCounters:
     # hold them
     probe_exchange_rows: int = 0
     probe_exchange_lanes: int = 0
+    # PR 46: payload bytes of the rows those exchanges delivered: the rows of
+    # exchange_rows and of probe_exchange_rows, each times the width of its
+    # routed columns (host ints from the plan's schemas; the receive
+    # tensors' dead lanes are not in it: probe_exchange_lanes has those).
+    # And the batches of the mesh's sharded scans (exec/distributed.py
+    # _ShardedScan.__getitem__): handed to a step from the page cache's
+    # entry, or generated for it (a first run, or a scan whose share a chip
+    # passes the entry cap and streams, every statement)
+    exchange_bytes: int = 0
+    mesh_scan_batches_resident: int = 0
+    mesh_scan_batches_generated: int = 0
     # PR 38: launches of a connector's page generator from the executor's scan
     # sources (record_generate: one a split, on whichever thread runs it, the
     # prefetch producer's mostly).  NOT part of device_dispatches, whose
@@ -430,6 +441,8 @@ class QueryCounters:
                    "exchange_rows", "exchange_rows_max_shard",
                    "mesh_fragment_hits", "mesh_fragment_compiles",
                    "probe_exchange_rows", "probe_exchange_lanes",
+                   "exchange_bytes", "mesh_scan_batches_resident",
+                   "mesh_scan_batches_generated",
                    "generator_dispatches")
     _FLOAT_FIELDS = ("compile_s", "queued_s", "batch_wait_s",
                      "executor_wait_s", "encode_s", "deliver_wait_s",
@@ -774,13 +787,26 @@ def record_mesh_fragment(hit: bool) -> None:
             c.mesh_fragment_compiles += 1
 
 
-def record_probe_exchange(rows: int, lanes: int) -> None:
+def record_probe_exchange(rows: int, lanes: int, row_bytes: int = 0) -> None:
     """One run of a mesh fragment's probe exchange (exec/distributed.py): the
-    rows it routed and the lanes its receive tensors held."""
+    rows it routed, the lanes its receive tensors held and the width of a
+    routed row."""
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.probe_exchange_rows += rows
         c.probe_exchange_lanes += lanes
+        c.exchange_bytes += rows * row_bytes
+
+
+def record_mesh_scan_batch(resident: bool) -> None:
+    """One batch of a sharded scan handed to a mesh step: from the page
+    cache's entry, or generated for it."""
+    c = getattr(_counter_local, "counters", None)
+    if c is not None:
+        if resident:
+            c.mesh_scan_batches_resident += 1
+        else:
+            c.mesh_scan_batches_generated += 1
 
 
 def _attribute_extra(site: Optional[str], **extras) -> None:
@@ -802,17 +828,24 @@ def _attribute_extra(site: Optional[str], **extras) -> None:
 
 
 def record_page_cache(hits: int = 0, misses: int = 0, bytes_saved: int = 0,
-                      site: Optional[str] = None) -> None:
+                      site: Optional[str] = None, over_cap: int = 0,
+                      store_failed: int = 0) -> None:
     """One buffer-pool page-tier lookup outcome (recorded on the QUERY
     thread — the scan page source resolves the cache before any prefetch
-    thread starts, so these never race the thread-local counters)."""
+    thread starts, so these never race the thread-local counters).
+    ``over_cap`` and ``store_failed`` are why a scan that missed is not
+    resident afterwards: its entry passes the pool's entry cap and it streams,
+    or the pool refused the finished entry (the mesh's sharded scans record
+    both; a site that records neither keeps its three keys)."""
     c = getattr(_counter_local, "counters", None)
     if c is not None:
         c.page_cache_hits += hits
         c.page_cache_misses += misses
         c.page_cache_bytes_saved += bytes_saved
+    why = {k: v for k, v in (("page_cache_over_cap", over_cap),
+                             ("page_cache_store_failed", store_failed)) if v}
     _attribute_extra(site, page_cache_hits=hits, page_cache_misses=misses,
-                     page_cache_bytes_saved=bytes_saved)
+                     page_cache_bytes_saved=bytes_saved, **why)
 
 
 def record_build_cache(hits: int = 0, misses: int = 0,
@@ -938,6 +971,7 @@ def record_shard_stats(site: str, per_worker, wall_s: float = 0.0,
             # each worker owns after the merge exchange
             c.exchange_rows += int(sum(rec["rows"]))
             c.exchange_rows_max_shard += int(mx)
+            c.exchange_bytes += int(sum(rec.get("bytes", ())))
     return rec
 
 

@@ -843,6 +843,15 @@ class CoordinatorServer:
                      "exchanges inside mesh fragments."),
                     ("probe_exchange_lanes", "Lanes the receive tensors of "
                      "those probe exchanges held."),
+                    ("exchange_bytes", "Payload bytes of the rows those "
+                     "exchanges delivered: rows times the width of their "
+                     "routed columns."),
+                    ("mesh_scan_batches_resident", "Batches of the mesh's "
+                     "sharded scans handed to a step from the page cache's "
+                     "entry."),
+                    ("mesh_scan_batches_generated", "Batches of the mesh's "
+                     "sharded scans generated for a step (a first run, or a "
+                     "scan over the entry cap, which streams)."),
                     ("generator_dispatches", "Launches of the connectors' "
                      "page generators from the executor's scan sources "
                      "(not in device_dispatches).")):
@@ -975,6 +984,23 @@ class CoordinatorServer:
                     lines.append(
                         f'trino_tpu_site_bytes_pulled_total'
                         f'{{site="{esc(key)}"}} {sites[key]["bytes"]}')
+                # PR 46: why a scan that missed the page cache is not
+                # resident afterwards (record_page_cache's over_cap and
+                # store_failed; the mesh's sharded scans record them)
+                why = [(key, w, sites[key]["page_cache_" + w])
+                       for key in sorted(sites)
+                       for w in ("over_cap", "store_failed")
+                       if sites[key].get("page_cache_" + w)]
+                if why:
+                    lines += ["# HELP trino_tpu_site_scans_not_resident_total "
+                              "Scans that missed the page cache and stayed "
+                              "out of it: over the entry cap (streamed), or "
+                              "refused by the pool.",
+                              "# TYPE trino_tpu_site_scans_not_resident_total "
+                              "counter"]
+                    lines += ["trino_tpu_site_scans_not_resident_total"
+                              f'{{site="{esc(key)}",why="{w}"}} {n}'
+                              for key, w, n in why]
             hist = getattr(ct, "dispatch_latency", None)
             if hist is not None:
                 from ..execution.tracing import LATENCY_BUCKETS_S

@@ -158,10 +158,18 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
             probes = ("; probe exchanges: "
                       f"{getattr(counters, 'probe_exchange_rows', 0)} rows in "
                       f"{pl} receive lanes") if pl else ""
+            # PR 46: the payload bytes of both, and where the sharded scans'
+            # batches came from
+            xb = getattr(counters, "exchange_bytes", 0)
+            sr = getattr(counters, "mesh_scan_batches_resident", 0)
+            sg = getattr(counters, "mesh_scan_batches_generated", 0)
             lines.append(
                 f"Exchange: {xr} rows routed, fullest shard "
                 f"{getattr(counters, 'exchange_rows_max_shard', 0)}; "
-                f"mesh fragments: {fh} kept, {fc} compiled{probes}")
+                f"mesh fragments: {fh} kept, {fc} compiled{probes}"
+                + (f"; {xb} bytes exchanged" if xb else "")
+                + (f"; scan batches: {sr} resident, {sg} generated"
+                   if sr or sg else ""))
         sp = getattr(counters, "spilled_bytes", 0)
         aq = getattr(counters, "admission_queued", 0)
         if sp or aq:
@@ -216,7 +224,8 @@ def format_plan(node: P.PlanNode, stats: dict = None, counters=None,
         sites = getattr(counters, "sites", None) or {}
         for key in sorted(sites, key=lambda k: (-sites[k]["dispatches"],
                                                 -sites[k]["bytes"], k)):
-            lines.append(f"    site {key}: " + _boundary_str(sites[key]))
+            lines.append(f"    site {key}: " + _boundary_str(sites[key])
+                         + _not_resident_str(sites[key]))
     return "\n".join(lines)
 
 
@@ -291,6 +300,15 @@ def _boundary_str(b: dict) -> str:
     return (f"{b.get('dispatches', 0)} dispatches, "
             f"{b.get('transfers', 0)} transfers, "
             f"{b.get('bytes', 0)} bytes")
+
+
+def _not_resident_str(site: dict) -> str:
+    """Why a scan site's entry is not in the page cache after a miss (PR 46:
+    tracing.record_page_cache's ``over_cap`` and ``store_failed``)."""
+    over, failed = (site.get("page_cache_" + k, 0)
+                    for k in ("over_cap", "store_failed"))
+    return ((f", {over} scans over the entry cap (streamed)" if over else "")
+            + (f", {failed} entries the pool refused" if failed else ""))
 
 
 def _schema_str(node: P.PlanNode) -> str:
